@@ -91,7 +91,8 @@ class Config:
     # reference's execution model; env override RAY_TPU_ISOLATION).
     isolation: str = "thread"
     # JAX platform forced into process-isolated workers ("" = inherit the
-    # driver's environment, including any TPU plugin registration).
+    # driver's environment). "cpu" because a chip belongs to one process —
+    # the driver; a worker granted TPU chips under it fails its task.
     worker_jax_platform: str = "cpu"
     # Worker pool
     prestart_workers: bool = True

@@ -1,37 +1,20 @@
-"""Version shims for jax APIs that moved between releases.
-
-`shard_map` graduated from `jax.experimental.shard_map` (where the
-replication-check kwarg is `check_rep`) to `jax.shard_map` (where it is
-`check_vma`). Callers use the new-style name and kwarg; this shim maps both
-onto whatever the installed jax provides.
+"""One spelling of `shard_map` and `axis_size` for the package, over the
+installed jax (0.9): `jax.shard_map` with the replication check spelled
+`check_vma` (None leaves jax's default), and `jax.lax.axis_size`.
 """
 
 from __future__ import annotations
 
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
+import jax
 
 
 def axis_size(axis_name) -> int:
-    """`jax.lax.axis_size` appeared in newer jax; `psum(1, axis)` is the
-    classic spelling (constant-folded to the mapped axis size, no actual
-    collective)."""
-    import jax
-
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
     if check_vma is not None:
-        kwargs[_CHECK_KW] = check_vma
-    return _shard_map(
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
     )

@@ -599,6 +599,26 @@ class Worker:
         CONTEXT.put_counter = 0
         tracing.activate_task(spec)
 
+    @staticmethod
+    def _check_tpu_grant(body: dict) -> None:
+        """A task or actor granted TPU chips must not run in a worker that
+        was started on the CPU jax platform: it would compute on the host
+        without a word."""
+        from ray_tpu._private.jax_setup import cpu_requested
+
+        chips = body.get("grant", {}).get("TPU", 0)
+        if chips and cpu_requested():
+            raise RuntimeError(
+                f"{body['name']} was granted {chips:g} TPU chip(s) but this "
+                "worker process was started with JAX_PLATFORMS=cpu: a chip "
+                "belongs to one process, and process-isolated workers leave "
+                "it to the driver (worker_jax_platform defaults to cpu, and "
+                "a driver that has opened the chip keeps it). Run the task "
+                "thread-isolated in the driver, or set "
+                '_system_config={"worker_jax_platform": ""} from a driver '
+                "that never touches JAX."
+            )
+
     def _resolve(self, body: dict) -> tuple[tuple, dict]:
         def materialize(value):
             if isinstance(value, wire.WireRef):
@@ -734,6 +754,7 @@ class Worker:
         spec.compute_return_ids()
         self._set_context(body, spec)
         try:
+            self._check_tpu_grant(body)
             func = cloudpickle.loads(body["func"])
             spec.func = func
             args, kwargs = self._resolve(body)
@@ -764,6 +785,7 @@ class Worker:
         self._set_context(body, spec)
         self.actor_creation = body
         try:
+            self._check_tpu_grant(body)
             cls = cloudpickle.loads(body["func"])
             args, kwargs = self._resolve(body)
             with _activate_runtime_env(spec):

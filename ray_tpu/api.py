@@ -10,6 +10,7 @@ from typing import Any, Iterable, Optional, Sequence, Union
 
 from ray_tpu._private import runtime as runtime_mod
 from ray_tpu._private.engine import CONTEXT
+from ray_tpu._private.jax_setup import cpu_requested
 from ray_tpu._private.object_ref import ObjectRef
 from ray_tpu._private.runtime import Runtime, get_runtime
 from ray_tpu.actor import ActorClass, ActorHandle
@@ -21,15 +22,16 @@ def _detect_num_tpu_chips() -> int:
 
     Mirrors the accelerator-detection idea of the reference's resource probe
     (the reference counts GPUs for the `GPU` resource); TPU chips appear as
-    /dev/accel* or /dev/vfio devices on TPU VMs. Explicit `num_tpus` or the
-    RAY_TPU_CHIPS env var always wins.
+    /dev/accel* or, behind VFIO (the v5e machines), as numbered groups
+    /dev/vfio/<n>. Explicit `num_tpus` or the RAY_TPU_CHIPS env var always
+    wins.
     """
     env = os.environ.get("RAY_TPU_CHIPS")
     if env is not None:
         return int(env)
-    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+    if cpu_requested():
         return 0
-    chips = len(glob.glob("/dev/accel*"))
+    chips = len(glob.glob("/dev/accel*")) or len(glob.glob("/dev/vfio/[0-9]*"))
     if chips:
         return chips
     # jax already imported and initialized? use it (cheap, no side effects).

@@ -2627,6 +2627,14 @@ class LLMServer:
                 },
             }
 
+    def device_report(self) -> dict:
+        """Bytes of weights and KV pools per device, and the compiled
+        decode program's memory, kernels and collectives
+        (GPTRunner.device_report). Holds the engine lock while the
+        program compiles: a diagnostic, not a scrape."""
+        with self._lock:
+            return self._engine.runner.device_report()
+
     def reset_prefix_cache(self) -> None:
         """Drop all cached-but-unreferenced KV blocks (e.g. after swapping
         the served params, whose cached activations would be stale)."""

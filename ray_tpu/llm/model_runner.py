@@ -41,6 +41,7 @@ Serving hot-path knobs (EngineConfig):
 
 from __future__ import annotations
 
+import re
 import threading
 from typing import List, Optional, Sequence, Tuple
 
@@ -48,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu._private.jax_setup import ensure_compile_cache, host_cpu_device
 from ray_tpu.llm.cache import kv_pool_bytes_sharded
 from ray_tpu.llm.config import EngineConfig
 from ray_tpu.models.gpt import GPT, GPTConfig, collect_kv_caches
@@ -411,6 +413,7 @@ class GPTRunner:
         self.tensor_parallel_size = engine_config.tensor_parallel_size
         validate_tp_heads(model_config.num_heads, self.tensor_parallel_size)
 
+        ensure_compile_cache()
         # Resolved once: the jitted programs below bake the choice in.
         self.attn_impl = resolve_paged_impl(engine_config.attn_impl)
         self.kv_cache_dtype = {
@@ -449,7 +452,8 @@ class GPTRunner:
                 # llm_shard_params below then device_puts each leaf
                 # straight from host memory into its Megatron placement,
                 # the same host->shards path a numpy checkpoint takes.
-                with jax.default_device(jax.local_devices(backend="cpu")[0]):
+                host = host_cpu_device("seed-initializing tensor-parallel weights")
+                with jax.default_device(host):
                     params = self.model.init(jax.random.PRNGKey(seed), probe)
             else:
                 params = self.model.init(jax.random.PRNGKey(seed), probe)
@@ -570,6 +574,55 @@ class GPTRunner:
             np.dtype(KV_SCALE_DTYPE).itemsize if self.quantized else None,
             tensor_parallel_size=self.tensor_parallel_size,
         )
+
+    def device_report(self) -> dict:
+        """Where the weights and the KV pools live, and what one decode
+        step costs the device, read from the arrays and the compiled
+        program themselves: bytes per device (`addressable_shards`, so a
+        replicated leaf counts on every chip holding it), the decode
+        program's `memory_analysis()` (per device under tensor
+        parallelism), and the kernels and collectives in its text.
+        Compiles the decode program — a persistent-cache hit after warmup."""
+
+        def bytes_by_device(arrays):
+            out: dict = {}
+            for array in arrays:
+                for shard in array.addressable_shards:
+                    key = f"{shard.device.platform}:{shard.device.id}"
+                    out[key] = out.get(key, 0) + int(shard.data.nbytes)
+            return out
+
+        ecfg = self.engine_config
+        slots, nb = ecfg.max_decode_slots, ecfg.max_blocks_per_seq
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        compiled = self._decode_fn.lower(
+            self.params, *self._pools, i32(slots), i32(slots),
+            i32(slots, nb), i32(slots),
+        ).compile()
+        memory = compiled.memory_analysis()
+        text = compiled.as_text()
+        collectives = (
+            "all-reduce", "all-gather", "reduce-scatter",
+            "collective-permute", "all-to-all",
+        )
+        return {
+            "param_bytes_by_device": bytes_by_device(
+                jax.tree_util.tree_leaves(self.params)
+            ),
+            "pool_bytes_by_device": bytes_by_device(
+                p for p in self._pools if p is not None
+            ),
+            "decode_argument_bytes": int(memory.argument_size_in_bytes),
+            "decode_temp_bytes": int(memory.temp_size_in_bytes),
+            "decode_kernels": text.count('custom_call_target="tpu_custom_call"'),
+            "decode_collectives": {
+                op: len(re.findall(rf" {op}(?:-start)?\(", text))
+                for op in collectives
+            },
+        }
 
     # ---------------- prefill ----------------
 

@@ -45,19 +45,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Renamed from TPUCompilerParams in newer pallas; alias locally rather than
-# patching the third-party module.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-if _CompilerParams is None:  # pallas too old for either spelling
-
-    def _CompilerParams(*args, **kwargs):
-        raise RuntimeError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-            "TPUCompilerParams; upgrade jax to use the flash attention kernels"
-        )
-
 from ray_tpu.ops.attention import NEG_INF, mha_reference
 
 _LANES = 128  # TPU lane width: min trailing dim for scratch tiles
@@ -167,7 +154,7 @@ def _flash_fwd_pallas(
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -352,7 +339,7 @@ def _flash_bwd_fused_pallas(q, k, v, o, do, lse, sm_scale, causal, interpret):
             jax.ShapeDtypeStruct((bh, s_len, d), k.dtype),
             jax.ShapeDtypeStruct((bh, s_len, d), v.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
@@ -389,7 +376,7 @@ def _flash_bwd_pallas(
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -420,7 +407,7 @@ def _flash_bwd_pallas(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -565,7 +552,7 @@ def _packed_fwd(qkv, heads, sm_scale, causal):
                    pl.BlockSpec((1, heads, s_len), full)],
         out_shape=[jax.ShapeDtypeStruct((b, s_len, embed), qkv.dtype),
                    jax.ShapeDtypeStruct((b, heads, s_len), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
@@ -593,7 +580,7 @@ def _packed_bwd(heads, sm_scale, causal, residuals, do):
                   pl.BlockSpec((1, heads, s_len), full)],
         out_specs=pl.BlockSpec((1, s_len, three_e), full),
         out_shape=jax.ShapeDtypeStruct((b, s_len, three_e), qkv.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),

@@ -51,9 +51,14 @@ from ray_tpu.ops.attention import (
     validate_kv_scales,
     validate_tp_heads,
 )
-from ray_tpu.ops.flash_attention import _CompilerParams, _on_cpu
+from ray_tpu.ops.flash_attention import _on_cpu
 
 _LANES = 128  # TPU lane width: min trailing dim for scratch tiles
+# VMEM one q tile's blocks may take. The compiler's default scoped limit on
+# v5e is 16 MiB; the rest is left to the cache blocks and the kernel's own
+# temporaries (float32 at "highest" matmul precision took 4.4 MiB of them
+# at 20 heads, measured on the chip).
+_Q_TILE_VMEM_BYTES = 10 * 1024 * 1024
 
 # Storage dtype for the KV-cache scale tensors. bf16 keeps the scale
 # overhead at 2 bytes per (token, head) — f32 scales at block_size=8 would
@@ -86,7 +91,7 @@ def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 def _online_update(s, h, m_scr, l_scr, acc_scr, p_scale, v_block, out_dtype):
     """One streaming-softmax step for head `h`: fold the score block `s`
-    ([S, block]) and its value rows into the running (m, l, acc) scratch.
+    ([tq, block]) and its value rows into the running (m, l, acc) scratch.
     `p_scale` optionally rescales the softmax weights columnwise (int8 V
     dequant folded into P instead of into a [block, D] dequant pass)."""
     m_prev = m_scr[h][:, 0:1]
@@ -110,19 +115,24 @@ def _paged_kernel(
     tables_ref, lens_ref,
     # inputs
     q_ref, k_ref, v_ref, nk_ref, nv_ref, *rest,
-    heads: int, bs: int, nb: int, quantized: bool,
+    heads: int, bs: int, nb: int, tq: int, quantized: bool,
 ):
-    """Grid (B, nb + 1). Steps j < nb consume cache block table[b, j]
-    (skipped past context_lens[b]); step j == nb folds the new tokens in
-    causally and finalizes. Running max / sum / accumulator live in VMEM
-    scratch across the sequential kv dimension."""
+    """Grid (B, nq, nb + nq): one q tile of `tq` fed tokens per (b, qi).
+    Steps j < nb consume cache block table[b, j] (skipped past
+    context_lens[b]); steps j >= nb fold new-token tile j - nb in causally
+    (skipped above the diagonal); the last step normalizes. Running max /
+    sum / accumulator live in VMEM scratch across the sequential kv
+    dimension. q / new-token K/V / out blocks are heads-leading
+    [1, H, tq, D]: Mosaic tiles the last two dims, so a per-head [tq, D]
+    view must not have the head dim between them."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
         ks_ref = vs_ref = None
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
     compute_dtype = q_ref.dtype
 
     @pl.when(j == 0)
@@ -140,17 +150,17 @@ def _paged_kernel(
     @pl.when((j < nb) & (j * bs < ctx))
     def _cache_block():
         for h in range(heads):
-            q = q_ref[0, :, h, :]  # [S, D], prescaled by sm_scale
+            q = q_ref[0, h]  # [tq, D], prescaled by sm_scale
             k = k_ref[0, :, h, :]  # [bs, D] (int8 when quantized)
             s = jax.lax.dot_general(
                 q, k.astype(compute_dtype), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [S, bs]
+            )  # [tq, bs]
             p_scale = None
             if quantized:
                 # Dequant folded into the score/weight matrices: K's
                 # per-token scale multiplies score columns, V's rescales
-                # the softmax weights — both [S, bs] ops, never [bs, D].
+                # the softmax weights — both [tq, bs] ops, never [bs, D].
                 s = s * ks_ref[0, :, h].astype(jnp.float32)[None, :]
                 p_scale = vs_ref[0, :, h].astype(jnp.float32)[None, :]
             t_ids = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -160,30 +170,49 @@ def _paged_kernel(
                 v_ref[0, :, h, :].astype(compute_dtype), compute_dtype,
             )
 
-    @pl.when(j == nb)
-    def _new_tokens_and_finalize():
+    t = j - nb  # new-token tile this step would fold in
+
+    @pl.when((t >= 0) & (t <= qi))
+    def _new_tokens():
         for h in range(heads):
-            q = q_ref[0, :, h, :]   # [S, D]
-            nk = nk_ref[0, :, h, :]  # [S, D] — new tokens, never quantized
+            q = q_ref[0, h]    # [tq, D]
+            nk = nk_ref[0, h]  # [tq, D] — new tokens, never quantized
             s = jax.lax.dot_general(
                 q, nk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [S, S]
-            qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            ki = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(qi >= ki, s, NEG_INF)
+            )  # [tq, tq]
+            rows = qi * tq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            cols = t * tq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(rows >= cols, s, NEG_INF)
             _online_update(
-                s, h, m_scr, l_scr, acc_scr, None, nv_ref[0, :, h, :],
+                s, h, m_scr, l_scr, acc_scr, None, nv_ref[0, h],
                 compute_dtype,
             )
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finalize():
+        for h in range(heads):
             l = l_scr[h][:, 0:1]
             safe = jnp.where(l == 0.0, 1.0, l)
             # Fully-masked rows (context_len 0 and no valid new token)
             # normalize to exact zeros, not garbage — finalize_partial's
             # l == 0 hygiene.
-            o_ref[0, :, h, :] = jnp.where(
+            o_ref[0, h] = jnp.where(
                 l == 0.0, 0.0, acc_scr[h] / safe
             ).astype(o_ref.dtype)
+
+
+def _q_tile(s_len: int, heads: int, head_dim: int, itemsize: int) -> int:
+    """Fed tokens per q tile: the largest tile whose blocks fit
+    _Q_TILE_VMEM_BYTES — q, new K, new V and out double-buffered in the
+    compute dtype plus the float32 running max, sum and accumulator, every
+    [tile, D] slab padded to whole lanes."""
+    lanes = -(-head_dim // _LANES) * _LANES
+    row_bytes = heads * lanes * (4 * 2 * itemsize + 3 * 4)
+    tile = next(
+        (t for t in (128, 64, 32) if t * row_bytes <= _Q_TILE_VMEM_BYTES), 16
+    )
+    return min(s_len, tile)
 
 
 def resolve_paged_impl(impl: str) -> str:
@@ -250,32 +279,50 @@ def paged_flash_attention(
     # epilogue by XLA): no per-score-element scale pass inside.
     q = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
 
-    def q_map(bi, j, tables_ref, lens_ref):
-        return (bi, 0, 0, 0)
+    # The fed tokens are tiled over S so VMEM holds one [H, tq, D] tile of
+    # q / new K / new V / out and its statistics, whatever the bucket. A
+    # length that is not a whole number of tiles is zero-padded: padded key
+    # columns sit above every real row's diagonal, padded q rows are cut.
+    tq = _q_tile(s_len, h, d, q.dtype.itemsize)
+    nq = -(-s_len // tq)
+    pad = nq * tq - s_len
 
-    def kv_map(bi, j, tables_ref, lens_ref):
+    def heads_leading(x):  # [B, S, H, D] -> [B, H, nq * tq, D]
+        x = x.transpose(0, 2, 1, 3)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
+
+    def q_map(bi, qi, j, tables_ref, lens_ref):
+        return (bi, 0, qi, 0)
+
+    def new_map(bi, qi, j, tables_ref, lens_ref):
+        # New-token tile j - nb, clamped into [0, qi]: the cache walk and
+        # the skipped above-diagonal steps re-name a tile that is already
+        # resident, so they copy nothing.
+        return (bi, 0, jnp.clip(j - nb, 0, qi), 0)
+
+    def block_id(bi, j, tables_ref):
         # Walk the block table: grid step j pipelines cache block
-        # table[b, j] into VMEM. The new-token step (j == nb) and padded
+        # table[b, j] into VMEM. The new-token steps (j >= nb) and padded
         # steps read the null block — copied but never unmasked.
-        return (
-            jnp.where(j < nb, tables_ref[bi, jnp.minimum(j, nb - 1)], 0),
-            0, 0, 0,
-        )
+        return jnp.where(j < nb, tables_ref[bi, jnp.minimum(j, nb - 1)], 0)
 
-    def scale_map(bi, j, tables_ref, lens_ref):
-        return (
-            jnp.where(j < nb, tables_ref[bi, jnp.minimum(j, nb - 1)], 0),
-            0, 0,
-        )
+    def kv_map(bi, qi, j, tables_ref, lens_ref):
+        return (block_id(bi, j, tables_ref), 0, 0, 0)
+
+    def scale_map(bi, qi, j, tables_ref, lens_ref):
+        return (block_id(bi, j, tables_ref), 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, s_len, h, d), q_map),
+        pl.BlockSpec((1, h, tq, d), q_map),
         pl.BlockSpec((1, bs, h, d), kv_map),
         pl.BlockSpec((1, bs, h, d), kv_map),
-        pl.BlockSpec((1, s_len, h, d), q_map),
-        pl.BlockSpec((1, s_len, h, d), q_map),
+        pl.BlockSpec((1, h, tq, d), new_map),
+        pl.BlockSpec((1, h, tq, d), new_map),
     ]
-    operands = [q, k_cache, v_cache, new_k, new_v]
+    operands = [
+        heads_leading(q), k_cache, v_cache,
+        heads_leading(new_k), heads_leading(new_v),
+    ]
     if quantized:
         in_specs += [
             pl.BlockSpec((1, bs, h), scale_map),
@@ -284,29 +331,30 @@ def paged_flash_attention(
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, nb + 1),
+        grid=(b, nq, nb + nq),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, s_len, h, d), q_map),
+        out_specs=pl.BlockSpec((1, h, tq, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((h, s_len, _LANES), jnp.float32),
-            pltpu.VMEM((h, s_len, _LANES), jnp.float32),
-            pltpu.VMEM((h, s_len, d), jnp.float32),
+            pltpu.VMEM((h, tq, _LANES), jnp.float32),
+            pltpu.VMEM((h, tq, _LANES), jnp.float32),
+            pltpu.VMEM((h, tq, d), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _paged_kernel, heads=h, bs=bs, nb=nb, quantized=quantized
+        _paged_kernel, heads=h, bs=bs, nb=nb, tq=tq, quantized=quantized
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        # Batch parallel; the block-table walk is sequential (online
-        # softmax state lives in scratch across kv steps).
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+        out_shape=jax.ShapeDtypeStruct((b, h, nq * tq, d), q.dtype),
+        # Batch and q tiles parallel; the block-table walk is sequential
+        # (online softmax state lives in scratch across kv steps).
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
     )(block_tables, context_lens, *operands)
+    return out[:, :, :s_len].transpose(0, 2, 1, 3)
 
 
 def paged_attention_impl(

@@ -20,6 +20,7 @@ import jax
 import numpy as np
 
 import ray_tpu
+from ray_tpu._private.jax_setup import host_cpu_device
 from ray_tpu.rllib.core.rl_module import RLModuleSpec
 from ray_tpu.rllib.env.env import MultiAgentEnv, make_env
 from ray_tpu.rllib.env.spaces import Box
@@ -74,17 +75,10 @@ class MultiAgentEnvRunner:
             )
         self.module = spec.build()
         device_kind = getattr(config, "sample_device", "cpu") or "cpu"
-        try:
+        if device_kind == "cpu":
+            self._device = host_cpu_device("env-runner rollout inference")
+        else:
             self._device = jax.local_devices(backend=device_kind)[0]
-        except RuntimeError:
-            import warnings
-
-            warnings.warn(
-                f"env-runner sample device {device_kind!r} unavailable; "
-                "falling back to the default device",
-                RuntimeWarning,
-            )
-            self._device = None
         self.module.params = jax.device_put(self.module.params, self._device)
         self._explore_fn = jax.jit(
             self.module.forward_exploration, device=self._device

@@ -70,9 +70,11 @@ def _form_mesh(context, config: JaxBackendConfig, num_workers: int):
     """
     import jax
 
+    from ray_tpu._private.jax_setup import ensure_compile_cache
     from ray_tpu.parallel import MeshSpec, auto_mesh
     from ray_tpu.util import collective
 
+    ensure_compile_cache()
     if config.multihost and num_workers > 1:
         from ray_tpu.parallel.mesh import initialize_multi_host
 

@@ -179,6 +179,32 @@ def test_paged_flash_partial_prefill_matches_reference():
             )
 
 
+@pytest.mark.parametrize("quantized", [False, True])
+def test_paged_flash_q_tiles_match_reference(monkeypatch, quantized):
+    """More fed tokens than one q tile holds (the kernel tiles S so any
+    bucket fits VMEM): with no VMEM to spare the tile is 16 tokens, so 40
+    tokens are three tiles, the last zero-padded. Every row still attends
+    its cached prefix and the new tokens below its diagonal — across tile
+    boundaries."""
+    from ray_tpu.ops import paged_flash
+
+    monkeypatch.setattr(paged_flash, "_Q_TILE_VMEM_BYTES", 0)
+    q, kc, vc, tables, nk, nv = _paged_case(11, b=3, s=40)
+    lens = jnp.asarray([9, 0, 16], jnp.int32)
+    scales = {}
+    if quantized:
+        kc, ks = quantize_kv(kc)
+        vc, vs = quantize_kv(vc)
+        scales = {"k_scale": ks, "v_scale": vs}
+    want = paged_attention(
+        q, kc, vc, tables, lens, new_k=nk, new_v=nv, **scales
+    )
+    got = paged_flash_attention(
+        q, kc, vc, tables, lens, new_k=nk, new_v=nv, **scales
+    )
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
 def test_paged_flash_null_padded_table_ignored():
     """Rows whose table is padded with the null block past their real
     blocks must not read it: mutating block 0 cannot change the output."""

@@ -20,7 +20,9 @@ from ray_tpu.exceptions import ActorDiedError, WorkerCrashedError
 
 @pytest.fixture(scope="module")
 def proc_runtime():
-    runtime = ray_tpu.init(num_cpus=8, _system_config={"isolation": "process"})
+    runtime = ray_tpu.init(
+        num_cpus=8, num_tpus=1, _system_config={"isolation": "process"}
+    )
     yield runtime
     ray_tpu.shutdown()
 
@@ -235,3 +237,34 @@ def test_unpicklable_argument_fails_cleanly(proc_runtime):
 
     # The scheduler survives the serialization failure.
     assert ray_tpu.get(takes.remote(5)) == 5
+
+
+@pytest.mark.parametrize("kind", ["task", "actor"])
+def test_tpu_grant_on_cpu_worker_is_an_error(proc_runtime, kind):
+    """Process workers start on the CPU jax platform (the driver owns the
+    chip). Work that was granted TPU chips must fail there with the reason,
+    not compute on the host without a word."""
+
+    def platform():
+        return os.environ.get("JAX_PLATFORMS")
+
+    if kind == "task":
+        submit = ray_tpu.remote(num_tpus=1)(platform).remote
+    else:
+
+        @ray_tpu.remote(num_tpus=1)
+        class Engine:
+            def platform(self):
+                return platform()
+
+        engine = Engine.remote()
+        submit = engine.platform.remote
+        # A call that races the failing constructor only learns that the
+        # actor is gone; every later one is told why.
+        with pytest.raises(Exception):
+            ray_tpu.get(submit(), timeout=60)
+    with pytest.raises(Exception, match="granted 1 TPU chip"):
+        ray_tpu.get(submit(), timeout=60)
+
+    # Without a TPU grant the same worker pool runs, on the CPU platform.
+    assert ray_tpu.get(ray_tpu.remote(platform).remote()) == "cpu"
