@@ -1,0 +1,105 @@
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+import optax
+
+from lib.reference import (
+    ServingReference, adam_momentum, gpt2_forward, relative_distance,
+    training_reference_step,
+)
+from ray_tpu.models.gpt import GPT, GPTConfig, cross_entropy_loss
+
+CFG = GPTConfig(
+    vocab_size=512, num_layers=2, num_heads=4, embed_dim=64, max_seq_len=128,
+    dtype=jnp.float32, attention_impl="reference",
+)
+
+
+def setup():
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 40), 0, 512)
+    return tokens, GPT(CFG).init(jax.random.PRNGKey(0), tokens)
+
+
+def test_plain_forward_is_the_programs_model():
+    tokens, params = setup()
+    ours = gpt2_forward(params, tokens, CFG.num_layers, CFG.num_heads)
+    theirs = GPT(CFG).apply(params, tokens)
+    assert float(jnp.abs(ours - theirs).max()) < 1e-5
+
+
+def _program_steps(params, batches, tx, spoil=lambda grads: grads):
+    """The timed loop's step, on the program's model."""
+    state = tx.init(params)
+    losses = []
+    for tokens in batches:
+        def loss_fn(p):
+            logits = GPT(CFG).apply(p, tokens)
+            return cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
+
+        loss, grads = jax.value_and_grad(loss_fn)(params)
+        updates, state = tx.update(spoil(grads), state, params)
+        params = optax.apply_updates(params, updates)
+        losses.append(float(loss))
+    return losses, adam_momentum(state)
+
+
+def test_reference_steps_agree_with_the_programs_and_tell_faults_apart():
+    params = nn.meta.unbox(setup()[1])
+    batches = [
+        jax.random.randint(jax.random.PRNGKey(k), (4, 40), 0, 512) for k in (2, 3, 4)
+    ]
+    tx = optax.adamw(3e-4)
+    weights, state = jax.tree_util.tree_map(jnp.copy, params), tx.init(params)
+    take = training_reference_step(CFG, tx, rows=2)
+    losses = []
+    for tokens in batches:
+        weights, state, loss = take(weights, state, tokens)
+        losses.append(float(loss))
+    reference = adam_momentum(state)
+
+    theirs, momentum = _program_steps(params, batches, tx)
+    assert max(abs(a - b) for a, b in zip(losses, theirs)) < 1e-5
+    same = relative_distance(momentum, reference)
+    assert same["all"] < 1e-4 and same["worst_matrix"] < 1e-4
+
+    # Other weights: the first loss barely moves, the gradients do.
+    other = nn.meta.unbox(GPT(CFG).init(jax.random.PRNGKey(9), batches[0]))
+    theirs, momentum = _program_steps(other, batches, tx)
+    assert abs(theirs[0] - losses[0]) < 0.05
+    assert relative_distance(momentum, reference)["all"] > 1.0
+
+    # A key gradient lost in the backward pass: small in the whole, the
+    # whole of its own entry.
+    def drop_keys(grads):
+        grads = jax.tree_util.tree_map(lambda g: g, grads)
+        for name, block in grads["params"].items():
+            if name.startswith("h_"):
+                kernel = block["attn_qkv"]["kernel"]
+                third = kernel.shape[-1] // 3
+                block["attn_qkv"]["kernel"] = kernel.at[:, third : 2 * third].set(0.0)
+        return grads
+
+    _, momentum = _program_steps(params, batches, tx, spoil=drop_keys)
+    spoiled = relative_distance(momentum, reference)
+    assert spoiled["all"] < 0.1
+    assert spoiled["attn_k/kernel"] > 0.9 and spoiled["worst_matrix"] > 0.9
+
+
+def test_judge_accepts_the_greedy_answer_and_refuses_another():
+    tokens, params = setup()
+    prompt = [int(t) for t in tokens[0, :12]]
+    answer = []
+    for _ in range(5):
+        fed = jnp.asarray([prompt + answer])
+        answer.append(int(gpt2_forward(params, fed, 2, 4)[0, -1].argmax()))
+    reference = ServingReference(CFG, params, 64)
+    good = reference.judge(prompt, answer, tolerance=1e-4, noise=True)
+    assert good["ok"] and good["flipped"] == 0 and good["bf16_logit_noise"] == 0.0
+    wrong = list(answer)
+    wrong[2] = (wrong[2] + 1) % 512
+    assert not reference.judge(prompt, wrong, tolerance=1e-4)["ok"]
+    other = GPT(CFG).init(jax.random.PRNGKey(9), tokens)
+    assert not ServingReference(CFG, other, 64).judge(prompt, answer, tolerance=1e-4)["ok"]
+    assert np.isfinite(good["worst_gap"])

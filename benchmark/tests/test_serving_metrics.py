@@ -1,0 +1,47 @@
+import pytest
+
+from lib.serving_metrics import reduce_log
+
+
+def record(rid, due, tokens, status="ok", phase="run", sent_lag=0.001):
+    return {
+        "id": rid, "phase": phase, "due": due, "sent": due + sent_lag,
+        "token_times": tokens, "token_ids": [1] * len(tokens), "status": status,
+        "prompt_tokens": 10, "max_new_tokens": len(tokens),
+    }
+
+
+def log(records, loop="open"):
+    return {"open": 100.0, "close": 110.0, "loop": loop, "records": records}
+
+
+def test_latencies_count_from_the_due_time_and_the_rules_for_failed():
+    records = [
+        record("lead", 99.0, [99.5, 100.5, 101.0]),            # due before the window
+        record("a", 100.0, [100.2, 100.3, 100.5]),             # complete
+        record("b", 101.0, [101.4, 101.5], status="error: boom"),
+        record("c", 105.0, [], status="cancelled"),            # first 4/5, no token: failed
+        record("d", 109.0, [], status="cancelled"),            # last fifth: neither
+        record("e", 108.0, [108.5, 111.0], status="cancelled"),  # first token, cut at close
+        record("p", 90.0, [90.5], phase="prime"),
+    ]
+    out = reduce_log(log(records))
+    assert out["due_in_window"] == 5
+    assert (out["attempted"], out["failed"]) == (3, 2)       # a, b, c
+    assert out["in_flight_at_close"] == 2
+    assert [r["id"] for r in out["complete"]] == ["a"]
+    # a: 200 ms, b and c: the window's length, e: 500 ms.
+    assert sorted([200.0, 10000.0, 10000.0, 500.0])[1] == pytest.approx(500.0)
+    assert out["ttft_samples"] == 4
+    assert out["ttft_p50_ms"] == pytest.approx((500.0 + 10000.0) / 2)
+    # gaps whose later token arrived inside the window, requests of the
+    # lead-in included; e's second token came after the close.
+    assert out["itl_samples"] == 5
+    assert out["completed_tokens_per_s"] == pytest.approx(8 / 10.0)
+    assert out["generator_lag_p99_ms"] == pytest.approx(1.0)
+
+
+def test_a_closed_loops_queue_is_not_a_failure():
+    records = [record("c", 101.0, [], status="cancelled")]
+    assert reduce_log(log(records, loop="closed"))["failed"] == 0
+    assert reduce_log(log(records, loop="open"))["failed"] == 1
