@@ -511,7 +511,7 @@ def bench_serving_async_step():
             s["host_gap_s"] for s in steps if s.get("host_gap_s") is not None
         )
         chained = sum(1 for s in steps if s.get("chained"))
-        dispatches = sum(1 for s in steps if s["dispatch_time"] is not None)
+        dispatches = sum(1 for s in steps if s["batch_size"])
         stats = engine.stats()
         assert stats["inflight_steps"] == 0
         return {

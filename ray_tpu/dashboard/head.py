@@ -156,8 +156,8 @@ function renderFleet(f){
   const el=document.getElementById('fleet');
   const reps=Object.entries(f.replicas||{});
   if(!reps.length){el.textContent='none';return}
-  const cols=['idle_s','prefill_s','fabric_wait_s','host_schedule_s',
-              'device_s','commit_s','other_s','loop_s'];
+  const cols=['idle_s','schedule_s','prepare_s','host_wait_s',
+              'commit_s','other_s','loop_s'];
   const pct=x=>x==null?'—':(100*x).toFixed(1)+'%';
   const rows=reps.map(([name,r])=>{
     if(r.error)return `<tr><td class=mono>${esc(name)}</td>`+

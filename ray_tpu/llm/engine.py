@@ -42,6 +42,8 @@ from ray_tpu.llm.observability import (
     STEP_SECONDS_BOUNDARIES,
     FlightRecorder,
     RequestTrace,
+    StepPhaseClock,
+    compile_clock,
 )
 from ray_tpu.llm.scheduler import (
     FINISH_EOS,
@@ -73,16 +75,19 @@ class _InflightStep:
 
     __slots__ = (
         "seqs", "rids", "tokens_dev", "tokens_host",
-        "dispatch_step", "commit_idx",
+        "dispatch_step", "commit_idx", "clock_seq",
     )
 
-    def __init__(self, seqs, rids, tokens_dev, dispatch_step):
+    def __init__(self, seqs, rids, tokens_dev, dispatch_step, clock_seq=None):
         self.seqs: List[Sequence] = seqs
         self.rids: List[str] = rids
         self.tokens_dev = tokens_dev
         self.tokens_host: Optional[np.ndarray] = None
         self.dispatch_step = dispatch_step
         self.commit_idx = 0
+        # StepPhaseClock's number of this dispatch (None uninstrumented):
+        # its fetch, a step later, tells the clock which program finished.
+        self.clock_seq = clock_seq
 
 
 class LLMEngine:
@@ -98,6 +103,13 @@ class LLMEngine:
     ):
         self.model_config = model_config or GPTConfig()
         self.engine_config = engine_config or EngineConfig()
+        # Set-up's clock (rides `instrument`): JAX's compile events are
+        # counted from here on; warm-up is LLMServer's, which writes
+        # `_warmup_s`.
+        self._compile_clock = (
+            compile_clock() if self.engine_config.instrument else None
+        )
+        self._warmup_s = 0.0
         if self.engine_config.draft_model_config is not None:
             # Fail fast with a message that names the DRAFT model before
             # any runner (and its device pools) is built: the draft mirror
@@ -364,10 +376,11 @@ class LLMEngine:
             Histogram,
             "llm_engine_step_host_gap_seconds",
             "Host time between consecutive decode/verify device "
-            "dispatches: how long the previous step's results had been "
-            "sitting on host before the next program was queued — the "
-            "device's scheduling-induced idle window, and the number "
-            "async_scheduling exists to shrink. A chained async dispatch "
+            "dispatches: how long the previous decode's results had been "
+            "sitting on host before the next DECODE program was queued. "
+            "A prefill chunk dispatched in between keeps the device busy "
+            "and is counted all the same; the device's idle window is "
+            "stats() host_exposed_total_s. A chained async dispatch "
             "issued BEFORE the previous step's results were fetched "
             "records 0.",
             boundaries=HOST_GAP_SECONDS_BOUNDARIES,
@@ -467,26 +480,31 @@ class LLMEngine:
         # commit-time failure is attributed one step late, against the
         # step that dispatched the failing program (failure_step()).
         self._attribution_step: Optional[int] = None
-        # Host-gap apparatus (both loop modes): perf_counter stamp of the
-        # moment the previous decode/verify results became host-readable,
-        # the per-step gap/dispatch/commit fields the flight record
-        # carries, and the cumulative aggregates stats() exposes.
+        # The step loop's one clock (both loop modes, instrument-gated
+        # like the records it feeds): it partitions the wall time into
+        # schedule / prepare / wait / commit / other / between, mirrors the
+        # phases as profiler annotations and samples host_exposed at every
+        # program dispatch. The runner calls the hook where a program's
+        # dispatch call has returned and nothing has been fetched yet:
+        # the boundary between prepare and wait.
+        self._clock = StepPhaseClock()
+        self.runner.on_dispatched = self._clock.dispatched
+        # Host-gap apparatus, on the clock's readings: the moment the
+        # previous decode/verify results became host-readable, the
+        # per-step gap/commit fields the flight record carries, and the
+        # cumulative aggregates stats() exposes. The gap runs to the next
+        # DECODE dispatch, over any prefill chunk in between; what the
+        # device idles is the clock's host_exposed.
         self._last_ready_t: Optional[float] = None
         self._step_gap: Optional[float] = None
-        self._step_dispatch_wall: Optional[float] = None
         self._step_commits: List[dict] = []
-        # Time-ledger stamps (instrument-gated, like the record they ride
-        # in): wall time the decode/verify results became host-readable,
-        # and measured seconds this step spent in prefill programs and in
-        # fabric restore RPCs — the fleet ledger decomposes duration_s
-        # into host-schedule / device / commit / prefill / fabric-wait
-        # from exactly these fields (ray_tpu.observability.ledger).
-        self._step_ready_wall: Optional[float] = None
-        self._step_prefill_s = 0.0
-        self._step_fabric_wait_s = 0.0
         self._host_gap_total = 0.0
         self._host_gap_count = 0
         self._host_gap_last: Optional[float] = None
+        # What the decode dispatches asked the paged kernel to read: a
+        # reader divides the kernel's device time by these.
+        self._decode_dispatches = 0
+        self._decode_context_tokens = 0
         # Preallocated per-step decode/verify input buffers, zero-filled
         # and repopulated each dispatch instead of np.zeros-allocated
         # (the steady decode loop does no numpy allocation at all —
@@ -800,7 +818,15 @@ class LLMEngine:
         finished sequences. A sequence mid-chunk stays `prefilling` — it
         never enters the decode batch, so a chunk failure (or a step
         retry) simply re-plans from its committed num_cached; no requeue
-        is needed to keep the running set consistent."""
+        is needed to keep the running set consistent.
+
+        Instrumented, the step runs on the phase clock: entry opens
+        `schedule`, the return opens `between` (or stops the clock when
+        nothing is live); a step that raises leaves its phase open and
+        the next step's entry closes it. The body stays in this frame:
+        JAX walks the Python stack at every traced operation, so each
+        frame between warm-up and a program costs set-up seconds (PR 24:
+        one frame more read +14 s on a 146 s set-up under Serve)."""
         if self._async:
             return self._step_async()
         ecfg = self.engine_config
@@ -809,18 +835,17 @@ class LLMEngine:
         self._current_rid = None
         maybe_fail("llm.step")
         instrument = self._instrument
-        # Wall clock for record identity ("time" field), perf_counter for
-        # the duration — wall time steps under NTP and would corrupt
-        # duration_s exactly when an operator is staring at the recorder.
+        clock = self._clock if instrument else None
+        if clock is not None:
+            clock.enter_step(self._steps)
+        # Wall clock for record identity ("time" field); the duration and
+        # its phases are the clock's perf_counter readings — wall time
+        # steps under NTP and would corrupt duration_s exactly when an
+        # operator is staring at the recorder.
         t_step = time.time() if instrument else 0.0
-        t_step_p = time.perf_counter() if instrument else 0.0
         bytes_before = self._host_transfer_bytes() if instrument else 0
         self._step_gap = None
-        self._step_dispatch_wall = None
         self._step_commits = []
-        self._step_ready_wall = None
-        self._step_prefill_s = 0.0
-        self._step_fabric_wait_s = 0.0
 
         # Deadline sweep BEFORE admission: a queued request whose deadline
         # passed must never reach schedule_prefills (resource-true expiry).
@@ -857,6 +882,8 @@ class LLMEngine:
             # No decode this step: the next dispatch follows an idle
             # stretch, not host scheduling work — don't count it as gap.
             self._last_ready_t = None
+        if clock is not None:
+            clock.switch("other")
 
         self._steps += 1
         # A stepping engine exports its whole metric family: counters and
@@ -944,22 +971,17 @@ class LLMEngine:
                 "cache_hit_tokens": step_hit_tokens,
                 "preempted": preempted,
                 "queue_depth": len(self.scheduler.waiting),
-                "duration_s": round(time.perf_counter() - t_step_p, 6),
                 "time": t_step,
                 # Dispatch/commit apparatus (sync loop: both halves run
                 # in this step, so commits reference this step's own
-                # dispatch index; host_gap_s is the device idle window
-                # the async loop exists to shrink).
-                "dispatch_time": self._step_dispatch_wall,
+                # dispatch index; host_gap_s runs from the previous
+                # decode's results to this step's decode dispatch, over
+                # any prefill chunk in between).
                 "commits": self._step_commits,
                 "host_gap_s": self._step_gap,
-                # Ledger inputs: wall time the decode/verify results were
-                # host-readable, measured prefill-plan seconds, measured
-                # fabric-restore seconds (observability.ledger decomposes
-                # duration_s into its time columns from these).
-                "ready_time": self._step_ready_wall,
-                "prefill_s": round(self._step_prefill_s, 6),
-                "fabric_wait_s": round(self._step_fabric_wait_s, 6),
+                # duration_s and the measured phase seconds that sum to
+                # it (observability.ledger reads its columns from these).
+                **clock.step_record(),
             }
             if spec_info is not None:
                 # Verify record: which proposer ran, how wide the fed
@@ -969,6 +991,8 @@ class LLMEngine:
             if self._fabric is not None:
                 record["fabric_restored_blocks"] = step_restored
             self.flight_recorder.record_step(record)
+        if clock is not None:
+            clock.exit_step(self.has_work())
         return {
             "num_prefilled": len(plans),
             "num_decoding": len(decoding),
@@ -1011,7 +1035,6 @@ class LLMEngine:
         bs = self.engine_config.block_size
         restored = 0
         hit_blocks = 0
-        t_fabric = time.perf_counter() if self._instrument else 0.0
         for seq in admitted:
             plan = seq.pending_restore
             if not plan:
@@ -1039,10 +1062,6 @@ class LLMEngine:
         if restored:
             self._fabric_restored_total += restored
             self._fabric_restores.inc(restored, tags=self._metric_tags)
-        if self._instrument:
-            # Wall this step spent blocked on fabric store RPCs + block
-            # copy-ins: the ledger's fabric-wait column.
-            self._step_fabric_wait_s = time.perf_counter() - t_fabric
         return restored
 
     def _spill_block(self, block: int, block_hash: int) -> None:
@@ -1076,8 +1095,8 @@ class LLMEngine:
         """One iteration-level decode dispatch: every running sequence
         advances exactly one token through the batched decode program."""
         ecfg = self.engine_config
-        instrument = self._instrument
-        t_decode = time.perf_counter() if instrument else 0.0
+        clock = self._clock if self._instrument else None
+        t_decode = clock.switch("prepare") if clock is not None else 0.0
         # Preallocated input buffers: zero-fill + repopulate, never
         # allocate. Reuse is safe here because runner.decode blocks on
         # the program's results before this step returns.
@@ -1089,20 +1108,22 @@ class LLMEngine:
         positions.fill(0)
         block_tables.fill(0)
         context_lens.fill(0)
+        context_tokens = 0
         for i, seq in enumerate(decoding):
             tokens[i] = seq.last_token
             positions[i] = seq.num_cached
             block_tables[i, : len(seq.block_table)] = seq.block_table
             context_lens[i] = seq.num_cached
+            context_tokens += seq.num_cached
+        self._note_decode_work(len(decoding), context_tokens)
         self._note_dispatch(pipelined=False)
         next_tokens = self.runner.decode(
             tokens, positions, block_tables, context_lens
         )
-        # decode() returned == the program ran and its tokens are on
-        # host: everything until the next dispatch is host-side gap.
-        self._last_ready_t = time.perf_counter()
-        if instrument:
-            self._step_ready_wall = time.time()
+        if clock is not None:
+            # decode() returned == the program ran and its tokens are on
+            # host: everything until the next dispatch is host-side gap.
+            self._last_ready_t = clock.ready()
         for i, seq in enumerate(decoding):
             # Per-sequence section; placed before any mutation so a
             # failure here leaves this sequence (and every later one,
@@ -1126,18 +1147,13 @@ class LLMEngine:
                 "dispatch_step": self._steps,
                 "time": time.time(),
                 "tokens": len(decoding),
-                # Measured commit seconds (results host-readable -> all
-                # emissions done): the ledger's commit column.
-                "commit_s": round(
-                    time.perf_counter() - self._last_ready_t, 6
-                ),
             }
         )
-        if instrument:
+        if clock is not None:
             # One observation per batched decode dispatch, never per
             # token — the whole emission loop rides in it.
             self._h_step.observe(
-                time.perf_counter() - t_decode,
+                clock.switch("other") - t_decode,
                 tags=self._step_tags["decode"],
             )
 
@@ -1157,11 +1173,11 @@ class LLMEngine:
         plain (already-compiled) decode program, which is exactly
         equivalent for one fed token per slot."""
         ecfg = self.engine_config
-        instrument = self._instrument
-        # Clock starts before the proposer: proposal cost (draft-model
+        clock = self._clock if self._instrument else None
+        # Prepare starts before the proposer: proposal cost (draft-model
         # steps, host-side matching) is part of what the verify phase
         # must amortize, so it belongs in the phase=verify histogram.
-        t_verify = time.perf_counter() if instrument else 0.0
+        t_verify = clock.switch("prepare") if clock is not None else 0.0
         k = ecfg.num_speculative_tokens
         proposals = self._spec.propose(decoding, k)
         plans: List[List[int]] = []
@@ -1184,7 +1200,7 @@ class LLMEngine:
             plans.append(props)
             max_fed = max(max_fed, 1 + len(props))
         if max_fed == 1:
-            return None
+            return None  # the caller's plain decode takes over prepare
         s_bucket = ecfg.verify_bucket_for(max_fed)
         # Preallocated per-bucket input buffers (zero-fill + repopulate);
         # reuse is safe — runner.verify blocks on the program's results.
@@ -1206,9 +1222,8 @@ class LLMEngine:
         out = self.runner.verify(
             tokens, block_tables, context_lens, true_lens
         )
-        self._last_ready_t = time.perf_counter()
-        if instrument:
-            self._step_ready_wall = time.time()
+        if clock is not None:
+            self._last_ready_t = clock.ready()
         proposed = accepted = emitted = 0
         for i, (seq, props) in enumerate(zip(decoding, plans)):
             # Per-sequence commit section; nothing mutates before the
@@ -1248,9 +1263,6 @@ class LLMEngine:
                 "dispatch_step": self._steps,
                 "time": time.time(),
                 "tokens": emitted,
-                "commit_s": round(
-                    time.perf_counter() - self._last_ready_t, 6
-                ),
             }
         )
         self._verify_steps += 1
@@ -1265,11 +1277,11 @@ class LLMEngine:
             self._spec_accepted_total / max(self._spec_proposed_total, 1),
             tags=self._metric_tags,
         )
-        if instrument:
+        if clock is not None:
             # One observation per batched verify dispatch (proposer +
             # program + the whole commit loop), never per token.
             self._h_step.observe(
-                time.perf_counter() - t_verify,
+                clock.switch("other") - t_verify,
                 tags=self._step_tags["verify"],
             )
         return {
@@ -1282,14 +1294,26 @@ class LLMEngine:
 
     # ---------------- async (double-buffered) stepping ----------------
 
+    def _note_decode_work(self, batch: int, context_tokens: int) -> None:
+        """What one decode dispatch asks the paged kernel to read: `batch`
+        sequences, `context_tokens` cached positions in all (the sum of
+        context_lens), in every layer."""
+        self._decode_dispatches += 1
+        self._decode_context_tokens += context_tokens
+        if self._instrument:
+            self._clock.describe_decode(batch, context_tokens)
+
     def _note_dispatch(self, pipelined: bool) -> None:
         """Host-gap sample at a decode/verify device dispatch: how long
-        the previous step's results had been host-readable before this
-        program was queued — the device idle window host scheduling
-        opened. A chained async dispatch is issued BEFORE the previous
-        step's results are even fetched, so it records exactly 0 (the
-        gap definition's clamp: the dispatch beat the fetch)."""
-        self._step_dispatch_wall = time.time()
+        the previous decode/verify results had been host-readable before
+        this one was queued. Not the device's idle window: a prefill
+        chunk in between runs on the device and is counted all the same
+        (that window is the clock's host_exposed). A chained async
+        dispatch is issued BEFORE the previous step's results are even
+        fetched, so it records exactly 0 (the gap definition's clamp:
+        the dispatch beat the fetch)."""
+        if not self._instrument:
+            return
         if pipelined:
             gap = 0.0
         else:
@@ -1333,15 +1357,13 @@ class LLMEngine:
         self._current_rid = None
         maybe_fail("llm.step")
         instrument = self._instrument
+        clock = self._clock if instrument else None
+        if clock is not None:
+            clock.enter_step(self._steps)
         t_step = time.time() if instrument else 0.0
-        t_step_p = time.perf_counter() if instrument else 0.0
         bytes_before = self._host_transfer_bytes() if instrument else 0
         self._step_gap = None
-        self._step_dispatch_wall = None
         self._step_commits = []
-        self._step_ready_wall = None
-        self._step_prefill_s = 0.0
-        self._step_fabric_wait_s = 0.0
 
         # Deadline sweep before the chain attempt: an expiry changes the
         # batch composition, so _try_chain refuses and the pipeline
@@ -1400,6 +1422,8 @@ class LLMEngine:
                     dispatched = True
             else:
                 self._last_ready_t = None
+        if clock is not None:
+            clock.switch("other")
 
         self._steps += 1
         family = (
@@ -1493,22 +1517,20 @@ class LLMEngine:
                 "cache_hit_tokens": step_hit_tokens,
                 "preempted": preempted,
                 "queue_depth": len(self.scheduler.waiting),
-                "duration_s": round(time.perf_counter() - t_step_p, 6),
                 "time": t_step,
-                "dispatch_time": self._step_dispatch_wall,
                 "commits": self._step_commits,
                 "host_gap_s": self._step_gap,
-                "ready_time": self._step_ready_wall,
-                "prefill_s": round(self._step_prefill_s, 6),
-                "fabric_wait_s": round(self._step_fabric_wait_s, 6),
                 "chained": chained_seqs is not None,
                 "inflight_depth": len(self._inflight),
+                **clock.step_record(),
             }
             if spec_info is not None:
                 record["speculation"] = spec_info
             if self._fabric is not None:
                 record["fabric_restored_blocks"] = step_restored
             self.flight_recorder.record_step(record)
+        if clock is not None:
+            clock.exit_step(self.has_work())
         return {
             "num_prefilled": len(plans),
             "num_decoding": len(decoding),
@@ -1555,6 +1577,9 @@ class LLMEngine:
         token's value. Unused slots carry whatever the previous program
         sampled — they scatter into the null block exactly like the sync
         path's zero padding."""
+        clock = self._clock if self._instrument else None
+        if clock is not None:
+            clock.switch("prepare")
         self._note_dispatch(pipelined=True)
         positions = self._dec_positions
         block_tables = self._dec_block_tables
@@ -1562,15 +1587,21 @@ class LLMEngine:
         positions.fill(0)
         block_tables.fill(0)
         context_lens.fill(0)
+        context_tokens = 0
         for i, seq in enumerate(rec.seqs):
             positions[i] = seq.num_cached + 1
             block_tables[i, : len(seq.block_table)] = seq.block_table
             context_lens[i] = seq.num_cached + 1
+            context_tokens += seq.num_cached + 1
+        self._note_decode_work(len(rec.seqs), context_tokens)
         tokens_dev = self.runner.decode_async(
             rec.tokens_dev, positions, block_tables, context_lens
         )
         self._inflight.append(
-            _InflightStep(rec.seqs, rec.rids, tokens_dev, self._steps)
+            _InflightStep(
+                rec.seqs, rec.rids, tokens_dev, self._steps,
+                self._close_async_dispatch(),
+            )
         )
 
     def _dispatch_decode_async(self, decoding: List[Sequence]) -> None:
@@ -1578,6 +1609,8 @@ class LLMEngine:
         start / after a flush): inputs build exactly like _run_decode,
         but the runner starts an async device->host copy instead of
         blocking — the commit runs one step later (_commit_head)."""
+        if self._instrument:
+            self._clock.switch("prepare")
         tokens = self._dec_tokens
         positions = self._dec_positions
         block_tables = self._dec_block_tables
@@ -1586,11 +1619,14 @@ class LLMEngine:
         positions.fill(0)
         block_tables.fill(0)
         context_lens.fill(0)
+        context_tokens = 0
         for i, seq in enumerate(decoding):
             tokens[i] = seq.last_token
             positions[i] = seq.num_cached
             block_tables[i, : len(seq.block_table)] = seq.block_table
             context_lens[i] = seq.num_cached
+            context_tokens += seq.num_cached
+        self._note_decode_work(len(decoding), context_tokens)
         self._note_dispatch(pipelined=False)
         tokens_dev = self.runner.decode_async(
             tokens, positions, block_tables, context_lens
@@ -1601,8 +1637,18 @@ class LLMEngine:
                 [s.request.request_id for s in decoding],
                 tokens_dev,
                 self._steps,
+                self._close_async_dispatch(),
             )
         )
+
+    def _close_async_dispatch(self) -> Optional[int]:
+        """After an async decode dispatch nothing is waited for: the step
+        goes on scheduling. Returns the clock's number of the dispatch
+        (None uninstrumented), which its commit hands back a step later."""
+        if not self._instrument:
+            return None
+        self._clock.switch("schedule")
+        return self._clock.dispatches
 
     def _commit_head(self) -> None:
         """Fetch and commit the OLDEST in-flight record — the deferred
@@ -1619,17 +1665,19 @@ class LLMEngine:
         exception against this record's DISPATCH index."""
         rec = self._inflight[0]
         ecfg = self.engine_config
-        instrument = self._instrument
-        t0 = time.perf_counter() if instrument else 0.0
+        clock = self._clock if self._instrument else None
+        fetch = rec.tokens_host is None
+        t0 = 0.0
+        if clock is not None:
+            t0 = clock.switch("wait" if fetch else "commit")
         self._attribution_step = rec.dispatch_step
-        if rec.tokens_host is None:
+        if fetch:
             # Materialize the async copy (usually already done — it has
             # been in flight since dispatch). A failed decode PROGRAM
             # surfaces here, one step after dispatch, attributed above.
             rec.tokens_host = np.asarray(rec.tokens_dev)
-            self._last_ready_t = time.perf_counter()
-            if instrument:
-                self._step_ready_wall = time.time()
+            if clock is not None:
+                self._last_ready_t = clock.ready(rec.clock_seq)
         next_tokens = rec.tokens_host
         committed = 0
         while rec.commit_idx < len(rec.seqs):
@@ -1662,18 +1710,13 @@ class LLMEngine:
                 "dispatch_step": rec.dispatch_step,
                 "time": time.time(),
                 "tokens": committed,
-                "commit_s": (
-                    round(time.perf_counter() - t0, 6)
-                    if instrument
-                    else None
-                ),
             }
         )
-        if instrument:
+        if clock is not None:
             # The async decode series measures the commit half (fetch +
             # emission loop) — the dispatch half is what the chain hides.
             self._h_step.observe(
-                time.perf_counter() - t0, tags=self._step_tags["decode"]
+                clock.switch("schedule") - t0, tags=self._step_tags["decode"]
             )
 
     def _run_prefill_chunks(
@@ -1693,13 +1736,18 @@ class LLMEngine:
         instrumentation, `info_out` collects one record per chunk for the
         flight recorder."""
         instrument = self._instrument
+        clock = self._clock if instrument else None
         hit_tokens = 0
-        t_plan = time.perf_counter() if (instrument and plans) else 0.0
         for seq, take in plans:
             # Per-sequence section: an exception below is attributable to
             # this request (LLMServer._loop fails only it and keeps going).
             rid = seq.request.request_id
             self._current_rid = rid
+            if clock is not None:
+                # Per chunk: prepare (CoW copy, input build, dispatch),
+                # wait (from the runner's hook to its return), commit
+                # (publication, spans, emission).
+                clock.switch("prepare")
             first_chunk = seq.num_chunks == 0
             final = take >= seq.prefill_len - seq.num_cached
             if first_chunk:
@@ -1749,6 +1797,8 @@ class LLMEngine:
                         )
                     ],
                 )
+            if clock is not None:
+                clock.ready()
             self._prefill_tokens += take
             self._prefill_chunk_dispatches += 1
             seq.num_cached = offset + take
@@ -1831,10 +1881,8 @@ class LLMEngine:
                 self._emit(seq)
                 self._maybe_finish(seq)
         self._current_rid = None
-        if instrument and plans:
-            # Whole-plan prefill seconds (programs + publication +
-            # emission): the ledger's prefill column for this step.
-            self._step_prefill_s = time.perf_counter() - t_plan
+        if clock is not None and plans:
+            clock.switch("schedule")
         return hit_tokens
 
     def _emit(self, seq: Sequence) -> None:
@@ -1970,6 +2018,36 @@ class LLMEngine:
                 else None
             ),
             "host_gap_last_s": self._host_gap_last,
+            # The step loop's phase clock: seconds in each phase (they
+            # sum to the wall time from the first instrumented step's
+            # entry on, idle stretches left out), steps that dispatched
+            # a program, and host_exposed, host time during which the
+            # device had nothing queued (what host_gap was taken for).
+            **self._clock.stats(),
+            # What the decode dispatches asked the paged kernel to read
+            # (sum of context_lens, in every layer), and the shape that
+            # turns it into bytes and operations.
+            "decode_dispatches": self._decode_dispatches,
+            "decode_context_tokens": self._decode_context_tokens,
+            "attention_shape": {
+                "num_layers": self.model_config.num_layers,
+                "num_heads": self.model_config.num_heads,
+                "head_dim": self.model_config.head_dim,
+                "kv_itemsize": np.dtype(self.runner.kv_cache_dtype).itemsize,
+            },
+            # Set-up on the same footing: wall seconds warming the
+            # programs and, with `instrument` on, what JAX spent compiling
+            # in this process since the first such engine was built
+            # (CompileClock).
+            "warmup_s": self._warmup_s,
+            **(
+                {
+                    f"jax_{key}": value
+                    for key, value in self._compile_clock.totals().items()
+                }
+                if self._compile_clock is not None
+                else {}
+            ),
             "mean_occupancy": (
                 self._decode_tokens / self._decode_slot_steps
                 if self._decode_slot_steps
@@ -2124,9 +2202,11 @@ class LLMServer:
             self._engine.allocator.on_evict = None
             self._engine.scheduler.fabric_probe = None
             self._engine._async = False
+            t_warmup = time.perf_counter()
             try:
                 self._warmup()
             finally:
+                self._engine._warmup_s = time.perf_counter() - t_warmup
                 self._engine._instrument = instrumented
                 self._engine._spec = spec
                 self._engine._publish_on_fill = publish
@@ -2145,6 +2225,20 @@ class LLMServer:
             target=self._loop, name="llm-engine-loop", daemon=True
         )
         self._thread.start()
+
+    def _round_start(self) -> tuple:
+        clock = self._engine._compile_clock
+        return time.monotonic(), clock and clock.totals()
+
+    def _record_round(self, program: str, bucket: int, start: tuple) -> None:
+        """One warm-up round into the flight record: its wall seconds and,
+        with `instrument` on, what JAX spent of them tracing and lowering
+        and in the compile step."""
+        t0, before = start
+        self._engine.flight_recorder.record_compile(
+            program, bucket, time.monotonic() - t0,
+            before and self._engine._compile_clock.since(before),
+        )
 
     def _warmup(self) -> None:
         ecfg = self._engine.engine_config
@@ -2171,7 +2265,7 @@ class LLMServer:
             # would hit them and take the partial-prefill path, leaving
             # this bucket's full program uncompiled.
             self._engine.allocator.reset_prefix_cache()
-            t0 = time.monotonic()
+            round_start = self._round_start()
             try:
                 self._engine.generate([[0] * n], max_new_tokens=budget)
             except ValueError:
@@ -2182,9 +2276,7 @@ class LLMServer:
             # Cold-compile blame: almost all of this round is XLA
             # compiling the bucket's full-prefill program (plus, on the
             # first round, the decode program).
-            self._engine.flight_recorder.record_compile(
-                "prefill", bucket, time.monotonic() - t0
-            )
+            self._record_round("prefill", bucket, round_start)
         if ecfg.enable_prefix_caching:
             # Also compile every partial-prefill bucket and the
             # copy-on-write block copy, so cache hits never trigger a
@@ -2198,7 +2290,7 @@ class LLMServer:
             for bucket in widths + (0,):
                 alloc.reset_prefix_cache()
                 n = min(bs + bucket, ecfg.max_model_len - 1, buckets[-1])
-                t0 = time.monotonic()
+                round_start = self._round_start()
                 try:
                     self._engine.generate([[0] * bs], max_new_tokens=1)
                     if n > bs:
@@ -2207,10 +2299,10 @@ class LLMServer:
                         self._engine.generate([[0] * bs], max_new_tokens=1)
                 except ValueError:
                     continue
-                self._engine.flight_recorder.record_compile(
+                self._record_round(
                     "cow" if n <= bs else "partial_prefill",
                     0 if n <= bs else bucket,
-                    time.monotonic() - t0,
+                    round_start,
                 )
             alloc.reset_prefix_cache()
         if ecfg.prefill_token_budget is not None:
@@ -2226,12 +2318,10 @@ class LLMServer:
             runner = self._engine.runner
             null_table = [0] * ecfg.max_blocks_per_seq
             for w in widths:
-                t0 = time.monotonic()
+                round_start = self._round_start()
                 runner.prefill([0] * w, [0])
                 runner.prefill_suffix([0] * w, null_table, 0)
-                self._engine.flight_recorder.record_compile(
-                    "chunk_prefill", w, time.monotonic() - t0
-                )
+                self._record_round("chunk_prefill", w, round_start)
 
     def _warmup_verify(self, spec) -> None:
         """Compile every k-token verify bucket program plus whatever the
@@ -2245,21 +2335,17 @@ class LLMServer:
         slots = ecfg.max_decode_slots
         nb = ecfg.max_blocks_per_seq
         for s_bucket in ecfg.verify_buckets():
-            t0 = time.monotonic()
+            round_start = self._round_start()
             runner.verify(
                 np.zeros((slots, s_bucket), np.int32),
                 np.zeros((slots, nb), np.int32),
                 np.zeros((slots,), np.int32),
                 np.full((slots,), s_bucket, np.int32),
             )
-            self._engine.flight_recorder.record_compile(
-                "verify", s_bucket, time.monotonic() - t0
-            )
-        t0 = time.monotonic()
+            self._record_round("verify", s_bucket, round_start)
+        round_start = self._round_start()
         spec.warmup()
-        self._engine.flight_recorder.record_compile(
-            f"proposer:{spec.name}", 0, time.monotonic() - t0
-        )
+        self._record_round(f"proposer:{spec.name}", 0, round_start)
 
     # ---------------- engine loop ----------------
 

@@ -43,7 +43,7 @@ from __future__ import annotations
 
 import re
 import threading
-from typing import List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -406,6 +406,11 @@ class GPTRunner:
             )
         self.model_config = model_config
         self.engine_config = engine_config
+        # Called where a step program's dispatch call has returned and
+        # its results have not been asked for (prefill, suffix, verify,
+        # decode; at the end of decode_async, which fetches nothing): the
+        # engine's phase clock ends `prepare` and begins `wait` there.
+        self.on_dispatched: Optional[Callable[[], None]] = None
         # Intra-replica tensor parallelism: one mesh with a `tp` axis over
         # the first tensor_parallel_size backend devices; None at tp=1 so
         # the single-chip path stays bit-for-bit unchanged (no device_put,
@@ -539,6 +544,10 @@ class GPTRunner:
     def _set_pools(self, pools) -> None:
         self.k_cache, self.v_cache, self.k_scale, self.v_scale = pools
 
+    def _dispatched(self) -> None:
+        if self.on_dispatched is not None:
+            self.on_dispatched()
+
     def _count_transfer(self, arrays_in, out) -> None:
         self.host_bytes_in += sum(int(a.nbytes) for a in arrays_in)
         self.host_bytes_out += int(out.nbytes)
@@ -647,6 +656,7 @@ class GPTRunner:
             jnp.int32(n),
         )
         self._set_pools(pools)
+        self._dispatched()
         self._count_transfer((tokens, blocks), next_token)
         return int(next_token)
 
@@ -675,6 +685,7 @@ class GPTRunner:
             jnp.int32(n),
         )
         self._set_pools(pools)
+        self._dispatched()
         self._count_transfer((tokens, table), next_token)
         return int(next_token)
 
@@ -781,6 +792,7 @@ class GPTRunner:
             jnp.asarray(true_lens, jnp.int32),
         )
         self._set_pools(pools)
+        self._dispatched()
         out = np.asarray(out)
         self._count_transfer(
             (tokens, block_tables, context_lens, true_lens), out
@@ -805,6 +817,7 @@ class GPTRunner:
             jnp.asarray(context_lens, jnp.int32),
         )
         self._set_pools(pools)
+        self._dispatched()
         next_tokens = np.asarray(next_tokens)
         self._count_transfer(
             (tokens, positions, block_tables, context_lens), next_tokens
@@ -853,4 +866,5 @@ class GPTRunner:
         if not chained:
             host_in = (tokens,) + host_in
         self._count_transfer(host_in, next_tokens)
+        self._dispatched()
         return next_tokens

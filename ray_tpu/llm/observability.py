@@ -14,11 +14,27 @@ Two pieces the engine hooks into (gated by EngineConfig.instrument):
 
   * FlightRecorder — a bounded ring of structured per-step records (step
     index, phase, batch size, tokens in/out, buckets, prefix-cache hits,
-    preemptions, duration; with speculative decoding on, verify steps add
-    a "speculation" record — proposer mode, fed bucket, proposed /
-    accepted / emitted counts) plus warmup compile events (cold-compile
-    blame) and step failures from the PR 3 poison-isolation path. Exposed
-    through LLMServer.flight_record() and the dashboard /api/llm panel.
+    preemptions, duration and the measured phase seconds that partition
+    it; with speculative decoding on, verify steps add a "speculation"
+    record — proposer mode, fed bucket, proposed / accepted / emitted
+    counts) plus warmup compile events (per round: wall seconds and how
+    much of them JAX spent tracing and lowering and in the compile
+    step) and step failures from the PR 3
+    poison-isolation path. Exposed through LLMServer.flight_record() and
+    the dashboard /api/llm panel.
+
+  * StepPhaseClock — the one clock of the step loop: every instant between
+    a step's entry and the next step's entry belongs to exactly one of
+    schedule / prepare / wait / commit / other / between, each boundary is
+    one perf_counter reading, and the same boundaries open and close
+    `jax.profiler.TraceAnnotation`s (`llm.step`, `llm.step.<phase>`) so a
+    profiler session shows the host phases beside the device's `XLA Ops`.
+    Beside the partition it keeps `host_exposed`: host time during which
+    the device had no program queued.
+
+  * CompileClock — process-wide totals of JAX's own compile events
+    (tracing, lowering, the compile step, persistent-cache misses), so
+    set-up is read on the same clock as the steps.
 
 The request latency histograms live here too so every engine shares one
 registered metric per name (vLLM reports the same trio — TTFT, time per
@@ -27,10 +43,14 @@ output token, e2e — as the primary serving SLO metrics).
 
 from __future__ import annotations
 
+import threading
 import time
 import uuid
 from collections import deque
-from typing import List, Optional
+from typing import Dict, List, Optional
+
+import jax.monitoring
+from jax.profiler import TraceAnnotation
 
 from ray_tpu.util import tracing
 
@@ -52,12 +72,16 @@ STEP_SECONDS_BOUNDARIES = [
     0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
     0.5, 1.0, 2.5,
 ]
-# Host gap between consecutive decode dispatches: how long the device sat
-# idle waiting on host scheduling/commit work before the next program was
-# queued. This is the number async_scheduling exists to shrink — a chained
-# dispatch issued before the previous step's results were even fetched
-# records 0, so the ladder starts at 10 µs and the first bucket is the
-# "pipelined" bucket.
+# Host gap between consecutive decode/verify dispatches: from the previous
+# decode's results being host-readable to the next DECODE dispatch. It is
+# not the device's idle window: a prefill chunk that runs in between keeps
+# the device busy for its whole program and is counted here all the same
+# (PR 22 read 24.9 ms a step where the device idled 7). The device's idle
+# window as the host sees it is StepPhaseClock's `host_exposed`
+# (stats()["host_exposed_total_s"]), sampled at every program's dispatch.
+# A chained async dispatch issued before the previous step's results were
+# even fetched records 0, so the ladder starts at 10 µs and the first
+# bucket is the "pipelined" bucket.
 HOST_GAP_SECONDS_BOUNDARIES = [
     0.00001, 0.000025, 0.00005, 0.0001, 0.00025, 0.0005, 0.001,
     0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
@@ -207,6 +231,239 @@ class RequestTrace:
         )
 
 
+# Phases of the step loop, in the order a synchronous step passes through
+# them. `between` runs from a step's return to the next step's entry while
+# a request is live (lock, loop-thread hand-off, stream delivery); `other`
+# is what a step does outside the four named phases (gauges, the flight
+# record), so the six sum to the wall time from one entry to the next.
+STEP_PHASES = ("schedule", "prepare", "wait", "commit", "other", "between")
+_ANNOTATED_PHASES = frozenset(("schedule", "prepare", "wait", "commit"))
+
+
+class StepPhaseClock:
+    """Partition of the step loop's wall time, and the device's idle window
+    as the host sees it.
+
+    At every moment exactly one phase is current; `switch` reads
+    `perf_counter` once, charges the time since the previous reading to the
+    phase that was current and opens the next, so the totals sum to the
+    wall time by construction. With no request live the current phase is
+    None and nothing accumulates. Single writer: the thread that steps the
+    engine.
+
+    `host_exposed` is sampled where a program's dispatch call returns: the
+    time since the device last had nothing left to run, as far as the host
+    can know: the newest program it dispatched had become host-readable
+    (programs finish in dispatch order, so that covers every older one). A
+    dispatch made while a program is still out, as a chained async decode
+    is, samples 0. In the synchronous loop the samples add up to the
+    non-wait phases.
+    """
+
+    def __init__(self):
+        self.totals: Dict[str, float] = dict.fromkeys(STEP_PHASES, 0.0)
+        self.dispatch_steps = 0  # steps that dispatched a program
+        self.dispatches = 0
+        self.exposed_total = 0.0
+        self.exposed_samples = 0
+        self._phase: Optional[str] = None
+        self._t = 0.0
+        self._annotation: Optional[TraceAnnotation] = None
+        self._step_annotation: Optional[TraceAnnotation] = None
+        self._entry_t = 0.0
+        self._entry_totals = self.totals
+        self._dispatches_at_entry = 0
+        self._ready_seq = 0  # newest dispatch known to have finished
+        self._idle_since: Optional[float] = None
+
+    def switch(self, phase: Optional[str]) -> float:
+        """Close the current phase and open `phase`; returns the reading."""
+        now = time.perf_counter()
+        if self._phase is not None:
+            self.totals[self._phase] += now - self._t
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+        if phase in _ANNOTATED_PHASES:
+            self._annotation = TraceAnnotation("llm.step." + phase)
+            self._annotation.__enter__()
+        self._phase = phase
+        self._t = now
+        return now
+
+    def enter_step(self, index: int) -> None:
+        if self._step_annotation is not None:
+            # The last step raised: close what it left open. The time
+            # since then stays with the phase it was in.
+            self.exit_step(live=True)
+        self._step_annotation = TraceAnnotation("llm.step", step=index)
+        self._step_annotation.__enter__()
+        self._entry_t = self.switch("schedule")
+        self._entry_totals = dict(self.totals)
+        self._dispatches_at_entry = self.dispatches
+
+    def exit_step(self, live: bool) -> None:
+        self.switch("between" if live else None)
+        if self.dispatches > self._dispatches_at_entry:
+            self.dispatch_steps += 1
+        if not live:
+            # The next dispatch follows an idle stretch, not host work.
+            self._idle_since = None
+        if self._step_annotation is not None:
+            self._step_annotation.__exit__(None, None, None)
+            self._step_annotation = None
+
+    def dispatched(self) -> None:
+        """A program's dispatch call returned (GPTRunner's hook): prepare
+        ends, wait begins and host_exposed takes its sample. The dispatch
+        is number `dispatches` from here on, for `ready`. Outside a step
+        (warm-up drives the runner directly, uninstrumented steps never
+        enter) nothing is on the clock."""
+        if self._step_annotation is None:
+            return
+        now = self.switch("wait")
+        if self._ready_seq < self.dispatches:
+            self.exposed_samples += 1  # queued behind a running program
+        elif self._idle_since is not None:
+            self.exposed_total += now - self._idle_since
+            self.exposed_samples += 1
+        self.dispatches += 1
+
+    def ready(self, seq: Optional[int] = None) -> float:
+        """The results of dispatch `seq` (the newest when None) are on the
+        host: commit begins, and the device is idle from now if nothing
+        newer is out."""
+        now = self.switch("commit")
+        self._ready_seq = max(self._ready_seq, seq or self.dispatches)
+        if self._ready_seq == self.dispatches:
+            self._idle_since = now
+        return now
+
+    def describe_decode(self, batch: int, context_tokens: int) -> None:
+        """What the decode dispatch of this step asks the paged kernel to
+        read, on the step's annotation."""
+        if self._step_annotation is not None:
+            self._step_annotation.set_metadata(
+                batch=batch, context_tokens=context_tokens
+            )
+
+    def step_record(self) -> dict:
+        """Seconds of the current step so far, by phase: the flight
+        record's `duration_s` and `phases`, which sum to it."""
+        now = self.switch(self._phase)
+        return {
+            "duration_s": round(now - self._entry_t, 6),
+            "phases": {
+                phase: round(self.totals[phase] - self._entry_totals[phase], 6)
+                for phase in STEP_PHASES
+                if phase != "between"
+            },
+        }
+
+    def stats(self) -> dict:
+        out = {f"step_{phase}_s": s for phase, s in self.totals.items()}
+        out["dispatch_steps"] = self.dispatch_steps
+        out["host_exposed_total_s"] = self.exposed_total
+        return out
+
+
+class _IntervalUnion:
+    """Seconds covered by the intervals added so far. JAX reports a timed
+    region when it ends, so a traced function arrives after the functions
+    traced inside it and contains them: a plain sum would count the inner
+    ones twice."""
+
+    def __init__(self):
+        self.total = 0.0
+        self._tail: List[tuple] = []  # disjoint, ascending
+
+    def add(self, start: float, end: float) -> None:
+        tail = self._tail
+        while tail and tail[-1][1] > start:
+            known_start, known_end = tail.pop()
+            self.total -= known_end - known_start
+            start, end = min(start, known_start), max(end, known_end)
+        tail.append((start, end))
+        self.total += end - start
+
+
+class CompileClock:
+    """Totals of the compile events JAX itself times, for this process.
+
+    JAX reports every traced function, every lowering and every pass
+    through its compile step (a compilation or a read from the persistent
+    cache) as a time span to the listeners registered with
+    `jax.monitoring`, and every program it had to compile and write to
+    that cache as a plain event. Spans nest: a traced function holds the
+    functions traced inside it and the small programs compiled for the
+    concrete values it computes on the way. So `compile_step_s` is the
+    time covered by compile steps and `trace_lower_s` the time covered by
+    tracing and lowering outside any compile step: the two never count a
+    second twice and sum to at most the wall time. Listeners are
+    process-wide and cannot be taken off again, so there is one clock a
+    process (`compile_clock()`), registered when the first instrumented
+    engine is built; its totals count from then, and from then on every
+    JAX event in the process costs one set lookup (a lock only for the
+    events counted here). Events arrive on whichever thread compiles.
+    """
+
+    COMPILE_STEP = "/jax/core/compile/backend_compile_duration"
+    SPANS = frozenset((
+        "/jax/core/compile/jaxpr_trace_duration",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration",
+        COMPILE_STEP,
+    ))
+    CACHE_MISS = "/jax/compilation_cache/cache_misses"
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._compiling = _IntervalUnion()  # all three kinds of span
+        self._compile_step = _IntervalUnion()
+        self._cache_misses = 0
+        jax.monitoring.register_event_time_span_listener(self._span)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _span(self, event: str, start: float, end: float, **_) -> None:
+        if event in self.SPANS:
+            with self._lock:
+                self._compiling.add(start, end)
+                if event == self.COMPILE_STEP:
+                    self._compile_step.add(start, end)
+
+    def _event(self, event: str, **_) -> None:
+        if event == self.CACHE_MISS:
+            with self._lock:
+                self._cache_misses += 1
+
+    def totals(self) -> dict:
+        with self._lock:
+            step = self._compile_step.total
+            return {
+                "trace_lower_s": self._compiling.total - step,
+                "compile_step_s": step,
+                "cache_misses": self._cache_misses,
+            }
+
+    def since(self, before: dict) -> dict:
+        """The totals' growth since `before` (an earlier `totals()`)."""
+        return {
+            key: round(value - before[key], 6)
+            for key, value in self.totals().items()
+        }
+
+
+_compile_clock: Optional[CompileClock] = None
+_compile_clock_lock = threading.Lock()
+
+
+def compile_clock() -> CompileClock:
+    global _compile_clock
+    with _compile_clock_lock:
+        if _compile_clock is None:
+            _compile_clock = CompileClock()
+        return _compile_clock
+
+
 class FlightRecorder:
     """Bounded rings of what the engine loop actually did.
 
@@ -230,15 +487,24 @@ class FlightRecorder:
         self.steps.append(record)
 
     def record_compile(
-        self, program: str, bucket: int, seconds: float
+        self,
+        program: str,
+        bucket: int,
+        seconds: float,
+        split: Optional[dict] = None,
     ) -> None:
-        """Warmup compile blame: which program/bucket cost how many cold
-        seconds before the engine reported ready."""
+        """Warmup compile blame: which program/bucket cost how many
+        seconds before the engine reported ready, and `split`, what
+        CompileClock saw during the round: seconds tracing and lowering,
+        seconds in the compile step (a compilation or a read from the
+        cache) and programs the cache did not hold. The rest of
+        `compile_s` is the round's execution."""
         self.compile_events.append(
             {
                 "program": program,
                 "bucket": bucket,
                 "compile_s": round(seconds, 6),
+                **(split or {}),
                 "time": time.time(),
             }
         )

@@ -1,48 +1,39 @@
-"""Fleet time ledger — decompose engine step wall time into components.
+"""Fleet time ledger — engine step wall time by measured phase.
 
-Every flight-recorder step record (llm/engine.py) carries the raw
-timeline of one engine step: wall-clock stamps (`time` at step start,
-`dispatch_time`, `ready_time`, per-commit `time`) plus measured
-sub-durations (`prefill_s`, `fabric_wait_s`, per-commit `commit_s`,
-`duration_s` for the whole step). `step_ledger` partitions `duration_s`
-into named columns that sum to it *by construction* — each component is
-allocated sequentially and clamped to the remaining budget, with the
-unattributed remainder landing in `other_s` — so a replica's ledger
-always sums to ~100% of its measured wall and a shortfall shows up as a
-named column instead of silently vanishing.
+Every flight-recorder step record (llm/engine.py) carries `duration_s`
+and `phases`: the seconds the step's phase clock
+(`llm.observability.StepPhaseClock`) charged to schedule / prepare /
+wait / commit / other between the step's entry and the record. One
+`perf_counter` reading closes a phase and opens the next, so the phases
+sum to `duration_s` by measurement; `step_ledger` renames them into
+columns and guesses nothing.
 
 Columns (the partition):
 
-- ``idle_s``          — steps that did no work (no dispatch, no prefill,
-                        no commits): the engine loop polled and found
-                        nothing runnable.
-- ``prefill_s``       — host time planning + dispatching chunked-prefill
-                        programs (measured in `_run_prefill_chunks`).
-- ``fabric_wait_s``   — blocking on KV-fabric restores (measured in
-                        `_apply_fabric_restores`).
-- ``host_schedule_s`` — host time between step start and decode dispatch
-                        not already attributed to prefill/fabric:
-                        scheduler admission, batch assembly, input prep.
-- ``device_s``        — dispatch → tokens ready on host. On the sync
-                        loop this spans device compute + the blocking
-                        fetch; on the async double-buffered loop the
-                        dispatch returns immediately and device time
-                        hides behind the *next* step (shows up ~0 here,
-                        with the wait folded into the commit stage that
-                        blocks on the previous step's tokens).
-- ``commit_s``        — token emission: detokenize-and-deliver after
-                        tokens are on host (measured per commit entry).
-- ``other_s``         — duration_s minus everything above (never
-                        negative): unattributed host time.
+- ``idle_s``      — steps that did no work (phase "idle": no dispatch,
+                    no prefill, no commit): the loop polled and found
+                    nothing runnable.
+- ``schedule_s``  — deadline sweep, admission, prefix match, fabric
+                    probe and restores, chunk and decode planning.
+- ``prepare_s``   — filling input buffers (and the proposer, under
+                    speculation) up to the return of each program's
+                    dispatch call.
+- ``host_wait_s`` — the host blocked on a program's results. Not device
+                    time: the device may have finished earlier (async
+                    loop: it usually has, the fetch is a step late) and
+                    a program queued behind another waits its turn
+                    inside this column.
+- ``commit_s``    — tokens appended, blocks published, emission,
+                    finishes, after results were on the host.
+- ``other_s``     — the rest of the step: gauges and the record itself.
+                    A record without `phases` lands here whole.
 
 Overlay (NOT part of the partition — do not add it to the sum):
 
-- ``host_gap_s``      — the device-idle gap the engine measures between
-                        consecutive dispatches. It straddles the
-                        previous step's commit tail and this step's
-                        pre-dispatch window, so it overlaps the
-                        partition columns; it is reported alongside them
-                        as the "device starvation" signal.
+- ``host_gap_s``  — the gap the engine measures from the previous
+                    decode's results to this step's decode dispatch. It
+                    straddles the step boundary and any prefill chunk in
+                    between, so it overlaps the columns.
 
 `replica_ledger` sums step ledgers over a flight-record ring and adds a
 ``loop_s`` column for the wall-clock span not covered by any step record
@@ -57,85 +48,36 @@ from __future__ import annotations
 import os
 from typing import Optional, Sequence
 
-# Partition columns, in allocation order. `replica_ledger` adds
+# Partition columns and the phase each reads. `replica_ledger` adds
 # "loop_s" (inter-step wall not inside any step record) at the end.
-LEDGER_COLUMNS = (
-    "idle_s",
-    "prefill_s",
-    "fabric_wait_s",
-    "host_schedule_s",
-    "device_s",
-    "commit_s",
-    "other_s",
+_PHASE_COLUMNS = (
+    ("schedule_s", "schedule"),
+    ("prepare_s", "prepare"),
+    ("host_wait_s", "wait"),
+    ("commit_s", "commit"),
+    ("other_s", "other"),
 )
+LEDGER_COLUMNS = ("idle_s",) + tuple(col for col, _ in _PHASE_COLUMNS)
 
 REPLICA_COLUMNS = LEDGER_COLUMNS + ("loop_s",)
 
 
-def _clamp(value: Optional[float], budget: float) -> float:
-    """A component can never exceed the unallocated remainder of the
-    step's duration — measured sub-durations overlap at the edges
-    (perf_counter rounding, wall-vs-perf skew), and clamping is what
-    makes the partition sum exactly."""
-    if value is None or value <= 0.0 or budget <= 0.0:
-        return 0.0
-    return min(float(value), budget)
-
-
 def step_ledger(record: dict) -> dict:
-    """Partition one flight-record step's `duration_s` into
-    LEDGER_COLUMNS (sums to duration_s by construction), plus the
+    """One flight-record step's `duration_s` by LEDGER_COLUMNS, from the
+    step's measured `phases` (they sum to the duration; what rounding or
+    a record without phases leaves over lands in `other_s`), plus the
     `host_gap_s` overlay."""
     duration = float(record.get("duration_s") or 0.0)
     out = {col: 0.0 for col in LEDGER_COLUMNS}
     out["duration_s"] = duration
     out["host_gap_s"] = float(record.get("host_gap_s") or 0.0)
-    budget = duration
-
-    t_start = record.get("time")
-    t_dispatch = record.get("dispatch_time")
-    t_ready = record.get("ready_time")
-    commits = record.get("commits") or ()
-    prefill_s = record.get("prefill_s") or 0.0
-    fabric_s = record.get("fabric_wait_s") or 0.0
-
-    did_work = bool(
-        t_dispatch is not None or commits or prefill_s > 0 or fabric_s > 0
-    )
-    if not did_work:
-        out["idle_s"] = budget
+    if record.get("phase") == "idle":
+        out["idle_s"] = duration
         return out
-
-    out["prefill_s"] = _clamp(prefill_s, budget)
-    budget -= out["prefill_s"]
-    out["fabric_wait_s"] = _clamp(fabric_s, budget)
-    budget -= out["fabric_wait_s"]
-
-    if t_dispatch is not None and t_start is not None:
-        # Pre-dispatch host time not already attributed to prefill or
-        # fabric: scheduler admission + batch assembly + input prep.
-        sched = (
-            float(t_dispatch)
-            - float(t_start)
-            - out["prefill_s"]
-            - out["fabric_wait_s"]
-        )
-        out["host_schedule_s"] = _clamp(sched, budget)
-        budget -= out["host_schedule_s"]
-
-    if t_dispatch is not None and t_ready is not None:
-        out["device_s"] = _clamp(float(t_ready) - float(t_dispatch), budget)
-        budget -= out["device_s"]
-
-    commit = 0.0
-    for entry in commits:
-        c = entry.get("commit_s") if isinstance(entry, dict) else None
-        if c:
-            commit += float(c)
-    out["commit_s"] = _clamp(commit, budget)
-    budget -= out["commit_s"]
-
-    out["other_s"] = max(budget, 0.0)
+    phases = record.get("phases") or {}
+    for col, phase in _PHASE_COLUMNS:
+        out[col] = float(phases.get(phase) or 0.0)
+    out["other_s"] += duration - sum(out[col] for col in LEDGER_COLUMNS)
     return out
 
 

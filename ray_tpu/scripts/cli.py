@@ -94,8 +94,8 @@ def cmd_train_stats(args) -> int:
 
 
 _LEDGER_COLS = (
-    "idle_s", "prefill_s", "fabric_wait_s", "host_schedule_s",
-    "device_s", "commit_s", "other_s", "loop_s",
+    "idle_s", "schedule_s", "prepare_s", "host_wait_s",
+    "commit_s", "other_s", "loop_s",
 )
 
 

@@ -92,9 +92,12 @@ def prepare_step(step_fn: Callable, donate_argnums=(0,)) -> Callable:
         return jitted
 
     def instrumented_step(*args, **kwargs):
+        # `train.compute` is the phase's annotation; the wait inside it
+        # tells the dispatch from the blocked host on a device trace.
         with profiler.phase("compute"):
             out = jitted(*args, **kwargs)
-            jax.block_until_ready(out)
+            with jax.profiler.TraceAnnotation("train.compute.wait"):
+                jax.block_until_ready(out)
         return out
 
     return instrumented_step

@@ -169,6 +169,13 @@ class StepProfiler:
         self._phases: Dict[str, float] = {p: 0.0 for p in TRAIN_PHASES}
         self._samples = 0
         self._data_sources: list = []
+        # Each phase on the profiler's clock too: with a jax.profiler
+        # session running, `train.<phase>` lies on a host plane beside
+        # the device's operations; with none, a flag test. Imported here
+        # so that reading the run registry needs no JAX.
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
 
     @contextlib.contextmanager
     def phase(self, name: str):
@@ -177,8 +184,9 @@ class StepProfiler:
         phase it targets (the straggler-test hook)."""
         t0 = time.perf_counter()
         try:
-            maybe_fail(f"train.{name}", self._detail)
-            yield
+            with self._annotation("train." + name):
+                maybe_fail(f"train.{name}", self._detail)
+                yield
         finally:
             self._phases[name] += time.perf_counter() - t0
 

@@ -124,24 +124,24 @@ def test_fraction_over_threshold_interpolates():
 
 
 def test_step_ledger_partitions_duration_exactly():
-    t0 = 1000.0
     rec = {
-        "time": t0,
+        "time": 1000.0,
+        "phase": "prefill+decode",
         "duration_s": 0.100,
-        "dispatch_time": t0 + 0.030,
-        "ready_time": t0 + 0.080,
-        "prefill_s": 0.012,
-        "fabric_wait_s": 0.003,
-        "commits": [{"tokens": 4, "commit_s": 0.010}],
+        "phases": {
+            "schedule": 0.015, "prepare": 0.015, "wait": 0.050,
+            "commit": 0.010, "other": 0.010,
+        },
+        "commits": [{"tokens": 4}],
         "host_gap_s": 0.002,
     }
     led = step_ledger(rec)
     assert led["idle_s"] == 0.0
-    assert led["prefill_s"] == pytest.approx(0.012)
-    assert led["fabric_wait_s"] == pytest.approx(0.003)
-    # dispatch - start minus prefill/fabric already attributed.
-    assert led["host_schedule_s"] == pytest.approx(0.015)
-    assert led["device_s"] == pytest.approx(0.050)
+    # Each column IS the phase the step's clock measured; nothing is
+    # derived from stamps of another clock.
+    assert led["schedule_s"] == pytest.approx(0.015)
+    assert led["prepare_s"] == pytest.approx(0.015)
+    assert led["host_wait_s"] == pytest.approx(0.050)
     assert led["commit_s"] == pytest.approx(0.010)
     assert led["other_s"] == pytest.approx(0.010)
     assert sum(led[c] for c in LEDGER_COLUMNS) == pytest.approx(0.100)
@@ -150,26 +150,32 @@ def test_step_ledger_partitions_duration_exactly():
     assert led["host_gap_s"] == pytest.approx(0.002)
 
 
-def test_step_ledger_idle_and_clamped_steps():
-    idle = step_ledger({"time": 5.0, "duration_s": 0.05, "commits": []})
+def test_step_ledger_idle_and_unphased_steps():
+    idle = step_ledger(
+        {"time": 5.0, "phase": "idle", "duration_s": 0.05, "commits": [],
+         "phases": {"schedule": 0.04, "other": 0.01}}
+    )
     assert idle["idle_s"] == pytest.approx(0.05)
     assert sum(idle[c] for c in LEDGER_COLUMNS) == pytest.approx(0.05)
-    # Components measured on a different clock can overrun duration_s;
-    # sequential clamping keeps the partition exact and non-negative.
-    t0 = 10.0
-    overrun = step_ledger(
+    # The phases sum to the duration by measurement; what rounding leaves
+    # over, or a record that carries no phases at all, lands in other_s,
+    # so the partition stays exact.
+    rounded = step_ledger(
         {
-            "time": t0,
+            "time": 10.0,
+            "phase": "decode",
             "duration_s": 0.010,
-            "dispatch_time": t0 + 0.002,
-            "ready_time": t0 + 0.500,  # "device" longer than the step
-            "prefill_s": 0.004,
-            "commits": [{"tokens": 1, "commit_s": 0.2}],
+            "phases": {"schedule": 0.001, "prepare": 0.002, "wait": 0.006,
+                       "commit": 0.0005},
+            "commits": [{"tokens": 1}],
         }
     )
-    assert sum(overrun[c] for c in LEDGER_COLUMNS) == pytest.approx(0.010)
-    assert all(overrun[c] >= 0.0 for c in LEDGER_COLUMNS)
-    assert overrun["idle_s"] == 0.0
+    assert sum(rounded[c] for c in LEDGER_COLUMNS) == pytest.approx(0.010)
+    assert rounded["other_s"] == pytest.approx(0.0005)
+    assert rounded["idle_s"] == 0.0
+    bare = step_ledger({"time": 11.0, "phase": "decode", "duration_s": 0.02})
+    assert bare["other_s"] == pytest.approx(0.02)
+    assert sum(bare[c] for c in LEDGER_COLUMNS) == pytest.approx(0.02)
 
 
 def test_replica_ledger_covers_wall_and_estimates_mfu():
@@ -180,12 +186,11 @@ def test_replica_ledger_covers_wall_and_estimates_mfu():
         steps.append(
             {
                 "time": start,
+                "phase": "decode",
                 "duration_s": 0.1,
-                "dispatch_time": start + 0.01,
-                "ready_time": start + 0.08,
-                "prefill_s": 0.0,
-                "fabric_wait_s": 0.0,
-                "commits": [{"tokens": 4, "commit_s": 0.01}],
+                "phases": {"schedule": 0.005, "prepare": 0.005,
+                           "wait": 0.07, "commit": 0.01, "other": 0.01},
+                "commits": [{"tokens": 4}],
                 "host_gap_s": None,
             }
         )
@@ -194,6 +199,7 @@ def test_replica_ledger_covers_wall_and_estimates_mfu():
     # between the steps is inter-step loop time.
     assert led["wall_s"] == pytest.approx(0.3)
     assert led["columns"]["loop_s"] == pytest.approx(0.1)
+    assert led["columns"]["host_wait_s"] == pytest.approx(0.14)
     assert led["ledger_sum_s"] == pytest.approx(0.3)
     assert led["coverage"] == pytest.approx(1.0)
     assert led["committed_tokens"] == 8
@@ -210,14 +216,14 @@ def test_fleet_ledger_merges_replicas():
     t0 = 100.0
     step = {
         "time": t0,
+        "phase": "decode",
         "duration_s": 0.1,
-        "dispatch_time": t0 + 0.01,
-        "ready_time": t0 + 0.09,
-        "commits": [{"tokens": 6, "commit_s": 0.005}],
+        "phases": {"schedule": 0.005, "prepare": 0.005, "wait": 0.08,
+                   "commit": 0.005, "other": 0.005},
+        "commits": [{"tokens": 6}],
     }
     a = replica_ledger([step])
-    b = replica_ledger([dict(step, time=t0 + 1.0, dispatch_time=t0 + 1.01,
-                             ready_time=t0 + 1.09)])
+    b = replica_ledger([dict(step, time=t0 + 1.0)])
     fleet = fleet_ledger({"r0": a, "r1": b})
     assert fleet["replicas"] == 2
     assert fleet["committed_tokens"] == 12
@@ -228,7 +234,7 @@ def test_fleet_ledger_merges_replicas():
     )
     assert fleet["min_coverage"] == pytest.approx(1.0)
     assert set(fleet["columns"]) == set(REPLICA_COLUMNS)
-    assert fleet["bottlenecks"][0] == "device_s"
+    assert fleet["bottlenecks"][0] == "host_wait_s"
 
 
 # ---------------- SLO burn-rate monitor ----------------

@@ -364,10 +364,10 @@ def test_host_gap_metrics_and_flight_record_surfaces():
         ]
         assert steps
         for s in steps:
-            # Every step that dispatched stamps the dispatch wall time;
+            # Every step that committed also dispatched a decode batch;
             # only an async drain-only step (commits the in-flight tail
             # without queueing new work) legitimately has none.
-            if s["dispatch_time"] is None:
+            if not s["batch_size"]:
                 assert s.get("loop") == "async" and not s.get("chained")
             for c in s["commits"]:
                 assert c["dispatch_step"] <= s["step"]
