@@ -686,7 +686,6 @@ def bench_serving_decode_attn_impl():
     from ray_tpu.ops.paged_flash import (
         kv_pool_bytes,
         paged_flash_attention,
-        quantize_kv,
     )
 
     # Engine-shaped inputs come from the profile script's shared fixture
@@ -732,9 +731,9 @@ def bench_serving_decode_attn_impl():
     report(f"serving_decode_attn_pallas_ms{tag}", 1e3 * pal_s, unit="ms")
     report(f"serving_decode_attn_impl_speedup{tag}", ref_s / pal_s, unit="x")
 
-    kq, ks = quantize_kv(kc)
-    vq, vs = quantize_kv(vc)
-    kc, vc = kq, vq
+    q, kc, vc, tables, lens, nk, nv, ks, vs = _build_case(
+        np.random.RandomState(0), b, 1, ctx, h, d, bs, nb, dtype, int8=True
+    )
     pal8_s = timed(paged_flash_attention, k_scale=ks, v_scale=vs)
     report(
         f"serving_decode_attn_pallas_int8_ms{tag}", 1e3 * pal8_s, unit="ms"

@@ -64,7 +64,8 @@ def _report(row: dict) -> None:
 
 
 def _build_case(rng, b, s, ctx, h, d, bs, nb, dtype, int8: bool):
-    """Engine-shaped inputs: per-row tables 0-padded past ceil(ctx/bs)."""
+    """Engine-shaped inputs: per-row tables 0-padded past ceil(ctx/bs),
+    pools in the stored form ([1, N, bs, H*D], scales [1, N, bs, H])."""
     num_blocks = b * nb + 1
     q = jnp.asarray(rng.randn(b, s, h, d), dtype)
     new_k = jnp.asarray(rng.randn(b, s, h, d), dtype)
@@ -81,6 +82,9 @@ def _build_case(rng, b, s, ctx, h, d, bs, nb, dtype, int8: bool):
     if int8:
         k_cache, k_scale = quantize_kv(k_cache)
         v_cache, v_scale = quantize_kv(v_cache)
+        k_scale, v_scale = k_scale[None], v_scale[None]
+    k_cache = k_cache.reshape(1, num_blocks, bs, h * d)
+    v_cache = v_cache.reshape(1, num_blocks, bs, h * d)
     return q, k_cache, v_cache, jnp.asarray(tables), lens, new_k, new_v, \
         k_scale, v_scale
 

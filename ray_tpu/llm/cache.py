@@ -1,6 +1,6 @@
 """Refcounted, content-addressed paged-KV block allocator.
 
-CPU-side bookkeeping for the preallocated [num_blocks, block_size, H, D]
+CPU-side bookkeeping for the preallocated [num_blocks, block_size, H*D]
 device pools owned by the model runner. Block 0 is never handed out — it is
 the null block that pads block tables and absorbs masked-lane scatters, so
 a gather through an id of 0 is always safe (and always masked).
@@ -76,7 +76,7 @@ def kv_pool_bytes_sharded(
     """Byte accounting for BOTH KV pools (K + V values, plus their scale
     tensors when quantized) under head-axis tensor parallelism.
 
-    The pools are [L, N, bs, H, D] (scales [L, N, bs, H]) sharded on H, so
+    The pools are [L, N, bs, H*D] (scales [L, N, bs, H]) sharded by heads, so
     each chip holds exactly aggregate / tp bytes — the number that decides
     whether a model's cache fits per-chip HBM, which is what
     `tensor_parallel_size` exists to change. Pure-int host math (this

@@ -3,7 +3,7 @@
 Everything here exists to keep XLA's compiled-program count O(1): fixed
 decode batch slots, a fixed block-table width, and a small set of
 power-of-two prefill buckets. The paged cache trades a static
-[num_blocks, block_size, H, D] pool for per-sequence dynamic lengths —
+[num_blocks, block_size, H*D] pool for per-sequence dynamic lengths —
 the standard continuous-batching layout (vLLM-style) restated under
 XLA's static-shape constraint.
 """

@@ -10,9 +10,11 @@ with speculative decoding on — one batched k-token verify program per fed
 width bucket (the partial-prefill shape generalized to [max_decode_slots]
 slots with per-slot position offsets, returning the argmax at EVERY fed
 position so the engine can accept the longest agreeing proposal prefix).
-The cache pools are [L, num_blocks, block_size, H, D] device arrays
-threaded functionally through every step with donated buffers, so steps
-update the cache in place without host round-trips.
+The cache pools are [L, num_blocks, block_size, H*D] device arrays — heads
+and head size merged on one lane-dense minor axis, the form the device
+keeps row-major and the paged kernel reads as it is — threaded
+functionally through every step with donated buffers, so steps update the
+cache in place without host round-trips and no program converts a pool.
 
 Serving hot-path knobs (EngineConfig):
 
@@ -30,9 +32,9 @@ Serving hot-path knobs (EngineConfig):
   * ``tensor_parallel_size`` — > 1 builds a `tp` mesh over the backend
     devices and runs ALL FIVE programs SPMD over it: weights shard
     Megatron-style from the model's logical axis annotations, the cache /
-    scale pools shard on the HEAD axis (the axis ``paged_flash`` already
-    loops over, so each chip's kernel instance DMAs only its local heads'
-    cache blocks), attention runs head-sliced under shard_map, and the
+    scale pools shard on their minor axis BY HEADS (a head is a contiguous
+    lane group of it, so each chip's kernel instance DMAs only its local
+    heads' cache blocks), attention runs head-sliced under shard_map, and the
     donated pool buffers stay sharded through every step (the returned
     pools carry an explicit sharding constraint, so donation aliases
     buffer-for-buffer and nothing ever gathers). Block ids are
@@ -139,12 +141,15 @@ class _StepPrograms:
                 v_scale)
 
     def _store_kv(self, new_kv: jax.Array) -> Tuple[jax.Array, Optional[jax.Array]]:
-        """New-token K or V [..., H, D] → (pool-dtype values, per-token
-        scales or None). int8 pools quantize at scatter time — per-token
-        scales are what a single-token decode write can maintain."""
+        """New-token K or V [..., H, D] → (pool-dtype values in the stored
+        form [..., H*D], per-token scales [..., H] or None). int8 pools
+        quantize at scatter time — per-token scales are what a
+        single-token decode write can maintain."""
+        stored = new_kv.shape[:-2] + (-1,)
         if self.quantized:
-            return quantize_kv(new_kv)
-        return new_kv.astype(self.kv_cache_dtype), None
+            values, scales = quantize_kv(new_kv)
+            return values.reshape(stored), scales
+        return new_kv.astype(self.kv_cache_dtype).reshape(stored), None
 
     # ---------------- the five step programs ----------------
 
@@ -161,20 +166,15 @@ class _StepPrograms:
         )
         kvs = collect_kv_caches(state["intermediates"], cfg.num_layers)
         s = tokens.shape[1]
-        nb = s // self.block_size
-        paged = (nb, self.block_size, cfg.num_heads, cfg.head_dim)
+        paged = (s // self.block_size, self.block_size, -1)
         for layer, (k, v) in enumerate(kvs):
             kq, ks = self._store_kv(k[0])
             vq, vs = self._store_kv(v[0])
             k_cache = k_cache.at[layer, blocks].set(kq.reshape(paged))
             v_cache = v_cache.at[layer, blocks].set(vq.reshape(paged))
             if ks is not None:
-                k_scale = k_scale.at[layer, blocks].set(
-                    ks.reshape(paged[:-1])
-                )
-                v_scale = v_scale.at[layer, blocks].set(
-                    vs.reshape(paged[:-1])
-                )
+                k_scale = k_scale.at[layer, blocks].set(ks.reshape(paged))
+                v_scale = v_scale.at[layer, blocks].set(vs.reshape(paged))
         next_token = jnp.argmax(logits[0, true_len - 1, :]).astype(jnp.int32)
         pools = self._constrain_pools((k_cache, v_cache, k_scale, v_scale))
         return pools, next_token
@@ -493,17 +493,12 @@ class GPTRunner:
         self.host_bytes_out = 0
 
         cfg, ecfg = model_config, engine_config
-        cache_shape = (
-            cfg.num_layers,
-            ecfg.num_blocks,
-            ecfg.block_size,
-            cfg.num_heads,
-            cfg.head_dim,
-        )
+        blocks = (cfg.num_layers, ecfg.num_blocks, ecfg.block_size)
+        cache_shape = blocks + (cfg.num_heads * cfg.head_dim,)
         self.k_cache = self._zeros_pool(cache_shape, self.kv_cache_dtype)
         self.v_cache = self._zeros_pool(cache_shape, self.kv_cache_dtype)
         if self.quantized:
-            scale_shape = cache_shape[:-1]  # [L, N, bs, H]
+            scale_shape = blocks + (cfg.num_heads,)
             self.k_scale = self._zeros_pool(scale_shape, KV_SCALE_DTYPE)
             self.v_scale = self._zeros_pool(scale_shape, KV_SCALE_DTYPE)
         else:
@@ -520,7 +515,7 @@ class GPTRunner:
 
     def _zeros_pool(self, shape, dtype):
         """Allocate one device pool — under tensor parallelism it is
-        assembled shard-by-shard in the head-sharded layout, so the full
+        assembled shard-by-shard, split by heads on the minor axis, so the full
         pool never materializes on a single chip (a tp-sharded pool may
         exceed per-chip HBM — the very situation tp exists for)."""
         if self._pool_sharding is None:
@@ -571,7 +566,7 @@ class GPTRunner:
     def kv_pool_bytes(self) -> dict:
         """Aggregate and per-shard bytes of both KV pools (+ scale tensors
         when quantized): per-chip HBM is aggregate / tensor_parallel_size
-        because the pools shard on the head axis."""
+        because the pools shard by heads."""
         cfg, ecfg = self.model_config, self.engine_config
         return kv_pool_bytes_sharded(
             cfg.num_layers,
@@ -715,10 +710,10 @@ class GPTRunner:
 
     def extract_block(self, block: int) -> dict:
         """Read one block's device content to host numpy — the spill half
-        of the fabric tier. The payload is pool-dtype values (+ int8
-        scales), so restore is bit-exact; `kv_dtype` stamps the storage
-        format so a mismatched engine treats the entry as a miss instead
-        of scattering garbage."""
+        of the fabric tier. The payload is pool-dtype values in the stored
+        form ([L, bs, H*D], + int8 scales [L, bs, H]), so restore is
+        bit-exact; `kv_dtype` stamps the storage format so a mismatched
+        engine treats the entry as a miss instead of scattering garbage."""
         payload = {
             "kv_dtype": self.kv_cache_dtype_str,
             "k": np.asarray(self.k_cache[:, block]),

@@ -91,6 +91,7 @@ class Block(nn.Module):
         *,
         return_kv: bool = False,
         paged_state: Optional[tuple] = None,
+        paged_layer: int = 0,
         paged_impl: str = "reference",
         paged_mesh: Optional[Any] = None,
     ):
@@ -110,17 +111,20 @@ class Block(nn.Module):
             k = k.reshape(b, s, cfg.num_heads, cfg.head_dim)
             v = v.reshape(b, s, cfg.num_heads, cfg.head_dim)
             if paged_state is not None:
-                (k_cache_l, v_cache_l, block_tables, context_lens,
-                 k_scale_l, v_scale_l) = paged_state
+                (k_cache, v_cache, block_tables, context_lens,
+                 k_scale, v_scale) = paged_state
                 # "pallas" runs the fused kernel (walks the block table
                 # inside the pipeline, never materializing the gathered
                 # pages or the logits — ops/paged_flash.py); "reference"
                 # the XLA gather+softmax op. The engine resolves "auto"
                 # before tracing, so the choice is compile-time static.
+                # The pools go in whole, every layer of them: the op reads
+                # this layer's blocks where it addresses them, and a
+                # `k_cache[i]` here would be a copy of the layer.
                 attn = paged_attention_impl(
-                    q, k_cache_l, v_cache_l, block_tables, context_lens,
-                    new_k=k, new_v=v,
-                    k_scale=k_scale_l, v_scale=v_scale_l,
+                    q, k_cache, v_cache, block_tables, context_lens,
+                    new_k=k, new_v=v, layer=paged_layer,
+                    k_scale=k_scale, v_scale=v_scale,
                     impl=paged_impl,
                     mesh=paged_mesh,
                 )
@@ -212,9 +216,10 @@ class GPT(nn.Module):
             back via :func:`collect_kv_caches`.
           * ``paged_caches=(k_cache, v_cache, block_tables, context_lens)``
             or ``(..., k_scale, v_scale)`` (decode and prefix-aware partial
-            prefill): k/v_cache are [L, num_blocks, block_size, H, D] paged
-            pools (int8 pools carry [L, N, bs, H] scale tensors; pass None
-            scales otherwise); tokens is [B, S] (S == 1 for decode, S > 1
+            prefill): k/v_cache are [L, num_blocks, block_size, H*D] paged
+            pools, heads and head size merged on the minor axis (int8
+            pools carry [L, N, bs, H] scale tensors; pass None scales
+            otherwise); tokens is [B, S] (S == 1 for decode, S > 1
             for the uncached suffix of a partially-cached prompt) and
             ``positions`` [B, S] must carry each token's absolute position.
             Attention reads the cached prefix through the block table and
@@ -252,24 +257,16 @@ class GPT(nn.Module):
         if paged_caches is not None:
             if len(paged_caches) == 4:  # legacy: no scale tensors
                 paged_caches = tuple(paged_caches) + (None, None)
-            (k_cache, v_cache, block_tables, context_lens,
-             k_scale, v_scale) = paged_caches
         for i in range(cfg.num_layers):
             use_moe = bool(
                 cfg.num_experts and (i % cfg.moe_every == cfg.moe_every - 1)
             )
-            paged_state = None
-            if paged_caches is not None:
-                paged_state = (
-                    k_cache[i], v_cache[i], block_tables, context_lens,
-                    None if k_scale is None else k_scale[i],
-                    None if v_scale is None else v_scale[i],
-                )
             x = Block(cfg, use_moe=use_moe, name=f"h_{i}")(
                 x,
                 deterministic=deterministic,
                 return_kv=return_kv,
-                paged_state=paged_state,
+                paged_state=paged_caches,
+                paged_layer=i,
                 paged_impl=paged_impl,
                 paged_mesh=paged_mesh,
             )

@@ -62,24 +62,23 @@ def validate_tp_heads(
         )
 
 
-def head_sharded_call(mesh, fn, args, head_args: Sequence[bool]):
-    """Run `fn(*args)` SPMD over the mesh's `tp` axis with the flagged
-    arrays sharded on their head dim and the rest replicated.
+def head_sharded_call(mesh, fn, args, in_specs: Sequence):
+    """Run `fn(*args)` SPMD over the mesh's `tp` axis, each argument under
+    its PartitionSpec, the result head-sharded.
 
-    Every head-carrying array in the paged-attention signature puts H at
-    dim 2 — q/new_k/new_v [B, S, H, D], per-layer pools [N, bs, H, D],
-    scale pools [N, bs, H] — so one PartitionSpec covers them all, and
-    inside the shard each kernel instance sees (and DMAs) only its local
+    Every head-carrying array of the paged-attention signature is one of
+    two shapes: q/new_k/new_v [B, S, H, D] put H at dim 2
+    (`LLM_HEAD_SPEC`), the stored pools [L, N, bs, H*D] and their scales
+    [L, N, bs, H] put the heads on dim 3 (`LLM_POOL_SPEC`: a head is a
+    contiguous lane group, so an even split of H*D is a split by heads).
+    Inside the shard each kernel instance sees (and DMAs) only its local
     heads' slice of the cache blocks. Block tables and context lengths
-    replicate: block ids are shard-invariant."""
-    from jax.sharding import PartitionSpec as P
-
+    replicate (`P()`): block ids are shard-invariant."""
     from ray_tpu._private.jax_compat import shard_map
     from ray_tpu.parallel.sharding import LLM_HEAD_SPEC
 
-    in_specs = tuple(LLM_HEAD_SPEC if h else P() for h in head_args)
     return shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=LLM_HEAD_SPEC,
+        fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=LLM_HEAD_SPEC,
         check_vma=False,
     )(*args)
 
@@ -101,6 +100,7 @@ def head_sharded_attention(
     the row-parallel output projection. No collective — heads never mix
     inside attention."""
     from ray_tpu.ops.flash_attention import attention as attention_op
+    from ray_tpu.parallel.sharding import LLM_HEAD_SPEC
 
     validate_tp_heads(q.shape[2], mesh.shape["tp"])
     if sm_scale is None:
@@ -111,15 +111,27 @@ def head_sharded_attention(
             q, k, v, causal=causal, sm_scale=sm_scale, impl=impl
         )
 
-    return head_sharded_call(mesh, shard, (q, k, v), (True, True, True))
+    return head_sharded_call(mesh, shard, (q, k, v), (LLM_HEAD_SPEC,) * 3)
 
 
-def validate_kv_scales(k_cache, v_cache, k_scale, v_scale) -> None:
+def validate_kv_pools(q, k_cache, v_cache, k_scale, v_scale) -> None:
     """One shared contract for both paged-attention implementations, so
     impl='auto' can never accept inputs on one backend that the other
-    rejects: pools must share a dtype, int8 pools require BOTH dequant
-    scales, and scales require int8 pools (silently dropping or applying
-    them would diverge)."""
+    rejects: the pools are the stored form [L, N, bs, H*D] for q's
+    [B, S, H, D] and share a dtype, int8 pools require BOTH dequant
+    scales ([L, N, bs, H]), and scales require int8 pools (silently
+    dropping or applying them would diverge)."""
+    h, d = q.shape[2:]
+    for name, pool, minor in (
+        ("k_cache", k_cache, h * d), ("v_cache", v_cache, h * d),
+        ("k_scale", k_scale, h), ("v_scale", v_scale, h),
+    ):
+        if pool is not None and (pool.ndim != 4 or pool.shape[3] != minor):
+            raise ValueError(
+                f"{name} has shape {pool.shape}; for {h} heads of {d} the "
+                f"stored form is [L, N, bs, {minor}] (heads and head size "
+                "merged on the minor axis, every layer in one array)"
+            )
     if k_cache.dtype != v_cache.dtype:
         raise ValueError(
             f"k_cache/v_cache dtypes differ ({k_cache.dtype} vs "
@@ -151,14 +163,17 @@ def paged_attention(
     *,
     new_k: Optional[jax.Array] = None,
     new_v: Optional[jax.Array] = None,
+    layer: int = 0,
     sm_scale: Optional[float] = None,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Attention over the paged KV cache through per-sequence block tables.
 
-    The KV cache is paged: `k_cache`/`v_cache` are [num_blocks, block_size,
-    H, D] pools, and each sequence owns a list of block ids. Shapes are fully
+    The KV cache is paged: `k_cache`/`v_cache` are the pools as the runner
+    stores them, [num_layers, num_blocks, block_size, H*D] (heads and head
+    size merged on the minor axis), read at `layer`, and each sequence
+    owns a list of block ids. Shapes are fully
     static — every sequence gathers `max_blocks_per_seq * block_size` cache
     slots and positions >= its `context_len` are masked, so XLA compiles one
     program regardless of how long each sequence actually is.
@@ -171,13 +186,14 @@ def paged_attention(
     plus new tokens 0..i.
 
     q:            [B, S, H, D]  new-token queries per batch slot.
-    k_cache:      [N, bs, H, D] shared block pool (block 0 is the null block).
+    k_cache:      [L, N, bs, H*D] shared block pool (block 0 of every layer
+                  is the null block); H and D are q's.
     block_tables: [B, nb] int32, padded with 0 past each sequence's blocks.
     context_lens: [B] int32 — tokens already written to the cache.
     new_k/new_v:  [B, S, H, D] the new tokens' K/V. They have not been
                   scattered into the cache yet, so they ride along as extra
                   always-gathered slots under a causal (j <= i) mask.
-    k_scale/v_scale: [N, bs, H] per-token dequant scales for int8 cache
+    k_scale/v_scale: [L, N, bs, H] per-token dequant scales for int8 cache
                   pools (ops.paged_flash.quantize_kv); the gathered pages
                   are dequantized in f32 before use, making this op the
                   exact oracle for the fused kernel's int8 path.
@@ -190,20 +206,20 @@ def paged_attention(
     """
     b, q_len, h, d = q.shape
     nb = block_tables.shape[1]
-    bs = k_cache.shape[1]
+    bs = k_cache.shape[2]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    validate_kv_scales(k_cache, v_cache, k_scale, v_scale)
-    # Gather the pages: [B, nb, bs, H, D] -> [B, nb*bs, H, D].
-    k_ctx = k_cache[block_tables].reshape(b, nb * bs, h, d)
-    v_ctx = v_cache[block_tables].reshape(b, nb * bs, h, d)
+    validate_kv_pools(q, k_cache, v_cache, k_scale, v_scale)
+    # Gather the pages: [B, nb, bs, H*D] -> [B, nb*bs, H, D].
+    k_ctx = k_cache[layer, block_tables].reshape(b, nb * bs, h, d)
+    v_ctx = v_cache[layer, block_tables].reshape(b, nb * bs, h, d)
     if k_scale is not None:
         k_ctx = dequantize_kv(
-            k_ctx, k_scale[block_tables].reshape(b, nb * bs, h)
+            k_ctx, k_scale[layer, block_tables].reshape(b, nb * bs, h)
         ).astype(q.dtype)
     if v_scale is not None:
         v_ctx = dequantize_kv(
-            v_ctx, v_scale[block_tables].reshape(b, nb * bs, h)
+            v_ctx, v_scale[layer, block_tables].reshape(b, nb * bs, h)
         ).astype(q.dtype)
     # [B, Q, K] mask: every query sees every valid cached position.
     valid = jnp.broadcast_to(

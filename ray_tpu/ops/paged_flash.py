@@ -4,15 +4,24 @@ The XLA-assembled decode path (`ops.paged_attention`) gathers whole pages —
 `k_cache[block_tables]` materializes [B, nb*bs, H, D] in HBM every step —
 and runs a full-matrix softmax over [B, H, Q, K] logits. This kernel walks
 each sequence's block table *inside the pipeline*: the grid is
-(batch, nb + 1) with the kv dimension sequential, and the k/v BlockSpec
-index maps read the scalar-prefetched block table, so each grid step DMAs
-exactly one [bs, H, D] cache block into VMEM. Block gather, QK^T, validity
-masking, streaming (online) softmax, and the weighted-V accumulation all
-happen in one pass; neither the gathered pages nor the logits ever touch
-HBM. The final grid step folds in the not-yet-scattered new tokens'
-K/V under a causal mask and normalizes — fully-masked rows (a padded slot
-with context_len 0 and no new tokens) come out as exact zeros, matching
-`finalize_partial`'s l == 0 hygiene.
+(batch, q tiles, nb + q tiles) with the kv dimension sequential, and the
+k/v BlockSpec index maps read the scalar-prefetched block table, so each
+grid step DMAs exactly one [bs, H*D] cache block of one layer into VMEM.
+Block gather, QK^T, validity masking, streaming (online) softmax, and the
+weighted-V accumulation all happen in one pass; neither the gathered pages
+nor the logits ever touch HBM. The final grid step folds in the
+not-yet-scattered new tokens' K/V under a causal mask and normalizes —
+fully-masked rows (a padded slot with context_len 0 and no new tokens) come
+out as exact zeros, matching `finalize_partial`'s l == 0 hygiene.
+
+The pools arrive as the runner stores them, [L, N, bs, H*D]: heads and head
+size merged into one lane-dense minor axis, all layers in one array. That
+is the form the device keeps row-major, which is what a Mosaic call takes
+its operands in — a pool whose minor dimensions are [H, D] = [20, 64] is
+kept with the block count minor-most, and every program that handed it to
+the kernel converted the whole pool on the way in and back out. The layer
+is picked in the index map ((layer, table[b, j], 0, 0)), never sliced out
+in XLA, and head h is lanes h*D:(h+1)*D of the block.
 
 Covers both program shapes ray_tpu.llm compiles: decode (S == 1) and
 prefix-aware partial prefill (S > 1, the uncached suffix attends the cached
@@ -48,7 +57,7 @@ from ray_tpu.ops.attention import (
     dequantize_kv,  # noqa: F401 — canonical home; re-exported via ops
     head_sharded_call,
     paged_attention,
-    validate_kv_scales,
+    validate_kv_pools,
     validate_tp_heads,
 )
 from ray_tpu.ops.flash_attention import _on_cpu
@@ -124,7 +133,8 @@ def _paged_kernel(
     sum / accumulator live in VMEM scratch across the sequential kv
     dimension. q / new-token K/V / out blocks are heads-leading
     [1, H, tq, D]: Mosaic tiles the last two dims, so a per-head [tq, D]
-    view must not have the head dim between them."""
+    view must not have the head dim between them. Cache blocks are
+    [bs, H*D] (scales [bs, H]): head h is a lane slice."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -134,6 +144,7 @@ def _paged_kernel(
     qi = pl.program_id(1)
     j = pl.program_id(2)
     compute_dtype = q_ref.dtype
+    d = q_ref.shape[-1]
 
     @pl.when(j == 0)
     def _init():
@@ -151,7 +162,7 @@ def _paged_kernel(
     def _cache_block():
         for h in range(heads):
             q = q_ref[0, h]  # [tq, D], prescaled by sm_scale
-            k = k_ref[0, :, h, :]  # [bs, D] (int8 when quantized)
+            k = k_ref[:, h * d:(h + 1) * d]  # [bs, D] (int8 when quantized)
             s = jax.lax.dot_general(
                 q, k.astype(compute_dtype), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -161,13 +172,14 @@ def _paged_kernel(
                 # Dequant folded into the score/weight matrices: K's
                 # per-token scale multiplies score columns, V's rescales
                 # the softmax weights — both [tq, bs] ops, never [bs, D].
-                s = s * ks_ref[0, :, h].astype(jnp.float32)[None, :]
-                p_scale = vs_ref[0, :, h].astype(jnp.float32)[None, :]
+                s = s * ks_ref[:, h].astype(jnp.float32)[None, :]
+                p_scale = vs_ref[:, h].astype(jnp.float32)[None, :]
             t_ids = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(t_ids < ctx, s, NEG_INF)
             _online_update(
                 s, h, m_scr, l_scr, acc_scr, p_scale,
-                v_ref[0, :, h, :].astype(compute_dtype), compute_dtype,
+                v_ref[:, h * d:(h + 1) * d].astype(compute_dtype),
+                compute_dtype,
             )
 
     t = j - nb  # new-token tile this step would fold in
@@ -241,6 +253,7 @@ def paged_flash_attention(
     *,
     new_k: jax.Array,
     new_v: jax.Array,
+    layer: int = 0,
     sm_scale: Optional[float] = None,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
@@ -249,12 +262,12 @@ def paged_flash_attention(
     """Fused paged attention over the block-table KV cache (Pallas TPU).
 
     Same contract as :func:`ray_tpu.ops.paged_attention` — q [B, S, H, D],
-    k/v_cache [N, bs, H, D] pools, block_tables [B, nb] (0-padded),
-    context_lens [B] — except `new_k`/`new_v` are REQUIRED (every
-    generation step of ray_tpu.llm carries the new tokens' K/V; a
+    k/v_cache [L, N, bs, H*D] pools read at `layer`, block_tables [B, nb]
+    (0-padded), context_lens [B] — except `new_k`/`new_v` are REQUIRED
+    (every generation step of ray_tpu.llm carries the new tokens' K/V; a
     cache-only query should use the reference op). S == 1 is decode,
     S > 1 is prefix-aware partial prefill. When the cache pools are int8,
-    `k_scale`/`v_scale` [N, bs, H] carry the per-token dequant scales
+    `k_scale`/`v_scale` [L, N, bs, H] carry the per-token dequant scales
     (see `quantize_kv`).
 
     Runs in interpret mode on CPU by default so tests exercise the same
@@ -266,11 +279,11 @@ def paged_flash_attention(
             "carries the new tokens' K/V); use ops.paged_attention for "
             "cache-only queries"
         )
-    validate_kv_scales(k_cache, v_cache, k_scale, v_scale)
+    validate_kv_pools(q, k_cache, v_cache, k_scale, v_scale)
     quantized = k_cache.dtype == jnp.int8
     b, s_len, h, d = q.shape
     nb = block_tables.shape[1]
-    bs = k_cache.shape[1]
+    bs = k_cache.shape[2]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     if interpret is None:
@@ -307,15 +320,15 @@ def paged_flash_attention(
         return jnp.where(j < nb, tables_ref[bi, jnp.minimum(j, nb - 1)], 0)
 
     def kv_map(bi, qi, j, tables_ref, lens_ref):
-        return (block_id(bi, j, tables_ref), 0, 0, 0)
-
-    def scale_map(bi, qi, j, tables_ref, lens_ref):
-        return (block_id(bi, j, tables_ref), 0, 0)
+        # The layer is chosen here, in the copy's address: the pool goes
+        # into the call whole and as stored, so XLA neither slices a layer
+        # out of it nor converts its layout.
+        return (layer, block_id(bi, j, tables_ref), 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, h, tq, d), q_map),
-        pl.BlockSpec((1, bs, h, d), kv_map),
-        pl.BlockSpec((1, bs, h, d), kv_map),
+        pl.BlockSpec((None, None, bs, h * d), kv_map),
+        pl.BlockSpec((None, None, bs, h * d), kv_map),
         pl.BlockSpec((1, h, tq, d), new_map),
         pl.BlockSpec((1, h, tq, d), new_map),
     ]
@@ -325,8 +338,8 @@ def paged_flash_attention(
     ]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, bs, h), scale_map),
-            pl.BlockSpec((1, bs, h), scale_map),
+            pl.BlockSpec((None, None, bs, h), kv_map),
+            pl.BlockSpec((None, None, bs, h), kv_map),
         ]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -366,6 +379,7 @@ def paged_attention_impl(
     *,
     new_k: Optional[jax.Array] = None,
     new_v: Optional[jax.Array] = None,
+    layer: int = 0,
     sm_scale: Optional[float] = None,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
@@ -374,15 +388,17 @@ def paged_attention_impl(
 ) -> jax.Array:
     """Dispatcher: the fused Pallas kernel on TPU, the XLA reference
     elsewhere (impl='auto'); 'pallas' forces the kernel (interpret mode on
-    CPU), 'reference' forces the gather+softmax reference. A cache-only
+    CPU), 'reference' forces the gather+softmax reference. Both take the
+    pools as stored ([L, N, bs, H*D], read at `layer`). A cache-only
     query (new_k=None) is outside the kernel's contract: 'auto' falls back
     to the reference, 'pallas' raises (inside paged_flash_attention).
 
     `mesh` (a Mesh whose `tp` axis is > 1) runs the chosen implementation
     head-sliced over the tensor-parallel axis via shard_map: each chip's
     instance receives only its local heads' q / new-token K/V / cache and
-    scale pool slices, so the kernel's per-block DMA touches local-head
-    bytes only and the attention output comes back head-sharded with no
+    scale pool slices (heads are contiguous lane groups of the pools'
+    minor axis), so the kernel's per-block DMA touches local-head bytes
+    only and the attention output comes back head-sharded with no
     collective (heads never mix inside attention — the psum this layering
     implies happens later, in the attn output projection)."""
     resolved = resolve_paged_impl(impl)
@@ -391,17 +407,21 @@ def paged_attention_impl(
     )
     op = paged_attention if use_reference else paged_flash_attention
     if mesh is not None and mesh.shape.get("tp", 1) > 1:
+        from jax.sharding import PartitionSpec as P
+
+        from ray_tpu.parallel.sharding import LLM_HEAD_SPEC, LLM_POOL_SPEC
+
         validate_tp_heads(q.shape[2], mesh.shape["tp"])
         if sm_scale is None:
             sm_scale = 1.0 / math.sqrt(q.shape[-1])
         args = [q, k_cache, v_cache, block_tables, context_lens]
-        head_args = [True, True, True, False, False]
+        specs = [LLM_HEAD_SPEC, LLM_POOL_SPEC, LLM_POOL_SPEC, P(), P()]
         if new_k is not None:
             args += [new_k, new_v]
-            head_args += [True, True]
+            specs += [LLM_HEAD_SPEC, LLM_HEAD_SPEC]
         if k_scale is not None:
             args += [k_scale, v_scale]
-            head_args += [True, True]
+            specs += [LLM_POOL_SPEC, LLM_POOL_SPEC]
 
         def sharded(q, k_cache, v_cache, block_tables, context_lens,
                     *rest):
@@ -412,14 +432,14 @@ def paged_attention_impl(
                 ks, vs = rest
             return op(
                 q, k_cache, v_cache, block_tables, context_lens,
-                new_k=nk, new_v=nv, sm_scale=sm_scale,
+                new_k=nk, new_v=nv, layer=layer, sm_scale=sm_scale,
                 k_scale=ks, v_scale=vs,
             )
 
-        return head_sharded_call(mesh, sharded, args, head_args)
+        return head_sharded_call(mesh, sharded, args, specs)
     return op(
         q, k_cache, v_cache, block_tables, context_lens,
-        new_k=new_k, new_v=new_v, sm_scale=sm_scale,
+        new_k=new_k, new_v=new_v, layer=layer, sm_scale=sm_scale,
         k_scale=k_scale, v_scale=v_scale,
     )
 

@@ -164,7 +164,7 @@ def replicated(mesh: Mesh) -> NamedSharding:
 # row-parallel — each block pays exactly one psum after attn-proj and one
 # after mlp-out, inserted by GSPMD); embeddings, layernorms, and the tied
 # LM head stay replicated so the per-slot argmax needs no gather. The paged
-# KV pools shard on the SAME head axis (see llm/model_runner.py), which is
+# KV pools shard by the SAME heads (see llm/model_runner.py), which is
 # what makes block ids shard-invariant: every chip holds the same blocks,
 # just its own heads' slice of them.
 LLM_TP_RULES: RuleTable = {
@@ -174,17 +174,19 @@ LLM_TP_RULES: RuleTable = {
     "heads": "tp",
 }
 
-# Head-carrying engine arrays all put H at dim 2 — queries/new K/V
-# [B, S, H, D], per-layer cache pools [N, bs, H, D], scale pools
-# [N, bs, H] — so one spec covers the whole paged-attention signature.
+# Queries and new-token K/V [B, S, H, D] put H at dim 2.
 LLM_HEAD_SPEC = P(None, None, "tp")
-# Full cache/scale pools [L, N, bs, H, ...]: H at dim 3.
+# The stored cache pools [L, N, bs, H*D] and scale pools [L, N, bs, H]
+# carry the heads on dim 3. In the pools' merged axis a head is D
+# contiguous lanes, so an even split of H*D over tp (H divisible by tp:
+# ops.attention.validate_tp_heads) gives each chip its own heads.
 LLM_POOL_SPEC = P(None, None, None, "tp")
 
 
 def llm_pool_sharding(mesh: Mesh) -> NamedSharding:
-    """Sharding for the runner's [L, N, bs, H, D] KV pools and
-    [L, N, bs, H] int8 scale pools (one spec fits both: H is dim 3)."""
+    """Sharding for the runner's [L, N, bs, H*D] KV pools and
+    [L, N, bs, H] int8 scale pools (one spec fits both: the heads are
+    dim 3)."""
     return NamedSharding(mesh, LLM_POOL_SPEC)
 
 

@@ -24,7 +24,7 @@ RTL803 sharding-nondivisible — a PartitionSpec shards a dim over mesh
     constants, cross-module imports; sizes additionally flow from
     `create_device_mesh((...))`-style device shapes.
 RTL804 paired-pool-geometry — an int8 K/V pool whose per-token scale
-    pool disagrees with the `pool.shape[:-1]` law or is not a float
+    pool is not the pool's rank and leading axes or is not a float
     dtype, plus the flow form: a function that owns both `X_cache` and
     `X_scale` and writes the pool without ever writing the scales (the
     CoW `copy_block` hazard — stale scales mean wrong magnitudes on
@@ -428,25 +428,27 @@ def _check_pool_pairs(
                     f"pool {sname} of dtype {sval.dtype}; dequant "
                     "scales must be a float dtype",
                 )
-            # The shape law holds for ANY quantized pool dtype: scales
-            # mirror pool.shape[:-1] (per-token per-head, no head_dim).
+            # The shape law holds for ANY quantized pool dtype: pools
+            # are stored [..., H*D] and scales [..., H] (per-token
+            # per-head), so the ranks agree and every axis but the
+            # minor one is the pool's.
             if isinstance(pval.shape, tuple) and isinstance(
                 sval.shape, tuple
             ):
-                if len(sval.shape) != len(pval.shape) - 1:
+                if len(sval.shape) != len(pval.shape):
                     analysis.add(
                         analysis.rtl804,
                         node,
                         f"scale pool {sname} is rank "
                         f"{len(sval.shape)} but the paired pool "
                         f"{base + suffix} is rank "
-                        f"{len(pval.shape)}: per-token scales "
-                        "must drop exactly the trailing (head_dim)"
-                        " axis — pool.shape[:-1]",
+                        f"{len(pval.shape)}: pools are stored "
+                        "[..., H*D] and per-token scales [..., H], "
+                        "the same rank",
                     )
                 else:
                     for i, (a, b) in enumerate(
-                        zip(sval.shape, pval.shape[:-1])
+                        zip(sval.shape[:-1], pval.shape[:-1])
                     ):
                         if dims_equal(a, b) is False:
                             analysis.add(
@@ -456,7 +458,8 @@ def _check_pool_pairs(
                                 f"{a!r} but the paired pool "
                                 f"{base + suffix} has {b!r} "
                                 "there; scales must mirror "
-                                "pool.shape[:-1] exactly",
+                                "pool.shape[:-1] on every axis but "
+                                "the minor one",
                             )
 
 
@@ -800,13 +803,13 @@ class PairedPoolGeometryRule(_ShapeRule):
     name = "paired-pool-geometry"
     bucket = "rtl804"
     description = (
-        "int8 K/V pool whose scale pool breaks the pool.shape[:-1] law, "
-        "is not float, or is skipped on a pool write"
+        "int8 K/V pool whose scale pool breaks the shared-leading-axes "
+        "law, is not float, or is skipped on a pool write"
     )
     rationale = (
         "int8 pools store per-token per-head scales in a mirror pool of "
-        "shape pool.shape[:-1] ([L, N, bs, H] against [L, N, bs, H, "
-        "D]). A scale pool with the wrong geometry scatters garbage "
+        "the same rank and leading axes ([L, N, bs, H] against the "
+        "lane-dense [L, N, bs, H*D]). A scale pool with the wrong geometry scatters garbage "
         "scales; an int dtype truncates them; and a block write or "
         "copy (CoW copy_block) that moves values without scales reads "
         "back at the wrong magnitude — all silent numeric corruption, "
@@ -817,18 +820,18 @@ class PairedPoolGeometryRule(_ShapeRule):
         import jax.numpy as jnp
 
         def build_pools(num_blocks, block_size, heads, head_dim):
-            shape = (4, num_blocks, block_size, heads, head_dim)
-            k_cache = jnp.zeros(shape, jnp.int8)
-            k_scale = jnp.zeros(shape[:2], jnp.bfloat16)
+            blocks = (4, num_blocks, block_size)
+            k_cache = jnp.zeros(blocks + (heads * head_dim,), jnp.int8)
+            k_scale = jnp.zeros(blocks[:2] + (heads,), jnp.bfloat16)
             return k_cache, k_scale
     """
     good_example = """
         import jax.numpy as jnp
 
         def build_pools(num_blocks, block_size, heads, head_dim):
-            shape = (4, num_blocks, block_size, heads, head_dim)
-            k_cache = jnp.zeros(shape, jnp.int8)
-            k_scale = jnp.zeros(shape[:-1], jnp.bfloat16)
+            blocks = (4, num_blocks, block_size)
+            k_cache = jnp.zeros(blocks + (heads * head_dim,), jnp.int8)
+            k_scale = jnp.zeros(blocks + (heads,), jnp.bfloat16)
             return k_cache, k_scale
     """
 

@@ -2462,7 +2462,7 @@ def test_paired_pool_scale_dtype_and_write_coverage():
         import jax.numpy as jnp
 
         def build(n, bs, h, d):
-            k_cache = jnp.zeros((2, n, bs, h, d), jnp.int8)
+            k_cache = jnp.zeros((2, n, bs, h * d), jnp.int8)
             k_scale = jnp.zeros((2, n, bs, h), jnp.int32)
             return k_cache, k_scale
     """
@@ -2481,6 +2481,28 @@ def test_paired_pool_scale_dtype_and_write_coverage():
             return k_cache, k_scale
     """
     assert "RTL804" not in rules_of(lint(guarded))
+
+
+@pytest.mark.parametrize(
+    "scale_shape, fires",
+    [
+        ("(2, n, bs, h)", False),      # the stored form: same rank
+        ("(2, n, bs)", True),          # a rank short
+        ("(2, n + 1, bs, h)", True),   # a leading axis that is not the pool's
+    ],
+)
+def test_paired_pool_shape_law_is_the_stored_form(scale_shape, fires):
+    """Pools are stored [L, N, bs, H*D] and scales [L, N, bs, H]: the same
+    rank, every axis but the minor one shared."""
+    src = f"""
+        import jax.numpy as jnp
+
+        def build(n, bs, h, d):
+            k_cache = jnp.zeros((2, n, bs, h * d), jnp.int8)
+            k_scale = jnp.zeros({scale_shape}, jnp.bfloat16)
+            return k_cache, k_scale
+    """
+    assert ("RTL804" in rules_of(lint(src))) is fires
 
 
 def test_paired_pool_unknown_geometry_stays_silent():

@@ -242,6 +242,15 @@ def test_scheduler_preempted_seq_folds_generated_into_prompt():
 # ---------------- paged attention op ----------------
 
 
+def _stored(pool, layer=1):
+    """A per-layer [N, bs, H, D] pool as the ops take it: [L, N, bs, H*D],
+    here at `layer` of two, the other layer loud."""
+    flat = pool.reshape(pool.shape[0], pool.shape[1], -1)
+    layers = [jnp.full_like(flat, 1e3)] * 2
+    layers[layer] = flat
+    return jnp.stack(layers)
+
+
 def test_paged_attention_matches_dense():
     rng = np.random.RandomState(0)
     bs, nblocks, nb, h, d = 4, 12, 3, 2, 8
@@ -253,8 +262,8 @@ def test_paged_attention_matches_dense():
     new_v = jnp.asarray(rng.randn(1, 1, h, d), jnp.float32)
     table = jnp.asarray([[5, 2, 7]], jnp.int32)
     out = paged_attention(
-        q, k_cache, v_cache, table, jnp.asarray([ctx], jnp.int32),
-        new_k=new_k, new_v=new_v,
+        q, _stored(k_cache), _stored(v_cache), table,
+        jnp.asarray([ctx], jnp.int32), new_k=new_k, new_v=new_v, layer=1,
     )
     # Dense equivalent: gather the context rows in order + the new token.
     k_seq = k_cache[table[0]].reshape(1, nb * bs, h, d)[:, :ctx]
@@ -281,8 +290,8 @@ def test_paged_attention_partial_prefill_matches_dense():
     new_v = jnp.asarray(rng.randn(1, s_new, h, d), jnp.float32)
     table = jnp.asarray([[5, 2, 0]], jnp.int32)  # padded past the prefix
     out = paged_attention(
-        q, k_cache, v_cache, table, jnp.asarray([ctx], jnp.int32),
-        new_k=new_k, new_v=new_v,
+        q, _stored(k_cache), _stored(v_cache), table,
+        jnp.asarray([ctx], jnp.int32), new_k=new_k, new_v=new_v, layer=1,
     )
     k_seq = k_cache[table[0]].reshape(1, nb * bs, h, d)[:, :ctx]
     v_seq = v_cache[table[0]].reshape(1, nb * bs, h, d)[:, :ctx]
@@ -571,7 +580,10 @@ def test_engine_int8_kv_cache_matches_reference_argmax():
         )
         assert eng.runner.k_cache.dtype == jnp.int8
         assert eng.runner.k_scale is not None
-        assert eng.runner.k_scale.shape == eng.runner.k_cache.shape[:-1]
+        # One scale per (token, head): the pools' minor axis is H*D.
+        assert eng.runner.k_cache.shape[:3] == eng.runner.k_scale.shape[:3]
+        assert eng.runner.k_cache.shape[3] == TINY.embed_dim
+        assert eng.runner.k_scale.shape[3] == TINY.num_heads
         got = eng.generate(prompts, max_new_tokens=4)
         assert got == want, f"int8 KV diverged from reference with {impl}"
         assert eng.stats()["kv_cache_dtype"] == "int8"

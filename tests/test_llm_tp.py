@@ -281,6 +281,98 @@ def test_tp2_runner_parity_multi_layer():
     assert r2.pool_sharding_spec() == HEAD_SPEC
 
 
+# ---------------- the stored pool form, tp = 1 and 2 ----------------
+
+DEEP = GPTConfig(
+    vocab_size=64,
+    num_layers=2,
+    num_heads=4,
+    embed_dim=32,
+    max_seq_len=128,
+    dtype=jnp.float32,
+    attention_impl="reference",
+)
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+def test_runner_stored_pool_heads_are_lane_groups(impl, tp):
+    """The pools are [L, N, bs, H*D]: head h of a token is lanes
+    h*D:(h+1)*D of its row, at every layer, whole or split over tp. A
+    full prefill writes exactly the K/V the model computes; a CoW copy
+    and a spill/restore carry it to other slots; decode and suffix
+    prefill through the table read the same tokens from the copies as
+    from the originals (two layers, so a layer index other than 0)."""
+    from ray_tpu.llm.model_runner import GPTRunner
+    from ray_tpu.models.gpt import collect_kv_caches
+
+    ecfg = EngineConfig(tensor_parallel_size=tp, attn_impl=impl, **BASE)
+    runner = GPTRunner(DEEP, ecfg, seed=0)
+    bs, slots = BASE["block_size"], BASE["max_decode_slots"]
+    assert runner.k_cache.shape == (
+        DEEP.num_layers, BASE["num_blocks"], bs, DEEP.embed_dim
+    )
+    prompt = random_prompts((8,), vocab=64, seed=11)[0]  # two full blocks
+    first = runner.prefill(prompt, [1, 2])
+    _, state = GPT(DEEP).apply(
+        runner.params, jnp.asarray([prompt]), return_kv=True,
+        mutable=["intermediates"],
+    )
+    kvs = collect_kv_caches(state["intermediates"], DEEP.num_layers)
+    for block, rows in ((1, slice(0, bs)), (2, slice(bs, 2 * bs))):
+        payload = runner.extract_block(block)
+        for layer, (k, v) in enumerate(kvs):
+            for got, want in ((payload["k"], k), (payload["v"], v)):
+                np.testing.assert_allclose(
+                    got[layer],
+                    np.asarray(want[0, rows]).reshape(bs, DEEP.embed_dim),
+                    atol=1e-5,
+                )
+    # Block 1 restored into slot 5, block 2 copied (CoW) into slot 6.
+    runner.restore_block(5, runner.extract_block(1))
+    runner.copy_block(2, 6)
+    assert runner.pool_sharding_spec() == (HEAD_SPEC if tp > 1 else None)
+
+    def decode_through(table):
+        toks = np.zeros(slots, np.int32)
+        pos, cl = np.zeros_like(toks), np.zeros_like(toks)
+        bt = np.zeros((slots, BASE["max_blocks_per_seq"]), np.int32)
+        toks[0], pos[0], cl[0], bt[0, :3] = first, 8, 8, table
+        return int(runner.decode(toks, pos, bt, cl)[0])
+
+    # The new token's K/V lands in the third block: a fresh one each time.
+    assert decode_through([1, 2, 3]) == decode_through([5, 6, 4])
+    # Suffix prefill over the cached first block, originals and copies.
+    assert runner.prefill_suffix(prompt[bs:], [1, 7], bs) == first
+    assert runner.prefill_suffix(prompt[bs:], [5, 8], bs) == first
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+def test_engine_token_identity_on_the_stored_pool(impl, tp):
+    """A two-layer engine through full prefill, decode, a prefix hit
+    (suffix prefill) and a fully cached prompt (CoW copy) stays
+    token-identical to the unbatched forward, with either attention
+    implementation, on one chip's pools and on pools split by heads."""
+    engine = LLMEngine(
+        DEEP,
+        EngineConfig(tensor_parallel_size=tp, attn_impl=impl, **BASE),
+        seed=0,
+    )
+    model = GPT(DEEP)
+    shared = random_prompts((8,), vocab=64, seed=12)[0]
+    prompts = [shared + [3, 1, 4], shared + [1, 5], shared]
+    want = [
+        reference_greedy(model, engine.runner.params, p, 5) for p in prompts
+    ]
+    assert engine.generate(prompts[:1], max_new_tokens=5) == want[:1]
+    # The shared blocks are cached now: the next two hit them, the last
+    # is cached in full and copies its last block before writing.
+    assert engine.generate(prompts[1:], max_new_tokens=5) == want[1:]
+    assert engine.stats()["prefix_cache_hit_tokens"] > 0
+    assert engine.scheduler.num_cow_blocks > 0
+
+
 # ---------------- pool bytes ----------------
 
 

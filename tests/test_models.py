@@ -134,11 +134,12 @@ def test_gpt_cache_carrying_forward(tiny_gpt):
     # cached step must match the full forward on prompt+token.
     block_size, num_blocks, nb_pad = 16, 8, 4
     n_blocks = s // block_size
-    shape = (cfg.num_layers, num_blocks, block_size, cfg.num_heads, cfg.head_dim)
+    # The stored form: heads and head size merged on the minor axis.
+    shape = (cfg.num_layers, num_blocks, block_size, cfg.embed_dim)
     k_cache, v_cache = jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
     blocks = jnp.arange(1, n_blocks + 1)
     for layer, (k, v) in enumerate(kvs):
-        paged = (n_blocks, block_size, cfg.num_heads, cfg.head_dim)
+        paged = (n_blocks, block_size, cfg.embed_dim)
         k_cache = k_cache.at[layer, blocks].set(k[0].reshape(paged))
         v_cache = v_cache.at[layer, blocks].set(v[0].reshape(paged))
     next_tok = jnp.argmax(logits_kv[0, s - 1]).astype(jnp.int32)

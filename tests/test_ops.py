@@ -12,6 +12,7 @@ from ray_tpu.ops import (
     flash_attention,
     mha_reference,
     paged_attention,
+    paged_attention_impl,
     paged_flash_attention,
     quantize_kv,
     ring_self_attention,
@@ -116,6 +117,43 @@ def test_packed_flash_single_subtile_odd_seq():
 
 # ---------------- fused paged attention (serving hot path) ----------------
 
+# The tests below state the mathematics in per-layer pools [N, bs, H, D]
+# (scales [N, bs, H]); the ops take the pools as the runner stores them,
+# [L, N, bs, H*D] (scales [L, N, bs, H]) read at `layer`. `_stored` puts a
+# per-layer pool at layer LAYER of three, with loud content in the other
+# two, so an op that read the wrong layer (or layer 0 always) fails every
+# comparison here.
+LAYER = 1
+
+
+def _stored(pool):
+    if pool is None:
+        return None
+    flat = pool.reshape(pool.shape[0], pool.shape[1], -1)
+    loud = jnp.full_like(flat, 77)
+    return jnp.stack([loud, flat, -loud])
+
+
+def _stored_call(op, q, k_cache, v_cache, *args, k_scale=None, v_scale=None,
+                 **kwargs):
+    return op(
+        q, _stored(k_cache), _stored(v_cache), *args, layer=LAYER,
+        k_scale=_stored(k_scale), v_scale=_stored(v_scale), **kwargs,
+    )
+
+
+def _paged_ref(*args, **kwargs):
+    return _stored_call(paged_attention, *args, **kwargs)
+
+
+def _paged_kernel(*args, **kwargs):
+    return _stored_call(paged_flash_attention, *args, **kwargs)
+
+
+def _paged_dispatch(*args, **kwargs):
+    return _stored_call(paged_attention_impl, *args, **kwargs)
+
+
 
 def _paged_case(seed, b, s, h=4, d=16, num_blocks=None, bs=4, nb=4):
     """Random paged-attention inputs: pools, 0-padded tables, new K/V."""
@@ -149,8 +187,8 @@ def test_paged_flash_decode_matches_reference(ctx_lens):
     exact block boundaries, and the full table."""
     q, kc, vc, tables, nk, nv = _paged_case(0, b=4, s=1)
     lens = jnp.asarray(ctx_lens, jnp.int32)
-    want = paged_attention(q, kc, vc, tables, lens, new_k=nk, new_v=nv)
-    got = paged_flash_attention(q, kc, vc, tables, lens, new_k=nk, new_v=nv)
+    want = _paged_ref(q, kc, vc, tables, lens, new_k=nk, new_v=nv)
+    got = _paged_kernel(q, kc, vc, tables, lens, new_k=nk, new_v=nv)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
@@ -159,8 +197,8 @@ def test_paged_flash_partial_prefill_matches_reference():
     the suffix tokens riding along as new_k/new_v."""
     q, kc, vc, tables, nk, nv = _paged_case(1, b=3, s=5)
     lens = jnp.asarray([9, 0, 16], jnp.int32)
-    want = paged_attention(q, kc, vc, tables, lens, new_k=nk, new_v=nv)
-    got = paged_flash_attention(q, kc, vc, tables, lens, new_k=nk, new_v=nv)
+    want = _paged_ref(q, kc, vc, tables, lens, new_k=nk, new_v=nv)
+    got = _paged_kernel(q, kc, vc, tables, lens, new_k=nk, new_v=nv)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
     # And against per-position dense attention (the oracle's own oracle).
     bsz = kc.shape[1]
@@ -196,10 +234,10 @@ def test_paged_flash_q_tiles_match_reference(monkeypatch, quantized):
         kc, ks = quantize_kv(kc)
         vc, vs = quantize_kv(vc)
         scales = {"k_scale": ks, "v_scale": vs}
-    want = paged_attention(
+    want = _paged_ref(
         q, kc, vc, tables, lens, new_k=nk, new_v=nv, **scales
     )
-    got = paged_flash_attention(
+    got = _paged_kernel(
         q, kc, vc, tables, lens, new_k=nk, new_v=nv, **scales
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
@@ -210,10 +248,10 @@ def test_paged_flash_null_padded_table_ignored():
     blocks must not read it: mutating block 0 cannot change the output."""
     q, kc, vc, tables, nk, nv = _paged_case(2, b=2, s=1)
     lens = jnp.asarray([6, 10], jnp.int32)
-    out1 = paged_flash_attention(q, kc, vc, tables, lens, new_k=nk, new_v=nv)
+    out1 = _paged_kernel(q, kc, vc, tables, lens, new_k=nk, new_v=nv)
     kc2 = kc.at[0].set(1e6)
     vc2 = vc.at[0].set(-1e6)
-    out2 = paged_flash_attention(q, kc2, vc2, tables, lens, new_k=nk, new_v=nv)
+    out2 = _paged_kernel(q, kc2, vc2, tables, lens, new_k=nk, new_v=nv)
     np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
 
 
@@ -227,7 +265,7 @@ def test_paged_attention_empty_context_returns_zeros():
     q = jnp.asarray(rng.randn(2, 1, 2, 8), jnp.float32)
     tables = jnp.asarray([[1, 2], [3, 0]], jnp.int32)
     lens = jnp.asarray([0, 5], jnp.int32)
-    out = paged_attention(q, kc, vc, tables, lens)
+    out = _paged_ref(q, kc, vc, tables, lens)
     assert np.all(np.asarray(out[0]) == 0.0)  # exact zeros, not garbage
     assert np.any(np.asarray(out[1]) != 0.0)  # live rows unaffected
 
@@ -241,16 +279,16 @@ def test_paged_flash_int8_matches_int8_reference():
     kq, ks = quantize_kv(kc)
     vq, vs = quantize_kv(vc)
     assert kq.dtype == jnp.int8 and ks.shape == kc.shape[:-1]
-    want = paged_attention(
+    want = _paged_ref(
         q, kq, vq, tables, lens, new_k=nk, new_v=nv, k_scale=ks, v_scale=vs
     )
-    got = paged_flash_attention(
+    got = _paged_kernel(
         q, kq, vq, tables, lens, new_k=nk, new_v=nv, k_scale=ks, v_scale=vs
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     # And the quantized result stays within quantization tolerance of the
     # exact f32 computation.
-    exact = paged_attention(q, kc, vc, tables, lens, new_k=nk, new_v=nv)
+    exact = _paged_ref(q, kc, vc, tables, lens, new_k=nk, new_v=nv)
     np.testing.assert_allclose(np.asarray(got), np.asarray(exact), atol=0.05)
 
 
@@ -279,10 +317,10 @@ def test_paged_flash_verify_shape_matches_reference(variant):
         vc, vs = quantize_kv(vc)
         kwargs = dict(k_scale=ks, v_scale=vs)
         atol = 2e-5
-    want = paged_attention(
+    want = _paged_ref(
         q, kc, vc, tables, lens, new_k=nk, new_v=nv, **kwargs
     )
-    got = paged_flash_attention(
+    got = _paged_kernel(
         q, kc, vc, tables, lens, new_k=nk, new_v=nv, **kwargs
     )
     np.testing.assert_allclose(
@@ -293,7 +331,7 @@ def test_paged_flash_verify_shape_matches_reference(variant):
     # depends on this to accept a prefix while rejecting the tail).
     nk2 = nk.at[:, -1].set(jnp.asarray(7.0, nk.dtype))
     nv2 = nv.at[:, -1].set(jnp.asarray(-7.0, nv.dtype))
-    got2 = paged_flash_attention(
+    got2 = _paged_kernel(
         q, kc, vc, tables, lens, new_k=nk2, new_v=nv2, **kwargs
     )
     np.testing.assert_array_equal(
@@ -322,7 +360,7 @@ def test_paged_flash_requires_new_kv():
     q, kc, vc, tables, nk, nv = _paged_case(6, b=1, s=1)
     lens = jnp.asarray([4], jnp.int32)
     with pytest.raises(ValueError, match="new_k/new_v"):
-        paged_flash_attention(q, kc, vc, tables, lens, new_k=None, new_v=None)
+        _paged_kernel(q, kc, vc, tables, lens, new_k=None, new_v=None)
     # Scales with non-int8 pools must raise in BOTH implementations —
     # silently dropping (kernel) or applying (reference) them would make
     # impl='auto' platform-dependent.
@@ -330,7 +368,7 @@ def test_paged_flash_requires_new_kv():
     _, vs = quantize_kv(vc)
     kq, _ = quantize_kv(kc)
     vq, _ = quantize_kv(vc)
-    for op in (paged_flash_attention, paged_attention):
+    for op in (_paged_kernel, _paged_ref):
         with pytest.raises(ValueError, match="non-int8"):
             op(
                 q, kc, vc, tables, lens, new_k=nk, new_v=nv,
@@ -341,22 +379,55 @@ def test_paged_flash_requires_new_kv():
             op(q, kq, vq, tables, lens, new_k=nk, new_v=nv)
 
 
+@pytest.mark.parametrize("variant", ["bf16", "int8"])
+@pytest.mark.parametrize("fed", [1, 5])
+@pytest.mark.parametrize("head_dim", [16, 64, 128])
+def test_paged_flash_lane_sliced_heads_match_reference(head_dim, fed, variant):
+    """The kernel reads head h as lanes h*D:(h+1)*D of a [bs, H*D] block
+    of the stored pool, at a layer other than 0: heads that share a lane
+    tile (16, and 64 at odd heads) and heads that are a whole tile (128),
+    decode (S == 1) and suffix prefill (S > 1), bf16 and int8."""
+    q, kc, vc, tables, nk, nv = _paged_case(21, b=3, s=fed, h=3, d=head_dim)
+    lens = jnp.asarray([9, 0, 16], jnp.int32)
+    q, nk, nv = (x.astype(jnp.bfloat16) for x in (q, nk, nv))
+    if variant == "int8":
+        kc, ks = quantize_kv(kc)
+        vc, vs = quantize_kv(vc)
+        scales = dict(k_scale=ks, v_scale=vs)
+    else:
+        kc, vc = kc.astype(jnp.bfloat16), vc.astype(jnp.bfloat16)
+        scales = {}
+    want = _paged_ref(q, kc, vc, tables, lens, new_k=nk, new_v=nv, **scales)
+    got = _paged_kernel(q, kc, vc, tables, lens, new_k=nk, new_v=nv, **scales)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32), atol=5e-2
+    )
+
+
+@pytest.mark.parametrize("op", ["reference", "kernel"])
+def test_paged_ops_reject_pools_not_in_the_stored_form(op):
+    """A per-layer [N, bs, H, D] pool (the form before the pools were
+    stored lane-dense) is refused by name, not read as four layers."""
+    q, kc, vc, tables, nk, nv = _paged_case(22, b=1, s=1)
+    fn = paged_attention if op == "reference" else paged_flash_attention
+    with pytest.raises(ValueError, match="stored form"):
+        fn(q, kc, vc, tables, jnp.asarray([4], jnp.int32), new_k=nk, new_v=nv)
+
+
 def test_paged_attention_impl_dispatcher():
     """impl='auto' takes the reference on CPU; 'pallas' forces the kernel
     (interpret mode here); both agree, unknown impls are rejected."""
-    from ray_tpu.ops import paged_attention_impl
-
     q, kc, vc, tables, nk, nv = _paged_case(7, b=2, s=1)
     lens = jnp.asarray([6, 3], jnp.int32)
-    auto = paged_attention_impl(
+    auto = _paged_dispatch(
         q, kc, vc, tables, lens, new_k=nk, new_v=nv, impl="auto"
     )
-    forced = paged_attention_impl(
+    forced = _paged_dispatch(
         q, kc, vc, tables, lens, new_k=nk, new_v=nv, impl="pallas"
     )
     np.testing.assert_allclose(np.asarray(forced), np.asarray(auto), atol=1e-5)
     with pytest.raises(ValueError, match="impl"):
-        paged_attention_impl(
+        _paged_dispatch(
             q, kc, vc, tables, lens, new_k=nk, new_v=nv, impl="cuda"
         )
 

@@ -6,9 +6,17 @@ compiler refuses passes there and fails at the first warmup on the chip
 (PR 21: every S > 1 shape of the paged kernel). These compile the real
 thing at gpt2_760m / gpt2_125m widths. Nothing runs, so they say nothing
 about results — the interpret-mode tests and chip_smoke.py do.
+
+The second half compiles the runner's own step programs at GPT-2 large
+widths (depth cut) and reads the compiled text: the KV pools are stored in
+the layout the paged kernel takes its operands in, so no program may hold a
+`copy` of a pool or temp the size of one (PR 25; before it the decode
+program converted both pools on the way into the kernel and back, seven
+whole-pool copies a step on the chip).
 """
 
 import os
+import re
 import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
@@ -18,6 +26,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from ray_tpu.llm.model_runner import _StepPrograms
+from ray_tpu.models.gpt import GPTConfig
 from ray_tpu.ops import flash_attention, paged_flash_attention
 from ray_tpu.ops.paged_flash import KV_SCALE_DTYPE
 
@@ -44,19 +54,23 @@ def chip():
     compilation_cache.reset_cache()
 
 
-def _paged(batch, fed, heads, pool_dtype=jnp.bfloat16):
+def _paged(batch, fed, heads, pool_dtype=jnp.bfloat16, head_dim=64):
     """(fn, shapes) for the paged kernel over the smoke's geometry: 1,024
-    blocks of 16 tokens, 64-block tables, heads of 64."""
+    blocks of 16 tokens, 64-block tables, heads of 64, the pools as stored
+    (two layers of lane-dense [16, H*D] blocks, read at layer 1)."""
 
     def fn(q, k_cache, v_cache, tables, lens, new_k, new_v, k_scale, v_scale):
         return paged_flash_attention(
             q, k_cache, v_cache, tables, lens, new_k=new_k, new_v=new_v,
-            k_scale=k_scale, v_scale=v_scale, interpret=False,
+            layer=1, k_scale=k_scale, v_scale=v_scale, interpret=False,
         )
 
-    q = ((batch, fed, heads, 64), jnp.bfloat16)
-    pool = ((1024, 16, heads, 64), pool_dtype)
-    scale = ((1024, 16, heads), KV_SCALE_DTYPE) if pool_dtype == jnp.int8 else None
+    q = ((batch, fed, heads, head_dim), jnp.bfloat16)
+    pool = ((2, 1024, 16, heads * head_dim), pool_dtype)
+    scale = (
+        ((2, 1024, 16, heads), KV_SCALE_DTYPE)
+        if pool_dtype == jnp.int8 else None
+    )
     return fn, [
         q, pool, pool, ((batch, 64), jnp.int32), ((batch,), jnp.int32),
         q, q, scale, scale,
@@ -79,8 +93,14 @@ CASES = {
     "paged_decode_bf16": lambda: _paged(8, 1, 20),
     "paged_decode_int8": lambda: _paged(8, 1, 20, jnp.int8),
     "paged_decode_tp_local_5_heads": lambda: _paged(8, 1, 5),
+    "paged_decode_tp_local_5_heads_int8": lambda: _paged(8, 1, 5, jnp.int8),
+    "paged_decode_16_slots": lambda: _paged(16, 1, 20),
+    "paged_decode_heads_of_128": lambda: _paged(8, 1, 8, head_dim=128),
+    "paged_verify_16_slots_4_fed": lambda: _paged(16, 4, 20),
     "paged_prefill_smallest_bucket": lambda: _paged(1, 16, 20),
+    "paged_prefill_64_bucket": lambda: _paged(1, 64, 20),
     "paged_prefill_largest_bucket": lambda: _paged(1, 256, 20),
+    "paged_prefill_largest_bucket_int8": lambda: _paged(1, 256, 20, jnp.int8),
     "flash_fwd_bwd_gpt2_125m": _flash_train,
 }
 
@@ -100,3 +120,93 @@ def test_kernel_compiles_for_v5e(chip, monkeypatch, case):
     ]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------- the runner's step programs: no copy of a pool ----------------
+
+# GPT-2 large widths (20 heads of 64, the real vocabulary), depth cut to 3;
+# the benchmark's geometry: 1,024 blocks of 16, 16 slots, 64-block tables.
+_LAYERS, _BLOCKS, _BLOCK, _HEADS, _HEAD_DIM = 3, 1024, 16, 20, 64
+_SLOTS, _TABLE = 16, 64
+
+
+def _step_program(programs, name):
+    """(jitted program, shapes of its arguments after params and pools)."""
+    i32 = lambda *shape: (shape, jnp.int32)  # noqa: E731
+    return {
+        "decode": (
+            programs.decode_fn,
+            [i32(_SLOTS), i32(_SLOTS), i32(_SLOTS, _TABLE), i32(_SLOTS)],
+        ),
+        "prefill_suffix": (
+            programs.prefill_suffix_fn, [i32(1, 64), i32(_TABLE), i32(), i32()],
+        ),
+        "prefill_full": (
+            programs.prefill_fn, [i32(1, 256), i32(256 // _BLOCK), i32()],
+        ),
+        "verify": (
+            programs.verify_fn,
+            [i32(_SLOTS, 4), i32(_SLOTS, _TABLE), i32(_SLOTS), i32(_SLOTS)],
+        ),
+    }[name]
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize(
+    "program", ["decode", "prefill_suffix", "prefill_full", "verify"]
+)
+def test_step_program_holds_no_copy_of_a_pool(
+    chip, monkeypatch, program, kv_dtype
+):
+    for module in ("ray_tpu.ops.flash_attention", "ray_tpu.ops.paged_flash"):
+        monkeypatch.setattr(sys.modules[module], "_on_cpu", lambda: False)
+    kv_dtype = jnp.dtype(kv_dtype)
+    cfg = GPTConfig(
+        num_layers=_LAYERS, num_heads=_HEADS, embed_dim=_HEADS * _HEAD_DIM
+    )
+    # Not through the process-wide program cache: these are traced with the
+    # kernels forced out of interpret mode.
+    programs = _StepPrograms(cfg, _BLOCK, "pallas", kv_dtype, 1)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype),
+        jax.eval_shape(
+            programs.model.init, jax.random.PRNGKey(0),
+            jnp.zeros((1, _BLOCK), jnp.int32),
+        ),
+    )
+    blocks = (_LAYERS, _BLOCKS, _BLOCK)
+    pool = on_chip(blocks + (_HEADS * _HEAD_DIM,), kv_dtype)
+    scale = (
+        on_chip(blocks + (_HEADS,), KV_SCALE_DTYPE)
+        if kv_dtype == jnp.int8 else None
+    )
+    fn, rest = _step_program(programs, program)
+    compiled = fn.lower(
+        params, pool, pool, scale, scale, *(on_chip(*s) for s in rest)
+    ).compile()
+    text = compiled.as_text()
+    if program != "prefill_full":  # full prefill reads no cache
+        assert "tpu_custom_call" in text
+    # Wherever the program names an array of the pools' shape, arguments
+    # and results included, it is in the kernel's layout: row-major.
+    stored = re.escape(
+        f"[{_LAYERS},{_BLOCKS},{_BLOCK},{_HEADS * _HEAD_DIM}]"
+    )
+    layouts = set(re.findall(stored + r"\{([\d,]+)", text))
+    assert layouts == {"3,2,1,0"}, layouts
+    pool_copies = [
+        line.strip()[:200] for line in text.splitlines()
+        if re.search(r"= \w+" + stored + r"\S* copy\(", line)
+    ]
+    assert not pool_copies, pool_copies
+    # Temp stays under ONE layer of one pool, beside the compute-dtype copy
+    # of the embedding table every program makes (float32 weights, bf16
+    # compute): before PR 25 it was several whole pools.
+    layer_pool_bytes = _BLOCKS * _BLOCK * _HEADS * _HEAD_DIM * kv_dtype.itemsize
+    wte_bytes = cfg.vocab_size * cfg.embed_dim * jnp.dtype(cfg.dtype).itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < wte_bytes + layer_pool_bytes, (temp, wte_bytes)
