@@ -35,6 +35,11 @@ def place_cache() -> str:
 
     jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
     jax.config.update("jax_compilation_cache_max_size", -1)
+    # Every program is written, however short its compilation: a program near
+    # JAX's default threshold of a second would be written by whichever run
+    # happened to compile it slowly, and "a warm run compiles nothing" could
+    # not be held exactly.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return CACHE_DIR
 
 
@@ -105,6 +110,8 @@ class CompileCounter:
     must see none."""
 
     EVENT = "/jax/core/compile/backend_compile_duration"
+    CACHE_HIT = "/jax/compilation_cache/cache_hits"
+    CACHE_MISS = "/jax/compilation_cache/cache_misses"
 
     def __init__(self):
         import jax.monitoring
@@ -112,7 +119,18 @@ class CompileCounter:
         self._lock = threading.Lock()
         self.count = 0
         self.seconds = 0.0
+        self.cache_hits = 0  # programs read from the cache
+        self.cache_misses = 0  # programs compiled and written to the cache
         jax.monitoring.register_event_duration_secs_listener(self._on_event)
+        jax.monitoring.register_event_listener(self._on_cache)
+
+    def _on_cache(self, event: str, **_) -> None:
+        if event in (self.CACHE_HIT, self.CACHE_MISS):
+            with self._lock:
+                if event == self.CACHE_HIT:
+                    self.cache_hits += 1
+                else:
+                    self.cache_misses += 1
 
     def _on_event(self, event: str, duration: float, **_) -> None:
         if event == self.EVENT:

@@ -14,7 +14,8 @@ conversation's start into the prefix cache); then one line on standard
 output, `{"event": "clock", "start": t, "open": t + lead_in, "close": ...}`;
 then the run's requests from `start`. An open-loop request is sent when it is
 due, whatever has come back. A closed-loop caller sends its next request when
-its last one has completed. At `close` every request still in flight is
+its last one has completed; the log says how many requests each caller had
+not yet begun at the close (the closed loop's margin). At `close` every request still in flight is
 cancelled by closing its connection, the log is written and the process
 exits.
 
@@ -127,6 +128,7 @@ async def replay(schedule: dict, url: str, run: str) -> dict:
 
     tasks = []
     ran_dry = []
+    left: dict = {}  # closed loop: requests each caller has not yet begun
     if schedule["loop"] == "open":
         async def fire():
             for request in schedule["requests"]:
@@ -143,10 +145,12 @@ async def replay(schedule: dict, url: str, run: str) -> dict:
         async def caller(client: int, queue: list):
             await sleep_until(start + client * CLOSED_LOOP_STAGGER_S)
             for request in queue:
+                left[client] -= 1
                 await one(target, request, time.monotonic(), records, run)
             ran_dry.append(client)
 
         for client, queue in sorted(queues.items()):
+            left[client] = len(queue)
             tasks.append(asyncio.ensure_future(caller(client, queue)))
 
     await sleep_until(close_at)
@@ -157,6 +161,7 @@ async def replay(schedule: dict, url: str, run: str) -> dict:
     return {
         "start": start, "open": open_at, "close": close_at, "closed": closed,
         "loop": schedule["loop"], "callers_that_ran_dry": sorted(ran_dry),
+        "requests_left_by_caller": [left[c] for c in sorted(left)],
         "records": records,
     }
 

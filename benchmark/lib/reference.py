@@ -37,14 +37,32 @@ def _layer_norm(x, p, dtype):
     return (y * p["scale"] + p["bias"]).astype(dtype)
 
 
-def _dense(x, p, dtype):
-    return x @ p["kernel"].astype(dtype) + p["bias"].astype(dtype)
+def _int8(x, axis: int):
+    """What a symmetric int8 path keeps of `x`, one scale along `axis`."""
+    import jax.numpy as jnp
+
+    scale = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / 127.0
+    scale = jnp.where(scale > 0, scale, 1.0)
+    return jnp.round(x / scale) * scale
 
 
-def gpt2_forward(params, tokens, num_layers: int, num_heads: int, dtype=None):
+def _dense(x, p, dtype, int8: bool = False):
+    import jax.numpy as jnp
+
+    kernel = p["kernel"]
+    if int8:  # a scale for each token's activations and each output channel
+        x = _int8(x.astype(jnp.float32), -1).astype(dtype)
+        kernel = _int8(kernel, 0)
+    return x @ kernel.astype(dtype) + p["bias"].astype(dtype)
+
+
+def gpt2_forward(params, tokens, num_layers: int, num_heads: int, dtype=None,
+                 int8: bool = False):
     """Logits [batch, seq, vocab] of `tokens` [batch, seq]. `dtype` is the
     type the matmuls run in: float32 for the reference, the serving type to
-    see how far its rounding alone moves a logit."""
+    see how far its rounding alone moves a logit. `int8` is the control: the
+    weights and every dense layer's input pass through int8 (the nearest
+    precision below the bfloat16 the configuration states)."""
     import flax.linen as nn
     import jax
     import jax.numpy as jnp
@@ -52,7 +70,8 @@ def gpt2_forward(params, tokens, num_layers: int, num_heads: int, dtype=None):
     dtype = dtype or jnp.float32
     p = nn.meta.unbox(params)["params"]
     batch, seq = tokens.shape
-    wte = p["wte"]["embedding"].astype(dtype)
+    wte = p["wte"]["embedding"]
+    wte = (_int8(wte, -1) if int8 else wte).astype(dtype)
     x = wte[tokens] + p["wpe"]["embedding"].astype(dtype)[jnp.arange(seq)][None]
     width = x.shape[-1]
     head = width // num_heads
@@ -60,16 +79,16 @@ def gpt2_forward(params, tokens, num_layers: int, num_heads: int, dtype=None):
     for i in range(num_layers):
         block = p[f"h_{i}"]
         h = _layer_norm(x, block["ln_1"], dtype)
-        q, k, v = jnp.split(_dense(h, block["attn_qkv"], dtype), 3, axis=-1)
+        q, k, v = jnp.split(_dense(h, block["attn_qkv"], dtype, int8), 3, axis=-1)
         q, k, v = (t.reshape(batch, seq, num_heads, head) for t in (q, k, v))
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
         scores = jnp.where(causal, scores / head**0.5, -jnp.inf)
         weights = jax.nn.softmax(scores, axis=-1).astype(dtype)
         mixed = jnp.einsum("bhqk,bkhd->bqhd", weights, v).reshape(batch, seq, width)
-        x = x + _dense(mixed, block["attn_proj"], dtype)
+        x = x + _dense(mixed, block["attn_proj"], dtype, int8)
         h = _layer_norm(x, block["ln_2"], dtype)
-        h = jax.nn.gelu(_dense(h, block["mlp_in"], dtype), approximate=True)
-        x = x + _dense(h, block["mlp_out"], dtype)
+        h = jax.nn.gelu(_dense(h, block["mlp_in"], dtype, int8), approximate=True)
+        x = x + _dense(h, block["mlp_out"], dtype, int8)
     x = _layer_norm(x, p["ln_f"], dtype)
     return (x @ wte.T).astype(jnp.float32)
 
@@ -83,17 +102,18 @@ class ServingReference:
         self.padded_len = padded_len
         self._params = params
 
-        def forward(dtype):
+        def forward(dtype, int8=False):
             def run(params, tokens):
                 with jax.default_matmul_precision("highest"):
                     return gpt2_forward(
                         params, tokens, model_cfg.num_layers,
-                        model_cfg.num_heads, dtype,
+                        model_cfg.num_heads, dtype, int8,
                     )[0]
             return jax.jit(run)
 
         self._exact = forward(None)
         self._noisy = forward(model_cfg.dtype)
+        self._control = forward(model_cfg.dtype, int8=True)
 
     def _padded(self, tokens):
         import numpy as np
@@ -121,6 +141,7 @@ class ServingReference:
             "tokens": int(len(answer)),
             "flipped": int((gaps > 0).sum()),
             "worst_gap": float(gaps.max()),
+            "gap_sum": float(gaps.sum()),
         }
         if noise:
             # How far the same dense forward in the serving type moves a
@@ -128,6 +149,22 @@ class ServingReference:
             moved = np.asarray(self._noisy(self._params, fed))[positions]
             verdict["bf16_logit_noise"] = float(np.abs(moved - rows).max())
         return verdict
+
+    def control_gaps(self, prompt, answer) -> dict:
+        """The control's reading of `worst_gap` and `gap_sum` on the same
+        prompt and tokens: at each position, how far the token that the int8
+        forward puts first lies below the reference's best. It need not
+        decode."""
+        import numpy as np
+
+        tokens = list(prompt) + list(answer)
+        fed = self._padded(tokens[:-1])
+        positions = slice(len(prompt) - 1, len(tokens) - 1)
+        rows = np.asarray(self._exact(self._params, fed))[positions]
+        picks = np.asarray(self._control(self._params, fed))[positions].argmax(axis=-1)
+        gaps = rows.max(axis=-1) - rows[np.arange(len(picks)), picks]
+        return {"tokens": int(len(picks)), "flipped": int((gaps > 0).sum()),
+                "worst_gap": float(gaps.max()), "gap_sum": float(gaps.sum())}
 
 
 def training_reference_step(model_cfg, tx, rows: int, dtype=None):

@@ -80,10 +80,14 @@ def reduce_log(log: dict) -> dict:
         "itl_samples": len(gaps_ms),
         "itl_p50_ms": percentile(gaps_ms, 50.0),
         "itl_p90_ms": percentile(gaps_ms, 90.0),
+        "itl_p95_ms": percentile(gaps_ms, 95.0),
         "completed_tokens_per_s": tokens_in_window / seconds,
         "prompt_tokens_sent": sum(r["prompt_tokens"] for r in mine),
         "generator_lag_p99_ms": percentile(lag_ms, 99.0),
         "generator_lag_max_ms": max(lag_ms, default=None),
         "window_s": seconds,
         "callers_that_ran_dry": log.get("callers_that_ran_dry", []),
+        # Closed loop: the fewest requests any caller had not yet begun when
+        # the window closed, which is how far the mix is from running dry.
+        "fewest_requests_left": min(log.get("requests_left_by_caller") or [None]),
     }

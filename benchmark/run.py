@@ -25,6 +25,11 @@ none of the cell's per-layer readers found a value.
                       (or client count) after another, to find the knee; no
                       result line
     --reference-seed  weights of the reference, to show `correct` go false
+    --control         serving cells: also put the int8 control's logit gaps on
+                      the same prompts and tokens, and the sample with one
+                      served token altered, through the comparison that decides
+                      `correct` (a `control` info line); exits 3 where either
+                      comes out correct. The driver never asks for it
 
 See benchmark/README.md for how a cell, a configuration, a mix or a per-layer
 metric is added as files.
@@ -39,6 +44,7 @@ PROCESS_START = time.monotonic()
 import argparse  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
+import logging  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 import types  # noqa: E402
@@ -55,10 +61,10 @@ TIME_SUFFIXES = ("_s", "_ms", "_per_s", "_share", "_mfu", "_by_third")
 def _not_measured(value, key: str = ""):
     """A rehearsal's clock times the CPU backend and the interpreter: no
     time, rate or share leaves it under any name."""
-    if isinstance(value, dict):
-        return {k: _not_measured(v, k) for k, v in value.items()}
     if key.endswith(TIME_SUFFIXES) and value is not None:
         return "not measured"
+    if isinstance(value, dict):
+        return {k: _not_measured(v, k) for k, v in value.items()}
     return value
 
 
@@ -83,6 +89,7 @@ def main(argv=None) -> int:
     parser.add_argument("--rehearse", action="store_true")
     parser.add_argument("--sweep", default="")
     parser.add_argument("--reference-seed", type=int, default=None)
+    parser.add_argument("--control", action="store_true")
     args = parser.parse_args(argv)
 
     # Everything that can be wrong without a chip is found before one is
@@ -127,7 +134,7 @@ def main(argv=None) -> int:
             trace=bool(args.trace), rehearse=args.rehearse,
             sweep=[float(v) for v in args.sweep.split(",") if v],
             reference_seed=args.seed if args.reference_seed is None else args.reference_seed,
-            device=found, out_dir=out_dir,
+            control=args.control, device=found, out_dir=out_dir,
             bench_dir=HERE, emit=emit, compiles=device.CompileCounter(),
         )
         result = load_runner(cell["config_file"]["runner"]).run(ctx)
@@ -135,9 +142,15 @@ def main(argv=None) -> int:
             return 0
 
         collected = result["collected"]
-        collected["setup_s"] = result["window_open"] - PROCESS_START
+        # Process start to the window's opening, less what the runner says
+        # `setup_s` leaves out (serving: the compile step, README.md).
+        excluded = result.get("setup_excluded_s", 0.0)
+        collected["setup_s"] = result["window_open"] - PROCESS_START - excluded
         trace = collected.get("trace")
-        report = {**found, "memory_peak_bytes": device.memory_peak_bytes(cell["chips"])}
+        # Read by the runner before its reference ran on the chip, where it
+        # can; a process's peak never falls again.
+        peak = collected.get("memory_peak_bytes") or device.memory_peak_bytes(cell["chips"])
+        report = {**found, "memory_peak_bytes": peak}
         missing = []
         if args.trace:
             metrics = layer_metrics.read_all(mine["per_layer"], collected)
@@ -168,7 +181,14 @@ def main(argv=None) -> int:
         }
         if trace is not None:
             final["breakdown"] = trace["breakdown"]
-        emit("summary", setup_s=collected["setup_s"], problems=result["problems"],
+        # Each number compared beside its limit, last on the line and last
+        # on standard error: what the driver keeps of a run that is not correct.
+        final["compared"] = {
+            name: {"value": value, "limit": limit}
+            for name, (value, limit) in result.get("compared", {}).items()
+        }
+        emit("summary", setup_s=collected["setup_s"], setup_excluded_s=excluded,
+             problems=result["problems"],
              compiles_total=ctx.compiles.count,
              compile_cache_entries=device.cache_entries(),
              trace_modules=(trace or {}).get("modules"), lines=lines_path,
@@ -176,6 +196,8 @@ def main(argv=None) -> int:
     if args.rehearse:
         print(json.dumps({"info": "rehearsal_done", **tag,
                           "note": "a CPU rehearsal proves no chip run",
+                          "correct": final["correct"], "problems": result["problems"],
+                          "passed_that_must_fail": result.get("passed_that_must_fail", []),
                           "would_report": sorted(final["metrics"])}), flush=True)
         return 0
     if collected["compiles_in_window"]:
@@ -186,7 +208,21 @@ def main(argv=None) -> int:
         sys.exit(f"benchmark: metrics without a value: {missing or metrics}")
     if missing:
         print(f"benchmark: per-layer metrics left out: {missing}", file=sys.stderr)
+    # The proxy's connection tasks that outlive `serve.shutdown` are reported
+    # by asyncio as the interpreter exits, after these lines and many times
+    # their length: that one message is kept off standard error, and nothing
+    # else, so that these stay its last.
+    logging.getLogger("asyncio").addFilter(
+        lambda record: "Task was destroyed but it is pending" not in record.getMessage()
+    )
+    for name, pair in final["compared"].items():
+        print(f"compared {name} {pair['value']} limit {pair['limit']}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(final), flush=True)
+    if result.get("passed_that_must_fail"):
+        print(f"benchmark: came out correct and must not: {result['passed_that_must_fail']}",
+              file=sys.stderr)
+        return 3
     return 0
 
 

@@ -257,6 +257,11 @@ def run(ctx) -> dict:
     return {
         "correct": agrees and finite and falling and not problems,
         "problems": problems,
+        "compared": {
+            "loss_distance": [max(loss_distances), correctness["loss_tolerance"]],
+            "gradient_distance": [gradient_distance, correctness["gradient_tolerance"]],
+            "compiles_in_window": [bench["compiles_in_window"], 0],
+        },
         "attempted": steps,
         "failed": 0 if finite else sum(1 for loss in losses if loss != loss),
         "window_open": bench["t_open"],

@@ -39,8 +39,24 @@ def test_every_cell_finds_its_files_and_its_readers(loaded):
             )
 
 
+def test_pr_28s_cells_are_there_and_what_it_retired_is_gone(loaded):
+    cells = {w["name"] for w in loaded["workloads"]}
+    assert {"gpt2-large.chat-sessions-loaded", "gpt2-large.batch-unshared",
+            "gpt2-small-train.packed-1k"} <= cells
+    assert "gpt2-large.chat-sessions" not in cells
+    names = {m["name"] for m in loaded["per_layer"]}
+    assert not names & {"host_gap_mean_ms", "tput_host_gap_mean_ms"}
+
+
+def test_a_per_layer_metric_lists_only_cells_that_report_what_it_moves(loaded):
+    for m in loaded["per_layer"]:
+        moved = next(e for e in loaded["end_to_end"] if e["name"] == m["moves"])
+        cells = moved.get("workloads", [w["name"] for w in loaded["workloads"]])
+        assert set(m["workloads"]) <= set(cells), m["name"]
+
+
 def test_gpt2_large_is_36_layers_at_published_widths(loaded):
-    config = manifest.cell(loaded, "gpt2-large.chat-sessions")["config_file"]
+    config = manifest.cell(loaded, "gpt2-large.chat-sessions-loaded")["config_file"]
     model, published = config["model"], config["published"]
     assert (model["num_layers"], model["embed_dim"], model["num_heads"]) == (36, 1280, 20)
     assert (published["n_layer"], published["n_embd"], published["n_head"]) == (36, 1280, 20)

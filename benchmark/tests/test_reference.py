@@ -103,3 +103,40 @@ def test_judge_accepts_the_greedy_answer_and_refuses_another():
     other = GPT(CFG).init(jax.random.PRNGKey(9), tokens)
     assert not ServingReference(CFG, other, 64).judge(prompt, answer, tolerance=1e-4)["ok"]
     assert np.isfinite(good["worst_gap"])
+
+
+# ---------------- PR 28: the control, the reference one precision lower ----------------
+
+
+def test_the_int8_control_fails_where_bfloat16_passes():
+    """The control of the serving check at a size a test holds: GPT-2 widths
+    cut to 4 layers of 256, 128 positions of seeded text. At each position
+    the token a forward puts first is held against the float32 reference's
+    best. bfloat16 (what the configuration states) flips near ties only;
+    int8 (the nearest precision below) flips more and wider ones, so a limit
+    between the two readings passes the one and fails the other."""
+    cfg = GPTConfig(
+        vocab_size=2048, num_layers=4, num_heads=4, embed_dim=256, max_seq_len=128,
+        dtype=jnp.bfloat16, attention_impl="reference",
+    )
+    served, control = [], []
+    for seed in (0, 1, 2):
+        params = jax.jit(GPT(cfg).init)(jax.random.PRNGKey(seed), jnp.zeros((1, 16), jnp.int32))
+        reference = ServingReference(cfg, params, 128)
+        text = np.random.RandomState(seed).randint(1, 2048, size=(1, 128)).astype(np.int32)
+        exact = np.asarray(reference._exact(params, text))
+        for forward, readings in ((reference._noisy, served), (reference._control, control)):
+            picks = np.asarray(forward(params, text)).argmax(axis=-1)
+            gaps = exact.max(axis=-1) - exact[np.arange(128), picks]
+            readings.append((float(gaps.max()), float(gaps.mean())))
+        # `control_gaps` reads the same statistic over a request's answer.
+        prompt, answer = list(text[0, :40]), list(text[0, 40:])
+        mine = reference.control_gaps(prompt, answer)
+        assert mine["tokens"] == 88 and 0.0 <= mine["worst_gap"] <= control[-1][0]
+    lower = max(mean for _, mean in served)     # 5.4e-5 when written
+    upper = min(mean for _, mean in control)    # 2.6e-4
+    assert upper > 3 * lower, (served, control)
+    limit = (lower * upper) ** 0.5
+    assert all(mean < limit for _, mean in served)
+    assert all(mean > limit for _, mean in control)
+    assert min(worst for worst, _ in control) > max(worst for worst, _ in served)

@@ -45,3 +45,31 @@ def test_a_closed_loops_queue_is_not_a_failure():
     records = [record("c", 101.0, [], status="cancelled")]
     assert reduce_log(log(records, loop="closed"))["failed"] == 0
     assert reduce_log(log(records, loop="open"))["failed"] == 1
+
+
+def test_closed_loops_margin_is_the_fewest_requests_a_caller_had_left():
+    records = [record("a", 100.0, [100.2, 100.3])]
+    closed = dict(log(records, loop="closed"), requests_left_by_caller=[52, 49, 51])
+    assert reduce_log(closed)["fewest_requests_left"] == 49
+    assert reduce_log(log(records))["fewest_requests_left"] is None  # an open loop has none
+
+
+def test_gap_percentiles_and_their_per_layer_readers():
+    """The median gap is the loaded chat cell's end-to-end metric; p90 and
+    p95 of the same gaps are read per layer from the `client` line."""
+    from lib import layer_metrics
+
+    # One request, 101 tokens: 80 gaps of 20 ms, 10 of 35 ms, 10 of 52 ms, as
+    # the loaded cell's two modes lie (PERF.md section 2).
+    gaps = [0.020] * 80 + [0.035] * 10 + [0.052] * 10
+    times = [100.0]
+    for gap in gaps:
+        times.append(times[-1] + gap)
+    out = reduce_log(log([record("a", 100.0, times)]))
+    assert out["itl_samples"] == 100
+    assert out["itl_p50_ms"] == pytest.approx(20.0)
+    assert out["itl_p90_ms"] == pytest.approx(35.0 + 0.1 * 17.0)
+    assert out["itl_p95_ms"] == pytest.approx(52.0)
+    read = layer_metrics.read_all(["token_gap_p90_ms", "token_gap_p95_ms"], {"client": out})
+    assert read == {"token_gap_p90_ms": out["itl_p90_ms"], "token_gap_p95_ms": out["itl_p95_ms"]}
+    assert layer_metrics.read("token_gap_p95_ms", {"client": {}}) is None

@@ -6,7 +6,8 @@ import pytest
 from lib import traffic
 from lib.manifest import BENCH
 
-MIXES = ("chat-sessions", "batch-unshared")
+MIXES = ("chat-sessions-loaded", "batch-unshared")
+CHAT = "chat-sessions-loaded"
 
 
 def mix(name):
@@ -49,7 +50,7 @@ def test_every_request_fits_the_engine(name):
 
 
 def test_chat_sessions_share_the_prefix_and_grow():
-    m = mix("chat-sessions")
+    m = mix(CHAT)
     schedule = traffic.generate(m, 1, 45.0, 50257, 1024)
     prefix = schedule["requests"][0]["prompt_ids"][: m["shared_prefix"]]
     assert all(r["prompt_ids"][: m["shared_prefix"]] == prefix for r in schedule["requests"])
@@ -85,7 +86,7 @@ def test_unshared_prompts_share_no_block():
 
 
 def test_scaled_keeps_requests_inside_a_toy_context():
-    toy = traffic.scaled(mix("chat-sessions"), 128 / 1024)
+    toy = traffic.scaled(mix(CHAT), 128 / 1024)
     schedule = traffic.generate(toy, 1, 5.0, 512, 128)
     assert max(len(r["prompt_ids"]) + r["max_new_tokens"] for r in schedule["requests"]) <= 128
 
@@ -97,3 +98,90 @@ def test_step_batches_repeat_for_a_seed():
     first = next(a)
     assert first.shape == (2, 8) and (first == next(b)).all() and not (first == next(c)).all()
     assert not (first == next(a)).all()
+
+
+# ---------------- PR 28: the closed loop's room and the loaded chat mix ----------------
+
+
+def test_closed_loop_at_64_a_caller_begins_with_the_576_requests_of_12():
+    """The draw is place-major from one generator, so a longer queue only
+    appends: the work at an unchanged speed is the work measured before."""
+    m = mix("batch-unshared")
+    assert m["requests_per_client"] == 64
+    schedule = traffic.generate(m, 1, 45.0, 50257, 1024)
+    before = traffic.generate(dict(m, requests_per_client=12), 1, 45.0, 50257, 1024)
+    assert len(before["requests"]) == 576
+    assert traffic.fingerprint(before)[:16] == "653802896675cee6"  # as drawn at PR 22
+    assert traffic.fingerprint({**schedule, "requests": schedule["requests"][:576]}) \
+        == traffic.fingerprint(before)
+
+
+def test_closed_loop_cannot_run_dry_below_2500_tokens_per_s():
+    """With callers served alike, the caller with the fewest answer tokens
+    finishes its queue first: at 48 x its tokens over lead-in + window."""
+    m = mix("batch-unshared")
+    schedule = traffic.generate(m, 1, 45.0, 50257, 1024)
+    queued = {}
+    for r in schedule["requests"]:
+        queued[r["client"]] = queued.get(r["client"], 0) + r["max_new_tokens"]
+    dry_at = m["clients"] * min(queued.values()) / (m["lead_in_s"] + 45.0)
+    assert min(queued.values()) == 3802 and "3,802" in m["what"]
+    assert round(dry_at) == 3443 and "3,443" in m["what"]
+    assert dry_at > 2500
+
+
+def _realised(m, schedule_seed, seconds=45.0):
+    schedule = traffic.generate(dict(m, schedule_seed=schedule_seed), 1, seconds, 50257, 1024)
+    lead, third = m["lead_in_s"], seconds / 3
+    due = [r["due_s"] for r in schedule["requests"]]
+    window = [r for r in schedule["requests"] if r["due_s"] >= lead]
+    return {
+        "lead_in": sum(d < lead for d in due),
+        "thirds": [sum(lead + i * third <= d < lead + (i + 1) * third for d in due)
+                   for i in range(3)],
+        "answer_mean": sum(r["max_new_tokens"] for r in window) / len(window),
+        "prompt_mean": sum(len(r["prompt_ids"]) for r in window) / len(window),
+    }
+
+
+def test_loaded_chat_realises_within_5_percent_of_its_expectations():
+    """`schedule_seed` is the first seed whose 45 s realisation is typical:
+    arrivals by the rate, lengths by the mean over 64 other seeds."""
+    m = mix(CHAT)
+    rate = m["arrivals"]["rate_per_s"]
+    many = [_realised(m, seed) for seed in range(100, 164)]
+    expect_answer = sum(r["answer_mean"] for r in many) / len(many)
+    expect_prompt = sum(r["prompt_mean"] for r in many) / len(many)
+
+    def typical(r):
+        counts = [r["lead_in"] / (rate * m["lead_in_s"])] + [t / (rate * 15.0) for t in r["thirds"]]
+        lengths = [r["answer_mean"] / expect_answer, r["prompt_mean"] / expect_prompt]
+        return all(abs(x - 1.0) <= 0.05 for x in counts + lengths)
+
+    assert typical(_realised(m, m["schedule_seed"]))
+    assert not any(typical(_realised(m, seed)) for seed in range(m["schedule_seed"]))
+
+
+def _blocks_held(m, schedule, block=16):
+    """Blocks that keep every conversation's longest prompt + answer of the
+    run cached: the shared prompt's once, each conversation's own beyond."""
+    shared = m["shared_prefix"] // block
+    longest = {}
+    for r in schedule["prime"] + schedule["requests"]:
+        total = len(r["prompt_ids"]) + r["max_new_tokens"]
+        longest[r["session"]] = max(longest.get(r["session"], 0), total)
+    return shared + sum(-(-n // block) - shared for n in longest.values())
+
+
+def test_loaded_chat_fits_the_cache_as_the_24_sessions_fit_1024_blocks():
+    with open(os.path.join(BENCH, "configs", "gpt2-large.json")) as f:
+        blocks = json.load(f)["engine"]["num_blocks"]
+    m = mix(CHAT)
+    held = _blocks_held(m, traffic.generate(m, 1, 45.0, 50257, 1024))
+    # What PR 22's cell had: 24 sessions at 1.5/s (schedule seed 16) in 1,024 blocks.
+    old = dict(m, sessions=24, schedule_seed=16,
+               arrivals=dict(m["arrivals"], rate_per_s=1.5))
+    old_share = _blocks_held(old, traffic.generate(old, 1, 45.0, 50257, 1024)) / 1024
+    # 2,363 of 3,072 (77%) against 757 of 1,024 (74%): over a fifth stays free.
+    assert held <= 0.8 * blocks
+    assert abs(held / blocks - old_share) <= 0.05, (held / blocks, old_share)
