@@ -13,11 +13,11 @@ K/V ride-along) and report per-step wall time plus two bandwidth views:
   * touched GB — what each implementation actually moves. The reference's
     `k_cache[block_tables]` writes the full padded [B, nb*bs, H, D] gather
     to HBM (then reads it back for the matmul), independent of how short
-    each sequence really is. The fused kernel still STREAMS one K and one
-    V block per grid step — padded slots stream the null block (the
-    data-dependent skip covers compute, not the pipeline's copies) — but
-    HBM→VMEM once each, never writing a gathered copy back; its touched
-    bytes are the padded read, roughly half the reference's write+read.
+    each sequence really is. The fused kernel copies only the table
+    entries a sequence's context reaches (whole blocks: the context rounded
+    up to the block size), HBM→VMEM once each, eight or sixteen blocks a
+    128-token compute block, never writing a gathered copy back; what a
+    table holds past the context, and a padded slot, cost no copy at all.
 
 Run:  python benchmarks/profile_attn_paged.py [--quick] [--json-out PATH]
       [--impl pallas|reference|both] [--int8] [--tp N]
@@ -123,12 +123,13 @@ def run_config(
         + 2 * 2 * b * nb * bs * h * d * elem
         + 4 * b * s * h * d * elem  # q, new_k, new_v, out (pool read above)
     )
-    # Bytes the kernel STREAMS: one K + one V block per grid step — all
-    # nb + 1 steps per row, padded slots included (their compute is
-    # skipped but the pipeline's block copies still run, through the null
-    # block) — read once into VMEM, never written back.
+    # Bytes the kernel COPIES: the K and V blocks each row's context
+    # reaches (the context rounded up to whole blocks), read once into
+    # VMEM, never written back. (int8 scales come through an XLA gather of
+    # the whole table width, written and read back: small beside the pool.)
     pallas_touched = (
-        2 * b * (nb + 1) * bs * h * (d * kv_elem + scale_b)
+        2 * b * math.ceil(ctx / bs) * bs * h * d * kv_elem
+        + 3 * 2 * b * nb * bs * h * scale_b
         + 4 * b * s * h * d * elem
     )
     tp = mesh.shape["tp"] if mesh is not None else 1

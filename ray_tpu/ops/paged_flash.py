@@ -3,14 +3,20 @@
 The XLA-assembled decode path (`ops.paged_attention`) gathers whole pages —
 `k_cache[block_tables]` materializes [B, nb*bs, H, D] in HBM every step —
 and runs a full-matrix softmax over [B, H, Q, K] logits. This kernel walks
-each sequence's block table *inside the pipeline*: the grid is
-(batch, q tiles, nb + q tiles) with the kv dimension sequential, and the
-k/v BlockSpec index maps read the scalar-prefetched block table, so each
-grid step DMAs exactly one [bs, H*D] cache block of one layer into VMEM.
-Block gather, QK^T, validity masking, streaming (online) softmax, and the
-weighted-V accumulation all happen in one pass; neither the gathered pages
-nor the logits ever touch HBM. The final grid step folds in the
-not-yet-scattered new tokens' K/V under a causal mask and normalizes —
+each sequence's block table itself. Its unit of work is a compute block:
+as many table entries as hold 128 cached tokens (8 blocks of 16, 16 of 8),
+gathered into one [128, H*D] tile of VMEM so that a score row fills whole
+lanes. The grid is (batch, q tiles, q tiles), sequential; a slot's context
+is a loop inside one grid step, bounded by the scalar-prefetched
+context_lens[b], so what a table holds past the context costs neither a
+copy nor a step, and an idle slot costs its new-token step alone. The
+pools stay in HBM: the kernel's own async copies bring in the blocks
+table[b, ...] of the context, two tiles deep, the next compute block (or
+the next slot's first) in flight while this one is computed. Block gather,
+QK^T, validity masking, streaming (online) softmax, and the weighted-V
+accumulation all happen in one pass; neither the gathered pages nor the
+logits ever touch HBM. After the walk the not-yet-scattered new tokens' K/V
+are folded in under a causal mask and the last grid step normalizes —
 fully-masked rows (a padded slot with context_len 0 and no new tokens) come
 out as exact zeros, matching `finalize_partial`'s l == 0 hygiene.
 
@@ -20,24 +26,33 @@ is the form the device keeps row-major, which is what a Mosaic call takes
 its operands in — a pool whose minor dimensions are [H, D] = [20, 64] is
 kept with the block count minor-most, and every program that handed it to
 the kernel converted the whole pool on the way in and back out. The layer
-is picked in the index map ((layer, table[b, j], 0, 0)), never sliced out
-in XLA, and head h is lanes h*D:(h+1)*D of the block.
+is picked in the copy's address (pool[layer, table[b, j]]), never sliced
+out in XLA, and head h is lanes h*D:(h+1)*D of the tile. Mosaic slices an
+HBM operand only in whole lanes: where H*D is not a multiple of 128 (a
+chip's 5 heads of 64 under tp = 4) XLA gathers the table's blocks and the
+kernel is handed each slot's tiles, the same body over another source.
 
-Covers both program shapes ray_tpu.llm compiles: decode (S == 1) and
-prefix-aware partial prefill (S > 1, the uncached suffix attends the cached
-prefix through the table and itself causally). `ops.paged_attention` is the
-correctness oracle; interpret mode on CPU runs the same code path in tests.
+Covers both program shapes ray_tpu.llm compiles, with the contraction
+chosen by shape. Decode (S == 1) lays q block-diagonally, [H, H*D], so one
+MXU product against the tile scores every head and one more weights V.
+Prefix-aware partial prefill and verify (S > 1: the fed tokens attend the
+cached prefix through the table and themselves causally) take one
+[tq, D] x [D, 128] product a head, heads walked a lane tile at a time in a
+loop the program traces once. `ops.paged_attention` is the correctness
+oracle; interpret mode on CPU runs the same code path in tests.
 
 int8 KV cache rides on top: the cache pools store int8 with per-token,
 per-head scales (written by `quantize_kv` at scatter time — per-token
 scales are the only granularity a one-token decode scatter can maintain
 without requantizing the rest of the block). Dequantization is fused into
 the block loop, folded into the score/weight matrices: K's scale multiplies
-the [S, bs] score columns after QK^T and V's scale folds into the softmax
-weights before PV, so the kernel never materializes a dequantized block.
-Scales are stored bfloat16 (math in f32): at block_size=8, head_dim=64 the
-pool + scale bytes per token come to ~52% of bf16, so the same HBM holds
-~1.9x the sequences.
+the score columns after QK^T and V's scale folds into the softmax weights
+before PV, so the kernel never materializes a dequantized block. The
+[L, N, bs, H] scale pools are narrower than a lane tile: XLA gathers a
+slot's scales, tokens on the lanes as the scores have them. Scales are
+stored bfloat16 (math in f32): at block_size=8, head_dim=64 the pool +
+scale bytes per token come to ~52% of bf16, so the same HBM holds ~1.9x the
+sequences.
 """
 
 from __future__ import annotations
@@ -63,10 +78,11 @@ from ray_tpu.ops.attention import (
 from ray_tpu.ops.flash_attention import _on_cpu
 
 _LANES = 128  # TPU lane width: min trailing dim for scratch tiles
-# VMEM one q tile's blocks may take. The compiler's default scoped limit on
-# v5e is 16 MiB; the rest is left to the cache blocks and the kernel's own
-# temporaries (float32 at "highest" matmul precision took 4.4 MiB of them
-# at 20 heads, measured on the chip).
+# VMEM one q tile's blocks and the cached K and V tiles may take together.
+# The compiler's default scoped limit on v5e is 16 MiB; the rest is left to
+# the kernel's own temporaries (float32 at "highest" matmul precision took
+# 4.4 MiB of them at 20 heads, measured on the chip). At 20 heads of 64 the
+# two-deep bf16 tiles are 1.25 MiB and a 128-token q tile the other 8.75.
 _Q_TILE_VMEM_BYTES = 10 * 1024 * 1024
 
 # Storage dtype for the KV-cache scale tensors. bf16 keeps the scale
@@ -98,132 +114,308 @@ def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return q, scale
 
 
-def _online_update(s, h, m_scr, l_scr, acc_scr, p_scale, v_block, out_dtype):
-    """One streaming-softmax step for head `h`: fold the score block `s`
-    ([tq, block]) and its value rows into the running (m, l, acc) scratch.
-    `p_scale` optionally rescales the softmax weights columnwise (int8 V
-    dequant folded into P instead of into a [block, D] dequant pass)."""
-    m_prev = m_scr[h][:, 0:1]
+def _online_update(s, stats, weigh):
+    """One streaming-softmax step: fold the score block `s` ([rows, cols])
+    into the running (max, sum, accumulator) scratch `stats`. `weigh(p)`
+    returns the softmax weights' product with the block's value rows."""
+    m_scr, l_scr, acc_scr = stats
+    m_prev = m_scr[:, 0:1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     # Masked lanes hold NEG_INF: exp underflows to exactly 0.
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_new = l_scr[h][:, 0:1] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    if p_scale is not None:
-        p = p * p_scale
-    acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
-        p.astype(out_dtype), v_block, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+    l_new = l_scr[:, 0:1] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + weigh(p)
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(
+        a, b, ((contract, ((), ()))), preferred_element_type=jnp.float32
     )
-    m_scr[h] = jnp.broadcast_to(m_new, m_scr[h].shape)
-    l_scr[h] = jnp.broadcast_to(l_new, l_scr[h].shape)
+
+
+def _each(n, body, looped: bool = True) -> None:
+    """body(i) for i < n: a loop whose body the program traces and lowers
+    once with i traced, or (`looped` false, n static) n times with i a
+    Python number. Rolled costs a 64-token chunk's call 56 us where it took
+    47 unrolled (on the chip), and a step program a fifth of the lowering:
+    36 layers of 20 unrolled heads were 50 s of every replica's start."""
+    if looped:
+        jax.lax.fori_loop(0, n, lambda i, carry: body(i) or carry, 0)
+    else:
+        for i in range(n):
+            body(i)
+
+
+def _head_mask(heads: int, head_dim: int) -> jax.Array:
+    """[H, H*D] bool: lane belongs to the row's head."""
+    shape = (heads, heads * head_dim)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    first = jax.lax.broadcasted_iota(jnp.int32, shape, 0) * head_dim
+    return (lane >= first) & (lane < first + head_dim)
 
 
 def _paged_kernel(
     # scalar prefetch
     tables_ref, lens_ref,
     # inputs
-    q_ref, k_ref, v_ref, nk_ref, nv_ref, *rest,
-    heads: int, bs: int, nb: int, tq: int, quantized: bool,
+    q_ref, nk_ref, nv_ref, k_src, v_src, *rest,
+    heads: int, head_dim: int, bs: int, nb: int, entries: int, tq: int,
+    layer: int, quantized: bool, batched_heads: bool, kernel_copies: bool,
 ):
-    """Grid (B, nq, nb + nq): one q tile of `tq` fed tokens per (b, qi).
-    Steps j < nb consume cache block table[b, j] (skipped past
-    context_lens[b]); steps j >= nb fold new-token tile j - nb in causally
-    (skipped above the diagonal); the last step normalizes. Running max /
-    sum / accumulator live in VMEM scratch across the sequential kv
-    dimension. q / new-token K/V / out blocks are heads-leading
-    [1, H, tq, D]: Mosaic tiles the last two dims, so a per-head [tq, D]
-    view must not have the head dim between them. Cache blocks are
-    [bs, H*D] (scales [bs, H]): head h is a lane slice."""
+    """Grid (B, nq, nq), every dimension sequential: one q tile of `tq` fed
+    tokens per (b, qi). Step j == 0 walks the slot's cached context in
+    compute blocks of `entries` table entries (`entries * bs` tokens): a
+    loop bounded by context_lens[b], so a block past the context costs
+    neither a copy nor a grid step. Every step j <= qi then folds new-token
+    tile j in causally, and the last step normalizes. Running max / sum /
+    accumulator live in VMEM scratch.
+
+    `kernel_copies`: the pools stay in HBM and the kernel copies the blocks
+    table[b, c*entries ...] of `layer` that the context reaches into one
+    [entries * bs, H*D] tile of VMEM, two tiles deep; the copy of the next
+    compute block (the next step's first one, when this is the last) is in
+    flight while this one is computed. Otherwise `k_src` / `v_src` are the
+    slot's context already gathered, [compute blocks, entries * bs, H*D].
+
+    Two contractions over the same tile, chosen by shape. Decode
+    (`batched_heads`, tq == 1): q, new K/V and out are lane-dense [1, H*D]
+    rows; q is laid block-diagonally [H, H*D], so one product against the
+    tile scores every head, one more weights V, and the other heads' lanes
+    of the accumulator are dropped at the end. Otherwise q / new-token K/V
+    / out blocks are heads-leading [1, H, tq, D] (Mosaic tiles the last two
+    dims, so a per-head [tq, D] view must not have the head dim between
+    them) and head h is a lane slice of the tile: one [tq, D] x [D, tokens]
+    product a head. int8 scales come gathered, [compute blocks, H, tokens]:
+    the layout the scores have."""
     if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr, *copy_scratch = rest
     else:
-        o_ref, m_scr, l_scr, acc_scr = rest
+        o_ref, m_scr, l_scr, acc_scr, *copy_scratch = rest
         ks_ref = vs_ref = None
     b = pl.program_id(0)
     qi = pl.program_id(1)
     j = pl.program_id(2)
     compute_dtype = q_ref.dtype
-    d = q_ref.shape[-1]
+    d = head_dim
+    tile = entries * bs  # cached tokens a compute block covers
+    ctx = lens_ref[b]
+    live_blocks = pl.cdiv(ctx, tile)
+    # Heads are walked in a loop, not unrolled, so a program traces and
+    # lowers one head's body a section whatever the model's. A head's lanes
+    # of the tile can be sliced at a traced offset only in whole lane
+    # tiles: heads narrower than one go a lane tile's worth a turn, and a
+    # shape that does not divide so is unrolled.
+    group = _LANES // d if _LANES % d == 0 else 1
+    looped = heads % group == 0 and (group * d) % _LANES == 0
+    if not looped:
+        group = 1
+
+    def head_stats(h):
+        if batched_heads:
+            return m_scr, l_scr, acc_scr
+        return m_scr.at[h], l_scr.at[h], acc_scr.at[h]
+
+    def block_diagonal_q():  # [H, H*D] float32: row h holds head h's q
+        return jnp.where(
+            _head_mask(heads, d), q_ref[...].astype(jnp.float32), 0.0
+        )
+
+    def cached_tile(c, k_ref, v_ref, at):
+        """Fold compute block `c`, whose K and V rows are `k_ref[at]` and
+        `v_ref[at]`: [tile, H*D], or that in `entries` blocks."""
+
+        def rows(ref, lo, lanes):
+            return ref[at, ..., pl.ds(lo, lanes)].reshape(tile, lanes)
+
+        t_ids = c * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+        live = t_ids < ctx
+
+        def fold(h, s, heads_here, v):
+            # int8 dequant is folded into the score / weight matrices: K's
+            # per-token scale multiplies score columns, V's rescales the
+            # softmax weights — never a dequantized [tile, D] block.
+            if quantized:
+                s = s * ks_ref[c, pl.ds(h, heads_here)]
+
+            def weigh(p):
+                if quantized:
+                    p = p * vs_ref[c, pl.ds(h, heads_here)]
+                return _dot(p.astype(compute_dtype), v, ((1,), (0,)))
+
+            _online_update(jnp.where(live, s, NEG_INF), head_stats(h), weigh)
+
+        if batched_heads:
+            s = _dot(
+                block_diagonal_q().astype(compute_dtype),
+                rows(k_ref, 0, heads * d).astype(compute_dtype),
+                ((1,), (1,)),
+            )  # [H, tile]
+            fold(0, s, heads, rows(v_ref, 0, heads * d).astype(compute_dtype))
+            return
+
+        def head_group(g):
+            lo = g * group * d
+            if looped:
+                lo = pl.multiple_of(lo, _LANES)
+            k = rows(k_ref, lo, group * d).astype(compute_dtype)
+            v = rows(v_ref, lo, group * d).astype(compute_dtype)
+            for i in range(group):
+                h, lanes = g * group + i, slice(i * d, (i + 1) * d)
+                s = _dot(q_ref[0, h], k[:, lanes], ((1,), (1,)))  # [tq, tile]
+                fold(h, s, 1, v[:, lanes])
+
+        _each(heads // group, head_group, looped)
+
+    def walk_copying():
+        base_ref, sems, k_buf, v_buf = copy_scratch
+        nq = pl.num_programs(1)
+
+        def tile_copies(slot_b, c, slot, act):
+            """Start or wait for the copies of compute block `c` of slot
+            `slot_b` into tile `slot`: the table entries its context
+            reaches, so one (slot_b, c) names the same copies both times."""
+            reached = pl.cdiv(lens_ref[slot_b] - c * tile, bs)
+
+            def entry(i):
+                page = tables_ref[slot_b, jnp.minimum(c * entries + i, nb - 1)]
+                for n, (hbm, buf) in enumerate(
+                    ((k_src, k_buf), (v_src, v_buf))
+                ):
+                    copy = pltpu.make_async_copy(
+                        hbm.at[layer, page], buf.at[slot, i], sems.at[n, slot]
+                    )
+                    getattr(copy, act)()  # "start" | "wait"
+
+            _each(jnp.clip(reached, 0, entries), entry)
+
+        @pl.when((b == 0) & (qi == 0))
+        def _first():
+            # A tile row no copy has written is weighted by an exact 0,
+            # which a stale NaN would survive.
+            k_buf[...] = jnp.zeros_like(k_buf)
+            v_buf[...] = jnp.zeros_like(v_buf)
+            base_ref[0] = 0
+            tile_copies(b, 0, 0, "start")
+
+        base = base_ref[0]  # the tile this walk's first block is in
+        # The step that walks next: its first block is this step's to
+        # start, so that only the call's first block is waited for cold.
+        b_next = jnp.where(qi == nq - 1, b + 1, b)
+        has_next = b_next < pl.num_programs(0)
+        b_next = jnp.minimum(b_next, pl.num_programs(0) - 1)
+
+        def body(c, carry):
+            slot = (base + c) % 2
+
+            last = c + 1 == live_blocks
+
+            @pl.when(~last | has_next)
+            def _():
+                tile_copies(
+                    jnp.where(last, b_next, b), jnp.where(last, 0, c + 1),
+                    1 - slot, "start",
+                )
+
+            tile_copies(b, c, slot, "wait")
+            cached_tile(c, k_buf, v_buf, slot)
+            return carry
+
+        jax.lax.fori_loop(0, live_blocks, body, 0)
+
+        @pl.when((live_blocks == 0) & has_next)
+        def _idle():
+            tile_copies(b_next, 0, base, "start")
+
+        base_ref[0] = (base + live_blocks) % 2
 
     @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def _walk():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        if kernel_copies:
+            walk_copying()
+            return
 
-    ctx = lens_ref[b]
+        def body(c, carry):
+            cached_tile(c, k_src, v_src, c)
+            return carry
 
-    # Blocks entirely past the context contribute nothing: skip their
-    # compute (their copies still run, through the null block — the
-    # data-dependent skip of the copies defeats the pipeline's prefetch,
-    # same trade as ops/flash_attention.py).
-    @pl.when((j < nb) & (j * bs < ctx))
-    def _cache_block():
-        for h in range(heads):
-            q = q_ref[0, h]  # [tq, D], prescaled by sm_scale
-            k = k_ref[:, h * d:(h + 1) * d]  # [bs, D] (int8 when quantized)
-            s = jax.lax.dot_general(
-                q, k.astype(compute_dtype), (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [tq, bs]
-            p_scale = None
-            if quantized:
-                # Dequant folded into the score/weight matrices: K's
-                # per-token scale multiplies score columns, V's rescales
-                # the softmax weights — both [tq, bs] ops, never [bs, D].
-                s = s * ks_ref[:, h].astype(jnp.float32)[None, :]
-                p_scale = vs_ref[:, h].astype(jnp.float32)[None, :]
-            t_ids = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(t_ids < ctx, s, NEG_INF)
-            _online_update(
-                s, h, m_scr, l_scr, acc_scr, p_scale,
-                v_ref[:, h * d:(h + 1) * d].astype(compute_dtype),
-                compute_dtype,
-            )
+        jax.lax.fori_loop(0, live_blocks, body, 0)
 
-    t = j - nb  # new-token tile this step would fold in
-
-    @pl.when((t >= 0) & (t <= qi))
+    @pl.when(j <= qi)
     def _new_tokens():
-        for h in range(heads):
-            q = q_ref[0, h]    # [tq, D]
-            nk = nk_ref[0, h]  # [tq, D] — new tokens, never quantized
-            s = jax.lax.dot_general(
-                q, nk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [tq, tq]
-            rows = qi * tq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = t * tq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
+        if batched_heads:
+            # One fed token: it attends itself, no mask. Scores are the
+            # block-diagonal q's lane sums against the new K row.
+            s = jnp.sum(
+                block_diagonal_q() * nk_ref[...].astype(jnp.float32),
+                axis=1, keepdims=True,
+            )  # [H, 1]
+            nv = nv_ref[...].astype(jnp.float32)
             _online_update(
-                s, h, m_scr, l_scr, acc_scr, None, nv_ref[0, h],
-                compute_dtype,
+                s, head_stats(0),
+                lambda p: p.astype(compute_dtype).astype(jnp.float32) * nv,
             )
+            return
+
+        def head(h):
+            s = _dot(q_ref[0, h], nk_ref[0, h], ((1,), (1,)))  # [tq, tq]
+            rows = qi * tq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            cols = j * tq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            _online_update(
+                jnp.where(rows >= cols, s, NEG_INF), head_stats(h),
+                lambda p: _dot(  # new tokens are never quantized
+                    p.astype(compute_dtype), nv_ref[0, h], ((1,), (0,))
+                ),
+            )
+
+        _each(heads, head)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
-        for h in range(heads):
-            l = l_scr[h][:, 0:1]
-            safe = jnp.where(l == 0.0, 1.0, l)
-            # Fully-masked rows (context_len 0 and no valid new token)
-            # normalize to exact zeros, not garbage — finalize_partial's
-            # l == 0 hygiene.
-            o_ref[0, h] = jnp.where(
-                l == 0.0, 0.0, acc_scr[h] / safe
-            ).astype(o_ref.dtype)
+        # Fully-masked rows (context_len 0 and no valid new token)
+        # normalize to exact zeros, not garbage — finalize_partial's
+        # l == 0 hygiene.
+        def normalized(stats):
+            _, l_scr, acc_scr = stats
+            l = l_scr[:, 0:1]
+            return jnp.where(
+                l == 0.0, 0.0, acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
+            )
+
+        if batched_heads:
+            out = jnp.where(_head_mask(heads, d), normalized(head_stats(0)), 0)
+            o_ref[...] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+            return
+
+        def head(h):
+            o_ref[0, h] = normalized(head_stats(h)).astype(o_ref.dtype)
+
+        _each(heads, head)
 
 
-def _q_tile(s_len: int, heads: int, head_dim: int, itemsize: int) -> int:
+_TILE_TOKENS = 128  # cached tokens a compute block covers: whole lanes
+
+
+def _whole_lanes(n: int) -> int:
+    return -(-n // _LANES) * _LANES
+
+
+def _q_tile(
+    s_len: int, heads: int, head_dim: int, itemsize: int, kv_vmem_bytes: int
+) -> int:
     """Fed tokens per q tile: the largest tile whose blocks fit
-    _Q_TILE_VMEM_BYTES — q, new K, new V and out double-buffered in the
-    compute dtype plus the float32 running max, sum and accumulator, every
-    [tile, D] slab padded to whole lanes."""
-    lanes = -(-head_dim // _LANES) * _LANES
-    row_bytes = heads * lanes * (4 * 2 * itemsize + 3 * 4)
-    tile = next(
-        (t for t in (128, 64, 32) if t * row_bytes <= _Q_TILE_VMEM_BYTES), 16
-    )
+    _Q_TILE_VMEM_BYTES beside the `kv_vmem_bytes` of the cached K and V (the
+    two-deep tiles, or a slot's gathered context) — q, new K, new V
+    and out double-buffered in the compute dtype plus the float32 running
+    max, sum and accumulator, every [tile, D] slab padded to whole lanes."""
+    row_bytes = heads * _whole_lanes(head_dim) * (4 * 2 * itemsize + 3 * 4)
+    room = _Q_TILE_VMEM_BYTES - kv_vmem_bytes
+    tile = next((t for t in (128, 64, 32) if t * row_bytes <= room), 16)
     return min(s_len, tile)
 
 
@@ -292,81 +484,130 @@ def paged_flash_attention(
     # epilogue by XLA): no per-score-element scale pass inside.
     q = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
 
+    # A compute block is as many table entries as make _TILE_TOKENS cached
+    # tokens (the whole table, where that is shorter).
+    entries = min(max(1, _TILE_TOKENS // bs), nb)
+    tile = entries * bs
+    n_tiles = -(-nb // entries)
+    # The kernel copies blocks out of a pool itself only in whole lanes.
+    # Mosaic refuses to slice a narrower or ragged minor axis in HBM (a
+    # chip's 5 heads of 64 under tp = 4; every scale pool): XLA gathers the
+    # table's blocks of those, and the kernel is handed a slot's tiles.
+    kernel_copies = (h * d) % _LANES == 0
+
+    def gathered(pool):  # [L, N, bs, X] -> [B, n_tiles, tile, X]
+        x = pool[layer][block_tables]
+        x = jnp.pad(x, ((0, 0), (0, n_tiles * entries - nb), (0, 0), (0, 0)))
+        return x.reshape(b, n_tiles, tile, pool.shape[3])
+
+    def slot_tiles(rows, lanes):  # a slot's gathered tiles, resident
+        return pl.BlockSpec(
+            (None, n_tiles, rows, lanes),
+            lambda bi, qi, j, tables_ref, lens_ref: (bi, 0, 0, 0),
+        )
+
+    itemsize = k_cache.dtype.itemsize
+    if kernel_copies:
+        # The pools go in whole, as stored: the copies' addresses choose
+        # the layer and the block, so XLA neither slices a layer out of a
+        # pool nor converts its layout.
+        kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        kv_operands = [k_cache, v_cache]
+        tile_buf = pltpu.VMEM((2, entries, bs, h * d), k_cache.dtype)
+        copy_scratch = [
+            pltpu.SMEM((1,), jnp.int32),       # the tile the next walk starts in
+            pltpu.SemaphoreType.DMA((2, 2)),   # (K | V, tile)
+            tile_buf, tile_buf,
+        ]
+        kv_vmem_bytes = 2 * 2 * tile * h * d * itemsize
+    else:
+        kv_specs = [slot_tiles(tile, h * d)] * 2
+        kv_operands = [gathered(k_cache), gathered(v_cache)]
+        copy_scratch = []
+        kv_vmem_bytes = 2 * 2 * n_tiles * tile * _whole_lanes(h * d) * itemsize
+    if quantized:
+        # Tokens on the lanes, as the scores have them.
+        kv_specs += [slot_tiles(h, tile)] * 2
+        kv_operands += [
+            gathered(scale).transpose(0, 1, 3, 2).astype(jnp.float32)
+            for scale in (k_scale, v_scale)
+        ]
+        kv_vmem_bytes += 2 * 2 * n_tiles * h * _whole_lanes(tile) * 4
+
     # The fed tokens are tiled over S so VMEM holds one [H, tq, D] tile of
     # q / new K / new V / out and its statistics, whatever the bucket. A
     # length that is not a whole number of tiles is zero-padded: padded key
     # columns sit above every real row's diagonal, padded q rows are cut.
-    tq = _q_tile(s_len, h, d, q.dtype.itemsize)
+    tq = _q_tile(s_len, h, d, q.dtype.itemsize, kv_vmem_bytes)
     nq = -(-s_len // tq)
     pad = nq * tq - s_len
+    # One fed token a slot (decode) takes every head in one product: q, new
+    # K/V and out as lane-dense [1, H*D] rows, which q's own reshape gives.
+    batched_heads = s_len == 1
 
-    def heads_leading(x):  # [B, S, H, D] -> [B, H, nq * tq, D]
-        x = x.transpose(0, 2, 1, 3)
-        return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
+    if batched_heads:
+        fed_block, stat_rows, acc_shape = (None, 1, h * d), (h,), (h, h * d)
 
-    def q_map(bi, qi, j, tables_ref, lens_ref):
-        return (bi, 0, qi, 0)
+        def fed(x):  # [B, 1, H, D] -> [B, 1, H*D]
+            return x.reshape(b, 1, h * d)
 
-    def new_map(bi, qi, j, tables_ref, lens_ref):
-        # New-token tile j - nb, clamped into [0, qi]: the cache walk and
-        # the skipped above-diagonal steps re-name a tile that is already
-        # resident, so they copy nothing.
-        return (bi, 0, jnp.clip(j - nb, 0, qi), 0)
+        def q_map(bi, qi, j, tables_ref, lens_ref):
+            return (bi, 0, 0)
 
-    def block_id(bi, j, tables_ref):
-        # Walk the block table: grid step j pipelines cache block
-        # table[b, j] into VMEM. The new-token steps (j >= nb) and padded
-        # steps read the null block — copied but never unmasked.
-        return jnp.where(j < nb, tables_ref[bi, jnp.minimum(j, nb - 1)], 0)
+        new_map = q_map
+    else:
+        fed_block, stat_rows, acc_shape = (1, h, tq, d), (h, tq), (h, tq, d)
 
-    def kv_map(bi, qi, j, tables_ref, lens_ref):
-        # The layer is chosen here, in the copy's address: the pool goes
-        # into the call whole and as stored, so XLA neither slices a layer
-        # out of it nor converts its layout.
-        return (layer, block_id(bi, j, tables_ref), 0, 0)
+        def fed(x):  # [B, S, H, D] -> [B, H, nq * tq, D]
+            x = x.transpose(0, 2, 1, 3)
+            return (
+                jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
+            )
 
-    in_specs = [
-        pl.BlockSpec((1, h, tq, d), q_map),
-        pl.BlockSpec((None, None, bs, h * d), kv_map),
-        pl.BlockSpec((None, None, bs, h * d), kv_map),
-        pl.BlockSpec((1, h, tq, d), new_map),
-        pl.BlockSpec((1, h, tq, d), new_map),
-    ]
-    operands = [
-        heads_leading(q), k_cache, v_cache,
-        heads_leading(new_k), heads_leading(new_v),
-    ]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((None, None, bs, h), kv_map),
-            pl.BlockSpec((None, None, bs, h), kv_map),
-        ]
-        operands += [k_scale, v_scale]
+        def q_map(bi, qi, j, tables_ref, lens_ref):
+            return (bi, 0, qi, 0)
+
+        def new_map(bi, qi, j, tables_ref, lens_ref):
+            # New-token tile j, held at qi above the diagonal: a skipped
+            # step re-names a tile that is already resident and copies
+            # nothing.
+            return (bi, 0, jnp.minimum(j, qi), 0)
+
+    q = fed(q)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, nq, nb + nq),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, tq, d), q_map),
+        grid=(b, nq, nq),
+        in_specs=[
+            pl.BlockSpec(fed_block, q_map),
+            pl.BlockSpec(fed_block, new_map), pl.BlockSpec(fed_block, new_map),
+            *kv_specs,
+        ],
+        out_specs=pl.BlockSpec(fed_block, q_map),
         scratch_shapes=[
-            pltpu.VMEM((h, tq, _LANES), jnp.float32),
-            pltpu.VMEM((h, tq, _LANES), jnp.float32),
-            pltpu.VMEM((h, tq, d), jnp.float32),
+            pltpu.VMEM(stat_rows + (_LANES,), jnp.float32),
+            pltpu.VMEM(stat_rows + (_LANES,), jnp.float32),
+            pltpu.VMEM(acc_shape, jnp.float32),
+            *copy_scratch,
         ],
     )
     kernel = functools.partial(
-        _paged_kernel, heads=h, bs=bs, nb=nb, tq=tq, quantized=quantized
+        _paged_kernel, heads=h, head_dim=d, bs=bs, nb=nb, entries=entries,
+        tq=tq, layer=layer, quantized=quantized, batched_heads=batched_heads,
+        kernel_copies=kernel_copies,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, nq * tq, d), q.dtype),
-        # Batch and q tiles parallel; the block-table walk is sequential
-        # (online softmax state lives in scratch across kv steps).
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        # Sequential throughout: a step starts the copies the next one
+        # waits for, and the online softmax state lives in scratch.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
-    )(block_tables, context_lens, *operands)
+    )(block_tables, context_lens, q, fed(new_k), fed(new_v), *kv_operands)
+    if batched_heads:
+        return out.reshape(b, 1, h, d)
     return out[:, :, :s_len].transpose(0, 2, 1, 3)
 
 

@@ -173,19 +173,38 @@ def _paged_case(seed, b, s, h=4, d=16, num_blocks=None, bs=4, nb=4):
     return q, k_cache, v_cache, jnp.asarray(tables), new_k, new_v
 
 
+# Geometries for the kernel's compute blocks (128 cached tokens: 8 table
+# entries of 16, 16 of 8). H*D = 128 is whole lanes, so the kernel copies the
+# blocks out of the pools itself; 48 lanes are not, and XLA gathers them.
+_COPIED = dict(h=4, d=32)
+_GATHERED = dict(h=3, d=16)
+_BLOCK_EDGES = (0, 1, 127, 128, 129, 256)
+
+
 @pytest.mark.parametrize(
-    "ctx_lens",
+    "ctx_lens, geometry",
     [
-        (9, 2, 16, 0),    # partial block / tiny / max / empty padded slot
-        (8, 4, 12, 16),   # block boundaries and full table
+        ((9, 2, 16, 0), {}),    # partial block / tiny / max / empty padded slot
+        ((8, 4, 12, 16), {}),   # block boundaries and full table
+        # A compute block's edges, a full 1,024-token table, idle slots
+        # between live ones.
+        ((0, 1, 127, 128, 129, 1024, 0, 300), dict(bs=16, nb=64, **_COPIED)),
+        # 16 entries a compute block; 5 tokens reach one of them.
+        ((129, 0, 256, 5, 0, 128), dict(bs=8, nb=32, **_COPIED)),
+        (_BLOCK_EDGES, dict(bs=16, nb=16, **_GATHERED)),
+        (_BLOCK_EDGES, dict(bs=8, nb=32, **_GATHERED)),
+        # A table shorter than a compute block, through the kernel's copies.
+        ((9, 0, 16, 3), _COPIED),
     ],
 )
-def test_paged_flash_decode_matches_reference(ctx_lens):
+def test_paged_flash_decode_matches_reference(ctx_lens, geometry):
     """Decode shape (S == 1): the fused kernel walking the block table must
     equal the XLA gather+softmax reference at every context length —
     including 0 (an idle padded slot attending only its own new token),
     exact block boundaries, and the full table."""
-    q, kc, vc, tables, nk, nv = _paged_case(0, b=4, s=1)
+    q, kc, vc, tables, nk, nv = _paged_case(
+        0, b=len(ctx_lens), s=1, **geometry
+    )
     lens = jnp.asarray(ctx_lens, jnp.int32)
     want = _paged_ref(q, kc, vc, tables, lens, new_k=nk, new_v=nv)
     got = _paged_kernel(q, kc, vc, tables, lens, new_k=nk, new_v=nv)
@@ -217,8 +236,20 @@ def test_paged_flash_partial_prefill_matches_reference():
             )
 
 
+@pytest.mark.parametrize(
+    "fed, ctx_lens, geometry",
+    [
+        (40, (9, 0, 16), {}),
+        # Two q tiles over contexts that end inside a compute block: each
+        # tile walks the cache again, behind the other's last block.
+        (20, (130, 0, 255), dict(bs=16, nb=16, **_COPIED)),
+        (20, (130, 0, 255), dict(bs=8, nb=32, **_GATHERED)),
+    ],
+)
 @pytest.mark.parametrize("quantized", [False, True])
-def test_paged_flash_q_tiles_match_reference(monkeypatch, quantized):
+def test_paged_flash_q_tiles_match_reference(
+    monkeypatch, quantized, fed, ctx_lens, geometry
+):
     """More fed tokens than one q tile holds (the kernel tiles S so any
     bucket fits VMEM): with no VMEM to spare the tile is 16 tokens, so 40
     tokens are three tiles, the last zero-padded. Every row still attends
@@ -227,8 +258,8 @@ def test_paged_flash_q_tiles_match_reference(monkeypatch, quantized):
     from ray_tpu.ops import paged_flash
 
     monkeypatch.setattr(paged_flash, "_Q_TILE_VMEM_BYTES", 0)
-    q, kc, vc, tables, nk, nv = _paged_case(11, b=3, s=40)
-    lens = jnp.asarray([9, 0, 16], jnp.int32)
+    q, kc, vc, tables, nk, nv = _paged_case(11, b=3, s=fed, **geometry)
+    lens = jnp.asarray(ctx_lens, jnp.int32)
     scales = {}
     if quantized:
         kc, ks = quantize_kv(kc)
@@ -270,12 +301,22 @@ def test_paged_attention_empty_context_returns_zeros():
     assert np.any(np.asarray(out[1]) != 0.0)  # live rows unaffected
 
 
-def test_paged_flash_int8_matches_int8_reference():
+@pytest.mark.parametrize(
+    "fed, ctx_lens, geometry",
+    [
+        (2, (9, 16, 0), {}),
+        (1, _BLOCK_EDGES, dict(bs=16, nb=16, **_COPIED)),
+        (3, _BLOCK_EDGES, dict(bs=8, nb=32, **_COPIED)),
+    ],
+)
+def test_paged_flash_int8_matches_int8_reference(fed, ctx_lens, geometry):
     """int8 KV: the kernel's fused dequant (scales folded into the score /
     weight matrices) must match the reference dequantizing gathered pages
     — same quantized inputs, near-identical outputs."""
-    q, kc, vc, tables, nk, nv = _paged_case(4, b=3, s=2)
-    lens = jnp.asarray([9, 16, 0], jnp.int32)
+    q, kc, vc, tables, nk, nv = _paged_case(
+        4, b=len(ctx_lens), s=fed, **geometry
+    )
+    lens = jnp.asarray(ctx_lens, jnp.int32)
     kq, ks = quantize_kv(kc)
     vq, vs = quantize_kv(vc)
     assert kq.dtype == jnp.int8 and ks.shape == kc.shape[:-1]
@@ -382,12 +423,18 @@ def test_paged_flash_requires_new_kv():
 @pytest.mark.parametrize("variant", ["bf16", "int8"])
 @pytest.mark.parametrize("fed", [1, 5])
 @pytest.mark.parametrize("head_dim", [16, 64, 128])
-def test_paged_flash_lane_sliced_heads_match_reference(head_dim, fed, variant):
+@pytest.mark.parametrize("heads", [3, 4])
+def test_paged_flash_lane_sliced_heads_match_reference(
+    heads, head_dim, fed, variant
+):
     """The kernel reads head h as lanes h*D:(h+1)*D of a [bs, H*D] block
     of the stored pool, at a layer other than 0: heads that share a lane
     tile (16, and 64 at odd heads) and heads that are a whole tile (128),
-    decode (S == 1) and suffix prefill (S > 1), bf16 and int8."""
-    q, kc, vc, tables, nk, nv = _paged_case(21, b=3, s=fed, h=3, d=head_dim)
+    decode (S == 1) and suffix prefill (S > 1), bf16 and int8. Four heads
+    of 64 are two whole lane tiles, which S > 1 walks in a loop."""
+    q, kc, vc, tables, nk, nv = _paged_case(
+        21, b=3, s=fed, h=heads, d=head_dim
+    )
     lens = jnp.asarray([9, 0, 16], jnp.int32)
     q, nk, nv = (x.astype(jnp.bfloat16) for x in (q, nk, nv))
     if variant == "int8":

@@ -54,10 +54,11 @@ def chip():
     compilation_cache.reset_cache()
 
 
-def _paged(batch, fed, heads, pool_dtype=jnp.bfloat16, head_dim=64):
-    """(fn, shapes) for the paged kernel over the smoke's geometry: 1,024
-    blocks of 16 tokens, 64-block tables, heads of 64, the pools as stored
-    (two layers of lane-dense [16, H*D] blocks, read at layer 1)."""
+def _paged(batch, fed, heads, pool_dtype=jnp.bfloat16, head_dim=64, block=16):
+    """(fn, shapes) for the paged kernel over the smoke's geometry: 16,384
+    cached tokens in blocks of 16, 1,024-token tables, heads of 64, the
+    pools as stored (two layers of lane-dense [16, H*D] blocks, read at
+    layer 1)."""
 
     def fn(q, k_cache, v_cache, tables, lens, new_k, new_v, k_scale, v_scale):
         return paged_flash_attention(
@@ -66,14 +67,14 @@ def _paged(batch, fed, heads, pool_dtype=jnp.bfloat16, head_dim=64):
         )
 
     q = ((batch, fed, heads, head_dim), jnp.bfloat16)
-    pool = ((2, 1024, 16, heads * head_dim), pool_dtype)
+    blocks = (2, 16384 // block, block)
+    pool = (blocks + (heads * head_dim,), pool_dtype)
     scale = (
-        ((2, 1024, 16, heads), KV_SCALE_DTYPE)
-        if pool_dtype == jnp.int8 else None
+        (blocks + (heads,), KV_SCALE_DTYPE) if pool_dtype == jnp.int8 else None
     )
     return fn, [
-        q, pool, pool, ((batch, 64), jnp.int32), ((batch,), jnp.int32),
-        q, q, scale, scale,
+        q, pool, pool, ((batch, 1024 // block), jnp.int32),
+        ((batch,), jnp.int32), q, q, scale, scale,
     ]
 
 
@@ -96,6 +97,10 @@ CASES = {
     "paged_decode_tp_local_5_heads_int8": lambda: _paged(8, 1, 5, jnp.int8),
     "paged_decode_16_slots": lambda: _paged(16, 1, 20),
     "paged_decode_heads_of_128": lambda: _paged(8, 1, 8, head_dim=128),
+    # The engine's default block: 16 table entries a compute block.
+    "paged_decode_blocks_of_8": lambda: _paged(16, 1, 20, block=8),
+    "paged_decode_blocks_of_8_int8": lambda: _paged(16, 1, 20, jnp.int8, block=8),
+    "paged_prefill_64_bucket_blocks_of_8": lambda: _paged(1, 64, 20, block=8),
     "paged_verify_16_slots_4_fed": lambda: _paged(16, 4, 20),
     "paged_prefill_smallest_bucket": lambda: _paged(1, 16, 20),
     "paged_prefill_64_bucket": lambda: _paged(1, 64, 20),
