@@ -218,8 +218,9 @@ class EngineConfig:
     # steps and batch-composition changes are pipeline-flush boundaries
     # (commit-before-plan), and a poisoned decode commit surfaces one
     # step after its dispatch (failure records attribute against the
-    # dispatch index). Greedy outputs are token-identical either way;
-    # False (the default) keeps the synchronous loop bit-for-bit.
+    # dispatch index). Greedy outputs are token-identical either way.
+    # It is the step loop's pipeline depth (True 1, False 0): False (the
+    # default) commits every decode in the step that dispatched it.
     async_scheduling: bool = False
     # Bounded admission: cap the scheduler backlog so overload fails fast
     # at submission instead of queueing without bound. None (the default)

@@ -61,24 +61,27 @@ from ray_tpu.util.metrics import Counter, Gauge, Histogram, get_or_create
 
 
 class _InflightStep:
-    """One dispatched-but-uncommitted decode step (async_scheduling).
+    """One dispatched-but-uncommitted decode step.
 
-    Holds everything the deferred commit needs: the batch exactly as it
-    was dispatched (slot order matters — the chained token input is
+    Holds everything the commit needs: the batch exactly as it was
+    dispatched (slot order matters — the chained token input is
     slot-aligned), the on-device `next_tokens` with its async host copy
     in flight, and the engine step index at dispatch time (failure
     attribution: a commit-time exception is pinned on the step that
-    DISPATCHED the program, one step before it surfaces). `commit_idx`
-    is the partial-commit resume pointer — after a poison dead-letter
-    mid-commit, the retry resumes the loop exactly where it stopped.
+    DISPATCHED the program, which at pipeline depth 1 is one step before
+    it surfaces). `commit_idx` is the partial-commit resume pointer —
+    after a poison dead-letter mid-commit, the retry resumes the loop
+    exactly where it stopped.
     """
 
     __slots__ = (
         "seqs", "rids", "tokens_dev", "tokens_host",
-        "dispatch_step", "commit_idx", "clock_seq",
+        "dispatch_step", "commit_idx", "clock_seq", "t_prepare",
     )
 
-    def __init__(self, seqs, rids, tokens_dev, dispatch_step, clock_seq=None):
+    def __init__(
+        self, seqs, rids, tokens_dev, dispatch_step, clock_seq, t_prepare
+    ):
         self.seqs: List[Sequence] = seqs
         self.rids: List[str] = rids
         self.tokens_dev = tokens_dev
@@ -86,8 +89,10 @@ class _InflightStep:
         self.dispatch_step = dispatch_step
         self.commit_idx = 0
         # StepPhaseClock's number of this dispatch (None uninstrumented):
-        # its fetch, a step later, tells the clock which program finished.
+        # its fetch tells the clock which program finished. And its
+        # reading where the dispatch's `prepare` began.
         self.clock_seq = clock_seq
+        self.t_prepare = t_prepare
 
 
 class LLMEngine:
@@ -470,18 +475,20 @@ class LLMEngine:
         self._spec_accepted_total = 0
         self._spec_emitted_total = 0
         self._verify_steps = 0
-        # Async (double-buffered) step loop state. `_inflight` holds
-        # dispatched-but-uncommitted decode records, oldest first; depth
-        # is transiently 2 between a chained dispatch and the commit of
-        # the record it chained from. Always empty with async off.
-        self._async = self.engine_config.async_scheduling
+        # The step loop's pipeline depth: how many decode records a step
+        # may leave in flight when it returns. 0 commits every dispatch
+        # in the step that made it; 1 (EngineConfig.async_scheduling)
+        # commits it in the next. `_inflight` holds the records, oldest
+        # first; it is transiently one longer than the depth between a
+        # dispatch and the commit of the record before it.
+        self._pipeline_depth = 1 if self.engine_config.async_scheduling else 0
         self._inflight: Deque[_InflightStep] = deque()
         # Dispatch index of the record being committed right now: a
-        # commit-time failure is attributed one step late, against the
-        # step that dispatched the failing program (failure_step()).
+        # commit-time failure is attributed against the step that
+        # dispatched the failing program (failure_step()).
         self._attribution_step: Optional[int] = None
-        # The step loop's one clock (both loop modes, instrument-gated
-        # like the records it feeds): it partitions the wall time into
+        # The step loop's one clock (instrument-gated like the records it
+        # feeds): it partitions the wall time into
         # schedule / prepare / wait / commit / other / between, mirrors the
         # phases as profiler annotations and samples host_exposed at every
         # program dispatch. The runner calls the hook where a program's
@@ -507,10 +514,10 @@ class LLMEngine:
         self._decode_context_tokens = 0
         # Preallocated per-step decode/verify input buffers, zero-filled
         # and repopulated each dispatch instead of np.zeros-allocated
-        # (the steady decode loop does no numpy allocation at all —
-        # asserted by test). Safe to reuse: the sync runner blocks on the
-        # program before the next fill, and the async runner converts
-        # with a guaranteed copy at dispatch.
+        # (the engine allocates none in the steady decode loop — asserted
+        # by test). Safe to reuse: runner.verify blocks on the program
+        # before the next fill, and runner.decode makes one small host
+        # copy of each input at dispatch.
         slots = self.engine_config.max_decode_slots
         nb = self.engine_config.max_blocks_per_seq
         self._dec_tokens = np.zeros((slots,), np.int32)
@@ -530,6 +537,28 @@ class LLMEngine:
             if self._spec is not None
             else {}
         )
+        # A stepping engine exports its whole metric family: counters and
+        # histograms that happen not to fire after a registry reset (test
+        # isolation) must still re-register, or their series vanish from
+        # the exposition. step() walks this once: one int compare each,
+        # nothing on the token path.
+        self._metric_family = (
+            self._preemptions, self._prefix_hits, self._tokens_generated,
+            self._dead_letter_count, self._shed_count, self._expired_count,
+            self._h_ttft, self._h_tpot,
+            self._h_queue, self._h_e2e, self._h_step, self._h_host_gap,
+        )
+        if self._spec is not None:
+            self._metric_family += (
+                self._spec_proposed, self._spec_accepted,
+                self._spec_acceptance,
+            )
+        if self._fabric is not None:
+            self._metric_family += (
+                self._fabric_spills, self._fabric_restores,
+                self._fabric_hits, self._fabric_hit_rate,
+                self._fabric_bytes_used, self._fabric_timeouts,
+            )
         self._start = time.monotonic()
 
     # ---------------- request lifecycle ----------------
@@ -676,11 +705,12 @@ class LLMEngine:
 
     def failure_step(self) -> int:
         """Step index a failure surfacing NOW should be attributed to.
-        Under async_scheduling a decode program's commit runs one step
+        At pipeline depth 1 a decode program's commit runs one step
         after its dispatch, so an exception raised inside the commit loop
         belongs to the in-flight record's DISPATCH index (where the
         failing program and its batch actually ran) — not the current
-        step counter. Outside a commit this is simply the current step."""
+        step counter. Outside a commit, and at depth 0, this is simply
+        the current step."""
         if self._attribution_step is not None:
             return self._attribution_step
         return self._steps
@@ -762,14 +792,14 @@ class LLMEngine:
     def _expire_deadlines(self) -> None:
         """Per-step deadline enforcement (monotonic clock, matching
         Request.deadline_s — never wall time, which steps under NTP).
-        Runs at the top of both step loops, so a queued request whose
-        deadline passed is dropped BEFORE schedule_prefills can feed it
-        to a prefill program, and a decoding one goes through the normal
+        Runs at the top of step(), so a queued request whose deadline
+        passed is dropped BEFORE schedule_prefills can feed it to a
+        prefill program, and a decoding one goes through the normal
         finish teardown — KV blocks, draft-mirror blocks, and any
-        lookahead reservation reclaimed within this step. Under
-        async_scheduling the sweep precedes the chain attempt: an expiry
-        is a batch-composition change, so the pipeline flushes and
-        _commit_head's inactive-skip drops the in-flight orphan token.
+        lookahead reservation reclaimed within this step. The sweep
+        precedes the chain attempt: an expiry is a batch-composition
+        change, so the pipeline flushes and _commit_head's inactive-skip
+        drops the in-flight orphan token.
         Engines that have never seen a deadline pay one int compare."""
         if not self._deadline_count:
             return
@@ -812,26 +842,51 @@ class LLMEngine:
     # ---------------- stepping ----------------
 
     def step(self) -> dict:
-        """One engine iteration: admit prefills, feed each in-flight
-        prompt its next chunk under the per-step token budget, decode
-        every decode-ready sequence one token, emit tokens, retire
-        finished sequences. A sequence mid-chunk stays `prefilling` — it
-        never enters the decode batch, so a chunk failure (or a step
+        """One engine iteration: commit what is in flight, admit prefills,
+        feed each in-flight prompt its next chunk under the per-step token
+        budget, decode every decode-ready sequence one token, emit tokens,
+        retire finished sequences. A sequence mid-chunk stays `prefilling`
+        — it never enters the decode batch, so a chunk failure (or a step
         retry) simply re-plans from its committed num_cached; no requeue
         is needed to keep the running set consistent.
+
+        A decode is a dispatch (`_dispatch_decode`) and a commit
+        (`_commit_head`); the pipeline depth says which step commits. At
+        depth 0 the commit follows its dispatch at once and the step
+        returns with nothing in flight. At depth 1
+        (EngineConfig.async_scheduling) the record stays in flight and
+        steady state CHAINS: the in-flight decode's on-device
+        `next_tokens` feed the next dispatch directly (positions and
+        context_lens advance +1 — deterministic, value-free), THEN the
+        in-flight step's values are fetched and committed one step
+        behind, so the device is already running step N+1 while the host
+        emits step N's tokens and plans admissions. Everything
+        value-dependent is a pipeline-flush boundary (commit everything,
+        then schedule normally): speculation (the proposer reads
+        committed token history), any batch-composition change (finish /
+        abort / preemption / a prompt joining — the chained token input
+        is slot-aligned), block pressure the lookahead cannot cover
+        without preempting (preemption must never run under an in-flight
+        write), and a partially committed record left by a poison retry
+        (the one way depth 0 enters a step with a record in flight).
+
+        At depth 1 finishes are detected one step late, at commit: a
+        chained dispatch may decode one token PAST a sequence's
+        EOS/length stop. That overshoot token lands in the null block or
+        a lookahead block freed with the sequence, is skipped at its
+        record's commit, and never reaches a client. Greedy outputs are
+        token-identical at both depths across every feature knob.
 
         Instrumented, the step runs on the phase clock: entry opens
         `schedule`, the return opens `between` (or stops the clock when
         nothing is live); a step that raises leaves its phase open and
-        the next step's entry closes it. The body stays in this frame:
-        JAX walks the Python stack at every traced operation, so each
-        frame between warm-up and a program costs set-up seconds (PR 24:
-        one frame more read +14 s on a 146 s set-up under Serve)."""
-        if self._async:
-            return self._step_async()
+        the next step's entry closes it. Every dispatch is called from
+        this frame or one helper below it: JAX walks the Python stack at
+        every traced operation, so each frame between warm-up and a
+        program costs set-up seconds (PR 24: one frame more read +14 s on
+        a 146 s set-up under Serve)."""
         ecfg = self.engine_config
         preempted_before = self.scheduler.num_preemptions
-        step_hit_tokens = 0
         self._current_rid = None
         maybe_fail("llm.step")
         instrument = self._instrument
@@ -847,9 +902,36 @@ class LLMEngine:
         self._step_gap = None
         self._step_commits = []
 
-        # Deadline sweep BEFORE admission: a queued request whose deadline
-        # passed must never reach schedule_prefills (resource-true expiry).
+        # Deadline sweep BEFORE admission and before the chain attempt: a
+        # queued request whose deadline passed must never reach
+        # schedule_prefills (resource-true expiry), and an expiry changes
+        # the batch composition, so _try_chain refuses and the pipeline
+        # flushes — the expired sequence's in-flight token is dropped by
+        # _commit_head's inactive-skip, never emitted.
         self._expire_deadlines()
+        # Chained dispatch FIRST — before any commit, admission, or
+        # metric work: the whole point is that the device gets its next
+        # program while the host still owes this step's bookkeeping. A
+        # record whose tokens were fetched (a poison retry, mid-commit)
+        # or a second in-flight record never chains; both flush below.
+        chained_seqs: Optional[List[Sequence]] = None
+        if (
+            self._pipeline_depth
+            and self._spec is None
+            and len(self._inflight) == 1
+            and self._inflight[0].tokens_host is None
+        ):
+            chained_seqs = self._try_chain(self._inflight[0])
+        if chained_seqs is not None:
+            # Commit the record the chain fed from; the chained record
+            # stays in flight for the next iteration.
+            self._commit_head()
+        else:
+            # Flush boundary: commit everything in dispatch order, then
+            # schedule normally from fully committed state.
+            while self._inflight:
+                self._commit_head()
+
         admitted = self.scheduler.schedule_prefills(
             ecfg.max_prefills_per_step
         )
@@ -866,48 +948,59 @@ class LLMEngine:
         # the pre-chunking behavior).
         plans = self.scheduler.schedule_prefill_chunks(self._prefill_budget)
         prefill_info: List[dict] = []
-        step_hit_tokens += self._run_prefill_chunks(plans, prefill_info)
+        step_hit_tokens = self._run_prefill_chunks(plans, prefill_info)
 
-        decoding = self.scheduler.schedule_decode()
         spec_info: Optional[dict] = None
-        if decoding:
-            if self._spec is not None:
-                spec_info = self._run_verify(decoding)
-            if spec_info is None:
-                # Speculation off, or no sequence had proposals this step:
-                # the plain decode program is already compiled and exactly
-                # equivalent for one fed token per slot.
-                self._run_decode(decoding)
+        if chained_seqs is not None:
+            decoding = chained_seqs
         else:
-            # No decode this step: the next dispatch follows an idle
-            # stretch, not host scheduling work — don't count it as gap.
-            self._last_ready_t = None
+            decoding = self.scheduler.schedule_decode()
+            if decoding:
+                if self._spec is not None:
+                    spec_info = self._run_verify(decoding)
+                if spec_info is None:
+                    # Speculation off, or no sequence had proposals this
+                    # step: the plain decode program is already compiled
+                    # and exactly equivalent for one fed token per slot.
+                    self._dispatch_decode(decoding)
+                    if not self._pipeline_depth or self._spec is not None:
+                        # Depth 0; and speculation at any depth, whose
+                        # acceptance is value-dependent: commit now.
+                        self._commit_head(follows_dispatch=True)
+            else:
+                # No decode this step: the next dispatch follows an idle
+                # stretch, not host scheduling work — don't count it as gap.
+                self._last_ready_t = None
+        return self._finish_step(
+            t_step=t_step, bytes_before=bytes_before,
+            preempted_before=preempted_before, plans=plans,
+            step_hit_tokens=step_hit_tokens, step_restored=step_restored,
+            prefill_info=prefill_info, decoding=decoding,
+            spec_info=spec_info, chained=chained_seqs is not None,
+        )
+
+    def _finish_step(
+        self,
+        *,
+        t_step: float,
+        bytes_before: int,
+        preempted_before: int,
+        step_hit_tokens: int,
+        step_restored: int,
+        plans: List[tuple],
+        prefill_info: List[dict],
+        decoding: List[Sequence],
+        spec_info: Optional[dict],
+        chained: bool,
+    ) -> dict:
+        """The step's bookkeeping, after its last program is dispatched:
+        the metric family, the gauges, the flight record, the clock's
+        exit and the dict step() returns."""
+        clock = self._clock if self._instrument else None
         if clock is not None:
             clock.switch("other")
-
         self._steps += 1
-        # A stepping engine exports its whole metric family: counters and
-        # histograms that happen not to fire after a registry reset (test
-        # isolation) must still re-register, or their series vanish from
-        # the exposition. One int compare each — nothing on the token path.
-        family = (
-            self._preemptions, self._prefix_hits, self._tokens_generated,
-            self._dead_letter_count, self._shed_count, self._expired_count,
-            self._h_ttft, self._h_tpot,
-            self._h_queue, self._h_e2e, self._h_step, self._h_host_gap,
-        )
-        if self._spec is not None:
-            family = family + (
-                self._spec_proposed, self._spec_accepted,
-                self._spec_acceptance,
-            )
-        if self._fabric is not None:
-            family = family + (
-                self._fabric_spills, self._fabric_restores,
-                self._fabric_hits, self._fabric_hit_rate,
-                self._fabric_bytes_used, self._fabric_timeouts,
-            )
-        for metric in family:
+        for metric in self._metric_family:
             metric._ensure_registered()
         preempted = self.scheduler.num_preemptions - preempted_before
         if preempted:
@@ -915,7 +1008,7 @@ class LLMEngine:
         if step_hit_tokens:
             self._cache_hit_tokens += step_hit_tokens
             self._prefix_hits.inc(step_hit_tokens, tags=self._metric_tags)
-        occupancy = len(decoding) / ecfg.max_decode_slots
+        occupancy = len(decoding) / self.engine_config.max_decode_slots
         self._occupancy.set(occupancy, tags=self._metric_tags)
         self._cache_util.set(self.allocator.utilization(), tags=self._metric_tags)
         self._queue_depth.set(len(self.scheduler.waiting), tags=self._metric_tags)
@@ -933,16 +1026,20 @@ class LLMEngine:
             )
         backlog = self.scheduler.prefill_backlog_tokens()
         self._prefill_backlog.set(backlog, tags=self._metric_tags)
-        if instrument:
-            decode_label = "verify" if spec_info is not None else "decode"
-            phase = "+".join(
-                p
-                for p, on in (("prefill", plans), (decode_label, decoding))
-                if on
-            ) or "idle"
+        if clock is not None:
+            parts = []
+            if plans:
+                parts.append("prefill")
+            if decoding:
+                parts.append("verify" if spec_info is not None else "decode")
+            elif self._step_commits:
+                # Drain-only iteration: nothing dispatched, but a stale
+                # in-flight record committed (e.g. every member finished
+                # or aborted since its dispatch).
+                parts.append("commit")
             record = {
                 "step": self._steps - 1,
-                "phase": phase,
+                "phase": "+".join(parts) or "idle",
                 "attn_impl": self._attn_impl,
                 "tensor_parallel_size": self._tp,
                 # Explicit host<->device bytes this step moved (program
@@ -962,27 +1059,29 @@ class LLMEngine:
                 "tokens_in": sum(p["tokens"] for p in prefill_info),
                 "prefill_budget": self._prefill_budget,
                 "prefill_backlog_tokens": backlog,
+                # Tokens COMMITTED this iteration (prefill finals + decode
+                # or verify commits) — a dispatched-but-uncommitted token
+                # is not out yet.
                 "tokens_out": sum(1 for p in prefill_info if p["final"])
-                + (
-                    spec_info["emitted"]
-                    if spec_info is not None
-                    else len(decoding)
-                ),
+                + sum(c["tokens"] for c in self._step_commits),
                 "cache_hit_tokens": step_hit_tokens,
                 "preempted": preempted,
                 "queue_depth": len(self.scheduler.waiting),
                 "time": t_step,
-                # Dispatch/commit apparatus (sync loop: both halves run
-                # in this step, so commits reference this step's own
-                # dispatch index; host_gap_s runs from the previous
+                # Which dispatch each commit of this step belongs to (its
+                # own at depth 0), and host_gap_s from the previous
                 # decode's results to this step's decode dispatch, over
-                # any prefill chunk in between).
+                # any prefill chunk in between.
                 "commits": self._step_commits,
                 "host_gap_s": self._step_gap,
                 # duration_s and the measured phase seconds that sum to
                 # it (observability.ledger reads its columns from these).
                 **clock.step_record(),
             }
+            if self._pipeline_depth:
+                record["loop"] = "async"
+                record["chained"] = chained
+                record["inflight_depth"] = len(self._inflight)
             if spec_info is not None:
                 # Verify record: which proposer ran, how wide the fed
                 # bucket was, and the proposed/accepted/emitted counts —
@@ -991,7 +1090,6 @@ class LLMEngine:
             if self._fabric is not None:
                 record["fabric_restored_blocks"] = step_restored
             self.flight_recorder.record_step(record)
-        if clock is not None:
             clock.exit_step(self.has_work())
         return {
             "num_prefilled": len(plans),
@@ -1090,72 +1188,6 @@ class LLMEngine:
             self._fabric_spilled_total += n
             self._fabric_spills.inc(n, tags=self._metric_tags)
         return n
-
-    def _run_decode(self, decoding: List[Sequence]) -> None:
-        """One iteration-level decode dispatch: every running sequence
-        advances exactly one token through the batched decode program."""
-        ecfg = self.engine_config
-        clock = self._clock if self._instrument else None
-        t_decode = clock.switch("prepare") if clock is not None else 0.0
-        # Preallocated input buffers: zero-fill + repopulate, never
-        # allocate. Reuse is safe here because runner.decode blocks on
-        # the program's results before this step returns.
-        tokens = self._dec_tokens
-        positions = self._dec_positions
-        block_tables = self._dec_block_tables
-        context_lens = self._dec_context_lens
-        tokens.fill(0)
-        positions.fill(0)
-        block_tables.fill(0)
-        context_lens.fill(0)
-        context_tokens = 0
-        for i, seq in enumerate(decoding):
-            tokens[i] = seq.last_token
-            positions[i] = seq.num_cached
-            block_tables[i, : len(seq.block_table)] = seq.block_table
-            context_lens[i] = seq.num_cached
-            context_tokens += seq.num_cached
-        self._note_decode_work(len(decoding), context_tokens)
-        self._note_dispatch(pipelined=False)
-        next_tokens = self.runner.decode(
-            tokens, positions, block_tables, context_lens
-        )
-        if clock is not None:
-            # decode() returned == the program ran and its tokens are on
-            # host: everything until the next dispatch is host-side gap.
-            self._last_ready_t = clock.ready()
-        for i, seq in enumerate(decoding):
-            # Per-sequence section; placed before any mutation so a
-            # failure here leaves this sequence (and every later one,
-            # whose decode simply re-runs from unchanged state next
-            # step) consistent.
-            self._current_rid = seq.request.request_id
-            maybe_fail("llm.decode.seq", detail=seq.request.request_id)
-            seq.num_cached += 1
-            seq.generated.append(int(next_tokens[i]))
-            if seq.num_cached % ecfg.block_size == 0:
-                # A block just filled: publish it to the prefix cache
-                # before a finish below could release it.
-                self.scheduler.note_filled_blocks(seq)
-            self._emit(seq)
-            self._maybe_finish(seq)
-        self._current_rid = None
-        self._decode_tokens += len(decoding)
-        self._decode_slot_steps += ecfg.max_decode_slots
-        self._step_commits.append(
-            {
-                "dispatch_step": self._steps,
-                "time": time.time(),
-                "tokens": len(decoding),
-            }
-        )
-        if clock is not None:
-            # One observation per batched decode dispatch, never per
-            # token — the whole emission loop rides in it.
-            self._h_step.observe(
-                clock.switch("other") - t_decode,
-                tags=self._step_tags["decode"],
-            )
 
     def _run_verify(self, decoding: List[Sequence]) -> Optional[dict]:
         """Speculative verify phase: ask the proposer for up to k tokens
@@ -1292,16 +1324,7 @@ class LLMEngine:
             "emitted": emitted,
         }
 
-    # ---------------- async (double-buffered) stepping ----------------
-
-    def _note_decode_work(self, batch: int, context_tokens: int) -> None:
-        """What one decode dispatch asks the paged kernel to read: `batch`
-        sequences, `context_tokens` cached positions in all (the sum of
-        context_lens), in every layer."""
-        self._decode_dispatches += 1
-        self._decode_context_tokens += context_tokens
-        if self._instrument:
-            self._clock.describe_decode(batch, context_tokens)
+    # ---------------- decode: dispatch and commit ----------------
 
     def _note_dispatch(self, pipelined: bool) -> None:
         """Host-gap sample at a decode/verify device dispatch: how long
@@ -1326,222 +1349,13 @@ class LLMEngine:
         self._host_gap_last = gap
         self._h_host_gap.observe(gap, tags=self._metric_tags)
 
-    def _step_async(self) -> dict:
-        """One iteration of the async step loop (EngineConfig.
-        async_scheduling): decode splits into a dispatch phase and a
-        deferred commit phase, pipelined one step deep.
-
-        Steady state CHAINS: the in-flight decode's on-device
-        `next_tokens` feed the next dispatch directly (positions and
-        context_lens advance +1 — deterministic, value-free), THEN the
-        in-flight step's values are fetched and committed one step
-        behind, so the device is already running step N+1 while the host
-        emits step N's tokens and plans admissions. Everything
-        value-dependent is a pipeline-flush boundary (commit everything,
-        then schedule normally): speculation (the proposer reads
-        committed token history), any batch-composition change (finish /
-        abort / preemption / a prompt joining — the chained token input
-        is slot-aligned), block pressure the lookahead cannot cover
-        without preempting (preemption must never run under an in-flight
-        write), and a partially committed record left by a poison retry.
-
-        Finishes are detected one step late, at commit: a chained
-        dispatch may decode one token PAST a sequence's EOS/length stop.
-        That overshoot token lands in the null block or a lookahead block
-        freed with the sequence, is skipped at its record's commit, and
-        never reaches a client. Greedy outputs are token-identical to the
-        sync loop across every feature knob."""
-        ecfg = self.engine_config
-        preempted_before = self.scheduler.num_preemptions
-        step_hit_tokens = 0
-        self._current_rid = None
-        maybe_fail("llm.step")
-        instrument = self._instrument
-        clock = self._clock if instrument else None
-        if clock is not None:
-            clock.enter_step(self._steps)
-        t_step = time.time() if instrument else 0.0
-        bytes_before = self._host_transfer_bytes() if instrument else 0
-        self._step_gap = None
-        self._step_commits = []
-
-        # Deadline sweep before the chain attempt: an expiry changes the
-        # batch composition, so _try_chain refuses and the pipeline
-        # flushes — the expired sequence's in-flight token is dropped by
-        # _commit_head's inactive-skip, never emitted.
-        self._expire_deadlines()
-        # Chained dispatch FIRST — before any commit, admission, or
-        # metric work: the whole point is that the device gets its next
-        # program while the host still owes this step's bookkeeping. A
-        # record mid-partial-commit (poison retry) or a second in-flight
-        # record never chains; both flush below.
-        chained_seqs: Optional[List[Sequence]] = None
-        if (
-            self._spec is None
-            and len(self._inflight) == 1
-            and self._inflight[0].commit_idx == 0
-        ):
-            chained_seqs = self._try_chain(self._inflight[0])
-        if chained_seqs is not None:
-            # Commit the record the chain fed from (its async host copy
-            # has been in flight since its dispatch); the chained record
-            # stays in flight for the next iteration.
-            self._commit_head()
-        else:
-            # Flush boundary: commit everything in dispatch order, then
-            # schedule normally from fully committed state.
-            while self._inflight:
-                self._commit_head()
-
-        admitted = self.scheduler.schedule_prefills(
-            ecfg.max_prefills_per_step
+    def _still_decoding(self, seq: Sequence, rid: str) -> bool:
+        """Whether a dispatched slot still holds its running request."""
+        return (
+            seq.is_running
+            and not seq.prefilling
+            and self.scheduler.is_active(rid)
         )
-        step_restored = 0
-        if self._fabric is not None:
-            step_restored = self._apply_fabric_restores(admitted)
-        plans = self.scheduler.schedule_prefill_chunks(self._prefill_budget)
-        prefill_info: List[dict] = []
-        step_hit_tokens += self._run_prefill_chunks(plans, prefill_info)
-
-        spec_info: Optional[dict] = None
-        dispatched = chained_seqs is not None
-        if chained_seqs is not None:
-            decoding = chained_seqs
-        else:
-            decoding = self.scheduler.schedule_decode()
-            if decoding:
-                if self._spec is not None:
-                    # Speculation composes as flush-every-step: acceptance
-                    # is value-dependent, so the verify path runs the sync
-                    # dispatch+commit inline (still token-identical).
-                    spec_info = self._run_verify(decoding)
-                    if spec_info is None:
-                        self._run_decode(decoding)
-                else:
-                    self._dispatch_decode_async(decoding)
-                    dispatched = True
-            else:
-                self._last_ready_t = None
-        if clock is not None:
-            clock.switch("other")
-
-        self._steps += 1
-        family = (
-            self._preemptions, self._prefix_hits, self._tokens_generated,
-            self._dead_letter_count, self._shed_count, self._expired_count,
-            self._h_ttft, self._h_tpot,
-            self._h_queue, self._h_e2e, self._h_step, self._h_host_gap,
-        )
-        if self._spec is not None:
-            family = family + (
-                self._spec_proposed, self._spec_accepted,
-                self._spec_acceptance,
-            )
-        if self._fabric is not None:
-            family = family + (
-                self._fabric_spills, self._fabric_restores,
-                self._fabric_hits, self._fabric_hit_rate,
-                self._fabric_bytes_used, self._fabric_timeouts,
-            )
-        for metric in family:
-            metric._ensure_registered()
-        preempted = self.scheduler.num_preemptions - preempted_before
-        if preempted:
-            self._preemptions.inc(preempted, tags=self._metric_tags)
-        if step_hit_tokens:
-            self._cache_hit_tokens += step_hit_tokens
-            self._prefix_hits.inc(step_hit_tokens, tags=self._metric_tags)
-        occupancy = len(decoding) / ecfg.max_decode_slots
-        self._occupancy.set(occupancy, tags=self._metric_tags)
-        self._cache_util.set(
-            self.allocator.utilization(), tags=self._metric_tags
-        )
-        self._queue_depth.set(
-            len(self.scheduler.waiting), tags=self._metric_tags
-        )
-        self._prefix_hit_rate.set(
-            self._cache_hit_tokens / max(self._prefill_tokens, 1),
-            tags=self._metric_tags,
-        )
-        self._evictable_blocks.set(
-            self.allocator.num_evictable, tags=self._metric_tags
-        )
-        if self._fabric is not None:
-            self._fabric_hit_rate.set(
-                self._fabric_restored_tokens / max(self._prefill_tokens, 1),
-                tags=self._metric_tags,
-            )
-        backlog = self.scheduler.prefill_backlog_tokens()
-        self._prefill_backlog.set(backlog, tags=self._metric_tags)
-        committed_tokens = sum(c["tokens"] for c in self._step_commits)
-        if instrument:
-            decode_label = "verify" if spec_info is not None else "decode"
-            parts = []
-            if plans:
-                parts.append("prefill")
-            if spec_info is not None or (decoding and not dispatched):
-                parts.append(decode_label)
-            elif dispatched:
-                parts.append("decode")
-            elif self._step_commits:
-                # Drain-only iteration: nothing dispatched, but a stale
-                # in-flight record committed (e.g. every member finished
-                # or aborted since its dispatch).
-                parts.append("commit")
-            phase = "+".join(parts) or "idle"
-            record = {
-                "step": self._steps - 1,
-                "loop": "async",
-                "phase": phase,
-                "attn_impl": self._attn_impl,
-                "tensor_parallel_size": self._tp,
-                "host_transfer_bytes": (
-                    self._host_transfer_bytes() - bytes_before
-                ),
-                "batch_size": len(decoding),
-                "num_prefills": len(plans),
-                "prefills": prefill_info,
-                "tokens_in": sum(p["tokens"] for p in prefill_info),
-                "prefill_budget": self._prefill_budget,
-                "prefill_backlog_tokens": backlog,
-                # Async semantics: tokens_out counts tokens COMMITTED
-                # this iteration (prefill finals + deferred decode
-                # commits) — a dispatched-but-uncommitted token is not
-                # out yet.
-                "tokens_out": sum(1 for p in prefill_info if p["final"])
-                + (
-                    spec_info["emitted"]
-                    if spec_info is not None
-                    else committed_tokens
-                ),
-                "cache_hit_tokens": step_hit_tokens,
-                "preempted": preempted,
-                "queue_depth": len(self.scheduler.waiting),
-                "time": t_step,
-                "commits": self._step_commits,
-                "host_gap_s": self._step_gap,
-                "chained": chained_seqs is not None,
-                "inflight_depth": len(self._inflight),
-                **clock.step_record(),
-            }
-            if spec_info is not None:
-                record["speculation"] = spec_info
-            if self._fabric is not None:
-                record["fabric_restored_blocks"] = step_restored
-            self.flight_recorder.record_step(record)
-        if clock is not None:
-            clock.exit_step(self.has_work())
-        return {
-            "num_prefilled": len(plans),
-            "num_decoding": len(decoding),
-            "occupancy": occupancy,
-            "cache_utilization": self.allocator.utilization(),
-            "queue_depth": len(self.scheduler.waiting),
-            "preempted": preempted,
-            "cache_hit_tokens": step_hit_tokens,
-            "evictable_blocks": self.allocator.num_evictable,
-            "prefill_backlog_tokens": backlog,
-        }
 
     def _try_chain(self, rec: _InflightStep) -> Optional[List[Sequence]]:
         """Chain the in-flight decode into the next dispatch if — and
@@ -1551,110 +1365,87 @@ class LLMEngine:
         covered without preempting anyone (reserve_decode_lookahead).
         On success the chained program is already dispatched when this
         returns; on any mismatch returns None and the caller flushes."""
-        for seq, rid in zip(rec.seqs, rec.rids):
-            if (
-                not seq.is_running
-                or seq.prefilling
-                or not self.scheduler.is_active(rid)
-            ):
-                return None
+        if not all(map(self._still_decoding, rec.seqs, rec.rids)):
+            return None
         current = [s for s in self.scheduler.running if not s.prefilling]
-        if len(current) != len(rec.seqs) or any(
-            a is not b for a, b in zip(current, rec.seqs)
-        ):
+        if current != rec.seqs:  # a Sequence equals only itself
             return None
         if not self.scheduler.reserve_decode_lookahead(rec.seqs):
             return None
-        self._dispatch_chained(rec)
+        self._dispatch_decode(rec.seqs, chained_from=rec)
         return rec.seqs
 
-    def _dispatch_chained(self, rec: _InflightStep) -> None:
-        """Dispatch the next decode with the in-flight step's on-device
-        tokens as input — no host sync anywhere on this path. The
-        in-flight token for slot i has not committed yet, so its write
-        position is num_cached + 1 and its context covers num_cached + 1
-        tokens; both advance deterministically without knowing the
-        token's value. Unused slots carry whatever the previous program
-        sampled — they scatter into the null block exactly like the sync
-        path's zero padding."""
-        clock = self._clock if self._instrument else None
-        if clock is not None:
-            clock.switch("prepare")
-        self._note_dispatch(pipelined=True)
-        positions = self._dec_positions
-        block_tables = self._dec_block_tables
-        context_lens = self._dec_context_lens
-        positions.fill(0)
-        block_tables.fill(0)
-        context_lens.fill(0)
-        context_tokens = 0
-        for i, seq in enumerate(rec.seqs):
-            positions[i] = seq.num_cached + 1
-            block_tables[i, : len(seq.block_table)] = seq.block_table
-            context_lens[i] = seq.num_cached + 1
-            context_tokens += seq.num_cached + 1
-        self._note_decode_work(len(rec.seqs), context_tokens)
-        tokens_dev = self.runner.decode_async(
-            rec.tokens_dev, positions, block_tables, context_lens
-        )
-        self._inflight.append(
-            _InflightStep(
-                rec.seqs, rec.rids, tokens_dev, self._steps,
-                self._close_async_dispatch(),
-            )
-        )
+    def _dispatch_decode(
+        self,
+        seqs: List[Sequence],
+        chained_from: Optional[_InflightStep] = None,
+    ) -> None:
+        """One iteration-level decode dispatch: every sequence of `seqs`
+        advances one token through the batched decode program, and the
+        record joins `_inflight` for `_commit_head`.
 
-    def _dispatch_decode_async(self, decoding: List[Sequence]) -> None:
-        """Fresh async dispatch from fully committed state (pipeline
-        start / after a flush): inputs build exactly like _run_decode,
-        but the runner starts an async device->host copy instead of
-        blocking — the commit runs one step later (_commit_head)."""
-        if self._instrument:
-            self._clock.switch("prepare")
+        From committed state the inputs are each sequence's last token
+        and num_cached. Chained, the tokens are the in-flight record's
+        on-device `next_tokens` — no host sync anywhere on this path —
+        and the in-flight token for slot i has not committed yet, so its
+        write position is num_cached + 1 and its context covers
+        num_cached + 1 tokens; both advance deterministically without
+        knowing the token's value. Unused slots then carry whatever the
+        previous program sampled — they scatter into the null block
+        exactly like the zero padding.
+
+        The runner's hook leaves the clock in `wait`: the commit that
+        follows, or the step's tail, moves it on."""
+        clock = self._clock if self._instrument else None
+        t_prepare = clock.switch("prepare") if clock is not None else 0.0
+        # Preallocated input buffers: zero-fill + repopulate, never
+        # allocate (runner.decode copies them at dispatch).
+        ahead = 0 if chained_from is None else 1
         tokens = self._dec_tokens
         positions = self._dec_positions
         block_tables = self._dec_block_tables
         context_lens = self._dec_context_lens
-        tokens.fill(0)
         positions.fill(0)
         block_tables.fill(0)
         context_lens.fill(0)
+        if not ahead:
+            tokens.fill(0)
         context_tokens = 0
-        for i, seq in enumerate(decoding):
-            tokens[i] = seq.last_token
-            positions[i] = seq.num_cached
+        for i, seq in enumerate(seqs):
+            cached = seq.num_cached + ahead
+            if not ahead:
+                tokens[i] = seq.last_token
+            positions[i] = cached
             block_tables[i, : len(seq.block_table)] = seq.block_table
-            context_lens[i] = seq.num_cached
-            context_tokens += seq.num_cached
-        self._note_decode_work(len(decoding), context_tokens)
-        self._note_dispatch(pipelined=False)
-        tokens_dev = self.runner.decode_async(
-            tokens, positions, block_tables, context_lens
+            context_lens[i] = cached
+            context_tokens += cached
+        # What this dispatch asks the paged kernel to read: len(seqs)
+        # sequences, context_tokens cached positions in all (the sum of
+        # context_lens), in every layer.
+        self._decode_dispatches += 1
+        self._decode_context_tokens += context_tokens
+        if clock is not None:
+            clock.describe_decode(len(seqs), context_tokens)
+        self._note_dispatch(pipelined=bool(ahead))
+        tokens_dev = self.runner.decode(
+            chained_from.tokens_dev if ahead else tokens,
+            positions, block_tables, context_lens,
         )
+        rids = [s.request.request_id for s in seqs]
+        clock_seq = clock.dispatches if clock is not None else None
         self._inflight.append(
             _InflightStep(
-                list(decoding),
-                [s.request.request_id for s in decoding],
-                tokens_dev,
-                self._steps,
-                self._close_async_dispatch(),
+                seqs, rids, tokens_dev, self._steps, clock_seq, t_prepare
             )
         )
 
-    def _close_async_dispatch(self) -> Optional[int]:
-        """After an async decode dispatch nothing is waited for: the step
-        goes on scheduling. Returns the clock's number of the dispatch
-        (None uninstrumented), which its commit hands back a step later."""
-        if not self._instrument:
-            return None
-        self._clock.switch("schedule")
-        return self._clock.dispatches
+    def _commit_head(self, follows_dispatch: bool = False) -> None:
+        """Fetch and commit the OLDEST in-flight record: per-sequence
+        poison site, num_cached advance, block publication, emission,
+        finish detection. `follows_dispatch` says that the step which
+        dispatched the record commits it at once (depth 0, speculation),
+        with the step's tail next and not admission.
 
-    def _commit_head(self) -> None:
-        """Fetch and commit the OLDEST in-flight record — the deferred
-        half of a dispatch made one iteration ago. The commit loop is the
-        sync path's, one step late: per-sequence poison site, num_cached
-        advance, block publication, emission, finish detection.
         Sequences that went inactive since dispatch (finished at the
         previous commit, aborted, preempted on a flush) are skipped —
         their fetched token is the EOS/length overshoot or an orphan, and
@@ -1672,29 +1463,35 @@ class LLMEngine:
             t0 = clock.switch("wait" if fetch else "commit")
         self._attribution_step = rec.dispatch_step
         if fetch:
-            # Materialize the async copy (usually already done — it has
-            # been in flight since dispatch). A failed decode PROGRAM
-            # surfaces here, one step after dispatch, attributed above.
+            # Materialize the async copy (in flight since dispatch). A
+            # failed decode PROGRAM surfaces here, attributed above. The
+            # device array goes now: freeing it releases the GIL, which
+            # after the emission loop the streams' consumer threads take
+            # with the device idle (1 ms a step in the chat cell, PR 30).
             rec.tokens_host = np.asarray(rec.tokens_dev)
+            rec.tokens_dev = None
             if clock is not None:
+                # The tokens are on host: everything until the next
+                # dispatch is host-side gap.
                 self._last_ready_t = clock.ready(rec.clock_seq)
         next_tokens = rec.tokens_host
         committed = 0
         while rec.commit_idx < len(rec.seqs):
             i = rec.commit_idx
             seq = rec.seqs[i]
-            if (
-                not seq.is_running
-                or seq.prefilling
-                or not self.scheduler.is_active(rec.rids[i])
-            ):
+            if not self._still_decoding(seq, rec.rids[i]):
                 rec.commit_idx += 1
                 continue
+            # Per-sequence section; placed before any mutation so a
+            # failure here leaves this sequence (and every later one,
+            # whose commit the retry resumes) consistent.
             self._current_rid = rec.rids[i]
             maybe_fail("llm.decode.seq", detail=rec.rids[i])
             seq.num_cached += 1
             seq.generated.append(int(next_tokens[i]))
             if seq.num_cached % ecfg.block_size == 0:
+                # A block just filled: publish it to the prefix cache
+                # before a finish below could release it.
                 self.scheduler.note_filled_blocks(seq)
             rec.commit_idx += 1
             committed += 1
@@ -1713,11 +1510,13 @@ class LLMEngine:
             }
         )
         if clock is not None:
-            # The async decode series measures the commit half (fetch +
-            # emission loop) — the dispatch half is what the chain hides.
-            self._h_step.observe(
-                clock.switch("schedule") - t0, tags=self._step_tags["decode"]
-            )
+            # One observation per batched decode dispatch, never per
+            # token; deferred, the commit's half (the chain hides the other).
+            if follows_dispatch:
+                took = clock.switch("other") - rec.t_prepare
+            else:
+                took = clock.switch("schedule") - t0
+            self._h_step.observe(took, tags=self._step_tags["decode"])
 
     def _run_prefill_chunks(
         self,
@@ -2003,12 +1802,12 @@ class LLMEngine:
             "host_transfer_bytes": self._host_transfer_bytes(),
             "steps": self._steps,
             "decode_tokens": self._decode_tokens,
-            # Async step loop (EngineConfig.async_scheduling) + the
-            # host-gap apparatus it is measured by: mean/last host time
-            # between consecutive device dispatches (0 for a chained
-            # async dispatch — it beat the previous step's fetch), and
-            # how many records are dispatched-but-uncommitted right now.
-            "async_scheduling": self._async,
+            # The pipeline's depth (EngineConfig.async_scheduling) + the
+            # host-gap apparatus: mean/last host time between consecutive
+            # decode dispatches (0 for a chained dispatch — it beat the
+            # previous step's fetch), and how many records are
+            # dispatched-but-uncommitted right now.
+            "async_scheduling": bool(self._pipeline_depth),
             "inflight_steps": len(self._inflight),
             "host_gap_samples": self._host_gap_count,
             "host_gap_total_s": self._host_gap_total,
@@ -2180,19 +1979,19 @@ class LLMServer:
             # (partial prefill), silently skipping the compile — and the
             # publish/spill side would flood the shared store with
             # zero-block entries every replica start.
-            # Async stepping is suppressed during warmup as well: the
-            # generate-based rounds must compile each bucket program in
-            # a deterministic order with deterministic step counts, and
-            # the async loop's chained decode dispatches the SAME
-            # compiled program anyway (identical avals — a device token
-            # array and a host one trace alike), so async mode needs no
-            # warmup pass of its own.
+            # The pipeline is suppressed during warmup as well (depth
+            # 0): the generate-based rounds must compile each bucket
+            # program in a deterministic order with deterministic step
+            # counts, and a chained decode dispatches the SAME compiled
+            # program anyway (identical avals — a device token array and
+            # a host one trace alike), so depth 1 needs no warmup pass
+            # of its own.
             instrumented = self._engine._instrument
             spec = self._engine._spec
             publish = self._engine._publish_on_fill
             on_evict = self._engine.allocator.on_evict
             probe = self._engine.scheduler.fabric_probe
-            async_loop = self._engine._async
+            depth = self._engine._pipeline_depth
             self._engine._instrument = False
             # ray-tpu: lint-ignore[RTL403] deliberate temporary clear —
             # the finally below restores _spec on every path, so no
@@ -2201,7 +2000,7 @@ class LLMServer:
             self._engine._publish_on_fill = False
             self._engine.allocator.on_evict = None
             self._engine.scheduler.fabric_probe = None
-            self._engine._async = False
+            self._engine._pipeline_depth = 0
             t_warmup = time.perf_counter()
             try:
                 self._warmup()
@@ -2212,7 +2011,7 @@ class LLMServer:
                 self._engine._publish_on_fill = publish
                 self._engine.allocator.on_evict = on_evict
                 self._engine.scheduler.fabric_probe = probe
-                self._engine._async = async_loop
+                self._engine._pipeline_depth = depth
             if spec is not None:
                 self._warmup_verify(spec)
         self._lock = threading.Lock()
@@ -2373,10 +2172,10 @@ class LLMServer:
                     # isolation entirely).
                     culprit = self._engine.culprit_for(exc)
                     recorder = self._engine.flight_recorder
-                    # Under async_scheduling a commit-time failure is
-                    # attributed one step late: failure_step() resolves
-                    # to the in-flight record's DISPATCH index (sync
-                    # mode: the current step, as before).
+                    # A commit-time failure is attributed to the step
+                    # that dispatched the program: failure_step() resolves
+                    # to the in-flight record's DISPATCH index (one step
+                    # back at pipeline depth 1, this step at depth 0).
                     step_idx = self._engine.failure_step()
                     if culprit is not None:
                         # Poison-request isolation: fail only the culpable

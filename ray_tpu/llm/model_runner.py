@@ -408,8 +408,8 @@ class GPTRunner:
         self.engine_config = engine_config
         # Called where a step program's dispatch call has returned and
         # its results have not been asked for (prefill, suffix, verify,
-        # decode; at the end of decode_async, which fetches nothing): the
-        # engine's phase clock ends `prepare` and begins `wait` there.
+        # decode): the engine's phase clock ends `prepare` and begins
+        # `wait` there.
         self.on_dispatched: Optional[Callable[[], None]] = None
         # Intra-replica tensor parallelism: one mesh with a `tp` axis over
         # the first tensor_parallel_size backend devices; None at tp=1 so
@@ -796,61 +796,41 @@ class GPTRunner:
 
     def decode(
         self,
-        tokens: np.ndarray,
-        positions: np.ndarray,
-        block_tables: np.ndarray,
-        context_lens: np.ndarray,
-    ) -> np.ndarray:
-        """Batched single-token decode; arrays must already be padded to
-        [max_decode_slots] / [max_decode_slots, max_blocks_per_seq]."""
-        pools, next_tokens = self._decode_fn(
-            self.params,
-            *self._pools,
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(positions, jnp.int32),
-            jnp.asarray(block_tables, jnp.int32),
-            jnp.asarray(context_lens, jnp.int32),
-        )
-        self._set_pools(pools)
-        self._dispatched()
-        next_tokens = np.asarray(next_tokens)
-        self._count_transfer(
-            (tokens, positions, block_tables, context_lens), next_tokens
-        )
-        return next_tokens
-
-    def decode_async(
-        self,
         tokens,
         positions: np.ndarray,
         block_tables: np.ndarray,
         context_lens: np.ndarray,
     ) -> jax.Array:
-        """Dispatch one batched decode WITHOUT waiting for its result.
+        """Dispatch one batched single-token decode WITHOUT waiting for
+        its result; arrays must already be padded to [max_decode_slots] /
+        [max_decode_slots, max_blocks_per_seq].
 
-        Same compiled program as `decode` (identical avals, so no extra
-        compile), but the sampled tokens stay on device: `tokens` may be
-        the previous step's on-device `next_tokens` (token chaining — it
-        is not donated, so the caller can still fetch it afterwards), and
-        the return value is the device array for THIS step with an async
+        The sampled tokens stay on device: `tokens` may be the previous
+        step's on-device `next_tokens` (token chaining — it is not
+        donated, so the caller can still fetch it afterwards), and the
+        return value is the device array for THIS step with an async
         device->host copy already started. The caller materializes the
-        values one step later with `np.asarray` at commit time.
+        values with `np.asarray` when it commits them: at once, or one
+        step later.
 
-        The host-side numpy inputs are converted with `jnp.array`
-        (guaranteed copy): the engine reuses these buffers across steps,
-        and a zero-copy alias would let next step's buffer fill corrupt a
-        still-running program's inputs.
+        The host-side numpy inputs are copied on the host first: the
+        engine reuses these buffers across steps, and a zero-copy alias
+        (the CPU backend makes one) would let next step's buffer fill
+        corrupt a still-running program's inputs. Not `jnp.array`: its
+        guaranteed copy is a `convert_element_type` program on the device
+        for each input, 0.5 ms a step for the four (chip run, PR 30).
         """
         chained = isinstance(tokens, jax.Array)
         pools, next_tokens = self._decode_fn(
             self.params,
             *self._pools,
-            tokens if chained else jnp.array(tokens, jnp.int32),
-            jnp.array(positions, jnp.int32),
-            jnp.array(block_tables, jnp.int32),
-            jnp.array(context_lens, jnp.int32),
+            tokens if chained else jnp.asarray(tokens.copy(), jnp.int32),
+            jnp.asarray(positions.copy(), jnp.int32),
+            jnp.asarray(block_tables.copy(), jnp.int32),
+            jnp.asarray(context_lens.copy(), jnp.int32),
         )
         self._set_pools(pools)
+        self._dispatched()
         try:
             next_tokens.copy_to_host_async()
         except (AttributeError, NotImplementedError):  # pragma: no cover
@@ -861,5 +841,4 @@ class GPTRunner:
         if not chained:
             host_in = (tokens,) + host_in
         self._count_transfer(host_in, next_tokens)
-        self._dispatched()
         return next_tokens

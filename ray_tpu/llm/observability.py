@@ -256,7 +256,7 @@ class StepPhaseClock:
     can know: the newest program it dispatched had become host-readable
     (programs finish in dispatch order, so that covers every older one). A
     dispatch made while a program is still out, as a chained async decode
-    is, samples 0. In the synchronous loop the samples add up to the
+    is, samples 0. At pipeline depth 0 the samples add up to the
     non-wait phases.
     """
 

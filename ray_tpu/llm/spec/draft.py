@@ -131,7 +131,9 @@ class DraftModelProposer(Proposer):
                 positions[j] = n + t - 1
                 tables[j, : len(st.block_table)] = st.block_table
                 ctx[j] = n + t - 1
-            next_tokens = self.runner.decode(tokens, positions, tables, ctx)
+            next_tokens = np.asarray(
+                self.runner.decode(tokens, positions, tables, ctx)
+            )
             for j, (i, n, st) in enumerate(live):
                 props[i].append(int(next_tokens[j]))
         return props

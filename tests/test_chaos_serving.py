@@ -805,16 +805,20 @@ def test_poisoned_request_isolated_through_serve_path(serve_ray):
     assert stats["wedged"] is False
 
 
-# ---------------- async step loop (PR 17) ----------------
+# ---------------- pipeline depth 1 (async_scheduling, PR 17) ----------------
 
 
 def test_async_poisoned_decode_attributes_one_step_late():
-    """Under async_scheduling a poisoned decode sequence surfaces at
-    COMMIT, one step after its program was dispatched. The failure must
-    be attributed to the DISPATCH step (failure_step() == current step
-    - 1, vs == current step in the sync loop), dead-letter only the
-    culprit with that step index, leave the innocent batchmate
-    token-identical, and return the pools to boot size."""
+    """A poisoned decode sequence surfaces at COMMIT: at pipeline depth
+    1 (async_scheduling) one step after its program was dispatched, at
+    depth 0 in the same step. The failure must be attributed to the
+    DISPATCH step (failure_step() == current step - 1, vs == current
+    step at depth 0), dead-letter only the culprit with that step index,
+    leave the innocent batchmate token-identical, and return the pools
+    to boot size. At both depths the record stays at the head of the
+    pipeline with its commit pointer on the culprit's slot, and the next
+    step resumes it: nothing is decoded twice, nothing is emitted for
+    the dead-lettered slot."""
     prompts = random_prompts((7, 6), seed=4)
     attributed = {}
     for mode in (False, True):
@@ -844,9 +848,25 @@ def test_async_poisoned_decode_attributes_one_step_late():
                 eng.step()
         attributed[mode] = (eng.failure_step(), eng._steps)
         assert eng.culprit_for(RuntimeError()) == "poison-me"
+        if not mode:
+            # The survivor's slot committed, the culprit's did not, and
+            # the record waits for the retry.
+            (record,) = eng._inflight
+            assert record.rids == ["ok-0", "poison-me"]
+            assert record.commit_idx == 1
         assert eng.fail_request(
             "poison-me", RuntimeError("decode bitflip")
         )
+        if not mode:
+            dispatches = eng.stats()["decode_dispatches"]
+            emitted = len(ok_tokens)
+            eng.step()
+            # The retry popped the record without a token for the dead
+            # slot (the survivor had its own already), then decoded the
+            # survivor once: one program, one token.
+            assert record not in eng._inflight and record.commit_idx == 2
+            assert eng.stats()["decode_dispatches"] == dispatches + 1
+            assert len(ok_tokens) == emitted + 1
         while eng.has_work():
             eng.step()
         want = reference_greedy(

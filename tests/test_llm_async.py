@@ -1,8 +1,9 @@
-"""Async double-buffered step loop (EngineConfig.async_scheduling).
+"""The step loop at pipeline depth 1 (EngineConfig.async_scheduling)
+against depth 0.
 
-The tentpole splits each decode step into a dispatch phase and a deferred
-commit phase, pipelined one step deep: while step N's program runs on
-device, the host plans and dispatches step N+1 by chaining decode's
+Depth 1 defers each decode's commit to the next step: while step N's
+program runs on device, the host plans and dispatches step N+1 by chaining
+decode's
 `next_tokens` device array straight into the next step's `tokens` input
 (positions/context_lens advance +1 deterministically) and fetching values
 one step behind via `copy_to_host_async`. These tests pin the contract:
@@ -12,8 +13,8 @@ one step behind via `copy_to_host_async`. These tests pin the contract:
     preempt-resume under a tight pool, int8 KV, ngram + draft speculation,
     the pallas kernel in interpret mode, tp=2, KV fabric);
   * EOS / max-token finishes are detected one step late but the overshoot
-    token NEVER reaches the client — proven with a fixed-point prompt
-    whose greedy stream repeats its own EOS (a leak would duplicate it);
+    token NEVER reaches the client — proven with a prompt, found from a
+    fixed seed, whose greedy stream first emits its EOS mid-stream;
   * the steady decode path allocates NO fresh host input buffers per step
     (preallocated, reused, asserted by allocation count) in either mode;
   * per-step dispatch/commit timestamps land in the flight record and the
@@ -124,7 +125,7 @@ def test_async_greedy_matches_sync_and_reference():
         assert out == reference_greedy(model, eng.runner.params, prompt, 8)
     steps = eng.flight_recorder.snapshot()["steps"]
     chained = [s for s in steps if s.get("chained")]
-    assert len(chained) >= 4, "async loop never chained a dispatch"
+    assert len(chained) >= 4, "depth 1 never chained a dispatch"
     assert all(s["host_gap_s"] == 0.0 for s in chained)
     assert all(s["loop"] == "async" for s in chained)
 
@@ -148,8 +149,8 @@ MATRIX = {
 def test_async_identity_feature_matrix(feature):
     """Async on/off token identity across the feature matrix. Spec modes
     flush the pipeline every step (the proposer reads committed tokens),
-    so they exercise the async loop's non-chained dispatch + one-step-late
-    commit path rather than chaining."""
+    so at depth 1 they exercise the dispatch that is committed in its own
+    step rather than chaining."""
     kw = dict(MATRIX[feature])
     two_layer = kw.pop("TINY", False)
     repeat = kw.pop("repeat", False)
@@ -172,7 +173,7 @@ def test_async_identity_feature_matrix(feature):
 
 def test_async_identity_under_preemption_pressure():
     """A pool far too small for the working set forces preempt-resume;
-    the async loop must flush before any step that preempts (a preempted
+    depth 1 must flush before any step that preempts (a preempted
     sequence's blocks cannot be freed with a dispatch in flight) and the
     recompute path stays token-identical."""
     kw = dict(
@@ -244,22 +245,35 @@ def test_async_identity_kv_fabric():
 # ---------------- EOS overshoot ----------------
 
 
+def find_late_eos(n_new=12, min_index=3, draws=64):
+    """A prompt whose greedy stream has a token that first appears at an
+    index >= min_index (and not at the very end, so a token exists past
+    it to overshoot into): (prompt, reference stream, index). Searched
+    from a fixed seed rather than pinned, because the stream is the
+    argmax of random weights and any change to the model's numerics
+    moves it."""
+    eng = LLMEngine(TINY, EngineConfig(**BASE), seed=0)
+    rng = np.random.RandomState(17)
+    for _ in range(draws):
+        prompt = list(map(int, rng.randint(0, TINY.vocab_size, size=6)))
+        want = eng.generate([prompt], max_new_tokens=n_new)[0]
+        for k in range(min_index, n_new - 1):
+            if want[k] not in want[:k]:
+                return prompt, want, k
+    pytest.fail(
+        f"no prompt in {draws} draws whose greedy stream first emits a "
+        f"token at an index >= {min_index}: the EOS fixture cannot be built"
+    )
+
+
 def test_async_eos_overshoot_never_emitted():
     """EOS finishes are detected one step late under async_scheduling:
     when the commit of step N sees the EOS, the chained step N+1 has
     already run on device. That overshoot token must never reach the
-    client. The prompt is a fixed point — its greedy stream repeats the
-    EOS value forever ([83, 83, 83, 83, 15, 15, 15, ...], eos=15 first
-    emitted at index 4) — so a leaked overshoot would show up as a
-    duplicate EOS, the one corruption a lenient client would miss."""
-    prompt = [67, 123, 67, 103, 9, 83]
-    eng_ref = LLMEngine(TINY, EngineConfig(**BASE), seed=0)
-    want = eng_ref.generate([prompt], max_new_tokens=12)[0]
-    k = 4
+    client: the stream ends with the EOS, exactly as the reference does
+    when cut there, at both depths."""
+    prompt, want, k = find_late_eos()
     eos = want[k]
-    assert want[k + 1] == eos and eos not in want[:k], (
-        "fixture drifted: stream no longer repeats its EOS", want
-    )
     for mode in (False, True):
         eng = LLMEngine(
             TINY, EngineConfig(async_scheduling=mode, **BASE), seed=0
@@ -303,10 +317,13 @@ def test_async_max_tokens_overshoot_not_emitted():
 
 @pytest.mark.parametrize("mode", (False, True))
 def test_steady_decode_allocates_no_fresh_host_buffers(mode):
-    """The per-step decode inputs (tokens/positions/block_tables/
-    context_lens) are preallocated at engine init and reused: steady
-    decode steps make ZERO np.zeros allocations in either loop mode,
-    and the buffer objects themselves are stable across steps."""
+    """The engine's per-step decode inputs (tokens/positions/
+    block_tables/context_lens) are preallocated at engine init and
+    reused: steady decode steps make ZERO np.zeros allocations at either
+    depth, and the buffer objects themselves are stable across steps.
+    What this does not count: `GPTRunner.decode` makes one small host
+    copy of each input at dispatch (the program must not alias a buffer
+    the next step refills)."""
     eng = LLMEngine(
         TINY, EngineConfig(async_scheduling=mode, **BASE), seed=0
     )

@@ -12,7 +12,7 @@ queueing toward collapse. These tests pin the two mechanisms:
     deadline passed while queued is dropped before schedule_prefills can
     feed it to a prefill program (prefill_tokens stays 0); one expiring
     mid-decode is aborted within one step with its KV (and, under
-    speculation=draft, mirror) blocks reclaimed, in BOTH step loops —
+    speculation=draft, mirror) blocks reclaimed, at BOTH pipeline depths —
     including between dispatch and deferred commit under
     async_scheduling, where _commit_head's inactive-skip must drop the
     in-flight orphan token;
@@ -192,7 +192,7 @@ def test_mid_decode_expiry_frees_blocks_within_one_step(async_mode):
     """A DECODING request crossing its deadline is aborted by the very
     next step's sweep — blocks back to zero immediately, not after a
     drain — and its delivered prefix plus an undisturbed neighbour are
-    token-identical to reference. Parametrized over both step loops: under
+    token-identical to reference. Parametrized over both pipeline depths: under
     async_scheduling the sweep runs between dispatch and deferred commit,
     so _commit_head's inactive-skip must drop the orphan token."""
     eng = LLMEngine(
@@ -336,7 +336,7 @@ def test_async_draft_abort_releases_mirror_blocks():
 @pytest.mark.parametrize("async_mode", [False, True])
 def test_expiry_under_draft_releases_mirror_blocks(async_mode):
     """Deadline expiry (not abort) with speculation=draft: mirror blocks
-    are reclaimed through the same finish teardown in both loops."""
+    are reclaimed through the same finish teardown at both depths."""
     eng = LLMEngine(
         TINY,
         EngineConfig(

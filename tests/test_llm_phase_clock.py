@@ -1,6 +1,6 @@
 """The engine's one clock (llm.observability.StepPhaseClock / CompileClock).
 
-  * the phases partition the step loop's wall time, in both loops, and every
+  * the phases partition the step loop's wall time, at both depths, and every
     flight record's phases sum to its duration;
   * a prefill chunk that runs between two decode batches adds its device time
     to `wait` and nothing to `host_exposed` — the fault of the old host gap,
@@ -88,7 +88,7 @@ def test_phases_partition_the_wall_time(mode):
             record["duration_s"], abs=1e-5
         )
     if not mode:
-        # Synchronous loop: the device has nothing queued whenever the host
+        # Depth 0: the device has nothing queued whenever the host
         # is not waiting, so exposed is the sum of the non-wait phases
         # (less the first dispatch after idle, which has no sample).
         exposed = after["host_exposed_total_s"] - before["host_exposed_total_s"]
@@ -98,7 +98,7 @@ def test_phases_partition_the_wall_time(mode):
 
 
 def test_prefill_chunk_between_decodes_is_wait_not_exposed():
-    """Sync loop, one stream decoding, a second prompt arrives: its chunk
+    """Depth 0, one stream decoding, a second prompt arrives: its chunk
     program runs between two decode batches. The device is busy for the
     chunk's whole run, so that time is `wait`; the old host gap (previous
     decode ready -> next decode dispatch) counts it as host time."""
