@@ -206,22 +206,27 @@ class EngineConfig:
     # expects handed-off requests whose prefix blocks are fabric hits.
     # Both non-unified roles require kv_fabric.
     engine_role: str = "unified"
-    # Async double-buffered step loop: split each decode step into a
-    # dispatch phase and a deferred commit phase, pipelined one step deep.
-    # While step N's decode program runs on device, the host plans and
-    # dispatches step N+1 with step N's on-device `next_tokens` chained
-    # directly into N+1's token input (positions/context_lens advance +1
-    # deterministically); an async device->host copy brings N's values
-    # back for emission one step behind. Consequences: EOS/max-token
-    # finishes are detected one step late (the overshoot token is
-    # committed to a scratch position and never emitted), verify/spec
-    # steps and batch-composition changes are pipeline-flush boundaries
-    # (commit-before-plan), and a poisoned decode commit surfaces one
-    # step after its dispatch (failure records attribute against the
-    # dispatch index). Greedy outputs are token-identical either way.
-    # It is the step loop's pipeline depth (True 1, False 0): False (the
-    # default) commits every decode in the step that dispatched it.
-    async_scheduling: bool = False
+    # The step loop's pipeline depth: True (the default since PR 31) is
+    # depth 1, False depth 0. At depth 1 each decode step is a dispatch
+    # and a commit one step behind it: while step N's decode program runs
+    # on device, the host dispatches step N+1 with step N's on-device
+    # `next_tokens` chained directly into N+1's token input
+    # (positions/context_lens advance +1 deterministically), then reads
+    # N's values back and emits them, so the device does not wait out the
+    # host's half of a step. What that changes for a user: a finish
+    # (EOS, max tokens) is detected one step late, so one overshoot token
+    # a finished sequence is computed into the null block or a look-ahead
+    # block and never emitted; a token reaches its client one commit
+    # after it was computed; has_work() stays true until the last
+    # in-flight record has been drained by one more step; a failed decode
+    # program surfaces one step after its dispatch, and failure records
+    # attribute it to the dispatch's step. Verify/spec steps commit at
+    # once at either depth, and every batch-composition change flushes
+    # the pipeline (commit-before-plan); stats() counts both
+    # (chained_decode_dispatches, pipeline_flushes). Greedy outputs are
+    # token-identical either way. False commits every decode in the step
+    # that dispatched it.
+    async_scheduling: bool = True
     # Bounded admission: cap the scheduler backlog so overload fails fast
     # at submission instead of queueing without bound. None (the default)
     # keeps the waiting deque unbounded — bit-for-bit the pre-overload-

@@ -60,6 +60,15 @@ from ray_tpu.util import tracing
 from ray_tpu.util.metrics import Counter, Gauge, Histogram, get_or_create
 
 
+# Why a step at pipeline depth 1 did not chain (stats()
+# "pipeline_flushes"): a member of the in-flight batch finished, was
+# aborted, expired or was preempted; a prompt joined the decode batch; the
+# look-ahead block could not be reserved without preempting; the record
+# was already fetched or a second one is in flight (a retried step); or
+# speculation is on, whose every decode commits in its own step.
+FLUSH_CAUSES = ("left", "joined", "lookahead", "retry", "speculation")
+
+
 class _InflightStep:
     """One dispatched-but-uncommitted decode step.
 
@@ -512,6 +521,12 @@ class LLMEngine:
         # reader divides the kernel's device time by these.
         self._decode_dispatches = 0
         self._decode_context_tokens = 0
+        # How often depth 1 engages, and why it does not: decode
+        # dispatches made from an in-flight record's device tokens, and
+        # the steps that could not chain, counted where step() and
+        # _try_chain decide (FLUSH_CAUSES). Both stay 0 at depth 0.
+        self._chained_dispatches = 0
+        self._pipeline_flushes = dict.fromkeys(FLUSH_CAUSES, 0)
         # Preallocated per-step decode/verify input buffers, zero-filled
         # and repopulated each dispatch instead of np.zeros-allocated
         # (the engine allocates none in the steady decode loop — asserted
@@ -915,13 +930,8 @@ class LLMEngine:
         # record whose tokens were fetched (a poison retry, mid-commit)
         # or a second in-flight record never chains; both flush below.
         chained_seqs: Optional[List[Sequence]] = None
-        if (
-            self._pipeline_depth
-            and self._spec is None
-            and len(self._inflight) == 1
-            and self._inflight[0].tokens_host is None
-        ):
-            chained_seqs = self._try_chain(self._inflight[0])
+        if self._pipeline_depth and self._spec is None and self._inflight:
+            chained_seqs = self._try_chain()
         if chained_seqs is not None:
             # Commit the record the chain fed from; the chained record
             # stays in flight for the next iteration.
@@ -957,6 +967,8 @@ class LLMEngine:
             decoding = self.scheduler.schedule_decode()
             if decoding:
                 if self._spec is not None:
+                    if self._pipeline_depth:
+                        self._pipeline_flushes["speculation"] += 1
                     spec_info = self._run_verify(decoding)
                 if spec_info is None:
                     # Speculation off, or no sequence had proposals this
@@ -1357,21 +1369,32 @@ class LLMEngine:
             and self.scheduler.is_active(rid)
         )
 
-    def _try_chain(self, rec: _InflightStep) -> Optional[List[Sequence]]:
+    def _try_chain(self) -> Optional[List[Sequence]]:
         """Chain the in-flight decode into the next dispatch if — and
-        only if — the next decode batch would be EXACTLY the dispatched
-        batch (same sequences, same slot order: the chained token input
-        is slot-aligned on device) AND every +1-position write can be
-        covered without preempting anyone (reserve_decode_lookahead).
-        On success the chained program is already dispatched when this
-        returns; on any mismatch returns None and the caller flushes."""
+        only if — its tokens are still on the device and it is the one
+        record in flight, the next decode batch would be EXACTLY the
+        dispatched batch (same sequences, same slot order: the chained
+        token input is slot-aligned on device) AND every +1-position
+        write can be covered without preempting anyone
+        (reserve_decode_lookahead). On success the chained program is
+        already dispatched when this returns; on any mismatch it counts
+        the cause, returns None and the caller flushes."""
+        rec = self._inflight[0]
+        flushes = self._pipeline_flushes
+        if rec.tokens_host is not None or len(self._inflight) > 1:
+            flushes["retry"] += 1
+            return None
         if not all(map(self._still_decoding, rec.seqs, rec.rids)):
+            flushes["left"] += 1
             return None
         current = [s for s in self.scheduler.running if not s.prefilling]
         if current != rec.seqs:  # a Sequence equals only itself
+            flushes["joined"] += 1
             return None
         if not self.scheduler.reserve_decode_lookahead(rec.seqs):
+            flushes["lookahead"] += 1
             return None
+        self._chained_dispatches += 1
         self._dispatch_decode(rec.seqs, chained_from=rec)
         return rec.seqs
 
@@ -1828,6 +1851,12 @@ class LLMEngine:
             # turns it into bytes and operations.
             "decode_dispatches": self._decode_dispatches,
             "decode_context_tokens": self._decode_context_tokens,
+            # Of those, the ones made from an in-flight record's device
+            # tokens (depth 1), and the steps that could not chain, in
+            # all and by cause (FLUSH_CAUSES).
+            "chained_decode_dispatches": self._chained_dispatches,
+            "pipeline_flushes": sum(self._pipeline_flushes.values()),
+            "pipeline_flushes_by_cause": dict(self._pipeline_flushes),
             "attention_shape": {
                 "num_layers": self.model_config.num_layers,
                 "num_heads": self.model_config.num_heads,
@@ -1936,6 +1965,47 @@ class _RequestState:
 _STREAM_END = object()
 
 
+class _HandoffLock:
+    """LLMServer's lock: whoever is already waiting for it is served
+    before a thread that releases it and asks again.
+
+    The step thread releases the server's lock between steps and takes
+    it again at once. A plain `threading.Lock` gives it back to that
+    thread nearly every time (the woken waiter has yet to run), and at
+    pipeline depth 1 the step thread no longer blocks on the device
+    inside a step either: a submitting or aborting thread could wait out
+    many steps with decode slots standing empty. Every acquire first
+    passes `_gate`, which a waiter holds while it blocks on `_lock`, so
+    the step thread queues behind it. No sleep, no polling; uncontended
+    it is one more lock operation a step. `threading.Condition` drives
+    it through acquire, release and _is_owned."""
+
+    __slots__ = ("_gate", "_lock")
+
+    def __init__(self):
+        self._gate = threading.Lock()
+        self._lock = threading.Lock()
+
+    def acquire(self) -> bool:
+        with self._gate:
+            # ray-tpu: lint-ignore[RTL202] this IS the lock's acquire:
+            # release() below is its pair, and callers hold it by `with`
+            return self._lock.acquire()
+
+    def release(self) -> None:
+        self._lock.release()
+
+    def _is_owned(self) -> bool:
+        # threading.Condition asks before notify; its default probes
+        # with a non-blocking acquire, which here would queue at the gate.
+        return self._lock.locked()
+
+    __enter__ = acquire
+
+    def __exit__(self, *exc) -> None:
+        self.release()
+
+
 class LLMServer:
     """Engine actor: background step loop + blocking / streaming generate.
 
@@ -2014,7 +2084,7 @@ class LLMServer:
                 self._engine._pipeline_depth = depth
             if spec is not None:
                 self._warmup_verify(spec)
-        self._lock = threading.Lock()
+        self._lock = _HandoffLock()
         self._work = threading.Condition(self._lock)
         self._requests: Dict[str, _RequestState] = {}
         self._shutdown = False

@@ -56,6 +56,9 @@ KNOB_CONFIGS: Tuple[Tuple[str, dict, bool], ...] = (
     ("int8_kv", {"kv_cache_dtype": "int8"}, False),
     # Async double-buffered step loop: dispatch N+1 while N's values are
     # still in flight; token-identical to base, host gap ~0 when chained.
+    # Since PR 31 this is EngineConfig's default, so the row repeats
+    # "base" (kept: its label keys the trajectory of the earlier rounds);
+    # the depth-0 loop is {"async_scheduling": False}.
     ("async_step", {"async_scheduling": True}, False),
     # Fused kernel on CPU = interpret mode: parity/latency-shape exercise
     # only, never a speedup claim (PR 7 convention).

@@ -41,11 +41,27 @@ _HOLDS_DOC_RE = re.compile(r"caller(s)?\s+(must\s+)?hold", re.IGNORECASE)
 _SKIP_METHODS = {"__init__", "__new__", "__del__", "__post_init__"}
 
 
+def _module_lock_classes(module: ModuleInfo) -> Set[str]:
+    """Classes of this module that are locks by shape: they define both
+    `acquire` and `release` (a lock wrapped for fairness or accounting
+    guards state exactly as the `threading.Lock` inside it does)."""
+    cached = module.memo.get("module_lock_classes")
+    if cached is None:
+        cached = module.memo["module_lock_classes"] = {
+            cls.name
+            for cls in module.tree.body
+            if isinstance(cls, ast.ClassDef)
+            and {"acquire", "release"}
+            <= {f.name for f in cls.body if isinstance(f, ast.FunctionDef)}
+        }
+    return cached
+
+
 def is_lock_ctor(module: ModuleInfo, node: ast.AST) -> bool:
     if not isinstance(node, ast.Call):
         return False
     target = module.call_target(node)
-    return target in LOCK_CTORS
+    return target in LOCK_CTORS or target in _module_lock_classes(module)
 
 
 def class_lock_attrs(module: ModuleInfo, cls: ast.ClassDef) -> Dict[str, str]:

@@ -747,7 +747,10 @@ def test_llm_stream_failover_real_replica_kill_token_identical(serve_ray):
 
     handle = _build_llm_app(serve.run, "chaos-kill", "llmchaos2")
     prompt = random_prompts((9,), seed=8)[0]
-    n_new = 10
+    # Long enough that the stream cannot have run to its end on the first
+    # replica between the third token's delivery and the kill: at pipeline
+    # depth 1 a 10-token answer sometimes had, and nothing failed over.
+    n_new = 24
     want = reference_greedy(
         GPT(TINY), LLMEngine(TINY, ECFG_SERVE, seed=0).runner.params, prompt, n_new
     )

@@ -281,6 +281,54 @@ def test_condition_alias_counts_as_same_lock():
     assert "RTL201" not in rules_of(findings)
 
 
+def test_module_class_with_acquire_and_release_counts_as_a_lock():
+    """A lock wrapped in a class of the module (LLMServer's hand-off lock)
+    guards state as the `threading.Lock` inside it does: a Condition over
+    it is the same lock, and a read outside it is still a finding."""
+    findings = lint(
+        """
+        import threading
+
+        class Fair:
+            def __init__(self):
+                self._gate = threading.Lock()
+                self._inner = threading.Lock()
+
+            def acquire(self):
+                with self._gate:
+                    return self._inner.acquire()  # ray-tpu: lint-ignore[RTL202] the lock's own acquire
+
+            def release(self):
+                self._inner.release()
+
+            __enter__ = acquire
+
+            def __exit__(self, *exc):
+                self._inner.release()
+
+        class Q:
+            def __init__(self):
+                self._lock = Fair()
+                self._cv = threading.Condition(self._lock)
+                self._queue = []
+
+            def put(self, x):
+                with self._cv:
+                    self._queue.append(x)
+
+            def drain(self):
+                with self._lock:
+                    out, self._queue = self._queue, []
+                    return out
+
+            def peek(self):
+                return len(self._queue)
+        """
+    )
+    assert rules_of(findings) == ["RTL201"]
+    assert findings[0].context.endswith("peek")
+
+
 def test_unguarded_attrs_and_init_not_flagged():
     findings = lint(
         """

@@ -428,13 +428,37 @@ def test_engine_preemption_recompute_matches_reference():
     assert eng.allocator.num_allocated == 0
 
 
-def test_engine_abort_releases_blocks(tiny_engine):
-    eng = tiny_engine
-    rid = eng.add_request(random_prompts((9,), seed=5)[0], max_new_tokens=8)
+@pytest.mark.parametrize("depth1", (False, True), ids=("depth0", "depth1"))
+def test_engine_abort_releases_blocks(depth1):
+    """An abort releases every block at once, at either pipeline depth.
+    At depth 1 the aborted request's decode is still in flight: the
+    engine has work until one more step has drained that record, which
+    emits nothing and takes no block."""
+    eng = LLMEngine(
+        TINY,
+        EngineConfig(
+            block_size=8, num_blocks=64, max_decode_slots=4,
+            max_blocks_per_seq=8, async_scheduling=depth1,
+        ),
+        seed=0,
+    )
+    stream = []
+    rid = eng.add_request(
+        random_prompts((9,), seed=5)[0], max_new_tokens=8,
+        on_token=stream.append,
+    )
     eng.step()  # prefill admits it
     assert eng.allocator.num_allocated > 0
     assert eng.abort(rid)
     assert eng.allocator.num_allocated == 0
+    emitted = list(stream)
+    if depth1:
+        assert eng.stats()["inflight_steps"] == 1
+        assert eng.has_work()
+        eng.step()  # drains the orphaned record
+        assert eng.stats()["inflight_steps"] == 0
+        assert eng.allocator.num_allocated == 0
+    assert stream == emitted  # nothing reaches an aborted request's stream
     assert not eng.has_work()
     assert not eng.abort("nonexistent")
 
@@ -506,15 +530,24 @@ def test_engine_cow_divergence_on_shared_prefix_block(tiny_engine):
     assert b_toks == ref[:3]
 
 
-def test_engine_preempt_resume_hits_prefix_cache_and_matches_uncached():
+@pytest.mark.parametrize("depth1", (False, True), ids=("depth0", "depth1"))
+def test_engine_preempt_resume_hits_prefix_cache_and_matches_uncached(depth1):
     """Acceptance: a mixed prefill/decode/preemption workload is
     token-identical with prefix caching on and off — and with caching on,
     a preempted victim's resume re-prefill hits its own still-cached
-    blocks instead of recomputing from token 0."""
+    blocks instead of recomputing from token 0, at either pipeline depth.
+
+    The victim's blocks stay cached only until pressure evicts them
+    (Scheduler.schedule_decode), and the depth moves the step at which
+    each preemption falls: with the 9 usable blocks and the four prompts
+    this test had until PR 31, depth 0 resumed one victim of two from the
+    cache and depth 1 its one victim from none (at 8 usable blocks it was
+    the other way round). These prompts resume from the cache at both."""
     kw = dict(
-        block_size=4, num_blocks=10, max_decode_slots=4, max_blocks_per_seq=8
+        block_size=4, num_blocks=9, max_decode_slots=4, max_blocks_per_seq=8,
+        async_scheduling=depth1,
     )
-    prompts = random_prompts((6, 7, 5, 6), seed=1)
+    prompts = random_prompts((6, 7, 9, 10), seed=1)
     cached = LLMEngine(
         TINY, EngineConfig(**kw, enable_prefix_caching=True), seed=0
     )

@@ -20,7 +20,10 @@ one step behind via `copy_to_host_async`. These tests pin the contract:
   * per-step dispatch/commit timestamps land in the flight record and the
     llm_engine_step_host_gap_seconds histogram + stats() counters expose
     the host gap, with chained dispatches recording exactly 0;
-  * async off is the default and leaves sync records free of async keys.
+  * async ON is the default (PR 31); async_scheduling=False leaves sync
+    records free of async keys and counts nothing;
+  * stats() counts the chained dispatches and the flushes by cause, and
+    each cause is reached by a test that provokes it alone.
 """
 
 import numpy as np
@@ -30,6 +33,7 @@ import jax.numpy as jnp
 
 import ray_tpu
 from ray_tpu.llm import EngineConfig, KVFabricConfig, LLMEngine
+from ray_tpu.llm.engine import FLUSH_CAUSES
 from ray_tpu.models.gpt import GPT, GPTConfig
 
 
@@ -430,13 +434,233 @@ def test_dashboard_percentiles_include_host_gap():
 
 
 def test_async_off_is_default_and_records_unchanged():
-    """async_scheduling defaults off; a default engine's flight records
-    carry no async keys and its stats report the loop disabled."""
-    assert EngineConfig(**BASE).async_scheduling is False
+    """async_scheduling defaults ON (PR 31): a default engine reports
+    depth 1 and its flight records carry the async keys; an engine built
+    with async_scheduling=False carries none and counts no chained
+    dispatch and no flush."""
+    assert EngineConfig(**BASE).async_scheduling is True
     eng = LLMEngine(TINY, EngineConfig(**BASE), seed=0)
     eng.generate(random_prompts((5,), seed=5), max_new_tokens=4)
     stats = eng.stats()
+    assert stats["async_scheduling"] is True
+    assert stats["inflight_steps"] == 0
+    steps = eng.flight_recorder.snapshot()["steps"]
+    assert all(s["loop"] == "async" and "chained" in s for s in steps)
+    assert stats["chained_decode_dispatches"] > 0
+
+    off = LLMEngine(TINY, EngineConfig(async_scheduling=False, **BASE), seed=0)
+    off.generate(random_prompts((5,), seed=5), max_new_tokens=4)
+    stats = off.stats()
     assert stats["async_scheduling"] is False
     assert stats["inflight_steps"] == 0
-    for s in eng.flight_recorder.snapshot()["steps"]:
+    for s in off.flight_recorder.snapshot()["steps"]:
         assert "chained" not in s and "loop" not in s
+    assert stats["chained_decode_dispatches"] == 0
+    assert stats["pipeline_flushes"] == 0
+    assert not any(stats["pipeline_flushes_by_cause"].values())
+
+
+# ---------------- how often depth 1 engages, and why it does not ----------------
+
+
+def flushes(eng) -> dict:
+    return dict(eng.stats()["pipeline_flushes_by_cause"])
+
+
+def only(cause: str, n: int) -> dict:
+    """The counts by cause with `n` under `cause` and nothing elsewhere."""
+    assert cause in FLUSH_CAUSES
+    return {c: (n if c == cause else 0) for c in FLUSH_CAUSES}
+
+
+def assert_only(eng, before: dict, cause: str, n: int = 1) -> None:
+    """Since `before`, `cause` was counted `n` times and no other was."""
+    after = flushes(eng)
+    assert {c: after[c] - before[c] for c in after} == only(cause, n)
+    stats = eng.stats()
+    assert stats["pipeline_flushes"] == sum(after.values())
+    assert stats["chained_decode_dispatches"] <= stats["decode_dispatches"]
+
+
+def steady(n_streams: int, max_new_tokens: int = 40):
+    """An engine at depth 1 whose streams are all decoding and chained."""
+    eng = LLMEngine(TINY, EngineConfig(**BASE), seed=0)
+    rids = [
+        eng.add_request(p, max_new_tokens=max_new_tokens)
+        for p in random_prompts((5, 9, 7)[:n_streams], seed=12)
+    ]
+    for _ in range(n_streams + 2):
+        eng.step()  # one admission a step, then the first chained steps
+    assert eng.flight_recorder.snapshot()["steps"][-1]["chained"]
+    return eng, rids
+
+
+def drain(eng) -> None:
+    while eng.has_work():
+        eng.step()
+    assert eng.stats()["inflight_steps"] == 0
+    assert eng.allocator.num_allocated == 0
+
+
+def test_steady_batch_chains_every_dispatch_after_the_first():
+    """A batch of unchanging composition: the first decode dispatch is made
+    from host tokens, every later one from the in-flight record's, and no
+    flush is counted until the stream ends (one, `left`: the drain)."""
+    eng = LLMEngine(TINY, EngineConfig(**BASE), seed=0)
+    eng.add_request(random_prompts((6,), seed=4)[0], max_new_tokens=20)
+    eng.step()  # prefill + the first decode, from committed state
+    base = eng.stats()
+    assert base["decode_dispatches"] == 1
+    assert base["chained_decode_dispatches"] == 0
+    for _ in range(10):
+        eng.step()
+    stats = eng.stats()
+    assert stats["decode_dispatches"] == 11
+    assert stats["chained_decode_dispatches"] == 10
+    assert stats["pipeline_flushes"] == 0
+    drain(eng)
+    stats = eng.stats()
+    # 19 decodes emit tokens 2..20, the 20th is the overshoot; all but the
+    # first chained; the one flush drains the overshoot's record.
+    assert stats["decode_dispatches"] == 20
+    assert stats["chained_decode_dispatches"] == 19
+    assert flushes(eng) == only("left", 1)
+    assert stats["chained_decode_dispatches"] <= stats["decode_dispatches"]
+
+
+def test_flush_cause_left_on_finish_and_on_abort():
+    eng, rids = steady(2)
+    before = flushes(eng)
+    assert eng.abort(rids[0])
+    eng.step()  # the in-flight batch lost a member: flush, re-plan
+    assert_only(eng, before, "left")
+    eng.step()
+    assert eng.flight_recorder.snapshot()["steps"][-1]["chained"]
+    # A finish: the survivor runs out its budget, the drain flushes once.
+    before = flushes(eng)
+    drain(eng)
+    assert_only(eng, before, "left")
+
+
+def test_flush_cause_joined_when_a_prompt_joins():
+    eng, _ = steady(1)
+    before = flushes(eng)
+    eng.add_request(random_prompts((7,), seed=13)[0], max_new_tokens=30)
+    eng.step()  # chains (the prompt is still waiting), then prefills it
+    assert flushes(eng) == before
+    eng.step()  # the decode batch is one wider than the record in flight
+    assert_only(eng, before, "joined")
+    eng.step()
+    assert eng.flight_recorder.snapshot()["steps"][-1]["chained"]
+
+
+def test_flush_cause_lookahead_under_block_pressure():
+    """A pool with no block to spare for the look-ahead: the chain is
+    refused without preempting, the step flushes and schedules normally."""
+    kw = dict(
+        block_size=4, num_blocks=6, max_decode_slots=2, max_blocks_per_seq=8
+    )
+    eng = LLMEngine(TINY, EngineConfig(**kw), seed=0)
+    # 5 usable blocks. Two prompts of 6 hold 2 blocks each; the first to
+    # cross into its third block takes the last free one, and the other's
+    # look-ahead is then refused.
+    for p in random_prompts((6, 6), seed=14):
+        eng.add_request(p, max_new_tokens=8)
+    seen = 0
+    while eng.has_work():
+        before = flushes(eng)
+        eng.step()
+        after = flushes(eng)
+        if after["lookahead"] > before["lookahead"]:
+            seen += 1
+            assert_only(eng, before, "lookahead")  # once, and no other
+    assert seen, flushes(eng)
+    assert eng.stats()["inflight_steps"] == 0
+    assert eng.allocator.num_allocated == 0
+
+
+def test_flush_cause_speculation_commits_every_decode_at_once():
+    eng = LLMEngine(
+        TINY,
+        EngineConfig(speculation="ngram", num_speculative_tokens=3, **BASE),
+        seed=0,
+    )
+    eng.generate([[3, 4, 5, 3, 4, 5, 3, 4]], max_new_tokens=10)
+    stats = eng.stats()
+    assert stats["async_scheduling"] is True
+    assert stats["chained_decode_dispatches"] == 0
+    assert stats["pipeline_flushes"] > 0
+    assert flushes(eng) == only("speculation", stats["pipeline_flushes"])
+
+
+def test_flush_cause_retry_when_a_commit_failed_midway():
+    """A poisoned sequence stops the commit of the in-flight record; the
+    retried step finds it already fetched and flushes (`retry`)."""
+    from ray_tpu._private import fault_injection as fi
+
+    eng, rids = steady(2)
+    before = flushes(eng)
+    spec = fi.inject("llm.decode.seq", match=rids[1])
+    try:
+        with pytest.raises(fi.InjectedFault):
+            eng.step()  # chained, then the head's commit raises on slot 1
+    finally:
+        fi.remove(spec)
+    assert eng.stats()["inflight_steps"] == 2
+    eng.step()  # the retry: nothing chains, both records commit
+    assert_only(eng, before, "retry")
+    drain(eng)
+
+
+# ---------------- the server's lock between steps ----------------
+
+
+def test_server_lock_serves_a_waiter_before_the_thread_that_released_it():
+    """LLMServer's step thread releases the lock between steps and takes
+    it again at once; at depth 1 it no longer blocks on the device inside
+    a step either. A thread already waiting (a submission, an abort) must
+    get the lock before the step thread's next turn: with a plain Lock it
+    waits out many turns, which is decode slots standing empty."""
+    import threading
+    import time
+
+    from ray_tpu.llm.engine import LLMServer, _HandoffLock
+
+    lock = _HandoffLock()
+    cond = threading.Condition(lock)  # what LLMServer builds on it
+    turns = []
+    stop = threading.Event()
+
+    def step_thread():
+        while not stop.is_set():
+            with cond:
+                pass  # `with self._work:` — nothing to wait for
+            with lock:  # `with self._lock:` — the step
+                turns.append(time.perf_counter())
+                time.sleep(0.002)
+
+    thread = threading.Thread(target=step_thread, daemon=True)
+    thread.start()
+    try:
+        waited = []
+        for _ in range(20):
+            time.sleep(0.003)  # arrive somewhere inside a step
+            asked = time.perf_counter()
+            with cond:
+                got = time.perf_counter()
+                cond.notify_all()
+            waited.append(sum(1 for t in turns if asked < t < got))
+    finally:
+        stop.set()
+        thread.join(timeout=5)
+    # At most the step in progress ends, and never a whole further one
+    # starts, between asking and getting.
+    assert max(waited) == 0, waited
+    assert not lock._is_owned()
+    with lock:
+        assert lock._is_owned()
+    server = LLMServer(TINY, EngineConfig(**BASE), seed=0, warmup=False)
+    try:
+        assert isinstance(server._lock, _HandoffLock)
+    finally:
+        server.shutdown()

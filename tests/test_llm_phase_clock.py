@@ -97,13 +97,18 @@ def test_phases_partition_the_wall_time(mode):
         assert exposed == pytest.approx(non_wait, rel=0.1)
 
 
-def test_prefill_chunk_between_decodes_is_wait_not_exposed():
-    """Depth 0, one stream decoding, a second prompt arrives: its chunk
-    program runs between two decode batches. The device is busy for the
-    chunk's whole run, so that time is `wait`; the old host gap (previous
-    decode ready -> next decode dispatch) counts it as host time."""
+@pytest.mark.parametrize("mode", (False, True), ids=("sync", "async"))
+def test_prefill_chunk_between_decodes_is_wait_not_exposed(mode):
+    """One stream decoding, a second prompt arrives: its chunk program
+    runs between two decode batches (at depth 1, behind the chained
+    decode of its own step). The device is busy for the chunk's whole
+    run, so that time is `wait`; at depth 0 the old host gap (previous
+    decode ready -> next decode dispatch) counts it as host time, at
+    depth 1 the chained dispatch beat the fetch and the gap reads 0."""
     chunk_s = 0.5  # far above a loaded host's own work in one step
-    eng = LLMEngine(TINY, EngineConfig(**BASE), seed=0)
+    eng = LLMEngine(
+        TINY, EngineConfig(async_scheduling=mode, **BASE), seed=0
+    )
     late = [9, 4, 7, 1, 8, 2, 6, 3, 5]
     eng.generate([[5, 9, 11, 3, 7], late], max_new_tokens=3)  # compile
     eng.allocator.reset_prefix_cache()
@@ -148,11 +153,16 @@ def test_prefill_chunk_between_decodes_is_wait_not_exposed():
     assert delta["step_wait_s"] >= chunk_s
     assert record["phases"]["wait"] >= chunk_s
     assert delta["host_exposed_total_s"] < chunk_s / 2
-    # The fault of the old gap, kept under its name until the benchmark
-    # retires the metric that reads it.
     assert delta["host_gap_samples"] == 1
-    assert delta["host_gap_total_s"] >= chunk_s
-    assert record["host_gap_s"] >= chunk_s
+    if mode:
+        assert record["chained"]
+        assert delta["host_gap_total_s"] == 0.0
+        assert record["host_gap_s"] == 0.0
+    else:
+        # The fault of the old gap, kept under its name until the
+        # benchmark retires the metric that reads it.
+        assert delta["host_gap_total_s"] >= chunk_s
+        assert record["host_gap_s"] >= chunk_s
     while eng.has_work():
         eng.step()
 
@@ -221,18 +231,25 @@ def test_instrument_off_reads_no_clock_in_the_decode_loop(mode, monkeypatch):
     assert stats["decode_context_tokens"] > stats["decode_dispatches"] > 0
 
 
-def test_decode_work_counters_follow_the_batches():
-    eng = LLMEngine(TINY, EngineConfig(**BASE), seed=0)
+@pytest.mark.parametrize("mode", (False, True), ids=("sync", "async"))
+def test_decode_work_counters_follow_the_batches(mode):
+    eng = LLMEngine(
+        TINY, EngineConfig(async_scheduling=mode, **BASE), seed=0
+    )
     eng.add_request([5, 9, 11, 3, 7], max_new_tokens=5)
     while eng.has_work():
         eng.step()
     stats = eng.stats()
     # The prefill emits token 1; decodes 2..5 read contexts 5, 6, 7, 8.
-    assert stats["decode_dispatches"] == 4
+    # Depth 1 sees the length stop one step late: one dispatch more, past
+    # the last token, reads a context of 9 and commits nothing.
+    overshoot = 1 if mode else 0
+    assert stats["decode_dispatches"] == 4 + overshoot
+    assert stats["chained_decode_dispatches"] == (4 if mode else 0)
     # One token a sequence a decode dispatch: the batch width is the
     # existing decode_tokens over the dispatches.
     assert stats["decode_tokens"] == 4
-    assert stats["decode_context_tokens"] == 5 + 6 + 7 + 8
+    assert stats["decode_context_tokens"] == 5 + 6 + 7 + 8 + 9 * overshoot
     assert stats["attention_shape"] == {
         "num_layers": 2, "num_heads": 4, "head_dim": 16, "kv_itemsize": 4,
     }
@@ -377,17 +394,22 @@ def test_train_phases_are_annotated_on_a_host_plane(tmp_path):
     assert record["phases"]["compute"] > 0.0
 
 
-def test_dispatch_outside_a_step_leaves_the_clock_alone():
+@pytest.mark.parametrize("mode", (False, True), ids=("sync", "async"))
+def test_dispatch_outside_a_step_leaves_the_clock_alone(mode):
     """Warm-up (and device_report) drive the runner directly, with the hook
     installed: nothing may start a phase that no step will close."""
-    eng = LLMEngine(TINY, EngineConfig(**BASE), seed=0)
+    eng = LLMEngine(
+        TINY, EngineConfig(async_scheduling=mode, **BASE), seed=0
+    )
     eng.runner.prefill([1, 2, 3], [1])
     time.sleep(0.1)  # would be charged to `wait` had the hook opened it
     t0 = time.perf_counter()
     eng.generate([[5, 9, 11]], max_new_tokens=2)
     wall = time.perf_counter() - t0
     stats = eng.stats()
-    assert eng._clock.dispatches == 2  # the request's prefill and decode
+    # The request's prefill and decode, and at depth 1 the chained decode
+    # past its last token.
+    assert eng._clock.dispatches == (3 if mode else 2)
     assert sum(stats[key] for key in PHASE_KEYS) <= wall
 
 
