@@ -125,6 +125,34 @@ def prefix_block_hashes(
     return out
 
 
+class StateSlots:
+    """Free list of the recurrent-state slots of a model whose layers carry
+    a state beside the paged cache (`model_config.recurrent_state`): one
+    slot a running sequence, as many as there are decode lanes, so a
+    sequence that is admitted always finds one. A slot is handed over
+    without being cleared on the device: the program that starts a
+    sequence begins from an empty state and does not read the slot
+    (`num_resets` counts the hand-overs)."""
+
+    def __init__(self, num_slots: int):
+        self.num_slots = num_slots
+        self._free = list(range(num_slots - 1, -1, -1))
+        self.num_resets = 0
+
+    @property
+    def num_in_use(self) -> int:
+        return self.num_slots - len(self._free)
+
+    def allocate(self) -> int:
+        if not self._free:
+            raise CacheOutOfBlocks("no free recurrent-state slot")
+        self.num_resets += 1
+        return self._free.pop()
+
+    def free(self, slot: int) -> None:
+        self._free.append(slot)
+
+
 class BlockAllocator:
     def __init__(
         self,

@@ -32,9 +32,9 @@ import numpy as np
 
 from ray_tpu._private.fault_injection import maybe_fail
 from ray_tpu.exceptions import EngineOverloadedError, PoisonRequestError
-from ray_tpu.llm.cache import BlockAllocator, blocks_for_tokens
+from ray_tpu.llm.cache import BlockAllocator, StateSlots, blocks_for_tokens
 from ray_tpu.llm.config import EngineConfig
-from ray_tpu.llm.model_runner import GPTRunner
+from ray_tpu.llm.model_runner import build_runner
 from ray_tpu.llm.observability import (
     HOST_GAP_SECONDS_BOUNDARIES,
     PER_TOKEN_SECONDS_BOUNDARIES,
@@ -85,7 +85,7 @@ class _InflightStep:
 
     __slots__ = (
         "seqs", "rids", "tokens_dev", "tokens_host",
-        "dispatch_step", "commit_idx", "clock_seq", "t_prepare",
+        "dispatch_step", "commit_idx", "clock_seq", "t_prepare", "lanes",
     )
 
     def __init__(
@@ -102,6 +102,10 @@ class _InflightStep:
         # reading where the dispatch's `prepare` began.
         self.clock_seq = clock_seq
         self.t_prepare = t_prepare
+        # The decode lane of each of `seqs` where it is not its index: a
+        # model with recurrent layers decodes a sequence in the lane of
+        # its state slot.
+        self.lanes: Optional[List[int]] = None
 
 
 class LLMEngine:
@@ -136,7 +140,35 @@ class LLMEngine:
                 self.engine_config.tensor_parallel_size,
                 role="draft model",
             )
-        self.runner = GPTRunner(
+        # A model whose layers carry a recurrent state beside the paged
+        # cache says so on its configuration. Nothing snapshots that state
+        # at a block boundary, so a cached prefix cannot be resumed from:
+        # the block cache is built without prefix sharing (no hashing, no
+        # hits), every running sequence owns a state slot, and the
+        # features that assume a cache-only model are refused here, by
+        # what the model declares.
+        self._recurrent = bool(
+            getattr(self.model_config, "recurrent_state", False)
+        )
+        if self._recurrent:
+            ecfg = self.engine_config
+            refused = {
+                "speculation": ecfg.speculation != "off",
+                "kv_fabric": ecfg.kv_fabric is not None,
+                'kv_cache_dtype="int8"': ecfg.kv_cache_dtype == "int8",
+                "tensor_parallel_size > 1": ecfg.tensor_parallel_size > 1,
+            }
+            for what, asked in refused.items():
+                if asked:
+                    raise ValueError(
+                        f"{what} is not supported for a model with "
+                        "recurrent layers "
+                        f"({type(self.model_config).__name__}): a rejected "
+                        "draft, a spilled block or a sharded pool would "
+                        "need the recurrent state rolled back, stored or "
+                        "sharded beside the K/V it belongs to"
+                    )
+        self.runner = build_runner(
             self.model_config, self.engine_config, params=params, seed=seed
         )
         # Speculative decoding (ray_tpu.llm.spec): None when off. The
@@ -148,13 +180,21 @@ class LLMEngine:
         self.allocator = BlockAllocator(
             self.engine_config.num_blocks,
             self.engine_config.block_size,
-            enable_prefix_caching=self.engine_config.enable_prefix_caching,
+            enable_prefix_caching=(
+                self.engine_config.enable_prefix_caching
+                and not self._recurrent
+            ),
             eviction_policy=self.engine_config.prefix_eviction_policy,
         )
         self.scheduler = Scheduler(
             self.allocator,
             self.engine_config.max_decode_slots,
             self.engine_config.max_blocks_per_seq,
+            state_slots=(
+                StateSlots(self.runner.state_slots)
+                if self._recurrent
+                else None
+            ),
         )
         # KV fabric (EngineConfig.kv_fabric): shared host-DRAM spill tier.
         # None keeps every hook cold — the allocator, scheduler, and step
@@ -612,8 +652,12 @@ class LLMEngine:
         # tokens), so the whole lifetime must fit the bucket table and the
         # block pool — otherwise the request could never be (re)admitted and
         # the engine would spin without progress.
+        # Under a chunk budget no dispatch feeds more than the budget.
         largest_bucket = ecfg.buckets()[-1]
-        if total - 1 > largest_bucket:
+        largest_chunk = total - 1
+        if self._prefill_budget is not None:
+            largest_chunk = min(largest_chunk, self._prefill_budget)
+        if largest_chunk > largest_bucket:
             raise ValueError(
                 f"prompt + max_new_tokens - 1 = {total - 1} exceeds the "
                 f"largest prefill bucket {largest_bucket}; raise "
@@ -1434,7 +1478,13 @@ class LLMEngine:
         if not ahead:
             tokens.fill(0)
         context_tokens = 0
+        recurrent = self._recurrent
         for i, seq in enumerate(seqs):
+            if recurrent:
+                # A sequence's decode lane is its state slot, dispatch
+                # after dispatch: the program updates the state pools in
+                # place, lane for lane.
+                i = seq.state_slot
             cached = seq.num_cached + ahead
             if not ahead:
                 tokens[i] = seq.last_token
@@ -1456,11 +1506,12 @@ class LLMEngine:
         )
         rids = [s.request.request_id for s in seqs]
         clock_seq = clock.dispatches if clock is not None else None
-        self._inflight.append(
-            _InflightStep(
-                seqs, rids, tokens_dev, self._steps, clock_seq, t_prepare
-            )
+        rec = _InflightStep(
+            seqs, rids, tokens_dev, self._steps, clock_seq, t_prepare
         )
+        if recurrent:
+            rec.lanes = [s.state_slot for s in seqs]
+        self._inflight.append(rec)
 
     def _commit_head(self, follows_dispatch: bool = False) -> None:
         """Fetch and commit the OLDEST in-flight record: per-sequence
@@ -1493,6 +1544,9 @@ class LLMEngine:
             # with the device idle (1 ms a step in the chat cell, PR 30).
             rec.tokens_host = np.asarray(rec.tokens_dev)
             rec.tokens_dev = None
+            if rec.lanes is not None:
+                # The step's routing counts ride the same fetch.
+                self.runner.count_routing(rec.tokens_host)
             if clock is not None:
                 # The tokens are on host: everything until the next
                 # dispatch is host-side gap.
@@ -1511,7 +1565,8 @@ class LLMEngine:
             self._current_rid = rec.rids[i]
             maybe_fail("llm.decode.seq", detail=rec.rids[i])
             seq.num_cached += 1
-            seq.generated.append(int(next_tokens[i]))
+            lane = i if rec.lanes is None else rec.lanes[i]
+            seq.generated.append(int(next_tokens[lane]))
             if seq.num_cached % ecfg.block_size == 0:
                 # A block just filled: publish it to the prefix cache
                 # before a finish below could release it.
@@ -1600,9 +1655,13 @@ class LLMEngine:
                 self.allocator.free([src])  # drop admission's copy-source ref
                 seq.pending_copy = None
             chunk_ids = seq.prefill_ids[offset : offset + take]
+            # The state slot the chunk starts from and leaves its state in
+            # (a model with recurrent layers; the first chunk starts from
+            # an empty state whatever the slot held).
+            slot = (seq.state_slot,) if self._recurrent else ()
             if offset > 0:
                 tok = self.runner.prefill_suffix(
-                    chunk_ids, seq.block_table, offset
+                    chunk_ids, seq.block_table, offset, *slot
                 )
                 if first_chunk:
                     hit_tokens += offset
@@ -1618,6 +1677,7 @@ class LLMEngine:
                             take, self.engine_config.block_size
                         )
                     ],
+                    *slot,
                 )
             if clock is not None:
                 clock.ready()
@@ -1857,12 +1917,37 @@ class LLMEngine:
             "chained_decode_dispatches": self._chained_dispatches,
             "pipeline_flushes": sum(self._pipeline_flushes.values()),
             "pipeline_flushes_by_cause": dict(self._pipeline_flushes),
-            "attention_shape": {
-                "num_layers": self.model_config.num_layers,
-                "num_heads": self.model_config.num_heads,
-                "head_dim": self.model_config.head_dim,
-                "kv_itemsize": np.dtype(self.runner.kv_cache_dtype).itemsize,
-            },
+            "attention_shape": (
+                self.runner.attention_shape()
+                if self._recurrent
+                else {
+                    "num_layers": self.model_config.num_layers,
+                    "num_heads": self.model_config.num_heads,
+                    "head_dim": self.model_config.head_dim,
+                    "kv_itemsize": np.dtype(
+                        self.runner.kv_cache_dtype
+                    ).itemsize,
+                }
+            ),
+            # Whether a cached prefix can be shared on this model (not
+            # with recurrent layers: nothing snapshots their state at a
+            # block boundary), and, for such a model, its state slots,
+            # state traffic and routing counts (HybridRunner.stats).
+            "prefix_caching": self.allocator.enable_prefix_caching,
+            "recurrent_state": self._recurrent,
+            **(
+                {
+                    **self.runner.stats(),
+                    "state_slots_in_use": (
+                        self.scheduler.state_slots.num_in_use
+                    ),
+                    "state_slot_resets": (
+                        self.scheduler.state_slots.num_resets
+                    ),
+                }
+                if self._recurrent
+                else {}
+            ),
             # Set-up on the same footing: wall seconds warming the
             # programs and, with `instrument` on, what JAX spent compiling
             # in this process since the first such engine was built
@@ -2146,7 +2231,7 @@ class LLMServer:
             # compiling the bucket's full-prefill program (plus, on the
             # first round, the decode program).
             self._record_round("prefill", bucket, round_start)
-        if ecfg.enable_prefix_caching:
+        if self._engine.allocator.enable_prefix_caching:
             # Also compile every partial-prefill bucket and the
             # copy-on-write block copy, so cache hits never trigger a
             # cold compile under live traffic. Each round seeds exactly
@@ -2186,10 +2271,13 @@ class LLMServer:
             # cold-compile under live traffic.
             runner = self._engine.runner
             null_table = [0] * ecfg.max_blocks_per_seq
+            # A model with recurrent layers: into state slot 0, which no
+            # sequence holds here and none reads before writing.
+            slot = (0,) if self._engine._recurrent else ()
             for w in widths:
                 round_start = self._round_start()
-                runner.prefill([0] * w, [0])
-                runner.prefill_suffix([0] * w, null_table, 0)
+                runner.prefill([0] * w, [0], *slot)
+                runner.prefill_suffix([0] * w, null_table, 0, *slot)
                 self._record_round("chunk_prefill", w, round_start)
 
     def _warmup_verify(self, spec) -> None:

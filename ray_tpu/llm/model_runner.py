@@ -389,6 +389,33 @@ def _step_programs(
     return programs
 
 
+def bytes_by_device(arrays) -> dict:
+    """Bytes a device holds of `arrays` (`addressable_shards`, so a
+    replicated leaf counts on every chip that holds it)."""
+    out: dict = {}
+    for array in arrays:
+        for shard in array.addressable_shards:
+            key = f"{shard.device.platform}:{shard.device.id}"
+            out[key] = out.get(key, 0) + int(shard.data.nbytes)
+    return out
+
+
+def build_runner(model_config, engine_config: EngineConfig, params=None,
+                 seed: int = 0):
+    """The runner of `model_config`'s type: the class its `llm_runner`
+    names ("module:Class", imported only then), `GPTRunner` for a
+    configuration that names none."""
+    named = getattr(type(model_config), "llm_runner", None)
+    if named is None:
+        return GPTRunner(model_config, engine_config, params=params, seed=seed)
+    import importlib
+
+    module, _, cls = named.partition(":")
+    return getattr(importlib.import_module(module), cls)(
+        model_config, engine_config, params=params, seed=seed
+    )
+
+
 class GPTRunner:
     """Owns the params, the paged cache pools, and the compiled steps."""
 
@@ -587,15 +614,6 @@ class GPTRunner:
         program's `memory_analysis()` (per device under tensor
         parallelism), and the kernels and collectives in its text.
         Compiles the decode program — a persistent-cache hit after warmup."""
-
-        def bytes_by_device(arrays):
-            out: dict = {}
-            for array in arrays:
-                for shard in array.addressable_shards:
-                    key = f"{shard.device.platform}:{shard.device.id}"
-                    out[key] = out.get(key, 0) + int(shard.data.nbytes)
-            return out
-
         ecfg = self.engine_config
         slots, nb = ecfg.max_decode_slots, ecfg.max_blocks_per_seq
 

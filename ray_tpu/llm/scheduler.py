@@ -35,6 +35,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 from ray_tpu.llm.cache import (
     BlockAllocator,
     CacheOutOfBlocks,
+    StateSlots,
     blocks_for_tokens,
     hash_block_tokens,
     prefix_block_hashes,
@@ -103,6 +104,9 @@ class Sequence:
         # until each restore commits, so a failed restore needs no
         # rollback: the slot simply stays a plain prefill target.
         self.pending_restore: List[Tuple[int, int]] = []
+        # The recurrent-state slot this sequence holds while it runs, on a
+        # model with such layers (Scheduler.state_slots); else None.
+        self.state_slot: Optional[int] = None
 
     @property
     def prefill_ids(self) -> List[int]:
@@ -133,8 +137,13 @@ class Scheduler:
         allocator: BlockAllocator,
         max_decode_slots: int,
         max_blocks_per_seq: int,
+        state_slots: Optional[StateSlots] = None,
     ):
         self.allocator = allocator
+        # A model with recurrent layers: a sequence owns blocks AND one
+        # state slot from admission to release (preemption frees both and
+        # the resume re-prefills from an empty state). None otherwise.
+        self.state_slots = state_slots
         self.max_decode_slots = max_decode_slots
         self.max_blocks_per_seq = max_blocks_per_seq
         self.waiting: Deque[Sequence] = deque()
@@ -232,6 +241,8 @@ class Scheduler:
                 break  # head-of-line blocking is deliberate: FIFO fairness
             self.waiting.popleft()
             seq.is_running = True
+            if self.state_slots is not None:
+                seq.state_slot = self.state_slots.allocate()
             # Pin the chunking target: prefill_ids grows as the sequence
             # generates, so "fully prefilled" must mean the length at
             # admission, not the live property.
@@ -536,3 +547,6 @@ class Scheduler:
         seq.block_hashes = []
         seq.num_cached = 0
         seq.pending_restore = []
+        if seq.state_slot is not None:
+            self.state_slots.free(seq.state_slot)
+            seq.state_slot = None

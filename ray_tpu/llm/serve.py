@@ -127,9 +127,18 @@ class LLMIngress:
         self._owns_engine = bool(engine_per_replica)
         if self._owns_engine:
             engine_name = f"{engine_name}-{uuid.uuid4().hex[:8]}"
+        # A generate call holds one of the engine actor's threads for as
+        # long as its request lives, so the actor's concurrency caps what
+        # the engine can hold, running and waiting together: at the default
+        # of 32 an engine with 32 decode lanes never has a request waiting
+        # for the lane that frees (chip runs, PR 32: occupancy 96.8 ->
+        # 98.9%, completed tokens/s +2.2%). Room for a queue as deep as the
+        # lanes; 32 up to 16 lanes, as before.
+        slots = (engine_config or EngineConfig()).max_decode_slots
         self._engine = get_or_create_engine_actor(
             engine_name, model_config, engine_config, params=params,
-            seed=seed, draft_params=draft_params,
+            seed=seed, max_concurrency=max(32, 2 * slots),
+            draft_params=draft_params,
         )
         self._as_snapshot: Optional[dict] = None
         self._as_snapshot_t = 0.0

@@ -114,14 +114,28 @@ def head_sharded_attention(
     return head_sharded_call(mesh, shard, (q, k, v), (LLM_HEAD_SPEC,) * 3)
 
 
-def validate_kv_pools(q, k_cache, v_cache, k_scale, v_scale) -> None:
+def validate_kv_pools(
+    q, k_cache, v_cache, k_scale, v_scale, num_kv_heads: Optional[int] = None
+) -> None:
     """One shared contract for both paged-attention implementations, so
     impl='auto' can never accept inputs on one backend that the other
     rejects: the pools are the stored form [L, N, bs, H*D] for q's
     [B, S, H, D] and share a dtype, int8 pools require BOTH dequant
     scales ([L, N, bs, H]), and scales require int8 pools (silently
-    dropping or applying them would diverge)."""
+    dropping or applying them would diverge). Under grouped-query
+    attention H is `num_kv_heads`, the cached heads, a divisor of q's."""
     h, d = q.shape[2:]
+    if num_kv_heads is not None and num_kv_heads != h:
+        if h % num_kv_heads:
+            raise ValueError(
+                f"{h} query heads are not a multiple of {num_kv_heads} "
+                "cached heads"
+            )
+        if k_cache.dtype == jnp.int8:
+            raise ValueError(
+                "int8 pools are not implemented for grouped-query attention"
+            )
+        h = num_kv_heads
     for name, pool, minor in (
         ("k_cache", k_cache, h * d), ("v_cache", v_cache, h * d),
         ("k_scale", k_scale, h), ("v_scale", v_scale, h),
@@ -209,7 +223,11 @@ def paged_attention(
     bs = k_cache.shape[2]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    validate_kv_pools(q, k_cache, v_cache, k_scale, v_scale)
+    # Grouped-query attention: the pools (and new_k / new_v) hold fewer
+    # heads than q has, each shared by a group of consecutive query heads.
+    h_q = h
+    h = new_k.shape[2] if new_k is not None else k_cache.shape[3] // d
+    validate_kv_pools(q, k_cache, v_cache, k_scale, v_scale, h)
     # Gather the pages: [B, nb, bs, H*D] -> [B, nb*bs, H, D].
     k_ctx = k_cache[layer, block_tables].reshape(b, nb * bs, h, d)
     v_ctx = v_cache[layer, block_tables].reshape(b, nb * bs, h, d)
@@ -234,6 +252,9 @@ def paged_attention(
         valid = jnp.concatenate(
             [valid, jnp.broadcast_to(causal[None], (b, q_len, s_new))], axis=2
         )
+    if h != h_q:
+        k_ctx = jnp.repeat(k_ctx, h_q // h, axis=2)
+        v_ctx = jnp.repeat(v_ctx, h_q // h, axis=2)
     logits = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k_ctx, preferred_element_type=jnp.float32
     )

@@ -1,0 +1,108 @@
+"""The routed experts a chip holds, without capacity and without drops.
+
+The router scores every expert of the layer (`num_experts`), a token takes
+its `top_k` best with gates that are a softmax over those `top_k` logits,
+and this chip computes the part of the result that the experts it holds
+give: what the absent experts would add is left out, the gates are not
+renormalised over the held ones. `local_of` [num_experts] maps an expert's
+id to its row in the held weights, or -1. A token's result depends on no
+other token in the batch, which a server needs: the same request gives the
+same logits alone and among others.
+
+Two forms of the same sum. `routed_dense` sends every token through every
+held expert and weighs by the gate (nought where the token did not choose
+it): for the few tokens of a decode step, whose time is reading the
+experts' matrices whatever is computed. `routed_grouped` sorts the held
+assignments by expert and takes two grouped products
+(`jax.lax.ragged_dot`, a grouped-matmul kernel on the TPU), so the work is
+that of the assignments made: for a prompt's chunk.
+
+An expert is `w_out (silu(g) * u)`, `[g, u] = w_in x`; w_in [E, D, 2F],
+w_out [E, F, D].
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+
+def route(
+    x: jax.Array, router: jax.Array, top_k: int
+) -> Tuple[jax.Array, jax.Array]:
+    """x [T, D], router [D, num_experts] -> (expert ids [T, k], gates
+    [T, k] float32). Logits and the softmax over the chosen are float32."""
+    logits = jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    top, ids = jax.lax.top_k(logits, top_k)
+    return ids, jax.nn.softmax(top, axis=-1)
+
+
+def _gated(h: jax.Array) -> jax.Array:
+    g, u = jnp.split(h, 2, axis=-1)
+    return jax.nn.silu(g) * u
+
+
+def routed_dense(
+    x: jax.Array, ids: jax.Array, gates: jax.Array, local_of: jax.Array,
+    w_in: jax.Array, w_out: jax.Array,
+) -> jax.Array:
+    """[T, D] float32: the held experts' gated sum for every token."""
+    held = w_in.shape[0]
+    local = local_of[ids]  # [T, k]
+    # Gate of token t for held expert e, nought where it did not choose it.
+    weight = jnp.sum(
+        jnp.where(
+            local[..., None] == jnp.arange(held)[None, None, :],
+            gates[..., None], 0.0,
+        ),
+        axis=1,
+    )  # [T, E]
+    h = jnp.einsum("td,edf->etf", x, w_in, preferred_element_type=jnp.float32)
+    act = _gated(h) * weight.T[..., None]
+    return jnp.einsum(
+        "etf,efd->td", act.astype(x.dtype), w_out,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def routed_grouped(
+    x: jax.Array, ids: jax.Array, gates: jax.Array, local_of: jax.Array,
+    w_in: jax.Array, w_out: jax.Array, valid: jax.Array,
+) -> jax.Array:
+    """As `routed_dense`, by grouped products over the assignments sorted
+    by expert. `valid` [T] marks the real tokens: a bucket's padding is
+    routed nowhere."""
+    t_len, k = ids.shape
+    held = w_in.shape[0]
+    # Assignments are numbered choice-major, choice * T + token: sorted
+    # back, the products are [k, T, D] and the sum over a token's choices
+    # runs over the leading axis. ([T, k, D] would put k = 10 on the
+    # second-minor axis, which the TPU pads to its tile and copies: 3 ms a
+    # layer of a 2,048-token chunk, more than both products; chip run,
+    # PR 32.)
+    local = jnp.where(valid[:, None], local_of[ids], -1).T  # [k, T]
+    key = jnp.where(local >= 0, local, held).reshape(-1)  # absent last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    rows = x[order % t_len]  # [k * T, D]
+    # The products round to the operands' type on the way out (float32
+    # inside): of a chunk's 20,480 rows by 4,096 a float32 copy is a third
+    # of a gigabyte, written, sorted back and read again in every layer.
+    h = jax.lax.ragged_dot(rows, w_in, sizes, preferred_element_type=x.dtype)
+    act = _gated(h.astype(jnp.float32)).astype(x.dtype)
+    y = jax.lax.ragged_dot(act, w_out, sizes, preferred_element_type=x.dtype)
+    # Back in the order of (choice, token), where the gates are. A choice
+    # no expert here serves sorted past the last group: whatever the
+    # product left in its row is not part of the sum.
+    y = y[jnp.argsort(order)].reshape(k, t_len, -1)
+    weight = jnp.where(local >= 0, gates.T, 0.0)
+    return jnp.sum(
+        jnp.where(local[..., None] >= 0, y.astype(jnp.float32), 0.0)
+        * weight[..., None],
+        axis=0,
+    )

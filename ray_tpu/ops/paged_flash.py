@@ -164,6 +164,7 @@ def _paged_kernel(
     q_ref, nk_ref, nv_ref, k_src, v_src, *rest,
     heads: int, head_dim: int, bs: int, nb: int, entries: int, tq: int,
     layer: int, quantized: bool, batched_heads: bool, kernel_copies: bool,
+    kv_heads: int,
 ):
     """Grid (B, nq, nq), every dimension sequential: one q tile of `tq` fed
     tokens per (b, qi). Step j == 0 walks the slot's cached context in
@@ -189,7 +190,16 @@ def _paged_kernel(
     dims, so a per-head [tq, D] view must not have the head dim between
     them) and head h is a lane slice of the tile: one [tq, D] x [D, tokens]
     product a head. int8 scales come gathered, [compute blocks, H, tokens]:
-    the layout the scores have."""
+    the layout the scores have.
+
+    Grouped-query attention (`kv_heads` < `heads`): the tile, the new K/V
+    and the lanes hold `kv_heads` heads, each read by `heads // kv_heads`
+    consecutive query heads. Decode's q then arrives already laid
+    block-diagonally, [H, kv_heads * D] with row h in the lanes of cached
+    head h // group, and its out leaves in that form (the caller keeps each
+    row's own lanes); elsewhere query head h reads lanes and new-token rows
+    of cached head h // group. With kv_heads == heads every branch below
+    is the one it was."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr, *copy_scratch = rest
     else:
@@ -209,9 +219,10 @@ def _paged_kernel(
     # tiles: heads narrower than one go a lane tile's worth a turn, and a
     # shape that does not divide so is unrolled.
     group = _LANES // d if _LANES % d == 0 else 1
-    looped = heads % group == 0 and (group * d) % _LANES == 0
+    looped = kv_heads % group == 0 and (group * d) % _LANES == 0
     if not looped:
         group = 1
+    shared = heads // kv_heads  # query heads a cached head serves
 
     def head_stats(h):
         if batched_heads:
@@ -219,6 +230,8 @@ def _paged_kernel(
         return m_scr.at[h], l_scr.at[h], acc_scr.at[h]
 
     def block_diagonal_q():  # [H, H*D] float32: row h holds head h's q
+        if shared > 1:
+            return q_ref[...].astype(jnp.float32)
         return jnp.where(
             _head_mask(heads, d), q_ref[...].astype(jnp.float32), 0.0
         )
@@ -250,10 +263,10 @@ def _paged_kernel(
         if batched_heads:
             s = _dot(
                 block_diagonal_q().astype(compute_dtype),
-                rows(k_ref, 0, heads * d).astype(compute_dtype),
+                rows(k_ref, 0, kv_heads * d).astype(compute_dtype),
                 ((1,), (1,)),
             )  # [H, tile]
-            fold(0, s, heads, rows(v_ref, 0, heads * d).astype(compute_dtype))
+            fold(0, s, heads, rows(v_ref, 0, kv_heads * d).astype(compute_dtype))
             return
 
         def head_group(g):
@@ -264,10 +277,12 @@ def _paged_kernel(
             v = rows(v_ref, lo, group * d).astype(compute_dtype)
             for i in range(group):
                 h, lanes = g * group + i, slice(i * d, (i + 1) * d)
-                s = _dot(q_ref[0, h], k[:, lanes], ((1,), (1,)))  # [tq, tile]
-                fold(h, s, 1, v[:, lanes])
+                for r in range(shared):
+                    hq = h if shared == 1 else h * shared + r
+                    s = _dot(q_ref[0, hq], k[:, lanes], ((1,), (1,)))  # [tq, tile]
+                    fold(hq, s, 1, v[:, lanes])
 
-        _each(heads // group, head_group, looped)
+        _each(kv_heads // group, head_group, looped)
 
     def walk_copying():
         base_ref, sems, k_buf, v_buf = copy_scratch
@@ -363,13 +378,14 @@ def _paged_kernel(
             return
 
         def head(h):
-            s = _dot(q_ref[0, h], nk_ref[0, h], ((1,), (1,)))  # [tq, tq]
+            hk = h if shared == 1 else h // shared
+            s = _dot(q_ref[0, h], nk_ref[0, hk], ((1,), (1,)))  # [tq, tq]
             rows = qi * tq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             cols = j * tq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             _online_update(
                 jnp.where(rows >= cols, s, NEG_INF), head_stats(h),
                 lambda p: _dot(  # new tokens are never quantized
-                    p.astype(compute_dtype), nv_ref[0, h], ((1,), (0,))
+                    p.astype(compute_dtype), nv_ref[0, hk], ((1,), (0,))
                 ),
             )
 
@@ -387,6 +403,9 @@ def _paged_kernel(
                 l == 0.0, 0.0, acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
             )
 
+        if batched_heads and shared > 1:
+            o_ref[...] = normalized(head_stats(0)).astype(o_ref.dtype)
+            return
         if batched_heads:
             out = jnp.where(_head_mask(heads, d), normalized(head_stats(0)), 0)
             o_ref[...] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
@@ -450,6 +469,7 @@ def paged_flash_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     interpret: Optional[bool] = None,
+    num_kv_heads: Optional[int] = None,
 ) -> jax.Array:
     """Fused paged attention over the block-table KV cache (Pallas TPU).
 
@@ -460,7 +480,11 @@ def paged_flash_attention(
     cache-only query should use the reference op). S == 1 is decode,
     S > 1 is prefix-aware partial prefill. When the cache pools are int8,
     `k_scale`/`v_scale` [L, N, bs, H] carry the per-token dequant scales
-    (see `quantize_kv`).
+    (see `quantize_kv`). `num_kv_heads` (default: new_k's) is the number of
+    cached heads under grouped-query attention: the pools are
+    [L, N, bs, num_kv_heads * D], new_k / new_v [B, S, num_kv_heads, D], and
+    query head h reads cached head h // (H // num_kv_heads). With
+    num_kv_heads == H the call lowers to the program it always did.
 
     Runs in interpret mode on CPU by default so tests exercise the same
     kernel the TPU compiles.
@@ -471,9 +495,16 @@ def paged_flash_attention(
             "carries the new tokens' K/V); use ops.paged_attention for "
             "cache-only queries"
         )
-    validate_kv_pools(q, k_cache, v_cache, k_scale, v_scale)
+    hkv = new_k.shape[2] if num_kv_heads is None else num_kv_heads
+    if new_k.shape[2] != hkv or new_v.shape[2] != hkv:
+        raise ValueError(
+            f"new_k/new_v hold {new_k.shape[2]}/{new_v.shape[2]} heads, "
+            f"num_kv_heads is {hkv}"
+        )
+    validate_kv_pools(q, k_cache, v_cache, k_scale, v_scale, hkv)
     quantized = k_cache.dtype == jnp.int8
     b, s_len, h, d = q.shape
+    grouped = hkv != h
     nb = block_tables.shape[1]
     bs = k_cache.shape[2]
     if sm_scale is None:
@@ -493,7 +524,7 @@ def paged_flash_attention(
     # Mosaic refuses to slice a narrower or ragged minor axis in HBM (a
     # chip's 5 heads of 64 under tp = 4; every scale pool): XLA gathers the
     # table's blocks of those, and the kernel is handed a slot's tiles.
-    kernel_copies = (h * d) % _LANES == 0
+    kernel_copies = (hkv * d) % _LANES == 0
 
     def gathered(pool):  # [L, N, bs, X] -> [B, n_tiles, tile, X]
         x = pool[layer][block_tables]
@@ -513,18 +544,18 @@ def paged_flash_attention(
         # pool nor converts its layout.
         kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
         kv_operands = [k_cache, v_cache]
-        tile_buf = pltpu.VMEM((2, entries, bs, h * d), k_cache.dtype)
+        tile_buf = pltpu.VMEM((2, entries, bs, hkv * d), k_cache.dtype)
         copy_scratch = [
             pltpu.SMEM((1,), jnp.int32),       # the tile the next walk starts in
             pltpu.SemaphoreType.DMA((2, 2)),   # (K | V, tile)
             tile_buf, tile_buf,
         ]
-        kv_vmem_bytes = 2 * 2 * tile * h * d * itemsize
+        kv_vmem_bytes = 2 * 2 * tile * hkv * d * itemsize
     else:
-        kv_specs = [slot_tiles(tile, h * d)] * 2
+        kv_specs = [slot_tiles(tile, hkv * d)] * 2
         kv_operands = [gathered(k_cache), gathered(v_cache)]
         copy_scratch = []
-        kv_vmem_bytes = 2 * 2 * n_tiles * tile * _whole_lanes(h * d) * itemsize
+        kv_vmem_bytes = 2 * 2 * n_tiles * tile * _whole_lanes(hkv * d) * itemsize
     if quantized:
         # Tokens on the lanes, as the scores have them.
         kv_specs += [slot_tiles(h, tile)] * 2
@@ -546,17 +577,27 @@ def paged_flash_attention(
     batched_heads = s_len == 1
 
     if batched_heads:
-        fed_block, stat_rows, acc_shape = (None, 1, h * d), (h,), (h, h * d)
+        fed_block, stat_rows, acc_shape = (None, 1, h * d), (h,), (h, hkv * d)
+        new_block = (None, 1, hkv * d)
 
         def fed(x):  # [B, 1, H, D] -> [B, 1, H*D]
-            return x.reshape(b, 1, h * d)
+            return x.reshape(b, 1, x.shape[2] * d)
 
         def q_map(bi, qi, j, tables_ref, lens_ref):
             return (bi, 0, 0)
 
         new_map = q_map
+        if grouped:
+            # q goes in laid block-diagonally, [B, H, Hkv*D]: row h holds
+            # query head h in the lanes of cached head h // group, nought
+            # elsewhere. Out comes back in the same form.
+            fed_block = (None, h, hkv * d)
+            own = (
+                jnp.arange(h)[:, None] // (h // hkv) == jnp.arange(hkv)[None, :]
+            )  # [H, Hkv]
     else:
         fed_block, stat_rows, acc_shape = (1, h, tq, d), (h, tq), (h, tq, d)
+        new_block = (1, hkv, tq, d)
 
         def fed(x):  # [B, S, H, D] -> [B, H, nq * tq, D]
             x = x.transpose(0, 2, 1, 3)
@@ -573,13 +614,18 @@ def paged_flash_attention(
             # nothing.
             return (bi, 0, jnp.minimum(j, qi), 0)
 
-    q = fed(q)
+    if batched_heads and grouped:
+        q = jnp.where(own[None, :, :, None], q[:, 0, :, None, :], 0).reshape(
+            b, h, hkv * d
+        )
+    else:
+        q = fed(q)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, nq, nq),
         in_specs=[
             pl.BlockSpec(fed_block, q_map),
-            pl.BlockSpec(fed_block, new_map), pl.BlockSpec(fed_block, new_map),
+            pl.BlockSpec(new_block, new_map), pl.BlockSpec(new_block, new_map),
             *kv_specs,
         ],
         out_specs=pl.BlockSpec(fed_block, q_map),
@@ -593,7 +639,7 @@ def paged_flash_attention(
     kernel = functools.partial(
         _paged_kernel, heads=h, head_dim=d, bs=bs, nb=nb, entries=entries,
         tq=tq, layer=layer, quantized=quantized, batched_heads=batched_heads,
-        kernel_copies=kernel_copies,
+        kernel_copies=kernel_copies, kv_heads=hkv,
     )
     out = pl.pallas_call(
         kernel,
@@ -606,6 +652,10 @@ def paged_flash_attention(
         ),
         interpret=interpret,
     )(block_tables, context_lens, q, fed(new_k), fed(new_v), *kv_operands)
+    if batched_heads and grouped:
+        # Row h's own lanes: those of cached head h // group.
+        out = out.reshape(b, h, hkv, d)
+        return jnp.sum(jnp.where(own[None, :, :, None], out, 0), axis=2)[:, None]
     if batched_heads:
         return out.reshape(b, 1, h, d)
     return out[:, :, :s_len].transpose(0, 2, 1, 3)

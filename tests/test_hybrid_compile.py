@@ -1,0 +1,82 @@
+"""granite-4.0-h-small's decode program at its real widths, compiled for a
+described TPU v5e without one (the style of tests/test_tpu_compile.py): the
+benchmark cell's geometry (layers 0-9, 36 of 72 experts held, 32 lanes with
+their state slots, 16,384 blocks of 16, 400-block tables), parameters as
+shapes only. Nothing runs. What it proves: Mosaic takes the grouped-query
+paged kernel at 32 query heads over 8 cached heads of 128, the weights,
+both K/V pools and every state pool fit one chip beside the program's
+temporaries with room for the widest prefill chunk (14.4 GB of the chip's
+16.9: the 2,048-token chunk needs 1.1 GB more than decode), and the donated
+pools alias, so no step copies a pool. About ten seconds: not slow.
+"""
+
+import os
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.llm import hybrid_runner as hr
+from ray_tpu.models import granite_hybrid as gh
+
+SLOTS, TABLE, BLOCK, BLOCKS = 32, 400, 16, 16384
+
+
+@pytest.fixture(scope="module")
+def chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topology = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # no libtpu, or it cannot describe a v5e
+        pytest.skip(f"cannot describe a TPU topology here: {exc!r}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topology.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def test_real_width_decode_program_fits_a_v5e(chip, monkeypatch):
+    # The paged kernel chooses interpret mode from the backend, the CPU here.
+    monkeypatch.setattr(sys.modules["ray_tpu.ops.paged_flash"], "_on_cpu", lambda: False)
+    cfg = gh.GraniteHybridConfig(experts_held=tuple(range(36)))
+    programs = hr._HybridPrograms(cfg, BLOCK, "pallas")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        lambda shape: sds(shape, jnp.bfloat16), gh._leaf_shapes(cfg),
+        is_leaf=lambda v: isinstance(v, tuple),
+    )
+    weights = 2 * sum(x.size for x in jax.tree_util.tree_leaves(params))
+    assert 9.92e9 < weights < 9.94e9  # 4.96 B parameters in bfloat16
+    kv = sds((1, BLOCKS, BLOCK, 8 * 128), jnp.bfloat16)
+    conv = tuple(sds((SLOTS, 3, cfg.conv_dim), jnp.bfloat16) for _ in range(9))
+    ssm = tuple(sds((SLOTS, 128, 64, 128), jnp.float32) for _ in range(9))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    compiled = programs.decode_fn.lower(
+        params, kv, kv, conv, ssm, i32(SLOTS + len(hr.DECODE_COUNTS)),
+        i32(SLOTS), i32(SLOTS, TABLE), i32(SLOTS),
+    ).compile()
+    memory = compiled.memory_analysis()
+    pools = 2 * BLOCKS * BLOCK * 1024 * 2 + SLOTS * 9 * (128 * 64 * 128 * 4 + 3 * 8448 * 2)
+    assert memory.alias_size_in_bytes >= pools  # every pool updated in place
+    held = (
+        memory.argument_size_in_bytes + memory.temp_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    )
+    assert held < 14.4e9, held
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1  # the paged kernel
+    scopes = set(hr.scopes_of(text).values())
+    assert {"llm.mixer.mamba.update", "llm.moe.routed", "llm.mixer.attention"} <= scopes

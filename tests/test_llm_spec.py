@@ -421,8 +421,13 @@ def test_engine_speculation_int8_kv_identical_to_plain_int8():
 def test_engine_abort_releases_draft_blocks():
     eng = LLMEngine(TINY, spec_cfg("draft"), seed=0)
     rid = eng.add_request([1, 2, 3] * 4, max_new_tokens=16)
-    for _ in range(3):
+    # Two steps, not three: the draft is the target's own seed, every
+    # proposal is accepted, and a step commits 1 + 4 tokens after the
+    # prefill's one (6, 11, 16), so the third step finishes the request and
+    # its finish, not the abort below, would release the mirror.
+    for _ in range(2):
         eng.step()
+    assert eng.scheduler.is_active(rid)
     assert eng._spec.allocator.num_allocated > 0  # draft mirror is live
     assert eng.abort(rid)
     assert eng.allocator.num_allocated == 0

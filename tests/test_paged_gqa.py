@@ -1,0 +1,174 @@
+"""Grouped-query attention in the paged kernel, and the promise that came
+with it: with as many cached heads as query heads the kernel and the GPT-2
+step programs trace to what they traced to before it could do anything
+else.
+
+The kernel (interpreted on the CPU) is compared with the XLA path
+(`ops.paged_attention`, the oracle) in float32: both sum a row's 40 to 80
+products in different orders, which reads 3e-7 at outputs about 1 wide;
+the tolerance 2e-6 is under a hundredth of what bfloat16 operands would
+move (4e-3).
+
+The second half pins digests of the traced programs, taken on the commit
+before this file existed: equal jaxprs are equal programs, so equal results
+bit for bit, on any machine. A change to the kernel's multi-head path shows
+here; whoever makes one on purpose re-pins the digest.
+"""
+
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.llm.model_runner import _StepPrograms
+from ray_tpu.models.gpt import GPTConfig
+from ray_tpu.ops.attention import paged_attention
+from ray_tpu.ops.paged_flash import paged_flash_attention
+
+TOLERANCE = 2e-6
+
+
+def _case(b, s, hq, hkv, d, bs=16, nb=4, layers=2, seed=0):
+    rng = np.random.RandomState(seed)
+    n = 1 + b * nb
+    f32 = lambda *shape: jnp.asarray(rng.randn(*shape), jnp.float32)  # noqa: E731
+    q, nk, nv = f32(b, s, hq, d), f32(b, s, hkv, d), f32(b, s, hkv, d)
+    kc, vc = f32(layers, n, bs, hkv * d), f32(layers, n, bs, hkv * d)
+    tables = jnp.asarray(1 + np.arange(b * nb).reshape(b, nb), jnp.int32)
+    lens = jnp.asarray(rng.randint(0, nb * bs - s, b), jnp.int32).at[0].set(0)
+    return (q, kc, vc, tables, lens), dict(new_k=nk, new_v=nv, layer=1, sm_scale=1 / 64)
+
+
+# (d) the kernel against the XLA path at num_kv_heads = num_heads and
+# num_heads / 4, one fed token a slot (decode) and several (a chunk).
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("fed", [1, 8, 24], ids=["decode", "chunk8", "chunk24"])
+@pytest.mark.parametrize("kv_heads", [8, 2], ids=["mha", "gqa4"])
+def test_kernel_matches_the_xla_path(kv_heads, fed, head_dim):
+    args, kwargs = _case(3, fed, 8, kv_heads, head_dim, seed=fed + kv_heads)
+    want = paged_attention(*args, **kwargs)
+    got = paged_flash_attention(*args, **kwargs, num_kv_heads=kv_heads)
+    assert got.shape == want.shape == (3, fed, 8, head_dim)
+    assert float(jnp.abs(got - want).max()) < TOLERANCE
+
+
+def test_one_cached_head_serves_every_query_head():
+    args, kwargs = _case(2, 1, 4, 1, 128, seed=9)
+    got = paged_flash_attention(*args, **kwargs)
+    assert float(jnp.abs(got - paged_attention(*args, **kwargs)).max()) < TOLERANCE
+
+
+def test_grouped_query_is_multi_head_over_repeated_heads():
+    (q, kc, vc, tables, lens), kwargs = _case(2, 8, 8, 2, 64, seed=3)
+    grouped = paged_attention(q, kc, vc, tables, lens, **kwargs)
+
+    def repeat(pool):  # [L, N, bs, 2 * d] -> [L, N, bs, 8 * d]
+        heads = pool.reshape(pool.shape[:3] + (2, 64))
+        return jnp.repeat(heads, 4, axis=3).reshape(pool.shape[:3] + (8 * 64,))
+
+    kwargs["new_k"], kwargs["new_v"] = (
+        jnp.repeat(kwargs[k], 4, axis=2) for k in ("new_k", "new_v")
+    )
+    full = paged_attention(q, repeat(kc), repeat(vc), tables, lens, **kwargs)
+    assert float(jnp.abs(grouped - full).max()) < TOLERANCE
+
+
+def test_refused_shapes():
+    (q, kc, vc, tables, lens), kwargs = _case(2, 1, 8, 2, 64)
+    with pytest.raises(ValueError, match="num_kv_heads"):
+        paged_flash_attention(q, kc, vc, tables, lens, **kwargs, num_kv_heads=4)
+    with pytest.raises(ValueError, match="multiple"):
+        paged_flash_attention(
+            q[:, :, :7], kc, vc, tables, lens, **kwargs, num_kv_heads=2
+        )
+    with pytest.raises(ValueError, match="int8"):
+        paged_flash_attention(
+            q, kc.astype(jnp.int8), vc.astype(jnp.int8), tables, lens, **kwargs
+        )
+
+
+# ---------------- num_kv_heads == num_heads: the programs of before ----------------
+
+
+def _digest(jaxpr) -> str:
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    text = re.sub(r" at [^\s:]+\.py:\d+", "", text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def _kernel_jaxpr(b, s, h, d=16, bs=8, nb=4):
+    n = 1 + b * nb
+    sds = jax.ShapeDtypeStruct
+    f32, i32 = jnp.float32, jnp.int32
+    args = (
+        sds((b, s, h, d), f32), sds((2, n, bs, h * d), f32), sds((2, n, bs, h * d), f32),
+        sds((b, nb), i32), sds((b,), i32), sds((b, s, h, d), f32), sds((b, s, h, d), f32),
+    )
+
+    def fn(q, kc, vc, tables, lens, nk, nv):
+        return paged_flash_attention(
+            q, kc, vc, tables, lens, new_k=nk, new_v=nv, layer=1, interpret=True
+        )
+
+    return jax.make_jaxpr(fn)(*args)
+
+
+def _gpt_program_jaxprs():
+    cfg = GPTConfig(
+        vocab_size=512, num_layers=2, num_heads=4, embed_dim=64, mlp_ratio=4,
+        max_seq_len=128, dtype=jnp.float32,
+    )
+    programs = _StepPrograms(cfg, 8, "pallas", jnp.float32, 1)
+    params = jax.eval_shape(
+        programs.model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )
+    pool = jax.ShapeDtypeStruct((2, 32, 8, 64), jnp.float32)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    return {
+        "gpt_decode_program": jax.make_jaxpr(programs._decode_step)(
+            params, pool, pool, None, None, i32(4), i32(4), i32(4, 16), i32(4)
+        ),
+        "gpt_suffix_program": jax.make_jaxpr(programs._prefill_suffix_step)(
+            params, pool, pool, None, None, i32(1, 16), i32(16), i32(), i32()
+        ),
+    }
+
+
+# Taken on commit f4535eb (PR 31), the parent of the PR that added
+# num_kv_heads, with the function above.
+PINNED = {
+    "decode_4_heads": "f35f70f0dfb08fa2",
+    "suffix_4_heads": "7ac8a4becb355360",
+    "decode_heads_of_128": "1e3d583d0f4eb18c",
+    "suffix_heads_of_128": "f7568ba10f95a7eb",
+    "gpt_decode_program": "48e837bf5aa3aefb",
+    "gpt_suffix_program": "82135a7661c46a57",
+}
+KERNELS = {
+    "decode_4_heads": (3, 1, 4),
+    "suffix_4_heads": (2, 8, 4),
+    "decode_heads_of_128": (2, 1, 2, 128),
+    "suffix_heads_of_128": (1, 16, 2, 128),
+}
+
+
+@pytest.mark.parametrize("name", list(KERNELS))
+def test_multi_head_kernel_traces_as_before(name):
+    assert _digest(_kernel_jaxpr(*KERNELS[name])) == PINNED[name]
+
+
+@pytest.mark.parametrize("name", ["gpt_decode_program", "gpt_suffix_program"])
+def test_gpt2_step_programs_trace_as_before(name):
+    assert _digest(_gpt_program_jaxprs()[name]) == PINNED[name]
+
+
+def test_a_grouped_kernel_traces_differently():
+    """The digest sees the kernel's body: it is no constant."""
+    (q, kc, vc, tables, lens), kwargs = _case(3, 1, 4, 2, 16, bs=8)
+    grouped = jax.make_jaxpr(
+        lambda *a: paged_flash_attention(*a, **kwargs, interpret=True)
+    )(q, kc, vc, tables, lens)
+    assert _digest(grouped) != PINNED["decode_4_heads"]
