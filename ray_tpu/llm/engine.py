@@ -1882,6 +1882,9 @@ class LLMEngine:
             # FLOPs ~= 2 * model_params per generated token). Counted
             # once at runner init, not per scrape.
             "model_params": getattr(self.runner, "num_params", None),
+            # Bytes of those weights as the runner holds them (matrices
+            # in the compute dtype): what a decode step reads of them.
+            "weight_bytes": getattr(self.runner, "weight_bytes", None),
             "host_transfer_bytes": self._host_transfer_bytes(),
             "steps": self._steps,
             "decode_tokens": self._decode_tokens,

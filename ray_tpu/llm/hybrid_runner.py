@@ -269,6 +269,9 @@ class HybridRunner:
             model.init_params(cfg, seed) if params is None else params
         )
         self.num_params = model.num_params(self.params)
+        self.weight_bytes = int(
+            sum(x.nbytes for x in jax.tree_util.tree_leaves(self.params))
+        )
         self.host_bytes_in = 0
         self.host_bytes_out = 0
 

@@ -15,6 +15,8 @@ and head size merged on one lane-dense minor axis, the form the device
 keeps row-major and the paged kernel reads as it is — threaded
 functionally through every step with donated buffers, so steps update the
 cache in place without host round-trips and no program converts a pool.
+Nor a weight: the runner holds its matrices in the compute dtype, rounded
+once when it takes them, so a step reads each as it multiplies it.
 
 Serving hot-path knobs (EngineConfig):
 
@@ -54,7 +56,12 @@ import numpy as np
 from ray_tpu._private.jax_setup import ensure_compile_cache, host_cpu_device
 from ray_tpu.llm.cache import kv_pool_bytes_sharded
 from ray_tpu.llm.config import EngineConfig
-from ray_tpu.models.gpt import GPT, GPTConfig, collect_kv_caches
+from ray_tpu.models.gpt import (
+    GPT,
+    GPTConfig,
+    collect_kv_caches,
+    serving_params,
+)
 from ray_tpu.ops.attention import validate_tp_heads
 from ray_tpu.ops.paged_flash import (
     KV_SCALE_DTYPE,
@@ -417,7 +424,12 @@ def build_runner(model_config, engine_config: EngineConfig, params=None,
 
 
 class GPTRunner:
-    """Owns the params, the paged cache pools, and the compiled steps."""
+    """Owns the params, the paged cache pools, and the compiled steps.
+
+    The params are held as the step programs multiply them: matrices and
+    embeddings in `model_config.dtype` (`models.gpt.serving_params`, once,
+    here), LayerNorm parameters as they came. `weight_bytes` says how
+    much that is; a tree handed in is left to its owner."""
 
     def __init__(
         self,
@@ -475,20 +487,35 @@ class GPTRunner:
         self.model = self._programs.model
         self.mesh = self._programs.mesh
         self._pool_sharding = self._programs.pool_sharding
+        # serving_params before the placement below and leaf by leaf where
+        # each leaf lives, so a tensor-parallel boot shards the rounded
+        # bytes from wherever they were: no program takes the tree whole.
         if params is None:
             probe = jnp.zeros((1, engine_config.block_size), jnp.int32)
-            if self.mesh is not None:
-                # Seed-init on the host CPU: the full tree must never
-                # materialize on one accelerator chip (a tp-sharded model
-                # may exceed per-chip HBM — the situation tp exists for).
-                # llm_shard_params below then device_puts each leaf
-                # straight from host memory into its Megatron placement,
-                # the same host->shards path a numpy checkpoint takes.
-                host = host_cpu_device("seed-initializing tensor-parallel weights")
-                with jax.default_device(host):
-                    params = self.model.init(jax.random.PRNGKey(seed), probe)
-            else:
-                params = self.model.init(jax.random.PRNGKey(seed), probe)
+            # Under tensor parallelism seed-init (and round) on the host
+            # CPU: the full tree must never materialize on one accelerator
+            # chip (a tp-sharded model may exceed per-chip HBM — the
+            # situation tp exists for). llm_shard_params below then
+            # device_puts each leaf straight from host memory into its
+            # Megatron placement, the same host->shards path a numpy
+            # checkpoint takes. On one chip the float32 tree lives there
+            # until its rounded copy is whole (1.5x the tree, before the
+            # pools exist), then nothing refers to it.
+            host = (
+                host_cpu_device("seed-initializing tensor-parallel weights")
+                if self.mesh is not None
+                else None
+            )
+            with jax.default_device(host):
+                params = serving_params(
+                    model_config,
+                    self.model.init(jax.random.PRNGKey(seed), probe),
+                )
+        else:
+            # A handed tree is rounded in place of residence: numpy leaves
+            # (a checkpoint) by numpy on the host, device leaves on their
+            # own devices. It is the caller's and is left alive.
+            params = serving_params(model_config, params)
         if self.mesh is not None:
             # Megatron-style weight placement from the model's logical axis
             # annotations (parallel.sharding.LLM_TP_RULES): qkv/mlp-in
@@ -502,9 +529,11 @@ class GPTRunner:
         # Parameter count, once at init (a tree reduce over the weights is
         # too slow for a stats() scrape): feeds the fleet ledger's MFU
         # estimate — decode FLOPs ~= 2 * num_params per generated token.
-        self.num_params = int(
-            sum(x.size for x in jax.tree_util.tree_leaves(params))
-        )
+        leaves = jax.tree_util.tree_leaves(params)
+        self.num_params = int(sum(x.size for x in leaves))
+        # Bytes of the leaves as held (every shard of them under tensor
+        # parallelism): what a decode step reads of the weights.
+        self.weight_bytes = int(sum(x.nbytes for x in leaves))
         # Host-transfer accounting: bytes explicitly moved across the
         # host/device boundary by the program dispatches below (token ids,
         # block tables, lengths in; sampled token ids out). The pools and

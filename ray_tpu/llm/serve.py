@@ -332,7 +332,11 @@ def build_app(
 ) -> serve.Application:
     """Bind the LLM ingress for `serve.run` (HTTP via the existing proxy:
     POST /<app> with the request JSON). Pass trained weights via `params`;
-    without them the engine serves a seed-initialized model.
+    without them the engine serves a seed-initialized model. The engine
+    keeps its own copy of the matrices and embeddings in
+    `model_config.dtype`, rounded once (`models.gpt.serving_params`; same
+    logits, half the bytes a step for float32 masters served in bf16):
+    the tree you pass is not touched and may be dropped afterwards.
 
     `engine_config.tensor_parallel_size > 1` makes the ONE shared engine
     actor span a multi-chip mesh (weights Megatron-sharded, KV pools

@@ -335,3 +335,59 @@ def collect_moe_losses(intermediates: Any) -> jax.Array:
         return total
 
     return collect(intermediates, jnp.zeros((), jnp.float32))
+
+
+# The leaves a forward pass rounds to `cfg.dtype` every time it runs: flax's
+# `promote_dtype` casts a Dense's kernel and bias and an Embed's table where
+# they are used. By owning module, as (module name, parameter names).
+_ROUNDED_EVERY_CALL = {
+    "attn_qkv": ("kernel", "bias"),
+    "attn_proj": ("kernel", "bias"),
+    "mlp_in": ("kernel", "bias"),
+    "mlp_out": ("kernel", "bias"),
+    "wte": ("embedding",),
+    "wpe": ("embedding",),
+}
+
+
+def serving_params(cfg: GPTConfig, params: Any) -> Any:
+    """`params` as a server should hold them: the leaves the modules round
+    to `cfg.dtype` in every call (`_ROUNDED_EVERY_CALL`) rounded once, here.
+
+    The logits are the same bit for bit: each matmul, the gather and the
+    tied head multiply these very values either way, and round-to-nearest
+    gives the same bits in a fusion as in a cast of its own. But a jitted
+    step takes the parameters as arguments, so XLA cannot hoist the
+    rounding out of it, and a decode step over float32 leaves reads twice
+    the bytes it multiplies (and writes `wte` rounded, for the gather and
+    the head to share). LayerNorm scales and biases stay as they came
+    (`_normalize` multiplies them in float32), and so does everything
+    under `moe_mlp` (the router scores in float32; models/moe.py). Boxes
+    (`nn.LogicallyPartitioned`) are pytree nodes and come back around the
+    new leaves, so the sharding rules still find their axis names. Each
+    leaf is cast where it lives, a numpy leaf by numpy on the host, so
+    nothing moves to a device that was not there, and nothing is donated:
+    the caller's tree is the caller's. Where no leaf needs it (`cfg.dtype`
+    float32, or a tree that was here before) `params` itself is returned.
+    Training keeps its float32 masters and never calls this."""
+    dtype = jnp.dtype(cfg.dtype)
+    cast_any = False
+
+    def cast(path, leaf):
+        nonlocal cast_any
+        names = [
+            key.key for key in path
+            if isinstance(key, jax.tree_util.DictKey)
+        ]
+        if (
+            len(names) < 2
+            or "moe_mlp" in names
+            or names[-1] not in _ROUNDED_EVERY_CALL.get(names[-2], ())
+            or leaf.dtype == dtype
+        ):
+            return leaf
+        cast_any = True
+        return leaf.astype(dtype)
+
+    held = jax.tree_util.tree_map_with_path(cast, params)
+    return held if cast_any else params
