@@ -10,9 +10,12 @@ failing item's slot, so the consumer raises exactly at that point.
 
 from __future__ import annotations
 
+import logging
 import threading
 from collections import deque
 from typing import Optional
+
+logger = logging.getLogger(__name__)
 
 _SENTINEL = object()
 
@@ -25,17 +28,48 @@ class ObjectRefStream:
         self._items: deque = deque()
         self._done = False
         self._total: Optional[int] = None
+        # One-shot callbacks of consumers that wait without a thread
+        # (`on_ready`), called by the producer's thread.
+        self._waiters: list = []
 
     def offer(self, ref) -> None:
         with self._cv:
             self._items.append(ref)
             self._cv.notify_all()
+            waiters, self._waiters = self._waiters, []
+        self._wake(waiters)
 
     def finish(self, total: int) -> None:
         with self._cv:
             self._done = True
             self._total = total
             self._cv.notify_all()
+            waiters, self._waiters = self._waiters, []
+        self._wake(waiters)
+
+    @staticmethod
+    def _wake(waiters: list) -> None:
+        """The callbacks run on the producer's thread, so none may fail the
+        producer or cost the waiters behind it their call: a consumer whose
+        event loop has closed (`call_soon_threadsafe` raises) is gone, and
+        its callback is dropped."""
+        for callback in waiters:
+            try:
+                callback()
+            except Exception:
+                logger.debug("stream waiter dropped", exc_info=True)
+
+    def on_ready(self, callback) -> None:
+        """Call `callback()` once, when `next()` would not block: at once
+        where an item is waiting or the stream has ended, else from the
+        thread that offers the next item or finishes the stream. What an
+        event loop waits on in place of a thread parked in `next()`."""
+        with self._cv:
+            ready = bool(self._items) or self._done
+            if not ready:
+                self._waiters.append(callback)
+        if ready:
+            callback()
 
     def next(self, timeout: Optional[float] = None):
         """Blocking pop; returns _SENTINEL when the stream is exhausted.

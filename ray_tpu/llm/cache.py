@@ -44,6 +44,7 @@ Automatic prefix caching (vLLM-style, restated for this allocator):
 
 from __future__ import annotations
 
+import dataclasses
 import itertools
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -125,6 +126,31 @@ def prefix_block_hashes(
     return out
 
 
+@dataclasses.dataclass(frozen=True)
+class CacheClass:
+    """One class of paged cache a model's configuration declares
+    (`cache_classes`): `layers` attention layers keep their K and V in it,
+    and a query of theirs sees the last `horizon` positions only (None:
+    every earlier position). Each class has a pool a layer group, an
+    allocator and a block table a sequence of its own (`CacheManager`)."""
+
+    name: str
+    layers: int
+    horizon: Optional[int] = None
+
+
+def window_class_of(model_config) -> Optional[CacheClass]:
+    """The class with a horizon among those `model_config` declares
+    (`cache_classes`; a configuration that declares none has the one full
+    class), or None. One at most, after the full class."""
+    windows = [
+        c for c in getattr(model_config, "cache_classes", ()) if c.horizon is not None
+    ]
+    if len(windows) > 1:
+        raise ValueError("more than one cache class with a horizon is not implemented")
+    return windows[0] if windows else None
+
+
 class StateSlots:
     """Free list of the recurrent-state slots of a model whose layers carry
     a state beside the paged cache (`model_config.recurrent_state`): one
@@ -151,6 +177,88 @@ class StateSlots:
 
     def free(self, slot: int) -> None:
         self._free.append(slot)
+
+
+class WindowBlocks:
+    """The block class of layers whose queries see the last `horizon`
+    positions only (`CacheClass.horizon`): an allocator of its own over
+    its own pools, and a table a sequence beside the full class's
+    (`Sequence.window_table`), indexed by position // block_size like it.
+    A block is held from the step that first writes into it until no later
+    query can see any of its positions: the query at position p sees
+    p - horizon < j <= p, so once positions below `n` are committed the
+    blocks whose last position lies below n - horizon + 1 are freed and
+    their entries become the null block, which a kernel told the horizon
+    never reads. No prefix sharing: a hit would need this class to still
+    hold the `horizon` tokens before the boundary.
+
+    Its size is derived, not configured (`blocks_needed`): every decode
+    lane's sequence holds at most horizon / block_size + 2 blocks between
+    steps (the window, the block being written, and one step's lookahead),
+    and one prefill chunk is in flight at a time."""
+
+    def __init__(self, num_blocks: int, block_size: int, horizon: int):
+        if horizon < 1:
+            raise ValueError("a window class needs a horizon of at least 1")
+        self.allocator = BlockAllocator(
+            num_blocks, block_size, enable_prefix_caching=False
+        )
+        self.block_size = block_size
+        self.horizon = horizon
+        self.num_freed = 0
+
+    @staticmethod
+    def blocks_needed(
+        lanes: int, horizon: int, block_size: int, chunk_tokens: int
+    ) -> int:
+        """Pool size (the null block included) that never refuses a
+        sequence holding a decode lane."""
+        steady = WindowBlocks.steady(horizon, block_size)
+        return 1 + lanes * steady + blocks_for_tokens(chunk_tokens, block_size) + 1
+
+    @staticmethod
+    def steady(horizon: int, block_size: int) -> int:
+        """What a sequence holds at most between steps: the window, the
+        block being written and one step's lookahead."""
+        return blocks_for_tokens(horizon, block_size) + 2
+
+    @property
+    def steady_blocks(self) -> int:
+        return self.steady(self.horizon, self.block_size)
+
+    def first_live(self, next_position: int) -> int:
+        """Index of the first block a query at `next_position` or later
+        can see into."""
+        return max(0, (next_position - self.horizon + 1) // self.block_size)
+
+    def missing(self, table: List[int], position: int) -> int:
+        """Blocks `table` lacks to cover `position`."""
+        return max(0, position // self.block_size + 1 - len(table))
+
+    def extend(self, table: List[int], position: int) -> None:
+        """Grow `table` to cover `position`; raises CacheOutOfBlocks and
+        changes nothing where the class cannot."""
+        table.extend(self.allocator.allocate(self.missing(table, position)))
+
+    def advance(self, table: List[int], first: int, next_position: int) -> int:
+        """Free the blocks of `table` from index `first` on that no query
+        at `next_position` or later sees; returns the new first live
+        index."""
+        live = min(self.first_live(next_position), len(table))
+        if live > first:
+            self.allocator.free(table[first:live])
+            table[first:live] = [NULL_BLOCK] * (live - first)
+            self.num_freed += live - first
+            return live
+        return first
+
+    def release(self, table: List[int], first: int) -> None:
+        if len(table) > first:
+            self.allocator.free(table[first:])
+
+    def held_tokens(self, first: int, num_cached: int) -> int:
+        """Committed positions of a sequence that lie in blocks it holds."""
+        return max(0, num_cached - first * self.block_size)
 
 
 class BlockAllocator:

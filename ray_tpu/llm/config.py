@@ -108,8 +108,12 @@ class EngineConfig:
     # (chain-hashed token ids) and freed blocks stay reusable until
     # evicted, so shared system prompts, repeated prompts, and
     # preempt-resume re-prefills skip recomputing the cached prefix.
-    # Greedy outputs are token-identical either way.
-    enable_prefix_caching: bool = True
+    # Greedy outputs are token-identical either way. None (the default):
+    # on wherever the model's cache can be resumed from a block boundary,
+    # which a cache with a window class or recurrent state beside it cannot
+    # (the block cache is then built without sharing). True asks for it:
+    # a model with sliding-window layers is refused at construction.
+    enable_prefix_caching: Optional[bool] = None
     # Which cached-but-unreferenced block to evict under pressure:
     # "lru" (least recently freed/used) or "fifo" (oldest registration).
     prefix_eviction_policy: str = "lru"
@@ -429,6 +433,19 @@ class EngineConfig:
             quarter = (self.max_model_len // 4) // self.block_size
             return max(1, quarter) * self.block_size
         return v
+
+    def window_class_blocks(self, horizon: int) -> int:
+        """Blocks (the null block included) of the cache class of a model's
+        sliding-window layers, derived and not configured: what the decode
+        lanes hold at `horizon` tokens each, and the one prefill chunk in
+        flight (`cache.WindowBlocks.blocks_needed`)."""
+        from ray_tpu.llm.cache import WindowBlocks
+
+        chunk = self.prefill_token_budget or self.buckets()[-1]
+        return WindowBlocks.blocks_needed(
+            self.max_decode_slots, horizon, self.block_size,
+            min(chunk, self.buckets()[-1]),
+        )
 
     def chunk_widths(self) -> Tuple[int, ...]:
         """The prefill buckets the chunked path can dispatch: every chunk

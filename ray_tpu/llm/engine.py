@@ -32,7 +32,13 @@ import numpy as np
 
 from ray_tpu._private.fault_injection import maybe_fail
 from ray_tpu.exceptions import EngineOverloadedError, PoisonRequestError
-from ray_tpu.llm.cache import BlockAllocator, StateSlots, blocks_for_tokens
+from ray_tpu.llm.cache import (
+    BlockAllocator,
+    StateSlots,
+    WindowBlocks,
+    blocks_for_tokens,
+    window_class_of,
+)
 from ray_tpu.llm.config import EngineConfig
 from ray_tpu.llm.model_runner import build_runner
 from ray_tpu.llm.observability import (
@@ -150,23 +156,51 @@ class LLMEngine:
         self._recurrent = bool(
             getattr(self.model_config, "recurrent_state", False)
         )
-        if self._recurrent:
-            ecfg = self.engine_config
-            refused = {
-                "speculation": ecfg.speculation != "off",
-                "kv_fabric": ecfg.kv_fabric is not None,
-                'kv_cache_dtype="int8"': ecfg.kv_cache_dtype == "int8",
-                "tensor_parallel_size > 1": ecfg.tensor_parallel_size > 1,
-            }
+        # A model with sliding-window layers declares a second cache class
+        # with a horizon (`cache_classes`): its blocks are freed from below
+        # as a sequence advances, so a cached prefix cannot be resumed from
+        # either (a hit would need the window class to still hold the
+        # horizon's tokens before the boundary), and the same features are
+        # refused, in the same words.
+        window_class = window_class_of(self.model_config)
+        ecfg = self.engine_config
+        refused = {
+            "speculation": ecfg.speculation != "off",
+            "kv_fabric": ecfg.kv_fabric is not None,
+            'kv_cache_dtype="int8"': ecfg.kv_cache_dtype == "int8",
+            "tensor_parallel_size > 1": ecfg.tensor_parallel_size > 1,
+        }
+        if window_class is not None and ecfg.enable_prefix_caching:
+            # Asked for, not defaulted (None): refused like the rest. A
+            # recurrent model keeps PR 32's answer, the cache built
+            # without sharing whatever was asked.
+            raise ValueError(
+                "enable_prefix_caching=True is not supported for a model "
+                f"with sliding-window layers ({type(self.model_config).__name__}): "
+                "a prefix hit would need the window class to still hold the "
+                f"{window_class.horizon} tokens before the boundary; leave "
+                "it None (off for this model) or False"
+            )
+        keeps = {
+            "recurrent layers": (
+                self._recurrent,
+                "need the recurrent state rolled back, stored or sharded "
+                "beside the K/V it belongs to",
+            ),
+            "sliding-window layers": (
+                window_class is not None,
+                "need the window class's freed blocks rolled back, stored "
+                "or sharded beside the full class's",
+            ),
+        }
+        for layers, (has, need) in keeps.items():
             for what, asked in refused.items():
-                if asked:
+                if has and asked:
                     raise ValueError(
                         f"{what} is not supported for a model with "
-                        "recurrent layers "
-                        f"({type(self.model_config).__name__}): a rejected "
-                        "draft, a spilled block or a sharded pool would "
-                        "need the recurrent state rolled back, stored or "
-                        "sharded beside the K/V it belongs to"
+                        f"{layers} ({type(self.model_config).__name__}): a "
+                        "rejected draft, a spilled block or a sharded pool "
+                        f"would {need}"
                     )
         self.runner = build_runner(
             self.model_config, self.engine_config, params=params, seed=seed
@@ -181,8 +215,9 @@ class LLMEngine:
             self.engine_config.num_blocks,
             self.engine_config.block_size,
             enable_prefix_caching=(
-                self.engine_config.enable_prefix_caching
+                self.engine_config.enable_prefix_caching is not False
                 and not self._recurrent
+                and window_class is None
             ),
             eviction_policy=self.engine_config.prefix_eviction_policy,
         )
@@ -195,7 +230,21 @@ class LLMEngine:
                 if self._recurrent
                 else None
             ),
+            window=(
+                WindowBlocks(
+                    ecfg.window_class_blocks(window_class.horizon),
+                    ecfg.block_size,
+                    window_class.horizon,
+                )
+                if window_class is not None
+                else None
+            ),
         )
+        self._window = self.scheduler.window
+        # A runner of a model with routed experts returns a decode step's
+        # routing counts behind its tokens (`count_routing`), and carries
+        # counters and shapes of its own for `stats()`.
+        self._counts_routing = hasattr(self.runner, "count_routing")
         # KV fabric (EngineConfig.kv_fabric): shared host-DRAM spill tier.
         # None keeps every hook cold — the allocator, scheduler, and step
         # loop behave bit-for-bit as before the fabric existed.
@@ -561,6 +610,13 @@ class LLMEngine:
         # reader divides the kernel's device time by these.
         self._decode_dispatches = 0
         self._decode_context_tokens = 0
+        # A model with sliding-window layers: what the decode dispatches
+        # asked of the window class (each lane's context up to the
+        # horizon), and the tokens each class held for the running
+        # sequences, summed over steps.
+        self._decode_window_tokens = 0
+        self._held_tokens_full = 0
+        self._held_tokens_window = 0
         # How often depth 1 engages, and why it does not: decode
         # dispatches made from an in-flight record's device tokens, and
         # the steps that could not chain, counted where step() and
@@ -578,6 +634,11 @@ class LLMEngine:
         self._dec_tokens = np.zeros((slots,), np.int32)
         self._dec_positions = np.zeros((slots,), np.int32)
         self._dec_block_tables = np.zeros((slots, nb), np.int32)
+        self._dec_window_tables = (
+            np.zeros((slots, nb), np.int32)
+            if self._window is not None
+            else None
+        )
         self._dec_context_lens = np.zeros((slots,), np.int32)
         self._verify_inputs = (
             {
@@ -1056,6 +1117,13 @@ class LLMEngine:
         if clock is not None:
             clock.switch("other")
         self._steps += 1
+        window = self._window
+        if window is not None:
+            for seq in self.scheduler.running:
+                self._held_tokens_full += seq.num_cached
+                self._held_tokens_window += window.held_tokens(
+                    seq.window_first, seq.num_cached
+                )
         for metric in self._metric_family:
             metric._ensure_registered()
         preempted = self.scheduler.num_preemptions - preempted_before
@@ -1158,6 +1226,25 @@ class LLMEngine:
             "evictable_blocks": self.allocator.num_evictable,
             "prefill_backlog_tokens": backlog,
         }
+
+    def _cache_class_stats(self) -> dict:
+        """Per cache class of a model with a window class: its layers,
+        horizon, blocks (the null block left out), blocks in use and the
+        bytes a cached token costs in it."""
+        token_bytes = self.runner.kv_token_bytes()
+        out = {}
+        for cls, allocator in zip(
+            self.model_config.cache_classes,
+            (self.allocator, self._window.allocator),
+        ):
+            out[cls.name] = {
+                "layers": cls.layers,
+                "horizon": cls.horizon,
+                "blocks": allocator.num_usable,
+                "blocks_in_use": allocator.num_allocated,
+                "bytes_per_token": cls.layers * token_bytes,
+            }
+        return out
 
     def _host_transfer_bytes(self) -> int:
         """Cumulative explicit host<->device bytes across the target
@@ -1479,6 +1566,14 @@ class LLMEngine:
             tokens.fill(0)
         context_tokens = 0
         recurrent = self._recurrent
+        window = self._window
+        extra = {}
+        if window is not None:
+            # The lanes' tables in the window class, and what the dispatch
+            # asks of it: each lane's context as far as its layers see it.
+            window_tables = extra["window_tables"] = self._dec_window_tables
+            window_tables.fill(0)
+            window_tokens = 0
         for i, seq in enumerate(seqs):
             if recurrent:
                 # A sequence's decode lane is its state slot, dispatch
@@ -1492,6 +1587,10 @@ class LLMEngine:
             block_tables[i, : len(seq.block_table)] = seq.block_table
             context_lens[i] = cached
             context_tokens += cached
+            if window is not None:
+                table = seq.window_table
+                window_tables[i, : len(table)] = table
+                window_tokens += min(cached, window.horizon)
         # What this dispatch asks the paged kernel to read: len(seqs)
         # sequences, context_tokens cached positions in all (the sum of
         # context_lens), in every layer.
@@ -1500,9 +1599,11 @@ class LLMEngine:
         if clock is not None:
             clock.describe_decode(len(seqs), context_tokens)
         self._note_dispatch(pipelined=bool(ahead))
+        if window is not None:
+            self._decode_window_tokens += window_tokens
         tokens_dev = self.runner.decode(
             chained_from.tokens_dev if ahead else tokens,
-            positions, block_tables, context_lens,
+            positions, block_tables, context_lens, **extra,
         )
         rids = [s.request.request_id for s in seqs]
         clock_seq = clock.dispatches if clock is not None else None
@@ -1544,7 +1645,7 @@ class LLMEngine:
             # with the device idle (1 ms a step in the chat cell, PR 30).
             rec.tokens_host = np.asarray(rec.tokens_dev)
             rec.tokens_dev = None
-            if rec.lanes is not None:
+            if self._counts_routing:
                 # The step's routing counts ride the same fetch.
                 self.runner.count_routing(rec.tokens_host)
             if clock is not None:
@@ -1565,6 +1666,8 @@ class LLMEngine:
             self._current_rid = rec.rids[i]
             maybe_fail("llm.decode.seq", detail=rec.rids[i])
             seq.num_cached += 1
+            if self._window is not None:
+                self.scheduler.advance_window(seq)
             lane = i if rec.lanes is None else rec.lanes[i]
             seq.generated.append(int(next_tokens[lane]))
             if seq.num_cached % ecfg.block_size == 0:
@@ -1620,6 +1723,10 @@ class LLMEngine:
             # this request (LLMServer._loop fails only it and keeps going).
             rid = seq.request.request_id
             self._current_rid = rid
+            if self._window is not None and not self.scheduler.reserve_chunk(
+                seq, take
+            ):
+                continue  # back in the queue: the window class was full
             if clock is not None:
                 # Per chunk: prepare (CoW copy, input build, dispatch),
                 # wait (from the runner's hook to its return), commit
@@ -1659,9 +1766,16 @@ class LLMEngine:
             # (a model with recurrent layers; the first chunk starts from
             # an empty state whatever the slot held).
             slot = (seq.state_slot,) if self._recurrent else ()
+            # The window class's table beside the full one (a model with
+            # sliding-window layers).
+            extra = (
+                {"window_ids": seq.window_table}
+                if self._window is not None
+                else {}
+            )
             if offset > 0:
                 tok = self.runner.prefill_suffix(
-                    chunk_ids, seq.block_table, offset, *slot
+                    chunk_ids, seq.block_table, offset, *slot, **extra
                 )
                 if first_chunk:
                     hit_tokens += offset
@@ -1678,6 +1792,7 @@ class LLMEngine:
                         )
                     ],
                     *slot,
+                    **extra,
                 )
             if clock is not None:
                 clock.ready()
@@ -1685,6 +1800,8 @@ class LLMEngine:
             self._prefill_chunk_dispatches += 1
             seq.num_cached = offset + take
             seq.num_chunks += 1
+            if self._window is not None:
+                self.scheduler.advance_window(seq)
             if final and seq.num_chunks > 1:
                 self._chunked_prefill_requests += 1
             # Publish every block this chunk filled: a concurrent request
@@ -1922,7 +2039,7 @@ class LLMEngine:
             "pipeline_flushes_by_cause": dict(self._pipeline_flushes),
             "attention_shape": (
                 self.runner.attention_shape()
-                if self._recurrent
+                if hasattr(self.runner, "attention_shape")
                 else {
                     "num_layers": self.model_config.num_layers,
                     "num_heads": self.model_config.num_heads,
@@ -1938,9 +2055,9 @@ class LLMEngine:
             # state traffic and routing counts (HybridRunner.stats).
             "prefix_caching": self.allocator.enable_prefix_caching,
             "recurrent_state": self._recurrent,
+            **(self.runner.stats() if self._counts_routing else {}),
             **(
                 {
-                    **self.runner.stats(),
                     "state_slots_in_use": (
                         self.scheduler.state_slots.num_in_use
                     ),
@@ -1949,6 +2066,20 @@ class LLMEngine:
                     ),
                 }
                 if self._recurrent
+                else {}
+            ),
+            # A model with sliding-window layers: its cache classes (the
+            # full class first), what the window class freed, what the
+            # decode dispatches asked of it and what each class held.
+            **(
+                {
+                    "cache_classes": self._cache_class_stats(),
+                    "window_blocks_freed": self._window.num_freed,
+                    "decode_window_tokens": self._decode_window_tokens,
+                    "held_tokens_full": self._held_tokens_full,
+                    "held_tokens_window": self._held_tokens_window,
+                }
+                if self._window is not None
                 else {}
             ),
             # Set-up on the same footing: wall seconds warming the
@@ -2277,10 +2408,17 @@ class LLMServer:
             # A model with recurrent layers: into state slot 0, which no
             # sequence holds here and none reads before writing.
             slot = (0,) if self._engine._recurrent else ()
+            # A model with sliding-window layers: the window class's null
+            # block too.
+            extra = (
+                {"window_ids": [0]}
+                if self._engine._window is not None
+                else {}
+            )
             for w in widths:
                 round_start = self._round_start()
-                runner.prefill([0] * w, [0], *slot)
-                runner.prefill_suffix([0] * w, null_table, 0, *slot)
+                runner.prefill([0] * w, [0], *slot, **extra)
+                runner.prefill_suffix([0] * w, null_table, 0, *slot, **extra)
                 self._record_round("chunk_prefill", w, round_start)
 
     def _warmup_verify(self, spec) -> None:

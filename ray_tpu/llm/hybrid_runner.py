@@ -1,24 +1,37 @@
-"""Jitted step programs for a model whose layers carry a recurrent state
-beside the paged cache (`ray_tpu.models.granite_hybrid`).
+"""Jitted step programs for a model whose layers declare a kind and the
+state each keeps: `mamba` (a recurrent state a sequence), `attention` /
+`full_attention` (K and V of every position, paged) and
+`sliding_attention` (K and V of the last `horizon` positions, paged in a
+cache class of its own). The model is a module of pure functions over a
+plain tree that its configuration names (`llm_model`:
+`ray_tpu.models.granite_hybrid`, `ray_tpu.models.laguna`); what this runner
+reads of it is `init_params`, `embed`, `head`, `run_layers`,
+`attention_qkv` / `attention_out`, `ATTENTION_SCOPES`,
+`expert_shape` (and `recurrent_shape`), and of its configuration
+`layer_types`, `cache_classes` / `cache_class_of`, `heads_of`,
+`attention_scale`, `num_key_value_heads`, `head_dim`.
 
 The same three program shapes `model_runner` compiles, under the same
 names: one decode program over all decode lanes, and for every prefill
 bucket a program that starts a sequence (`_prefill_step`: empty state, no
 cached context) and one that continues it (`_prefill_suffix_step`: the
 chunk starts from the slot's state and attends the cached context through
-the block table). Beside the K/V pools, which cover the attention layers
-only ([attention layers, N, bs, kv heads * head size]), every Mamba layer
-has a state pool [slots, H, P, N] float32 and a convolution-tail pool
-[slots, d_conv - 1, conv_dim], one array a layer, all donated through
-every step.
+the block tables). A cache class has one K and one V pool over its
+attention layers ([layers of the class, N of the class, bs, kv heads * head
+size]) and a block table a sequence; every Mamba layer has a state pool
+[slots, H, P, N] float32 and a convolution-tail pool [slots, d_conv - 1,
+conv_dim], one array a layer. All are donated through every step. A layer
+of a class with a horizon attends through `paged_attention_impl(...,
+window=horizon)`: the entries of its table below the window are null and
+never read.
 
-A sequence owns one state slot for as long as it runs (the scheduler hands
-them out), and its slot is its lane in the decode batch: the decode
-program updates the state pools in place, lane for lane, and leaves the
-lanes that do not decode this step (context length 0: idle, or a sequence
-still prefilling) as they were. Nothing gathers or scatters a state. A
-slot is never cleared: the program that starts a sequence does not read
-it.
+On a model with recurrent layers a sequence owns one state slot for as
+long as it runs (the scheduler hands them out), and its slot is its lane
+in the decode batch: the decode program updates the state pools in place,
+lane for lane, and leaves the lanes that do not decode this step (context
+length 0: idle, or a sequence still prefilling) as they were. Nothing
+gathers or scatters a state. A slot is never cleared: the program that
+starts a sequence does not read it.
 
 The decode program returns the sampled tokens and the step's routing
 counts in one int32 vector, so the engine reads both in the one fetch it
@@ -28,6 +41,7 @@ same length so that a step can be chained on the last one's output.
 
 from __future__ import annotations
 
+import importlib
 import re
 import threading
 from typing import Callable, Dict, Optional, Sequence
@@ -40,23 +54,31 @@ from ray_tpu._private.jax_setup import ensure_compile_cache
 from ray_tpu.llm.cache import kv_pool_bytes_sharded
 from ray_tpu.llm.config import EngineConfig
 from ray_tpu.llm.model_runner import bytes_by_device
-from ray_tpu.models import granite_hybrid as model
 from ray_tpu.ops.paged_flash import paged_attention_impl, resolve_paged_impl
 
 # The routing counts a decode step appends to its tokens, in this order.
 DECODE_COUNTS = ("held", "absent", "touched", "load_max")
-SCOPES = (
-    "llm.mixer.mamba.proj", "llm.mixer.mamba.scan", "llm.mixer.mamba.update",
-    "llm.mixer.attention", "llm.moe.router", "llm.moe.routed",
-    "llm.moe.shared", "llm.head",
-)
+MAMBA = "mamba"
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?(?P<name>[^\s=]+) = ")
 _OP_NAME = re.compile(r'op_name="(?P<path>[^"]*)"')
 
 
+def model_of(cfg):
+    """The module of pure functions `cfg` names (`llm_model`)."""
+    return importlib.import_module(type(cfg).llm_model)
+
+
+def visible_pairs(offset: int, tokens: int, horizon: int) -> int:
+    """(query, key) pairs a chunk of `tokens` queries at positions offset ..
+    sees in one layer of a class with `horizon`: the query at position p
+    sees min(p + 1, horizon) keys."""
+    return sum(min(p + 1, horizon) for p in range(offset, offset + tokens))
+
+
 def scopes_of(hlo_text: str) -> Dict[str, str]:
-    """HLO instruction name -> the innermost of SCOPES its `op_name`
-    metadata passes through, for the instructions that have one. A fusion
+    """HLO instruction name -> the innermost part of a layer (a
+    `jax.named_scope` whose name starts with "llm.": the model's SCOPES) its
+    `op_name` metadata passes through, for the instructions that have one. A fusion
     carries its root's metadata, so an operation fused across two parts
     counts to its root's."""
     out: Dict[str, str] = {}
@@ -65,7 +87,7 @@ def scopes_of(hlo_text: str) -> Dict[str, str]:
         path = _OP_NAME.search(line)
         if not found or not path:
             continue
-        inside = [part for part in path["path"].split("/") if part in SCOPES]
+        inside = [part for part in path["path"].split("/") if part.startswith("llm.")]
         if inside:
             out[found["name"]] = inside[-1]
         elif path["path"].startswith("ragged-dot"):
@@ -79,8 +101,9 @@ def scopes_of(hlo_text: str) -> Dict[str, str]:
 class _HybridPrograms:
     """The jitted programs of one (model, block size, attention impl)."""
 
-    def __init__(self, cfg: model.GraniteHybridConfig, block_size: int, attn_impl: str):
+    def __init__(self, cfg, block_size: int, attn_impl: str):
         self.cfg = cfg
+        self.model = model_of(cfg)
         self.block_size = block_size
         self.attn_impl = attn_impl
         donated = (1, 2, 3, 4)
@@ -95,21 +118,38 @@ class _HybridPrograms:
         become tokens: tests observe them here."""
         return jnp.argmax(logits, axis=-1)
 
-    def _attend(self, p, u, k_cache, v_cache, tables, lens, layer, new):
-        """q of u against the cached context and the new tokens' own K/V
-        (kept in `new` for the scatter). u [B, S, D]."""
-        cfg = self.cfg
-        with jax.named_scope("llm.mixer.attention"):
-            q, k, v = model.attention_qkv(cfg, p, u)
-            new[layer] = (k, v)
+    def _attend(self, kind, p, u, positions, k_cache, v_cache, tables, lens,
+                layer, new):
+        """q of u (tokens at `positions`) against the cached context of the
+        kind's cache class, as far as the class's horizon lets it see, and
+        the new tokens' own K/V (kept in `new` for the scatter). u
+        [B, S, D], positions [B, S]; `layer` counts the layers of the
+        kind, which are the class's."""
+        cfg, model = self.cfg, self.model
+        cls = cfg.cache_class_of(kind)
+        horizon = cfg.cache_classes[cls].horizon
+        projections, attention = model.ATTENTION_SCOPES[kind]
+        with jax.named_scope(projections):
+            q, k, v = model.attention_qkv(cfg, kind, p, u, positions)
+            new[cls, layer] = (k, v)
+        with jax.named_scope(attention):
             out = paged_attention_impl(
-                q, k_cache, v_cache, tables, lens, new_k=k, new_v=v,
-                layer=layer, sm_scale=cfg.attention_multiplier,
+                q, k_cache[cls], v_cache[cls], tables[cls], lens, new_k=k,
+                new_v=v, layer=layer, sm_scale=cfg.attention_scale,
                 impl=self.attn_impl,
+                **({} if horizon is None else {"window": horizon}),
             )
-            return model._matmul(
-                out.reshape(u.shape[:-1] + (-1,)), p["o"], cfg.dtype
+        with jax.named_scope(projections):
+            return model.attention_out(cfg, kind, p, u, out)
+
+    def _mixers(self, mamba, attend) -> dict:
+        """`run_layers`' mixers by the kinds of layer the model has."""
+        return {
+            kind: mamba if kind == MAMBA else (
+                lambda i, p, u, kind=kind: attend(kind, i, p, u)
             )
+            for kind in dict.fromkeys(self.cfg.layer_types)
+        }
 
     def _decode_step(
         self, params, k_cache, v_cache, conv, ssm, tokens, positions,
@@ -117,8 +157,10 @@ class _HybridPrograms:
     ):
         """One token for every lane that decodes. tokens [B + counts] (the
         last step's output or the host's; the first B are read), the rest
-        [B] / [B, nb] -> (pools, [B tokens, counts])."""
-        cfg = self.cfg
+        [B] / a [B, nb] table a cache class -> (pools, [B tokens, counts]).
+        k_cache and v_cache are a pool a cache class."""
+        cfg, model = self.cfg, self.model
+        k_cache, v_cache = list(k_cache), list(v_cache)
         b = positions.shape[0]
         live = context_lens > 0
         conv, ssm = list(conv), list(ssm)
@@ -131,29 +173,33 @@ class _HybridPrograms:
                 ssm[i] = jnp.where(live[:, None, None, None], state, ssm[i])
             return out
 
-        def attend(i, p, u):
+        def attend(kind, i, p, u):
             return self._attend(
-                p, u[:, None], k_cache, v_cache, block_tables, context_lens,
-                i, new,
+                kind, p, u[:, None], positions[:, None], k_cache, v_cache,
+                block_tables, context_lens, i, new,
             )[:, 0]
 
         h, counts = model.run_layers(
-            cfg, params, model.embed(cfg, params, tokens[:b]), mamba, attend,
-            grouped=False, valid=live,
+            cfg, params, model.embed(cfg, params, tokens[:b]),
+            self._mixers(mamba, attend), grouped=False, valid=live,
         )
         # Each lane's new K/V at its own position; an idle lane's table is
         # all null, so it lands in block 0.
-        block_ids = block_tables[jnp.arange(b), positions // self.block_size]
+        block_ids = [
+            table[jnp.arange(b), positions // self.block_size]
+            for table in block_tables
+        ]
         offsets = positions % self.block_size
-        for layer, (k, v) in new.items():
-            k_cache = k_cache.at[layer, block_ids, offsets].set(k.reshape(b, -1))
-            v_cache = v_cache.at[layer, block_ids, offsets].set(v.reshape(b, -1))
+        for (cls, layer), (k, v) in new.items():
+            at = (layer, block_ids[cls], offsets)
+            k_cache[cls] = k_cache[cls].at[at].set(k.reshape(b, -1))
+            v_cache[cls] = v_cache[cls].at[at].set(v.reshape(b, -1))
         next_tokens = self._sample(model.head(cfg, params, h))
         out = jnp.concatenate([
             next_tokens.astype(jnp.int32),
             jnp.stack([counts[k] for k in DECODE_COUNTS]).astype(jnp.int32),
         ])
-        return (k_cache, v_cache, tuple(conv), tuple(ssm)), out
+        return (tuple(k_cache), tuple(v_cache), tuple(conv), tuple(ssm)), out
 
     def _chunk(
         self, params, k_cache, v_cache, conv, ssm, tokens, block_table,
@@ -161,8 +207,9 @@ class _HybridPrograms:
     ):
         """tokens [1, S_bucket] (0-padded past true_len) of the sequence in
         state slot `slot`, at positions offset.. -> (pools, [next token,
-        held assignments])."""
-        cfg = self.cfg
+        held assignments]). block_table holds a [nb] table a cache class."""
+        cfg, model = self.cfg, self.model
+        k_cache, v_cache = list(k_cache), list(v_cache)
         sb = tokens.shape[1]
         lane = jnp.arange(sb)
         valid = lane < true_len
@@ -181,25 +228,29 @@ class _HybridPrograms:
                 ssm[i] = ssm[i].at[slot].set(state)
             return out
 
-        def attend(i, p, u):
+        def attend(kind, i, p, u):
             return self._attend(
-                p, u[None], k_cache, v_cache, block_table[None, :],
+                kind, p, u[None], positions[None], k_cache, v_cache,
+                [table[None, :] for table in block_table],
                 jnp.reshape(offset, (1,)), i, new,
             )[0]
 
         h, counts = model.run_layers(
-            cfg, params, model.embed(cfg, params, tokens[0]), mamba, attend,
-            grouped=True, valid=valid,
+            cfg, params, model.embed(cfg, params, tokens[0]),
+            self._mixers(mamba, attend), grouped=True, valid=valid,
         )
         bs = self.block_size
-        block_ids = jnp.where(valid, block_table[positions // bs], 0)
+        block_ids = [
+            jnp.where(valid, table[positions // bs], 0) for table in block_table
+        ]
         offsets = jnp.where(valid, positions % bs, 0)
-        for layer, (k, v) in new.items():
-            k_cache = k_cache.at[layer, block_ids, offsets].set(k[0].reshape(sb, -1))
-            v_cache = v_cache.at[layer, block_ids, offsets].set(v[0].reshape(sb, -1))
+        for (cls, layer), (k, v) in new.items():
+            at = (layer, block_ids[cls], offsets)
+            k_cache[cls] = k_cache[cls].at[at].set(k[0].reshape(sb, -1))
+            v_cache[cls] = v_cache[cls].at[at].set(v[0].reshape(sb, -1))
         logits = model.head(cfg, params, h[true_len - 1])
         out = jnp.stack([self._sample(logits), counts["held"]]).astype(jnp.int32)
-        return (k_cache, v_cache, tuple(conv), tuple(ssm)), out
+        return (tuple(k_cache), tuple(v_cache), tuple(conv), tuple(ssm)), out
 
     def _prefill_step(
         self, params, k_cache, v_cache, conv, ssm, tokens, block_table,
@@ -236,12 +287,14 @@ def _hybrid_programs(cfg, block_size: int, attn_impl: str) -> _HybridPrograms:
 
 
 class HybridRunner:
-    """Owns the params, the K/V and state pools, and the compiled steps.
-    The engine's side of `GPTRunner`, with a state slot beside the blocks."""
+    """Owns the params, the K/V pools of every cache class, the state pools
+    and the compiled steps. The engine's side of `GPTRunner`, with a state
+    slot or a second block table beside the blocks where the model's layers
+    keep such."""
 
     def __init__(
         self,
-        model_config: model.GraniteHybridConfig,
+        model_config,
         engine_config: EngineConfig,
         params=None,
         seed: int = 0,
@@ -255,6 +308,7 @@ class HybridRunner:
         # from what the model's configuration declares.
         self.model_config = cfg = model_config
         self.engine_config = ecfg = engine_config
+        self.model = model = model_of(cfg)
         self.on_dispatched: Optional[Callable[[], None]] = None
         self.tensor_parallel_size = 1
         self.mesh = None
@@ -275,39 +329,58 @@ class HybridRunner:
         self.host_bytes_in = 0
         self.host_bytes_out = 0
 
-        kv_shape = (
-            cfg.attention_layers, ecfg.num_blocks, ecfg.block_size,
-            cfg.num_key_value_heads * cfg.head_dim,
+        # One K and one V pool a cache class. The full class has the
+        # engine's `num_blocks`; a class with a horizon is sized from the
+        # lanes, the horizon and the chunk in flight.
+        self.classes = cfg.cache_classes
+        self.class_blocks = tuple(
+            ecfg.num_blocks if cls.horizon is None
+            else ecfg.window_class_blocks(cls.horizon)
+            for cls in self.classes
         )
-        self.k_cache = jnp.zeros(kv_shape, cfg.dtype)
-        self.v_cache = jnp.zeros(kv_shape, cfg.dtype)
+        minor = cfg.num_key_value_heads * cfg.head_dim
+        self.k_cache, self.v_cache = (
+            tuple(
+                jnp.zeros((cls.layers, blocks, ecfg.block_size, minor), cfg.dtype)
+                for cls, blocks in zip(self.classes, self.class_blocks)
+            )
+            for _ in range(2)
+        )
         # One state slot a decode lane: a running sequence holds a lane
         # from admission on, prefilling or decoding.
+        self.recurrent = bool(cfg.recurrent_state)
         self.state_slots = slots = ecfg.max_decode_slots
+        mamba_layers = cfg.mamba_layers if self.recurrent else 0
         self.conv = tuple(
             jnp.zeros((slots, cfg.mamba_d_conv - 1, cfg.conv_dim), cfg.dtype)
-            for _ in range(cfg.mamba_layers)
+            for _ in range(mamba_layers)
         )
         self.ssm = tuple(
             jnp.zeros(
                 (slots, cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state),
                 jnp.float32,
             )
-            for _ in range(cfg.mamba_layers)
+            for _ in range(mamba_layers)
         )
         self.state_slot_bytes = sum(
             int(pool.nbytes) for pool in self.conv + self.ssm
         ) // slots
         # Routing and state traffic, cumulative (stats()).
-        self.counters = dict.fromkeys(
-            (
-                "decode_state_bytes", "decode_expert_assignments",
-                "decode_expert_assignments_absent", "decode_experts_touched",
-                "decode_expert_load_max", "prefill_expert_assignments",
-                "prefill_scan_tokens",
-            ),
-            0,
+        names = [
+            "decode_expert_assignments", "decode_expert_assignments_absent",
+            "decode_experts_touched", "decode_expert_load_max",
+            "prefill_expert_assignments",
+        ]
+        if self.recurrent:
+            names = ["decode_state_bytes", *names, "prefill_scan_tokens"]
+        # The horizon of the window class, where the model has one: what a
+        # chunk's sliding layers see is counted in pairs.
+        self.horizon = next(
+            (cls.horizon for cls in self.classes if cls.horizon is not None), None
         )
+        if self.horizon is not None:
+            names.append("prefill_window_pairs")
+        self.counters = dict.fromkeys(names, 0)
 
     # ---------------- pools ----------------
 
@@ -332,52 +405,68 @@ class HybridRunner:
     def pool_sharding_spec(self) -> Optional[str]:
         return None
 
-    def kv_pool_bytes(self) -> dict:
-        cfg, ecfg = self.model_config, self.engine_config
-        return kv_pool_bytes_sharded(
-            cfg.attention_layers, ecfg.num_blocks, ecfg.block_size,
-            cfg.num_key_value_heads, cfg.head_dim,
-            np.dtype(self.kv_cache_dtype).itemsize,
+    def kv_token_bytes(self) -> int:
+        """K and V of one token in one attention layer."""
+        cfg = self.model_config
+        return (
+            2 * cfg.num_key_value_heads * cfg.head_dim
+            * np.dtype(self.kv_cache_dtype).itemsize
         )
 
-    def attention_shape(self) -> dict:
-        """The K/V pools as the paged kernel reads them."""
-        cfg = self.model_config
+    def kv_pool_bytes(self) -> dict:
+        """Both pools of every cache class, together."""
+        cfg, ecfg = self.model_config, self.engine_config
+        per_class = [
+            kv_pool_bytes_sharded(
+                cls.layers, blocks, ecfg.block_size, cfg.num_key_value_heads,
+                cfg.head_dim, np.dtype(self.kv_cache_dtype).itemsize,
+            )
+            for cls, blocks in zip(self.classes, self.class_blocks)
+        ]
         return {
-            "num_layers": cfg.attention_layers,
-            "num_heads": cfg.num_key_value_heads,
-            "head_dim": cfg.head_dim,
-            "kv_itemsize": np.dtype(self.kv_cache_dtype).itemsize,
-            "num_query_heads": cfg.num_attention_heads,
+            "aggregate": sum(c["aggregate"] for c in per_class),
+            "per_shard": sum(c["per_shard"] for c in per_class),
+            "tensor_parallel_size": 1,
         }
+
+    def attention_shape(self) -> dict:
+        """The K/V pools as the paged kernel reads them: of the one cache
+        class, or by class name where the model has several."""
+        cfg = self.model_config
+        kinds = {cfg.cache_class_of(kind): kind for kind in cfg.layer_types if kind != MAMBA}
+        shapes = {
+            cls.name: {
+                "num_layers": cls.layers,
+                "num_heads": cfg.num_key_value_heads,
+                "head_dim": cfg.head_dim,
+                "kv_itemsize": np.dtype(self.kv_cache_dtype).itemsize,
+                "num_query_heads": max(cfg.heads_of(kinds[i]), default=0),
+                **({} if cls.horizon is None else {"horizon": cls.horizon}),
+            }
+            for i, cls in enumerate(self.classes) if i in kinds
+        }
+        return shapes if len(self.classes) > 1 else next(iter(shapes.values()))
 
     def stats(self) -> dict:
         """The counters and shapes the engine's `stats()` carries for a
-        model with recurrent layers and routed experts."""
-        cfg = self.model_config
-        return {
-            **self.counters,
+        model with routed experts and, where it has them, recurrent
+        layers."""
+        cfg, model = self.model_config, self.model
+        recurrent = {
             "state_slots": self.state_slots,
             "state_slot_bytes": self.state_slot_bytes,
             "state_pool_bytes": self.state_slot_bytes * self.state_slots,
             "recurrent_shape": {
-                "num_layers": cfg.mamba_layers,
-                "num_heads": cfg.mamba_n_heads,
-                "head_dim": cfg.mamba_d_head,
-                "state_size": cfg.mamba_d_state,
-                "conv_width": cfg.mamba_d_conv,
-                "conv_dim": cfg.conv_dim,
-                "chunk_size": cfg.mamba_chunk_size,
+                **model.recurrent_shape(cfg),
                 "state_itemsize": 4,
                 "conv_itemsize": np.dtype(cfg.dtype).itemsize,
             },
+        } if self.recurrent else {}
+        return {
+            **self.counters,
+            **recurrent,
             "expert_shape": {
-                "num_layers": cfg.num_layers,
-                "num_experts": cfg.num_local_experts,
-                "experts_held": len(cfg.experts_held),
-                "experts_per_token": cfg.num_experts_per_tok,
-                "hidden_size": cfg.hidden_size,
-                "expert_width": cfg.intermediate_size,
+                **model.expert_shape(cfg),
                 "weight_itemsize": np.dtype(cfg.param_dtype).itemsize,
             },
         }
@@ -393,17 +482,19 @@ class HybridRunner:
         ecfg = self.engine_config
         slots, nb = ecfg.max_decode_slots, ecfg.max_blocks_per_seq
         i32 = self._i32
+        per_class = len(self.classes)
         yield "jit__decode_step", None, self._programs.decode_fn.lower(
             self.params, *self._pools, i32(slots + len(DECODE_COUNTS)),
-            i32(slots), i32(slots, nb), i32(slots),
+            i32(slots), (i32(slots, nb),) * per_class, i32(slots),
         )
+        tables = (i32(nb),) * per_class
         for width in ecfg.chunk_widths():
             yield "jit__prefill_step", width, self._programs.prefill_fn.lower(
-                self.params, *self._pools, i32(1, width), i32(nb), i32(), i32(),
+                self.params, *self._pools, i32(1, width), tables, i32(), i32(),
             )
             yield "jit__prefill_suffix_step", width, (
                 self._programs.prefill_suffix_fn.lower(
-                    self.params, *self._pools, i32(1, width), i32(nb), i32(),
+                    self.params, *self._pools, i32(1, width), tables, i32(),
                     i32(), i32(),
                 )
             )
@@ -411,9 +502,9 @@ class HybridRunner:
     def device_report(self) -> dict:
         """As `GPTRunner.device_report`, plus `op_scopes`: for the decode
         program and the prefill programs, HLO instruction name -> the part
-        of a layer it belongs to (SCOPES), which is what lets a reader
-        split a trace's time by part whatever implements the part. The
-        prefill programs of every bucket share a name in a trace; where
+        of a layer it belongs to (the model's SCOPES), which is what lets a
+        reader split a trace's time by part whatever implements the part.
+        The prefill programs of every bucket share a name in a trace; where
         their instruction names disagree on a part, the widest bucket's
         says (`op_scope_conflicts` counts them)."""
         op_scopes: Dict[str, Dict[str, str]] = {}
@@ -448,44 +539,62 @@ class HybridRunner:
         table[: len(block_ids)] = block_ids
         return table
 
+    def _tables(self, block_ids, window_ids) -> tuple:
+        """A sequence's table in every cache class, the full class first."""
+        if len(self.classes) == 1:
+            return (self._table(block_ids),)
+        return (self._table(block_ids), self._table(window_ids))
+
     def _padded(self, token_ids: Sequence[int]) -> np.ndarray:
         tokens = np.zeros((1, self.engine_config.bucket_for(len(token_ids))), np.int32)
         tokens[0, : len(token_ids)] = token_ids
         return tokens
 
-    def _chunk_done(self, pools, out, arrays_in, n: int) -> int:
+    def _chunk_done(self, pools, out, arrays_in, n: int, offset: int = 0) -> int:
         self._set_pools(pools)
         self._dispatched()
         self._count_transfer(arrays_in, out)
         token, held = (int(v) for v in np.asarray(out))
         self.counters["prefill_expert_assignments"] += held
-        self.counters["prefill_scan_tokens"] += n
+        if self.recurrent:
+            self.counters["prefill_scan_tokens"] += n
+        if self.horizon is not None:
+            self.counters["prefill_window_pairs"] += visible_pairs(
+                offset, n, self.horizon
+            )
         return token
 
     def prefill(
-        self, token_ids: Sequence[int], block_ids: Sequence[int], state_slot: int
+        self, token_ids: Sequence[int], block_ids: Sequence[int],
+        state_slot: int = 0, window_ids: Sequence[int] = (),
     ) -> int:
-        """Start a sequence in `state_slot`: its first chunk, from an empty
-        state. Returns the greedily sampled next token."""
-        tokens, table = self._padded(token_ids), self._table(block_ids)
+        """Start a sequence: its first chunk, from an empty state (left in
+        `state_slot` on a model with recurrent layers), its blocks those of
+        `block_ids` and, in a window class, `window_ids`. Returns the
+        greedily sampled next token."""
+        tokens, tables = self._padded(token_ids), self._tables(block_ids, window_ids)
         pools, out = self._programs.prefill_fn(
-            self.params, *self._pools, jnp.asarray(tokens), jnp.asarray(table),
+            self.params, *self._pools, jnp.asarray(tokens),
+            tuple(jnp.asarray(t) for t in tables),
             jnp.int32(len(token_ids)), jnp.int32(state_slot),
         )
-        return self._chunk_done(pools, out, (tokens, table), len(token_ids))
+        return self._chunk_done(pools, out, (tokens, *tables), len(token_ids))
 
     def prefill_suffix(
         self, token_ids: Sequence[int], block_ids: Sequence[int], offset: int,
-        state_slot: int,
+        state_slot: int = 0, window_ids: Sequence[int] = (),
     ) -> int:
-        """The next chunk of the sequence in `state_slot`, whose first
-        `offset` tokens are in the cache and in the slot's state."""
-        tokens, table = self._padded(token_ids), self._table(block_ids)
+        """The next chunk of a sequence whose first `offset` tokens are in
+        the cache (and in `state_slot`'s state)."""
+        tokens, tables = self._padded(token_ids), self._tables(block_ids, window_ids)
         pools, out = self._programs.prefill_suffix_fn(
-            self.params, *self._pools, jnp.asarray(tokens), jnp.asarray(table),
+            self.params, *self._pools, jnp.asarray(tokens),
+            tuple(jnp.asarray(t) for t in tables),
             jnp.int32(offset), jnp.int32(len(token_ids)), jnp.int32(state_slot),
         )
-        return self._chunk_done(pools, out, (tokens, table), len(token_ids))
+        return self._chunk_done(
+            pools, out, (tokens, *tables), len(token_ids), offset
+        )
 
     def decode(
         self,
@@ -493,20 +602,26 @@ class HybridRunner:
         positions: np.ndarray,
         block_tables: np.ndarray,
         context_lens: np.ndarray,
+        window_tables: Optional[np.ndarray] = None,
     ) -> jax.Array:
         """As `GPTRunner.decode`: dispatch one decode over the lanes without
-        waiting. Lane i is state slot i. The result (and a chained `tokens`)
-        is [lanes + len(DECODE_COUNTS)]: the sampled tokens, then the
-        step's routing counts, which `count_routing` takes once fetched."""
+        waiting. On a model with recurrent layers lane i is state slot i.
+        `window_tables` are the lanes' tables in the window class, where
+        the model has one. The result (and a chained `tokens`) is
+        [lanes + len(DECODE_COUNTS)]: the sampled tokens, then the step's
+        routing counts, which `count_routing` takes once fetched."""
         chained = isinstance(tokens, jax.Array)
         if not chained:
             tokens = jnp.asarray(
                 np.concatenate([tokens, np.zeros(len(DECODE_COUNTS), np.int32)])
             )
+        tables = (block_tables,) if window_tables is None else (
+            block_tables, window_tables
+        )
         pools, out = self._programs.decode_fn(
             self.params, *self._pools, tokens,
             jnp.asarray(positions.copy(), jnp.int32),
-            jnp.asarray(block_tables.copy(), jnp.int32),
+            tuple(jnp.asarray(t.copy(), jnp.int32) for t in tables),
             jnp.asarray(context_lens.copy(), jnp.int32),
         )
         self._set_pools(pools)
@@ -515,12 +630,13 @@ class HybridRunner:
             out.copy_to_host_async()
         except (AttributeError, NotImplementedError):  # pragma: no cover
             pass
-        host_in = (positions, block_tables, context_lens)
+        host_in = (positions, *tables, context_lens)
         self._count_transfer(host_in if chained else (tokens,) + host_in, out)
-        # Every decoding lane's state is read and written once.
-        self.counters["decode_state_bytes"] += (
-            2 * int(np.count_nonzero(context_lens)) * self.state_slot_bytes
-        )
+        if self.recurrent:
+            # Every decoding lane's state is read and written once.
+            self.counters["decode_state_bytes"] += (
+                2 * int(np.count_nonzero(context_lens)) * self.state_slot_bytes
+            )
         return out
 
     def count_routing(self, fetched: np.ndarray) -> None:

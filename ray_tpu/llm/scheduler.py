@@ -36,6 +36,7 @@ from ray_tpu.llm.cache import (
     BlockAllocator,
     CacheOutOfBlocks,
     StateSlots,
+    WindowBlocks,
     blocks_for_tokens,
     hash_block_tokens,
     prefix_block_hashes,
@@ -107,6 +108,11 @@ class Sequence:
         # The recurrent-state slot this sequence holds while it runs, on a
         # model with such layers (Scheduler.state_slots); else None.
         self.state_slot: Optional[int] = None
+        # The window class's table, on a model with such layers
+        # (Scheduler.window): indexed like block_table, the entries below
+        # window_first freed and null.
+        self.window_table: List[int] = []
+        self.window_first = 0
 
     @property
     def prefill_ids(self) -> List[int]:
@@ -138,8 +144,18 @@ class Scheduler:
         max_decode_slots: int,
         max_blocks_per_seq: int,
         state_slots: Optional[StateSlots] = None,
+        window: Optional[WindowBlocks] = None,
     ):
+        """One manager of everything a running sequence holds: blocks of
+        the full class (`allocator`: every model has it), blocks of a
+        window class and a recurrent-state slot where the model declares
+        them. Admission, growth by a block, preemption and release ask
+        every class, and succeed or fail together."""
         self.allocator = allocator
+        # A model with sliding-window layers: a sequence holds a second
+        # table in this class, grown with the full one and freed from
+        # below as the sequence advances (advance_window). None otherwise.
+        self.window = window
         # A model with recurrent layers: a sequence owns blocks AND one
         # state slot from admission to release (preemption frees both and
         # the resume re-prefills from an empty state). None otherwise.
@@ -317,6 +333,13 @@ class Scheduler:
         n = len(ids)
         bs = self.allocator.block_size
         total = blocks_for_tokens(n, bs)
+        # Both classes or neither: the window class must have a lane's
+        # worth free (its blocks are taken chunk by chunk, reserve_chunk),
+        # asked before the full class gives anything.
+        if self.window is not None and not self.window.allocator.can_allocate(
+            self.window.steady_blocks
+        ):
+            return False
         if not self.allocator.enable_prefix_caching:
             if not self.allocator.can_allocate(total):
                 return False
@@ -399,9 +422,11 @@ class Scheduler:
                     f"max_blocks_per_seq={self.max_blocks_per_seq}; the "
                     "engine must bound prompt+max_new_tokens at admission"
                 )
-            while len(seq.block_table) < needed:
+            while len(seq.block_table) < needed or self._window_missing(
+                seq, seq.num_cached
+            ):
                 try:
-                    seq.block_table.extend(self.allocator.allocate(1))
+                    self._grow(seq, seq.num_cached)
                 except CacheOutOfBlocks:
                     # Evict the lowest-priority (youngest-arrival) running
                     # sequence — possibly the requester itself. Its keyed
@@ -412,6 +437,50 @@ class Scheduler:
                     if victim is seq:
                         break
         return [s for s in self.running if not s.prefilling]
+
+    def _window_missing(self, seq: Sequence, position: int) -> int:
+        if self.window is None:
+            return 0
+        return self.window.missing(seq.window_table, position)
+
+    def _grow(self, seq: Sequence, position: int) -> None:
+        """Extend `seq`'s tables, in every class, to cover `position`:
+        all of it or, with CacheOutOfBlocks, nothing."""
+        extra = position // self.allocator.block_size + 1 - len(seq.block_table)
+        if self.window is not None:
+            if not self.window.allocator.can_allocate(
+                self.window.missing(seq.window_table, position)
+            ):
+                raise CacheOutOfBlocks("the window class has no free block")
+            if not self.allocator.can_allocate(max(extra, 0)):
+                raise CacheOutOfBlocks("the full class has no free block")
+            self.window.extend(seq.window_table, position)
+        if extra > 0:
+            seq.block_table.extend(self.allocator.allocate(extra))
+
+    def reserve_chunk(self, seq: Sequence, take: int) -> bool:
+        """Before a prefill chunk of `take` tokens is dispatched: the window
+        class's blocks for the positions it writes (the full class gave the
+        whole prompt's at admission). With the class sized as derived this
+        always holds; where it does not, `seq` goes back to the queue (it
+        is prefilling, so no step in flight writes its blocks) and the
+        chunk is not run."""
+        if self.window is None:
+            return True
+        try:
+            self.window.extend(seq.window_table, seq.num_cached + take - 1)
+        except CacheOutOfBlocks:
+            self.preempt(seq)
+            return False
+        return True
+
+    def advance_window(self, seq: Sequence) -> None:
+        """After a committed step or chunk: the window class frees every
+        block of `seq` that no later query can see."""
+        if self.window is not None:
+            seq.window_first = self.window.advance(
+                seq.window_table, seq.window_first, seq.num_cached
+            )
 
     def reserve_decode_lookahead(self, seqs: List[Sequence]) -> bool:
         """Extend block tables so a CHAINED decode step can run before the
@@ -427,17 +496,23 @@ class Scheduler:
         All-or-nothing: the batch chains together or not at all."""
         bs = self.allocator.block_size
         extras: List[Tuple[Sequence, int]] = []
+        window_total = 0
         for seq in seqs:
             needed = (seq.num_cached + 1) // bs + 1
             if needed > self.max_blocks_per_seq:
                 return False
             extras.append((seq, max(0, needed - len(seq.block_table))))
+            window_total += self._window_missing(seq, seq.num_cached + 1)
         total = sum(extra for _, extra in extras)
         if total and not self.allocator.can_allocate(total):
+            return False
+        if window_total and not self.window.allocator.can_allocate(window_total):
             return False
         for seq, extra in extras:
             if extra:
                 seq.block_table.extend(self.allocator.allocate(extra))
+            if window_total:
+                self.window.extend(seq.window_table, seq.num_cached + 1)
         return True
 
     def reserve_speculative(self, seq: Sequence, num_tokens: int) -> int:
@@ -550,3 +625,7 @@ class Scheduler:
         if seq.state_slot is not None:
             self.state_slots.free(seq.state_slot)
             seq.state_slot = None
+        if self.window is not None:
+            self.window.release(seq.window_table, seq.window_first)
+            seq.window_table = []
+            seq.window_first = 0

@@ -19,6 +19,10 @@ from ray_tpu.llm.engine import LLMServer
 from ray_tpu.models.gpt import GPTConfig
 
 
+# Threads of the engine actor beyond `max(32, 2 x lanes)`.
+CONTROL_THREADS = 4
+
+
 def get_or_create_engine_actor(
     engine_name: str = "default",
     model_config: Optional[GPTConfig] = None,
@@ -133,11 +137,19 @@ class LLMIngress:
         # of 32 an engine with 32 decode lanes never has a request waiting
         # for the lane that frees (chip runs, PR 32: occupancy 96.8 ->
         # 98.9%, completed tokens/s +2.2%). Room for a queue as deep as the
-        # lanes; 32 up to 16 lanes, as before.
+        # lanes; 32 up to 16 lanes, as before. And a few threads over for
+        # the calls that are not requests (metrics, snapshots, the flight
+        # record): with every thread held by a request they waited for one
+        # to complete, half a minute where an answer is a thousand tokens
+        # long and as many callers as threads are waiting (chip run, PR 35).
+        # Nothing reserves them: they are free only while the callers
+        # number at most `max(32, 2 x lanes)`; beyond that requests take
+        # them too: control calls want a thread group of their own, which
+        # the actor runtime does not have.
         slots = (engine_config or EngineConfig()).max_decode_slots
         self._engine = get_or_create_engine_actor(
             engine_name, model_config, engine_config, params=params,
-            seed=seed, max_concurrency=max(32, 2 * slots),
+            seed=seed, max_concurrency=max(32, 2 * slots) + CONTROL_THREADS,
             draft_params=draft_params,
         )
         self._as_snapshot: Optional[dict] = None

@@ -35,17 +35,35 @@ Not imported by `ray_tpu` or `ray_tpu.models`: import this module by name.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.grouped_experts import route, routed_dense, routed_grouped
+from ray_tpu.llm.cache import CacheClass
+from ray_tpu.models import parts
+from ray_tpu.models.parts import (  # noqa: F401  (this module's names for them)
+    experts,
+    gated_mlp as _gated_mlp,
+    matmul as _matmul,
+    normal as _normal,
+    num_params,
+    rms_norm,
+)
+from ray_tpu.ops.grouped_experts import route  # noqa: F401  (tests call it here)
 from ray_tpu.ops.ssd import ssd_chunked_scan, ssm_decode_update
 
 MAMBA, ATTENTION = "mamba", "attention"
+# The parts of a layer a trace's time is split by
+# (`hybrid_runner.scopes_of`), and the scope of an attention layer's
+# projections and of attention alone: one here.
+SCOPES = (
+    "llm.mixer.mamba.proj", "llm.mixer.mamba.scan", "llm.mixer.mamba.update",
+    "llm.mixer.attention", "llm.moe.router", "llm.moe.routed",
+    "llm.moe.shared", "llm.head",
+)
+ATTENTION_SCOPES = {ATTENTION: ("llm.mixer.attention", "llm.mixer.attention")}
 # One period of granite-4.0-h-small's `layer_types`.
 GRANITE_4_H_PERIOD = (MAMBA,) * 5 + (ATTENTION,) + (MAMBA,) * 4
 
@@ -86,7 +104,11 @@ class GraniteHybridConfig:
     # state beside the paged cache (so there is no prefix to share, and
     # the features that assume a cache-only model are refused).
     llm_runner = "ray_tpu.llm.hybrid_runner:HybridRunner"
+    llm_model = "ray_tpu.models.granite_hybrid"
     recurrent_state = True
+    # The router's rule (`ray_tpu.ops.grouped_experts.route`): gates a
+    # softmax over the chosen logits.
+    router_score = "chosen"
 
     def __post_init__(self):
         if self.mamba_n_groups != 1:
@@ -97,11 +119,7 @@ class GraniteHybridConfig:
             raise ValueError("query heads must be a multiple of cached heads")
         if set(self.layer_types) - {MAMBA, ATTENTION}:
             raise ValueError(f"unknown layer types in {self.layer_types}")
-        held = self.experts_held
-        if len(set(held)) != len(held) or not all(
-            0 <= e < self.num_local_experts for e in held
-        ):
-            raise ValueError(f"experts_held {held} of {self.num_local_experts}")
+        parts.check_experts_held(self.experts_held, self.num_local_experts)
 
     # The names the engine knows a model's geometry by.
     @property
@@ -119,6 +137,21 @@ class GraniteHybridConfig:
     @property
     def max_seq_len(self) -> int:
         return self.max_position_embeddings
+
+    @property
+    def attention_scale(self) -> float:
+        return self.attention_multiplier
+
+    @property
+    def cache_classes(self) -> Tuple[CacheClass, ...]:
+        """One class: the attention layers keep every position."""
+        return (CacheClass("full", self.attention_layers, None),)
+
+    def cache_class_of(self, kind: str) -> int:
+        return 0
+
+    def heads_of(self, kind: str) -> Tuple[int, ...]:
+        return (self.num_attention_heads,)
 
     @property
     def mamba_layers(self) -> int:
@@ -139,10 +172,32 @@ class GraniteHybridConfig:
     def local_of(self) -> jax.Array:
         """[num_local_experts] int32: an expert's row in the held weights,
         -1 for an expert another chip holds."""
-        table = [-1] * self.num_local_experts
-        for row, expert in enumerate(self.experts_held):
-            table[expert] = row
-        return jnp.asarray(table, jnp.int32)
+        return parts.local_of(self.num_local_experts, self.experts_held)
+
+
+def recurrent_shape(cfg: GraniteHybridConfig) -> Dict[str, int]:
+    """The Mamba layers' state as `stats()` publishes it."""
+    return {
+        "num_layers": cfg.mamba_layers,
+        "num_heads": cfg.mamba_n_heads,
+        "head_dim": cfg.mamba_d_head,
+        "state_size": cfg.mamba_d_state,
+        "conv_width": cfg.mamba_d_conv,
+        "conv_dim": cfg.conv_dim,
+        "chunk_size": cfg.mamba_chunk_size,
+    }
+
+
+def expert_shape(cfg: GraniteHybridConfig) -> Dict[str, int]:
+    """The routed experts as `stats()` publishes them."""
+    return {
+        "num_layers": cfg.num_layers,
+        "num_experts": cfg.num_local_experts,
+        "experts_held": len(cfg.experts_held),
+        "experts_per_token": cfg.num_experts_per_tok,
+        "hidden_size": cfg.hidden_size,
+        "expert_width": cfg.intermediate_size,
+    }
 
 
 # ---------------- parameters ----------------
@@ -222,33 +277,7 @@ def init_params(cfg: GraniteHybridConfig, seed: int) -> Dict[str, Any]:
     return jax.tree_util.tree_unflatten(tree, made)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _normal(key, shape, dtype, std):
-    return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
-
-
-def num_params(params) -> int:
-    return int(sum(x.size for x in jax.tree_util.tree_leaves(params)))
-
-
 # ---------------- the parts of a layer ----------------
-
-
-def rms_norm(x, weight, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (y * weight.astype(jnp.float32)).astype(x.dtype)
-
-
-def _matmul(x, w, dtype):
-    return jnp.dot(
-        x.astype(dtype), w.astype(dtype), preferred_element_type=jnp.float32
-    )
-
-
-def _gated_mlp(x, w_in, w_out, dtype):
-    g, u = jnp.split(_matmul(x, w_in, dtype), 2, axis=-1)
-    return _matmul(jax.nn.silu(g) * u, w_out, dtype)
 
 
 def _mamba_split(cfg, p, u):
@@ -326,8 +355,9 @@ def mamba_decode(cfg, p, u, conv_tail, ssm):
     return out, window[:, 1:], new_ssm
 
 
-def attention_qkv(cfg, p, u):
-    """u [..., D] -> q [..., Hq, d], k and v [..., Hkv, d] in `dtype`."""
+def attention_qkv(cfg, kind, p, u, positions=None):
+    """u [..., D] -> q [..., Hq, d], k and v [..., Hkv, d] in `dtype`. This
+    model's attention has no positions."""
     def heads(w, n):
         out = _matmul(u, w, cfg.dtype).astype(cfg.dtype)
         return out.reshape(u.shape[:-1] + (n, cfg.head_dim))
@@ -337,6 +367,11 @@ def attention_qkv(cfg, p, u):
         heads(p["k"], cfg.num_key_value_heads),
         heads(p["v"], cfg.num_key_value_heads),
     )
+
+
+def attention_out(cfg, kind, p, u, mixed):
+    """The output projection of mixed [..., Hq, d] -> [..., D] float32."""
+    return _matmul(mixed.reshape(u.shape[:-1] + (-1,)), p["o"], cfg.dtype)
 
 
 def causal_attention(cfg, q, k, v):
@@ -353,75 +388,38 @@ def causal_attention(cfg, q, k, v):
     return jnp.einsum("hqk,khd->qhd", weights, v, preferred_element_type=jnp.float32)
 
 
-def experts(cfg, p, x, *, grouped: bool, valid=None):
-    """routed(x) + shared(x) for x [T, D], float32, and the routing's
-    counts over the tokens `valid` marks (all, where None): assignments to
-    experts held here and to absent ones, held experts that a token
-    reached, and the fullest held expert's load."""
-    local_of = cfg.local_of()
-    with jax.named_scope("llm.moe.router"):
-        ids, gates = route(x, p["router"], cfg.num_experts_per_tok)
-        if valid is None:
-            valid = jnp.ones(x.shape[:1], bool)
-        local = jnp.where(valid[:, None], local_of[ids], -2)
-        load = jnp.sum(
-            local[..., None] == jnp.arange(len(cfg.experts_held)), axis=(0, 1)
-        )
-        counts = {
-            "held": jnp.sum(local >= 0), "absent": jnp.sum(local == -1),
-            "touched": jnp.sum(load > 0), "load_max": jnp.max(load),
-        }
-    xc = x.astype(cfg.dtype)
-    w_in, w_out = p["experts_in"].astype(cfg.dtype), p["experts_out"].astype(cfg.dtype)
-    with jax.named_scope("llm.moe.routed"):
-        if grouped:
-            routed = routed_grouped(xc, ids, gates, local_of, w_in, w_out, valid)
-        else:
-            routed = routed_dense(xc, ids, gates, local_of, w_in, w_out)
-    with jax.named_scope("llm.moe.shared"):
-        shared = _gated_mlp(x, p["shared_in"], p["shared_out"], cfg.dtype)
-    return routed + shared, counts
-
-
 def embed(cfg, params, ids):
-    return (
-        params["wte"][ids].astype(jnp.float32) * cfg.embedding_multiplier
-    ).astype(cfg.dtype)
+    return parts.embed(params["wte"], ids, cfg.dtype, cfg.embedding_multiplier)
 
 
 def head(cfg, params, h):
     """Logits (float32) of the residual rows h [..., D]."""
-    with jax.named_scope("llm.head"):
-        x = rms_norm(h, params["norm_f"], cfg.rms_norm_eps)
-        return jnp.dot(
-            x.astype(cfg.dtype), params["wte"].astype(cfg.dtype).T,
-            preferred_element_type=jnp.float32,
-        ) / cfg.logits_scaling
+    return parts.head(
+        h, params["norm_f"], cfg.rms_norm_eps, params["wte"], cfg.dtype,
+        tied=True, scaling=cfg.logits_scaling,
+    )
 
 
 def run_layers(
-    cfg: GraniteHybridConfig, params, h,
-    mamba: Callable, attend: Callable, *, grouped: bool, valid=None,
+    cfg: GraniteHybridConfig, params, h, mixers: Dict[str, Callable], *,
+    grouped: bool, valid=None,
 ):
-    """The layer stack over the residual rows h [T, D]. `mamba(i, p, u)`
-    and `attend(i, p, u)` are the mixers of the i-th layer of their kind:
-    they own where the layer's memory lives. Returns h and the routing's
-    counts summed over the layers."""
+    """The layer stack over the residual rows h [T, D]. `mixers[kind](i,
+    p, u)` is the mixer of the i-th layer of its kind: it owns where the
+    layer's memory lives. Returns h and the routing's counts summed over
+    the layers."""
     r = cfg.residual_multiplier
-    seen = {MAMBA: 0, ATTENTION: 0}
-    totals: Optional[Dict[str, jax.Array]] = None
+    seen = dict.fromkeys(mixers, 0)
+    totals = None
     for kind, p in zip(cfg.layer_types, params["layers"]):
         u = rms_norm(h, p["norm1"], cfg.rms_norm_eps)
-        mixer = mamba if kind == MAMBA else attend
-        mixed = mixer(seen[kind], p["mixer"], u)
+        mixed = mixers[kind](seen[kind], p["mixer"], u)
         seen[kind] += 1
         h = (h.astype(jnp.float32) + r * mixed).astype(cfg.dtype)
         x = rms_norm(h, p["norm2"], cfg.rms_norm_eps)
         out, counts = experts(cfg, p, x, grouped=grouped, valid=valid)
         h = (h.astype(jnp.float32) + r * out).astype(cfg.dtype)
-        totals = counts if totals is None else {
-            k: totals[k] + v for k, v in counts.items()
-        }
+        totals = parts.add_counts(totals, counts)
     return h, totals
 
 
@@ -440,10 +438,12 @@ def forward(cfg: GraniteHybridConfig, params, tokens, *, grouped: bool = True):
 
     def attend(_, p, u):
         with jax.named_scope("llm.mixer.attention"):
-            q, k, v = attention_qkv(cfg, p, u)
+            q, k, v = attention_qkv(cfg, ATTENTION, p, u)
             mixed = causal_attention(cfg, q, k, v).astype(cfg.dtype)
-            return _matmul(mixed.reshape(t_len, -1), p["o"], cfg.dtype)
+            return attention_out(cfg, ATTENTION, p, u, mixed)
 
-    h, _ = run_layers(cfg, params, embed(cfg, params, tokens), mamba, attend,
-                      grouped=grouped)
+    h, _ = run_layers(
+        cfg, params, embed(cfg, params, tokens),
+        {MAMBA: mamba, ATTENTION: attend}, grouped=grouped,
+    )
     return head(cfg, params, h)

@@ -181,6 +181,7 @@ def paged_attention(
     sm_scale: Optional[float] = None,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Attention over the paged KV cache through per-sequence block tables.
 
@@ -211,6 +212,9 @@ def paged_attention(
                   pools (ops.paged_flash.quantize_kv); the gathered pages
                   are dequantized in f32 before use, making this op the
                   exact oracle for the fused kernel's int8 path.
+    window:       query i of a slot is the token at position
+                  context_len + i and sees the keys at positions
+                  p - window < j <= p only (None: every j <= p).
 
     Fully-masked rows (a padded slot with context_len 0 and no new
     tokens) return exact zeros rather than a uniform average of garbage
@@ -244,11 +248,20 @@ def paged_attention(
         (jnp.arange(nb * bs)[None, :] < context_lens[:, None])[:, None, :],
         (b, q_len, nb * bs),
     )
+    if window is not None:
+        q_pos = context_lens[:, None] + jnp.arange(q_len)[None, :]  # [B, Q]
+        valid = valid & (
+            jnp.arange(nb * bs)[None, None, :] + window > q_pos[:, :, None]
+        )
     if new_k is not None:
         s_new = new_k.shape[1]
         k_ctx = jnp.concatenate([k_ctx, new_k], axis=1)
         v_ctx = jnp.concatenate([v_ctx, new_v], axis=1)
         causal = jnp.tril(jnp.ones((q_len, s_new), dtype=bool), s_new - q_len)
+        if window is not None:
+            causal = causal & ~jnp.tril(
+                jnp.ones((q_len, s_new), dtype=bool), s_new - q_len - window
+            )
         valid = jnp.concatenate(
             [valid, jnp.broadcast_to(causal[None], (b, q_len, s_new))], axis=2
         )

@@ -1,8 +1,10 @@
 """The routed experts a chip holds, without capacity and without drops.
 
 The router scores every expert of the layer (`num_experts`), a token takes
-its `top_k` best with gates that are a softmax over those `top_k` logits,
-and this chip computes the part of the result that the experts it holds
+its `top_k` best with gates by one of two rules (`route`'s `score`): a
+softmax over those `top_k` logits, or a softmax over all the experts of
+which the chosen ones' shares are divided by their sum and multiplied by
+`scale`. This chip computes the part of the result that the experts it holds
 give: what the absent experts would add is left out, the gates are not
 renormalised over the held ones. `local_of` [num_experts] maps an expert's
 id to its row in the held weights, or -1. A token's result depends on no
@@ -30,16 +32,25 @@ import jax.numpy as jnp
 
 
 def route(
-    x: jax.Array, router: jax.Array, top_k: int
+    x: jax.Array, router: jax.Array, top_k: int, *,
+    score: str = "chosen", scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array]:
     """x [T, D], router [D, num_experts] -> (expert ids [T, k], gates
-    [T, k] float32). Logits and the softmax over the chosen are float32."""
+    [T, k] float32). Logits and the softmax are float32. `score`
+    "chosen": the gates are a softmax over the `top_k` chosen logits.
+    "all": a softmax over every expert, the `top_k` largest, their shares
+    divided by their sum and multiplied by `scale`."""
     logits = jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
-    top, ids = jax.lax.top_k(logits, top_k)
-    return ids, jax.nn.softmax(top, axis=-1)
+    if score == "chosen":
+        top, ids = jax.lax.top_k(logits, top_k)
+        return ids, jax.nn.softmax(top, axis=-1)
+    if score != "all":
+        raise ValueError(f"unknown router score {score!r}")
+    top, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return ids, top / jnp.sum(top, axis=-1, keepdims=True) * scale
 
 
 def _gated(h: jax.Array) -> jax.Array:

@@ -136,16 +136,16 @@ def _dot(a, b, contract):
     )
 
 
-def _each(n, body, looped: bool = True) -> None:
-    """body(i) for i < n: a loop whose body the program traces and lowers
-    once with i traced, or (`looped` false, n static) n times with i a
-    Python number. Rolled costs a 64-token chunk's call 56 us where it took
+def _each(n, body, looped: bool = True, first=0) -> None:
+    """body(i) for `first` <= i < n: a loop whose body the program traces
+    and lowers once with i traced, or (`looped` false, n static) n times
+    with i a Python number. Rolled costs a 64-token chunk's call 56 us where it took
     47 unrolled (on the chip), and a step program a fifth of the lowering:
     36 layers of 20 unrolled heads were 50 s of every replica's start."""
     if looped:
-        jax.lax.fori_loop(0, n, lambda i, carry: body(i) or carry, 0)
+        jax.lax.fori_loop(first, n, lambda i, carry: body(i) or carry, 0)
     else:
-        for i in range(n):
+        for i in range(first, n):
             body(i)
 
 
@@ -164,7 +164,7 @@ def _paged_kernel(
     q_ref, nk_ref, nv_ref, k_src, v_src, *rest,
     heads: int, head_dim: int, bs: int, nb: int, entries: int, tq: int,
     layer: int, quantized: bool, batched_heads: bool, kernel_copies: bool,
-    kv_heads: int,
+    kv_heads: int, window: Optional[int] = None,
 ):
     """Grid (B, nq, nq), every dimension sequential: one q tile of `tq` fed
     tokens per (b, qi). Step j == 0 walks the slot's cached context in
@@ -199,7 +199,15 @@ def _paged_kernel(
     head h // group, and its out leaves in that form (the caller keeps each
     row's own lanes); elsewhere query head h reads lanes and new-token rows
     of cached head h // group. With kv_heads == heads every branch below
-    is the one it was."""
+    is the one it was.
+
+    `window`: the query at position p sees the keys at p - window < j <= p
+    (a fed token's position is context_lens[b] + its index). The walk of a
+    q tile then starts at the compute block that holds its first row's
+    lowest visible key, copies of it only the table entries from that key's
+    block on (what lies below may be the null block: a window cache frees
+    those blocks), and masks row by row; a new-token tile no row of the q
+    tile can see is skipped. None: every branch below is the one it was."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr, *copy_scratch = rest
     else:
@@ -213,6 +221,20 @@ def _paged_kernel(
     tile = entries * bs  # cached tokens a compute block covers
     ctx = lens_ref[b]
     live_blocks = pl.cdiv(ctx, tile)
+
+    def horizon(slot_b, q_tile):
+        """The lowest cached position the first row of q tile `q_tile` of
+        slot `slot_b` sees: no row of the tile sees below it."""
+        return jnp.maximum(lens_ref[slot_b] + q_tile * tq - (window - 1), 0)
+
+    def first_block(slot_b, q_tile):
+        """The compute block a walk starts at."""
+        if window is None:
+            return 0
+        return jnp.minimum(
+            horizon(slot_b, q_tile) // tile, pl.cdiv(lens_ref[slot_b], tile)
+        )
+
     # Heads are walked in a loop, not unrolled, so a program traces and
     # lowers one head's body a section whatever the model's. A head's lanes
     # of the tile can be sliced at a traced offset only in whole lane
@@ -245,6 +267,13 @@ def _paged_kernel(
 
         t_ids = c * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
         live = t_ids < ctx
+        if window is not None:
+            # Row r of the tile is the query at ctx + qi * tq + r (decode's
+            # rows are the heads of the one query at ctx).
+            q_pos = ctx + qi * tq + jax.lax.broadcasted_iota(
+                jnp.int32, (1 if batched_heads else tq, 1), 0
+            )
+            live = live & (t_ids + window > q_pos)
 
         def fold(h, s, heads_here, v):
             # int8 dequant is folded into the score / weight matrices: K's
@@ -288,11 +317,18 @@ def _paged_kernel(
         base_ref, sems, k_buf, v_buf = copy_scratch
         nq = pl.num_programs(1)
 
-        def tile_copies(slot_b, c, slot, act):
+        def tile_copies(slot_b, q_tile, c, slot, act):
             """Start or wait for the copies of compute block `c` of slot
             `slot_b` into tile `slot`: the table entries its context
-            reaches, so one (slot_b, c) names the same copies both times."""
+            reaches (from the one that holds q tile `q_tile`'s horizon on,
+            under a window), so one (slot_b, q_tile, c) names the same
+            copies both times."""
             reached = pl.cdiv(lens_ref[slot_b] - c * tile, bs)
+            below = 0
+            if window is not None:
+                below = jnp.clip(
+                    (horizon(slot_b, q_tile) - c * tile) // bs, 0, entries
+                )
 
             def entry(i):
                 page = tables_ref[slot_b, jnp.minimum(c * entries + i, nb - 1)]
@@ -304,7 +340,7 @@ def _paged_kernel(
                     )
                     getattr(copy, act)()  # "start" | "wait"
 
-            _each(jnp.clip(reached, 0, entries), entry)
+            _each(jnp.clip(reached, 0, entries), entry, first=below)
 
         @pl.when((b == 0) & (qi == 0))
         def _first():
@@ -313,7 +349,7 @@ def _paged_kernel(
             k_buf[...] = jnp.zeros_like(k_buf)
             v_buf[...] = jnp.zeros_like(v_buf)
             base_ref[0] = 0
-            tile_copies(b, 0, 0, "start")
+            tile_copies(b, qi, first_block(b, qi), 0, "start")
 
         base = base_ref[0]  # the tile this walk's first block is in
         # The step that walks next: its first block is this step's to
@@ -321,30 +357,42 @@ def _paged_kernel(
         b_next = jnp.where(qi == nq - 1, b + 1, b)
         has_next = b_next < pl.num_programs(0)
         b_next = jnp.minimum(b_next, pl.num_programs(0) - 1)
+        # The q tile that walks next and, under a window, where each walk
+        # starts; with none every walk starts at block 0 and a q tile does
+        # not enter a copy's name.
+        qi_next = first = first_next = 0
+        if window is not None:
+            qi_next = jnp.where(qi == nq - 1, 0, qi + 1)
+            first = first_block(b, qi)
+            first_next = first_block(b_next, qi_next)
+
+        def walked(c):  # blocks of this walk before block c
+            return c if window is None else c - first
 
         def body(c, carry):
-            slot = (base + c) % 2
+            slot = (base + walked(c)) % 2
 
             last = c + 1 == live_blocks
 
             @pl.when(~last | has_next)
             def _():
                 tile_copies(
-                    jnp.where(last, b_next, b), jnp.where(last, 0, c + 1),
-                    1 - slot, "start",
+                    jnp.where(last, b_next, b),
+                    0 if window is None else jnp.where(last, qi_next, qi),
+                    jnp.where(last, first_next, c + 1), 1 - slot, "start",
                 )
 
-            tile_copies(b, c, slot, "wait")
+            tile_copies(b, qi, c, slot, "wait")
             cached_tile(c, k_buf, v_buf, slot)
             return carry
 
-        jax.lax.fori_loop(0, live_blocks, body, 0)
+        jax.lax.fori_loop(first, live_blocks, body, 0)
 
-        @pl.when((live_blocks == 0) & has_next)
+        @pl.when((live_blocks == first) & has_next)
         def _idle():
-            tile_copies(b_next, 0, base, "start")
+            tile_copies(b_next, qi_next, first_next, base, "start")
 
-        base_ref[0] = (base + live_blocks) % 2
+        base_ref[0] = (base + walked(live_blocks)) % 2
 
     @pl.when(j == 0)
     def _walk():
@@ -359,9 +407,16 @@ def _paged_kernel(
             cached_tile(c, k_src, v_src, c)
             return carry
 
-        jax.lax.fori_loop(0, live_blocks, body, 0)
+        jax.lax.fori_loop(first_block(b, qi), live_blocks, body, 0)
 
-    @pl.when(j <= qi)
+    # New-token tile j is folded in where some row of q tile qi sees some
+    # column of it: at or below the diagonal and, under a window, with the
+    # tile's nearest pair (its first row, j's last column) inside it.
+    seen = j <= qi
+    if window is not None:
+        seen = seen & ((qi - j) * tq - (tq - 1) < window)
+
+    @pl.when(seen)
     def _new_tokens():
         if batched_heads:
             # One fed token: it attends itself, no mask. Scores are the
@@ -382,8 +437,11 @@ def _paged_kernel(
             s = _dot(q_ref[0, h], nk_ref[0, hk], ((1,), (1,)))  # [tq, tq]
             rows = qi * tq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             cols = j * tq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            visible = rows >= cols
+            if window is not None:
+                visible = visible & (rows - cols < window)
             _online_update(
-                jnp.where(rows >= cols, s, NEG_INF), head_stats(h),
+                jnp.where(visible, s, NEG_INF), head_stats(h),
                 lambda p: _dot(  # new tokens are never quantized
                     p.astype(compute_dtype), nv_ref[0, hk], ((1,), (0,))
                 ),
@@ -470,6 +528,7 @@ def paged_flash_attention(
     v_scale: Optional[jax.Array] = None,
     interpret: Optional[bool] = None,
     num_kv_heads: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Fused paged attention over the block-table KV cache (Pallas TPU).
 
@@ -485,6 +544,10 @@ def paged_flash_attention(
     [L, N, bs, num_kv_heads * D], new_k / new_v [B, S, num_kv_heads, D], and
     query head h reads cached head h // (H // num_kv_heads). With
     num_kv_heads == H the call lowers to the program it always did.
+    `window`: a fed token at position p (context_lens[b] + its index) sees
+    the keys at p - window < j <= p only, and the table entries of blocks
+    wholly below a slot's lowest such key are never read (they may be the
+    null block). None lowers to the program it always did.
 
     Runs in interpret mode on CPU by default so tests exercise the same
     kernel the TPU compiles.
@@ -503,6 +566,11 @@ def paged_flash_attention(
         )
     validate_kv_pools(q, k_cache, v_cache, k_scale, v_scale, hkv)
     quantized = k_cache.dtype == jnp.int8
+    if window is not None and (window < 1 or quantized):
+        raise ValueError(
+            f"window {window}: at least 1, and not over int8 pools (their "
+            "gathered scales are not cut to a window)"
+        )
     b, s_len, h, d = q.shape
     grouped = hkv != h
     nb = block_tables.shape[1]
@@ -640,6 +708,7 @@ def paged_flash_attention(
         _paged_kernel, heads=h, head_dim=d, bs=bs, nb=nb, entries=entries,
         tq=tq, layer=layer, quantized=quantized, batched_heads=batched_heads,
         kernel_copies=kernel_copies, kv_heads=hkv,
+        **({} if window is None else {"window": window}),
     )
     out = pl.pallas_call(
         kernel,
@@ -676,6 +745,7 @@ def paged_attention_impl(
     v_scale: Optional[jax.Array] = None,
     impl: str = "auto",
     mesh=None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Dispatcher: the fused Pallas kernel on TPU, the XLA reference
     elsewhere (impl='auto'); 'pallas' forces the kernel (interpret mode on
@@ -691,12 +761,19 @@ def paged_attention_impl(
     minor axis), so the kernel's per-block DMA touches local-head bytes
     only and the attention output comes back head-sharded with no
     collective (heads never mix inside attention — the psum this layering
-    implies happens later, in the attn output projection)."""
+    implies happens later, in the attn output projection).
+
+    `window` (both implementations, not under a mesh): a fed token at
+    position p sees the keys at p - window < j <= p."""
     resolved = resolve_paged_impl(impl)
     use_reference = resolved == "reference" or (
         impl == "auto" and new_k is None
     )
     op = paged_attention if use_reference else paged_flash_attention
+    if window is not None:
+        if mesh is not None and mesh.shape.get("tp", 1) > 1:
+            raise ValueError("a window is not implemented under a tp mesh")
+        op = functools.partial(op, window=window)
     if mesh is not None and mesh.shape.get("tp", 1) > 1:
         from jax.sharding import PartitionSpec as P
 

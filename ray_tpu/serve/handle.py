@@ -28,6 +28,7 @@ from typing import Any, Callable, Optional
 
 from ray_tpu._private.object_ref import ObjectRef
 from ray_tpu._private.runtime import get_runtime
+from ray_tpu._private.streaming import _SENTINEL
 from ray_tpu.exceptions import (
     ActorDiedError,
     ActorUnavailableError,
@@ -170,7 +171,9 @@ class DeploymentResponse:
         return self._ref
 
 
-_PENDING = object()  # executor-poll slice expired with no item yet
+def _resolve(future) -> None:
+    if not future.done():
+        future.set_result(None)
 
 
 class DeploymentResponseGenerator:
@@ -283,15 +286,27 @@ class DeploymentResponseGenerator:
         while True:
             try:
                 while True:
-                    # Short-sliced executor polls: a stalled stream never
-                    # parks a shared executor thread for long (0.2s max), so
-                    # concurrent streams timeshare the pool and a cancelled
-                    # consumer leaks at most one slice of thread time.
-                    ref = await loop.run_in_executor(None, self._poll_next)
-                    if ref is None:
-                        return
-                    if ref is _PENDING:
+                    # The wait for the next item holds no thread: the
+                    # stream calls back from the producer's thread. (It was
+                    # a 0.2 s poll on the loop's default executor, about 17
+                    # threads: with 48 streams waiting for a decode lane
+                    # the pool was parked in their polls and the 48 that
+                    # had tokens got 120 a second between them; chip run,
+                    # PR 35.) A cancelled consumer leaves a callback that
+                    # finds its future done. An item that is already
+                    # there is taken without a future, a callback or a
+                    # wake of the loop.
+                    try:
+                        ref = self._gen._stream.next(timeout=0)
+                    except TimeoutError:
+                        ready = loop.create_future()
+                        self._gen._stream.on_ready(
+                            lambda: loop.call_soon_threadsafe(_resolve, ready)
+                        )
+                        await ready
                         continue
+                    if ref is _SENTINEL:
+                        return
                     item = await ref
                     self._record(item)
                     yield item
@@ -303,16 +318,6 @@ class DeploymentResponseGenerator:
                 self._gen = await loop.run_in_executor(
                     None, self._router.dispatch, self._ctx, True
                 )
-
-    def _poll_next(self):
-        from ray_tpu._private.streaming import _SENTINEL
-
-        try:
-            ref = self._gen._stream.next(timeout=0.2)
-        except TimeoutError:
-            return _PENDING
-        return None if ref is _SENTINEL else ref
-
 
 class Router:
     """Client-side replica selection: power-of-two-choices over in-flight

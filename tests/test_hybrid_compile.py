@@ -65,8 +65,8 @@ def test_real_width_decode_program_fits_a_v5e(chip, monkeypatch):
     ssm = tuple(sds((SLOTS, 128, 64, 128), jnp.float32) for _ in range(9))
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
     compiled = programs.decode_fn.lower(
-        params, kv, kv, conv, ssm, i32(SLOTS + len(hr.DECODE_COUNTS)),
-        i32(SLOTS), i32(SLOTS, TABLE), i32(SLOTS),
+        params, (kv,), (kv,), conv, ssm, i32(SLOTS + len(hr.DECODE_COUNTS)),
+        i32(SLOTS), (i32(SLOTS, TABLE),), i32(SLOTS),
     ).compile()
     memory = compiled.memory_analysis()
     pools = 2 * BLOCKS * BLOCK * 1024 * 2 + SLOTS * 9 * (128 * 64 * 128 * 4 + 3 * 8448 * 2)

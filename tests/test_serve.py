@@ -370,10 +370,13 @@ def test_graceful_shutdown_hook_runs(serve_instance, tmp_path):
 
     serve.run(WithCleanup.bind())
     serve.shutdown()
+    def written():  # the file exists before the hook has written into it
+        return marker.exists() and marker.read_text() == "clean"
+
     deadline = time.time() + 10
-    while time.time() < deadline and not marker.exists():
+    while time.time() < deadline and not written():
         time.sleep(0.1)
-    assert marker.exists() and marker.read_text() == "clean"
+    assert written()
 
 
 def test_model_multiplexing(serve_instance):
@@ -708,6 +711,105 @@ def test_streaming_handle_direct(serve_instance):
     handle = serve.run(gen_app.bind(), name="genapp")
     items = list(handle.options(stream=True).remote(4))
     assert items == [0, 10, 20, 30]
+
+
+def test_idle_streams_do_not_starve_a_stream_with_items(serve_instance):
+    """Forty proxy streams that yield nothing yet (requests waiting for a
+    decode lane, say) hold no thread while they wait: one more stream still
+    gets its 200 items in a fraction of a second. With each wait a 0.2 s
+    poll on the loop's default executor the forty parked its threads and
+    the live stream got a turn a second (chip run, PR 35)."""
+    import threading as _threading
+    import time as _time
+
+    from ray_tpu.serve._private.http_proxy import start_proxy, stop_proxy
+
+    release = _threading.Event()
+
+    @serve.deployment(max_concurrent_queries=64)
+    class Streams:
+        def __call__(self, n):
+            def gen():
+                if int(n) == 0:
+                    release.wait(30.0)  # an idle stream: nothing to yield yet
+                for i in range(int(n)):
+                    yield i
+            return gen()
+
+    serve.run(Streams.bind(), name="streams")
+    host, port = start_proxy()
+    url = f"http://{host}:{port}/streams?stream=1"
+
+    def read(n, out):
+        req = urllib.request.Request(url, data=json.dumps(n).encode())
+        with urllib.request.urlopen(req, timeout=60) as resp:
+            out.append([json.loads(line)["result"] for line in resp.read().splitlines() if line])
+
+    idle_out, threads = [], []
+    try:
+        for _ in range(40):
+            threads.append(_threading.Thread(target=read, args=(0, idle_out)))
+            threads[-1].start()
+        _time.sleep(1.0)  # every idle stream is waiting for its first item
+        live = []
+        t0 = _time.monotonic()
+        read(200, live)
+        took = _time.monotonic() - t0
+        assert live == [list(range(200))]
+        assert took < 5.0, took  # 200 turns behind forty 0.2 s polls took 90 s
+    finally:
+        release.set()
+        for thread in threads:
+            thread.join(timeout=30)
+        stop_proxy()
+    assert idle_out == [[]] * 40
+
+
+def test_stream_on_ready_calls_back_once():
+    from ray_tpu._private.streaming import _SENTINEL, ObjectRefStream
+
+    stream, calls = ObjectRefStream(), []
+    stream.on_ready(lambda: calls.append("a"))
+    assert calls == []  # nothing to read yet: no thread waits, no call
+    stream.offer("ref-0")
+    assert calls == ["a"] and stream.next(timeout=0) == "ref-0"
+    stream.offer("ref-1")
+    assert calls == ["a"]  # one-shot
+    stream.on_ready(lambda: calls.append("b"))  # an item is waiting: at once
+    assert calls == ["a", "b"] and stream.next(timeout=0) == "ref-1"
+    stream.on_ready(lambda: calls.append("c"))
+    stream.finish(2)
+    assert calls == ["a", "b", "c"] and stream.next(timeout=0) is _SENTINEL
+    with pytest.raises(TimeoutError):
+        ObjectRefStream().next(timeout=0)
+
+
+@pytest.mark.parametrize("end", ["offer", "finish"])
+def test_stream_waiter_of_a_closed_loop_fails_nobody(end):
+    """A consumer whose event loop closed while it waited (its callback's
+    `call_soon_threadsafe` raises) neither fails the producer's `offer` /
+    `finish` nor costs the waiter behind it its call."""
+    import asyncio
+
+    from ray_tpu._private.streaming import ObjectRefStream
+
+    loop = asyncio.new_event_loop()
+    gone = loop.create_future()
+    loop.close()
+    stream, calls = ObjectRefStream(), []
+    stream.on_ready(lambda: loop.call_soon_threadsafe(gone.set_result, None))
+    stream.on_ready(lambda: calls.append("behind"))
+    if end == "offer":
+        stream.offer("ref-0")  # must not raise RuntimeError("Event loop is closed")
+        assert stream.next(timeout=0) == "ref-0"
+    else:
+        stream.finish(0)
+    assert calls == ["behind"]
+    # And the stream still serves a waiter that comes later.
+    stream.on_ready(lambda: calls.append("later"))
+    if end == "offer":
+        stream.offer("ref-1")
+    assert calls == ["behind", "later"]
 
 
 def test_per_node_proxies(serve_instance):
