@@ -196,7 +196,11 @@ class WirePeer:
         if method == "submit_task":
             func = cloudpickle.loads(payload["func"])
             out = runtime.submit_task(
-                func, payload["args"], payload["kwargs"], **payload["options"]
+                func,
+                payload["args"],
+                payload["kwargs"],
+                **payload["options"],
+                consumer_is_peer=True,
             )
             return self._reply_refs(out, payload["options"])
         if method == "create_actor":
@@ -215,6 +219,7 @@ class WirePeer:
                 payload["args"],
                 payload["kwargs"],
                 **payload["options"],
+                consumer_is_peer=True,
             )
             return self._reply_refs(out, payload["options"])
         if method == "next_stream_item":

@@ -47,7 +47,7 @@ from ray_tpu._private.ids import (
     TaskID,
     _Counter,
 )
-from ray_tpu._private.object_ref import ObjectRef
+from ray_tpu._private.object_ref import ObjectRef, capture_serialized_refs
 from ray_tpu._private.object_store import InProcessStore
 from ray_tpu._private.refcount import ReferenceCounter
 from ray_tpu._private.scheduler import Scheduler
@@ -220,6 +220,11 @@ class Runtime:
         self._actor_grants: dict[ActorID, tuple[NodeID, dict[str, float]]] = {}
         self._task_records: dict[TaskID, _TaskRecord] = {}
         self._streams: dict[TaskID, Any] = {}
+        # How often a stream item travels with its ref (report_stream_item):
+        # plain ints, bumped where the decision is made, under no lock.
+        self.stream_items_reported = 0
+        self.stream_items_inline = 0
+        self.stream_items_promoted = 0
         from ray_tpu._private.task_events import TaskEventBuffer
 
         self.task_events = TaskEventBuffer()
@@ -577,10 +582,15 @@ class Runtime:
         deadline = None if timeout is None else _time.monotonic() + timeout
         values = []
         for ref in refs:
+            carried = ref._carried
+            if carried is not None:
+                # A small stream item, held by its ref: no trip to the store.
+                values.append(carried.load())
+                continue
             remaining = None
             if deadline is not None:
                 remaining = max(0.0, deadline - _time.monotonic())
-            value = self.get_value(ref.id, remaining)
+            value = self.get_value(ref._id, remaining)
             if isinstance(value, ErrorObject):
                 value.raise_()
             values.append(value)
@@ -674,11 +684,21 @@ class Runtime:
         num_returns: int,
         timeout: Optional[float],
     ) -> tuple[list[ObjectRef], list[ObjectRef]]:
-        by_id = {ref.id: ref for ref in refs}
-        ready_ids, remaining_ids = self.store.wait(
-            [r.id for r in refs], num_returns, timeout
+        by_id = {ref._id: ref for ref in refs}
+        # A ref that carries its value is ready and the store has not heard
+        # of it: the store waits for the others, and for fewer of them.
+        carried = {ref._id for ref in refs if ref._carried is not None}
+        sealed_ids, _ = self.store.wait(
+            [i for i in by_id if i not in carried],
+            max(0, num_returns - len(carried)),
+            timeout,
         )
-        return [by_id[i] for i in ready_ids], [by_id[i] for i in remaining_ids]
+        ready_ids = ([i for i in by_id if i in carried] + sealed_ids)[:num_returns]
+        taken = set(ready_ids)
+        return (
+            [by_id[i] for i in ready_ids],
+            [ref for i, ref in by_id.items() if i not in taken],
+        )
 
     # ---------------------------------------------------------- task submit
 
@@ -696,6 +716,7 @@ class Runtime:
         retry_exceptions: Any,
         runtime_env: Optional[dict] = None,
         trace_ctx: Optional[tuple] = None,
+        consumer_is_peer: bool = False,
     ) -> list[ObjectRef]:
         from ray_tpu._private.runtime_env import validate_runtime_env
 
@@ -734,7 +755,7 @@ class Runtime:
                 # release would also never fire — no tracked outputs).
                 self._lineage[spec.task_id] = (spec, dict(resources))
         if streaming:
-            gen = self._register_stream(spec, completion_ref=refs[0])
+            gen = self._register_stream(spec, refs[0], consumer_is_peer)
             self._submit_when_ready(spec, resources)
             return [gen]
         self._submit_when_ready(spec, resources)
@@ -742,12 +763,14 @@ class Runtime:
 
     # ------------------------------------------------------- streaming gens
 
-    def _register_stream(self, spec: TaskSpec, completion_ref: ObjectRef):
+    def _register_stream(
+        self, spec: TaskSpec, completion_ref: ObjectRef, consumer_is_peer: bool
+    ):
         """Create the owner-side ObjectRefStream for a streaming task
         (reference: TaskManager ObjectRefStream, task_manager.h:100)."""
         from ray_tpu._private.streaming import ObjectRefGenerator, ObjectRefStream
 
-        stream = ObjectRefStream()
+        stream = ObjectRefStream(carries_values=not consumer_is_peer)
         with self._lock:
             self._streams[spec.task_id] = stream
         gen = ObjectRefGenerator(stream, spec.task_id)
@@ -763,31 +786,73 @@ class Runtime:
         error: Optional[BaseException] = None,
         traceback_str: str = "",
     ) -> None:
-        """Seal one yielded item and hand its ref to the consumer (reference:
-        CoreWorker::ReportGeneratorItemReturns, core_worker.h:770)."""
-        with self._lock:
-            stream = self._streams.get(spec.task_id)
+        """Hand one yielded item's ref to the consumer (reference:
+        CoreWorker::ReportGeneratorItemReturns, core_worker.h:770).
+
+        A small value whose consumer is in this process travels with its ref
+        (`ObjectRef._carrying`), as the reference returns a small object in
+        the task's reply and not through plasma (`max_direct_call_object_size`,
+        which bounds inlined task arguments here too): the reference counter
+        and the store never hear of it (nor does the state API's listing of
+        their tables show it) unless the ref escapes (its id is taken or it
+        is pickled), and then it is promoted to an ordinary object. An unconsumed stream thus holds its small items in its
+        ObjectRefStream, not in the store: outside the store's budget and
+        never spilled, at most the threshold an item. Errors, values over
+        the threshold or that do not pickle, and the items of a stream that a
+        peer process consumes are sealed as every other object is."""
+        # ray-tpu: lint-ignore[RTL201] one dict read, atomic under the GIL:
+        # the runtime's lock is shared by every thread of the process, and
+        # this runs once a streamed token.
+        stream = self._streams.get(spec.task_id)
+        self.stream_items_reported += 1
         oid = ObjectID.of(spec.task_id, _STREAM_INDEX_OFFSET + index)
+        if error is None and stream is not None and stream.carries_values:
+            ref = self._carrying_ref(oid, value)
+            if ref is not None:
+                self.stream_items_inline += 1
+                stream.offer(ref)
+                return
         self.refcount.add_owned_object(oid, owner_task=spec.task_id)
         ref = ObjectRef(oid)
         if error is not None:
-            exc = error
-            if not isinstance(
-                exc,
-                (
-                    TaskError,
-                    ActorDiedError,
-                    ObjectLostError,
-                    TaskCancelledError,
-                    PoisonRequestError,
-                ),
-            ):
-                exc = TaskError(exc, traceback_str, spec.name)
-            self.store.seal(oid, ErrorObject(exc, traceback_str))
+            self.store.seal(oid, self._stream_error(spec, error, traceback_str))
         else:
             self.store.seal(oid, value)
         if stream is not None:
             stream.offer(ref)
+
+    def _carrying_ref(self, oid: ObjectID, value: Any) -> Optional[ObjectRef]:
+        """A ref that carries `value`, or None where the value has to be
+        sealed: it is large, it does not pickle (the store keeps such a value
+        live), or the store keeps values live by configuration."""
+        if not self.config.serialize_objects:
+            return None
+        nested: list = []
+        try:
+            with capture_serialized_refs(nested):
+                data = cloudpickle.dumps(value, protocol=5)
+        except Exception:
+            return None
+        if len(data) > self.config.max_direct_call_object_size:
+            return None
+        return ObjectRef._carrying(oid, data, nested or None)
+
+    @staticmethod
+    def _stream_error(
+        spec: TaskSpec, exc: BaseException, traceback_str: str
+    ) -> "ErrorObject":
+        if not isinstance(
+            exc,
+            (
+                TaskError,
+                ActorDiedError,
+                ObjectLostError,
+                TaskCancelledError,
+                PoisonRequestError,
+            ),
+        ):
+            exc = TaskError(exc, traceback_str, spec.name)
+        return ErrorObject(exc, traceback_str)
 
     def _finish_stream(self, spec: TaskSpec, result: TaskResult) -> None:
         with self._lock:
@@ -797,22 +862,12 @@ class Runtime:
         if result.exc is not None:
             # Failure before the generator produced (bad args, actor death):
             # surface it as the stream's last item so iteration raises.
-            exc = result.exc
-            if not isinstance(
-                exc,
-                (
-                    TaskError,
-                    ActorDiedError,
-                    ObjectLostError,
-                    TaskCancelledError,
-                    PoisonRequestError,
-                ),
-            ):
-                exc = TaskError(exc, result.traceback_str, spec.name)
             oid = ObjectID.of(spec.task_id, _STREAM_INDEX_OFFSET + _STREAM_ERROR_INDEX)
             self.refcount.add_owned_object(oid, owner_task=spec.task_id)
             ref = ObjectRef(oid)
-            self.store.seal(oid, ErrorObject(exc, result.traceback_str))
+            self.store.seal(
+                oid, self._stream_error(spec, result.exc, result.traceback_str)
+            )
             stream.offer(ref)
         total = result.value if isinstance(result.value, int) else 0
         stream.finish(total)
@@ -938,6 +993,7 @@ class Runtime:
         name: str,
         num_returns: int,
         trace_ctx: Optional[tuple] = None,
+        consumer_is_peer: bool = False,
     ) -> list[ObjectRef]:
         maybe_fail("actor.submit", detail=name)
         record = self.controller.get_actor_record(actor_id)
@@ -970,7 +1026,7 @@ class Runtime:
         with self._lock:
             self._task_records[spec.task_id] = _TaskRecord(spec, {})
         if streaming:
-            gen = self._register_stream(spec, completion_ref=refs[0])
+            gen = self._register_stream(spec, refs[0], consumer_is_peer)
             self._enqueue_actor_task_when_ready(spec)
             return [gen]
         self._enqueue_actor_task_when_ready(spec)
@@ -1120,7 +1176,7 @@ class Runtime:
     def cancel(
         self, ref: ObjectRef, force: bool = False, recursive: bool = False
     ) -> bool:
-        return self._cancel_task(ref.id.task_id, force=force, recursive=recursive)
+        return self._cancel_task(ref.task_id(), force=force, recursive=recursive)
 
     def _cancel_task(
         self,

@@ -5,7 +5,9 @@ _raylet.pyx:228 StreamingObjectRefGenerator: a generator task's items are
 sealed as individual objects as they are yielded; the consumer iterates an
 ObjectRefGenerator whose __next__ blocks until the producer reports the next
 item (or the stream ends). Errors raised mid-generator are sealed into the
-failing item's slot, so the consumer raises exactly at that point.
+failing item's slot, so the consumer raises exactly at that point. (A small
+item read in the producing runtime's process is not sealed until its ref
+escapes: `Runtime.report_stream_item`.)
 """
 
 from __future__ import annotations
@@ -21,9 +23,15 @@ _SENTINEL = object()
 
 
 class ObjectRefStream:
-    """Owner-side stream state: refs appear in yield order."""
+    """Owner-side stream state: refs appear in yield order.
 
-    def __init__(self):
+    `carries_values` says that the consumer reads the refs in the process
+    that offers them, so a small item's ref may carry its value
+    (`Runtime.report_stream_item`); a stream handed to a peer process, which
+    gets its items by id, does not."""
+
+    def __init__(self, carries_values: bool = False):
+        self.carries_values = carries_values
         self._cv = threading.Condition()
         self._items: deque = deque()
         self._done = False

@@ -2718,10 +2718,23 @@ class LLMServer:
 
     def metrics(self) -> dict:
         with self._lock:
-            stats = self._engine.stats()
-            stats["wedged"] = self._wedged
-            stats["consecutive_step_failures"] = self._consecutive_step_failures
-            return stats
+            return self._server_stats()
+
+    def _server_stats(self) -> dict:
+        """The engine's stats and what only its server knows. Caller holds
+        the lock. The two stream counts are the hosting runtime's, of every
+        streaming generator in the process (`Runtime.report_stream_item`):
+        how many items were reported, and how many travelled with their refs
+        and not through the object store."""
+        from ray_tpu._private import runtime as runtime_mod
+
+        stats = self._engine.stats()
+        stats["wedged"] = self._wedged
+        stats["consecutive_step_failures"] = self._consecutive_step_failures
+        for key in ("stream_items_reported", "stream_items_inline"):
+            # 0 in a worker process or with no runtime: nothing is inline there.
+            stats[key] = getattr(runtime_mod._RUNTIME, key, 0)
+        return stats
 
     def autoscaling_snapshot(self) -> dict:
         """Compact SLO signal bundle for the serve controller's
@@ -2780,11 +2793,8 @@ class LLMServer:
         engine's lock)."""
         with self._lock:
             e = self._engine
-            stats = e.stats()
-            stats["wedged"] = self._wedged
-            stats["consecutive_step_failures"] = self._consecutive_step_failures
             return {
-                "metrics": stats,
+                "metrics": self._server_stats(),
                 "dead_letters": e.dead_letters(),
                 "shed_requests": e.shed_requests(),
                 "flight_record": e.flight_recorder.snapshot(steps_limit),

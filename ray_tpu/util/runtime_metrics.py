@@ -100,6 +100,12 @@ def _gauges() -> dict:
                     "ray_tpu_object_store_objects",
                     "Objects tracked by the in-process store",
                 ),
+                "stream_items": Gauge(
+                    "ray_tpu_stream_items",
+                    "Streaming-generator items since start: reported, carried "
+                    "inline by their refs, promoted into the store later",
+                    tag_keys=("path",),
+                ),
                 "shm_used": Gauge(
                     "ray_tpu_shm_store_used_bytes",
                     "Native shared-memory store usage",
@@ -168,6 +174,11 @@ def sample_runtime_metrics(runtime) -> None:
     used = getattr(store, "used_bytes", 0)
     g["object_store_used"].set(float(used() if callable(used) else used))
     g["object_store_objects"].set(float(len(getattr(store, "_entries", ()))))
+    for path in ("reported", "inline", "promoted"):
+        g["stream_items"].set(
+            float(getattr(runtime, "stream_items_" + path)),
+            tags={"path": path},
+        )
     native = runtime._native_store
     if native is not None:
         try:

@@ -92,6 +92,41 @@ def test_remote_driver_full_api(head):
     assert "CLIENT_OK" in proc.stdout
 
 
+def test_stream_read_by_a_remote_driver_is_not_inline(head):
+    """A remote driver gets a stream's items by id over the wire: the head
+    seals them as it always did, none travels with its ref."""
+    runtime, address = head
+    script = textwrap.dedent(
+        """
+        import sys
+        import ray_tpu
+
+        ray_tpu.init(address=sys.argv[1])
+
+        @ray_tpu.remote
+        def gen(n):
+            for i in range(n):
+                yield {"token_id": i}
+
+        stream = gen.options(num_returns="streaming").remote(5)
+        assert [ray_tpu.get(r)["token_id"] for r in stream] == [0, 1, 2, 3, 4]
+        ray_tpu.shutdown()
+        print("CLIENT_OK")
+        """
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", script, address],
+        capture_output=True,
+        text=True,
+        timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr
+    assert "CLIENT_OK" in proc.stdout
+    assert runtime.stream_items_reported == 5
+    assert runtime.stream_items_inline == 0
+    assert runtime.stream_items_promoted == 0
+
+
 def test_wrong_token_refused(head, monkeypatch):
     """The head must refuse an unauthenticated peer before unpickling
     anything it sends (the wire protocol is code execution by design)."""

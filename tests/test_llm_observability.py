@@ -539,6 +539,39 @@ def test_stream_resume_counter_increments(serve_ray):
     )
 
 
+def test_streamed_tokens_travel_inline_and_the_engine_counts_them(serve_ray):
+    """A streamed generation's tokens are small items of two streaming
+    generators (engine actor -> replica -> caller), so every one travels
+    with its ref: LLMServer copies the runtime's two counts into `metrics()`
+    and `observability_snapshot()["metrics"]`, beside `wedged`, where the
+    benchmark's `stream_inline_share` reads them."""
+    from ray_tpu import serve
+    from ray_tpu.llm.serve import build_app
+
+    handle = serve.run(
+        build_app(TINY, ECFG_SERVE, engine_name="obs-inline", num_replicas=1),
+        name="llmobsinline",
+    )
+    engine = ray_tpu.get_actor("llm_engine:obs-inline")
+    before = ray_tpu.get(engine.metrics.remote())
+    prompt = random_prompts((5,), seed=11)[0]
+    stream = handle.options(stream=True).remote(
+        {"prompt_ids": prompt, "max_new_tokens": 6, "stream": True}
+    )
+    assert len(list(stream)) == 6
+    stats = ray_tpu.get(engine.metrics.remote())
+    snapshot = ray_tpu.get(engine.observability_snapshot.remote())["metrics"]
+    for view in (stats, snapshot):
+        assert view["wedged"] is False
+        assert view["stream_items_inline"] == view["stream_items_reported"] > 0
+    # Six tokens, two hops each.
+    assert (
+        stats["stream_items_reported"] - before["stream_items_reported"] >= 12
+    )
+    assert stats["stream_items_inline"] == serve_ray.stream_items_inline
+    assert serve_ray.stream_items_promoted == 0
+
+
 # ---------------- dashboard ----------------
 
 

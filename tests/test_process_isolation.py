@@ -156,6 +156,43 @@ def test_streaming_generator_across_process(proc_runtime):
     assert items == [0, 1, 4, 9, 16]
 
 
+def test_stream_consumed_in_a_worker_process_is_not_inline(proc_runtime):
+    """A small item travels with its ref only where the consumer reads the
+    ref in the owner's process. A task in a worker process gets its items by
+    id over the wire, so the owner seals them as it always did; the driver's
+    own stream (produced in a worker, consumed here) carries them."""
+    runtime = proc_runtime
+
+    @ray_tpu.remote
+    def gen(n):
+        for i in range(n):
+            yield i * i
+
+    @ray_tpu.remote
+    def consume(n):
+        stream = gen.options(num_returns="streaming").remote(n)
+        return [ray_tpu.get(ref) for ref in stream]
+
+    @ray_tpu.remote
+    def consume_one(item):
+        return item
+
+    reported, inline = runtime.stream_items_reported, runtime.stream_items_inline
+    assert ray_tpu.get(consume.remote(6)) == [0, 1, 4, 9, 16, 25]
+    assert runtime.stream_items_reported == reported + 6
+    assert runtime.stream_items_inline == inline
+    assert runtime.stream_items_promoted == 0
+
+    refs = list(gen.options(num_returns="streaming").remote(4))
+    assert runtime.stream_items_inline == inline + 4
+    assert not any(runtime.store.contains(ref._id) for ref in refs)
+    assert ray_tpu.get(refs) == [0, 1, 4, 9]
+    # Handed on to a worker process, a carried item becomes an object.
+    assert ray_tpu.get(consume_one.remote(refs[2])) == 4
+    assert runtime.stream_items_promoted == 1
+    assert runtime.store.contains(refs[2]._id)
+
+
 def test_large_object_via_shared_memory(proc_runtime):
     @ray_tpu.remote
     def produce():

@@ -345,3 +345,354 @@ def test_parallel_iterator_from_iterators(ray_start_regular):
 
     it = par_iter.from_iterators([make_gen(0), make_gen(100)])
     assert sorted(it.gather_sync()) == [0, 1, 2, 100, 101, 102]
+
+
+# -- small stream items travel with their refs ------------------------------
+# (Runtime.report_stream_item: neither the reference counter nor the store
+# hears of such an item unless its ref escapes.)
+
+
+def _tables_hold(runtime, ref) -> bool:
+    """Whether the store or the reference counter has an entry for the id
+    (read through `_id`: taking `ref.id` is itself an escape)."""
+    oid = ref._id
+    with runtime.refcount._lock:
+        counted = oid in runtime.refcount._refs
+    with runtime.store._lock:
+        stored = oid in runtime.store._entries
+    return counted or stored
+
+
+def _wait_until(predicate, timeout_s=10.0):
+    deadline = time.monotonic() + timeout_s
+    while not predicate():
+        assert time.monotonic() < deadline, "condition not reached"
+        time.sleep(0.02)
+
+
+def _small_items(n=3):
+    @ray_tpu.remote(num_returns="streaming")
+    def gen(n):
+        for i in range(n):
+            yield {"i": i, "payload": [i, i]}
+
+    return list(gen.remote(n))
+
+
+def _read_by_get(ref):
+    return ray_tpu.get(ref)
+
+
+def _read_by_await(ref):
+    import asyncio
+
+    async def read():
+        return await ref
+
+    return asyncio.run(read())
+
+
+def _read_by_future(ref):
+    fut = ref.future()
+    assert fut.done()  # filled by the caller's own thread
+    return fut.result()
+
+
+def _read_in_a_mixed_list(ref):
+    sealed = ray_tpu.put("sealed")
+    assert ray_tpu.get([sealed, ref, sealed])[::2] == ["sealed", "sealed"]
+    return ray_tpu.get([ref, sealed])[0]
+
+
+def _read_after_wait(ref):
+    sealed = ray_tpu.put("sealed")
+    ready, rest = ray_tpu.wait([ref, sealed], num_returns=2, timeout=5)
+    assert ready == [ref, sealed] and rest == []
+    ready, rest = ray_tpu.wait([sealed, ref], num_returns=1, timeout=5)
+    assert len(ready) == 1 and len(rest) == 1
+    ready, rest = ray_tpu.wait([ref], num_returns=1, timeout=0)
+    assert ready == [ref] and rest == []
+    return ray_tpu.get(ready[0])
+
+
+@pytest.mark.parametrize(
+    "read",
+    [
+        _read_by_get,
+        _read_by_await,
+        _read_by_future,
+        _read_in_a_mixed_list,
+        _read_after_wait,
+    ],
+)
+def test_small_stream_item_is_read_from_its_ref(ray_start_regular, read):
+    runtime = ray_start_regular
+    refs = _small_items(3)
+    assert runtime.stream_items_reported == 3
+    assert runtime.stream_items_inline == 3
+    assert read(refs[1]) == {"i": 1, "payload": [1, 1]}
+    assert runtime.stream_items_promoted == 0
+    assert not any(_tables_hold(runtime, ref) for ref in refs)
+
+
+def test_small_stream_item_two_gets_do_not_alias(ray_start_regular):
+    (ref,) = _small_items(1)
+    first, second = ray_tpu.get(ref), ray_tpu.get(ref)
+    assert first == second == {"i": 0, "payload": [0, 0]}
+    assert first is not second
+    assert first["payload"] is not second["payload"]
+    first["payload"].append("mutated")
+    assert ray_tpu.get([ref])[0] == {"i": 0, "payload": [0, 0]}
+
+
+def _escape_as_task_argument(ref):
+    @ray_tpu.remote
+    def receive(item):
+        return item
+
+    return ray_tpu.get(receive.remote(ref))
+
+
+def _escape_as_task_return(ref):
+    @ray_tpu.remote
+    def pass_on(box):
+        return box[0]  # the ref itself, sealed into the task's return object
+
+    return ray_tpu.get(ray_tpu.get(pass_on.remote([ref])))
+
+
+def _escape_inside_a_put(ref):
+    outer = ray_tpu.put({"inner": ref})
+    return ray_tpu.get(ray_tpu.get(outer)["inner"])
+
+
+def _escape_by_pickle(ref):
+    import pickle
+
+    return ray_tpu.get(pickle.loads(pickle.dumps(ref)))
+
+
+def _escape_by_copy(ref):
+    import copy
+
+    # A copy does not share the carried value: it goes through __reduce__,
+    # so it promotes, and both handles are counted.
+    twin, deep = copy.copy(ref), copy.deepcopy(ref)
+    assert twin == ref and deep == ref and twin is not ref
+    assert ray_tpu.get(deep) == ray_tpu.get(ref)
+    return ray_tpu.get(twin)
+
+
+def _escape_by_its_id(ref):
+    from ray_tpu._private.runtime import get_runtime
+
+    return get_runtime().store.get(ref.id, timeout=5)
+
+
+@pytest.mark.parametrize(
+    "escape",
+    [
+        _escape_as_task_argument,
+        _escape_as_task_return,
+        _escape_inside_a_put,
+        _escape_by_pickle,
+        _escape_by_copy,
+        _escape_by_its_id,
+    ],
+)
+def test_small_stream_item_is_promoted_once_when_it_escapes(
+    ray_start_regular, escape
+):
+    import gc
+
+    runtime = ray_start_regular
+    refs = _small_items(3)
+    ref = refs[1]
+    assert escape(ref) == {"i": 1, "payload": [1, 1]}
+    assert runtime.stream_items_promoted == 1
+    # From then on an ordinary object: sealed, counted, readable as before.
+    assert runtime.store.contains(ref._id)
+    assert runtime.refcount.counts(ref._id)[0] >= 1
+    assert ray_tpu.get(ref) == {"i": 1, "payload": [1, 1]}
+    assert escape(ref) == {"i": 1, "payload": [1, 1]}
+    assert runtime.stream_items_promoted == 1
+    assert not _tables_hold(runtime, refs[0])
+    assert not _tables_hold(runtime, refs[2])
+    # ... and collected like one when its handles die.
+    oid = ref._id
+    del ref, refs
+    _wait_until(lambda: (gc.collect(), runtime.refcount.num_tracked())[1] == 0)
+    _wait_until(lambda: not runtime.store._entries)
+    assert runtime.refcount.counts(oid) == (0, 0)
+
+
+def test_small_stream_items_leave_nothing_behind(ray_start_regular):
+    """1,000 items consumed and dropped, some freed, some never read: the
+    reference counter's and the store's tables end up empty."""
+    import gc
+
+    from ray_tpu.exceptions import ObjectFreedError
+
+    runtime = ray_start_regular
+
+    @ray_tpu.remote(num_returns="streaming")
+    def gen(n):
+        for i in range(n):
+            yield i
+
+    stream = gen.remote(1000)
+    total = 0
+    for i, ref in enumerate(stream):
+        if i % 100 == 7:
+            # Freed before any read: the id goes to the store, which forgets
+            # the value; the entry goes when the handle does.
+            runtime.store.free([ref.id])
+            with pytest.raises(ObjectFreedError):
+                ray_tpu.get(ref)
+        elif i % 2:
+            total += ray_tpu.get(ref)
+        # else: dies unread
+    del ref
+    assert total == sum(i for i in range(1000) if i % 2 and i % 100 != 7)
+    assert runtime.stream_items_inline == 1000
+    assert runtime.stream_items_promoted == 10
+    del stream  # the completion object's handle
+    _wait_until(lambda: (gc.collect(), runtime.refcount.num_tracked())[1] == 0)
+    _wait_until(lambda: not runtime.store._entries)
+    assert not runtime.refcount._task_outputs
+
+
+def test_error_and_large_stream_items_are_sealed(ray_start_regular):
+    """What does not travel with its ref goes the way every object goes: an
+    error (it must surface at its item), and a value over
+    `max_direct_call_object_size`."""
+    runtime = ray_start_regular
+    limit = runtime.config.max_direct_call_object_size
+
+    @ray_tpu.remote(num_returns="streaming")
+    def gen():
+        yield b"x" * (limit // 2)
+        yield b"y" * (limit + 1)
+        raise RuntimeError("boom")
+
+    small, large, failed = list(gen.remote())
+    assert runtime.stream_items_reported == 3
+    assert runtime.stream_items_inline == 1
+    assert not _tables_hold(runtime, small)
+    assert runtime.store.contains(large._id) and runtime.store.contains(failed._id)
+    assert runtime.refcount.counts(large._id) == (1, 0)
+    assert ray_tpu.get(small) == b"x" * (limit // 2)
+    assert ray_tpu.get(large) == b"y" * (limit + 1)
+    with pytest.raises(Exception, match="boom"):
+        ray_tpu.get(failed)
+    assert runtime.stream_items_promoted == 0
+
+
+def test_stream_item_holding_a_ref_keeps_it_alive(ray_start_regular):
+    """A carried value pins the refs pickled inside it, as a store entry
+    pins its nested refs."""
+    import gc
+
+    runtime = ray_start_regular
+
+    @ray_tpu.remote(num_returns="streaming")
+    def gen():
+        yield {"inner": ray_tpu.put("kept")}
+
+    (ref,) = list(gen.remote())
+    gc.collect()
+    assert not _tables_hold(runtime, ref)
+    assert ray_tpu.get(ray_tpu.get(ref)["inner"]) == "kept"
+    del ref
+    _wait_until(lambda: (gc.collect(), runtime.refcount.num_tracked())[1] == 0)
+
+
+def test_concurrent_streams_stay_off_the_shared_tables(ray_start_regular):
+    """The convoy's regression test, on counts and not on time. 32 streams
+    of 200 small items from one actor whose producer thread stays busy,
+    each consumed by a thread of its own: at no sample does the reference
+    counter or the store hold an item's id (every thread of the process
+    used to queue on those two locks, seven trips an item), and the
+    counter's per-task output sets do not grow with the items."""
+    import queue
+    import sys
+    import threading
+
+    runtime = ray_start_regular
+    streams, items = 32, 200
+
+    @ray_tpu.remote
+    class Producer:
+        def __init__(self, streams, items):
+            self._queues = [queue.Queue() for _ in range(streams)]
+            self._items = items
+            threading.Thread(target=self._produce, daemon=True).start()
+
+        def _produce(self):
+            for i in range(self._items):
+                sum(range(2000))  # the interpreter stays busy between items
+                for q in self._queues:
+                    q.put(i)
+
+        @ray_tpu.method(num_returns="streaming")
+        def stream(self, lane):
+            for _ in range(self._items):
+                yield {"token_id": self._queues[lane].get(timeout=30)}
+
+    producer = Producer.options(max_concurrency=streams + 1).remote(streams, items)
+    gens = [producer.stream.remote(lane) for lane in range(streams)]
+    tasks = {gen._task_id for gen in gens}
+    completions = {gen._completion_ref._id for gen in gens}
+    received = [[] for _ in range(streams)]
+
+    def consume(lane):
+        for ref in gens[lane]:
+            received[lane].append(ray_tpu.get(ref)["token_id"])
+
+    seen = {"samples": 0, "item_ids": 0, "largest_output_set": 0}
+    done = threading.Event()
+
+    def sample():
+        while not done.is_set():
+            with runtime.refcount._lock:
+                counted = list(runtime.refcount._refs)
+                outputs = [
+                    len(runtime.refcount._task_outputs.get(t, ())) for t in tasks
+                ]
+            with runtime.store._lock:
+                stored = list(runtime.store._entries)
+            seen["item_ids"] += sum(
+                1
+                for oid in counted + stored
+                if oid.task_id in tasks and oid not in completions
+            )
+            seen["largest_output_set"] = max(seen["largest_output_set"], *outputs)
+            seen["samples"] += 1
+            time.sleep(0.005)
+
+    sampler = threading.Thread(target=sample)
+    consumers = [
+        threading.Thread(target=consume, args=(lane,)) for lane in range(streams)
+    ]
+    # Threads change places often: an update of the plain-int counters that
+    # could be lost would be, and the exact counts below would miss it.
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    try:
+        sampler.start()
+        for thread in consumers:
+            thread.start()
+        for thread in consumers:
+            thread.join(timeout=120)
+        done.set()
+        sampler.join(timeout=30)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(thread.is_alive() for thread in consumers + [sampler])
+    assert all(lane == list(range(items)) for lane in received)
+    assert runtime.stream_items_reported == streams * items
+    assert runtime.stream_items_inline == streams * items
+    assert runtime.stream_items_promoted == 0
+    assert seen["samples"] > 5
+    assert seen["item_ids"] == 0
+    assert seen["largest_output_set"] <= 1  # the completion object alone
