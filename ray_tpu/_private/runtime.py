@@ -20,9 +20,12 @@ Key invariants preserved from the reference:
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
+import time
 import traceback
+import weakref
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Optional
@@ -64,6 +67,26 @@ _RUNTIME: Optional["Runtime"] = None
 _PUT_INDEX_OFFSET = 1 << 20  # puts live above return indices in the ObjectID space
 _STREAM_INDEX_OFFSET = 1 << 19  # streaming-generator items live below puts
 _STREAM_ERROR_INDEX = (1 << 19) - 1  # slot for pre-generator failures
+
+
+def _no_delivery() -> dict:
+    """A group of `Runtime.stream_delivery` before its first stream."""
+    return {
+        "streams": 0,
+        "items_offered": 0,
+        "items_taken": 0,
+        "items_dropped": 0,
+        "wait_s": 0.0,
+        "wait_max_s": 0.0,
+    }
+
+
+def _fold_delivery(group: dict, stream: dict) -> None:
+    """One stream's `ObjectRefStream.delivery()` into its group's totals."""
+    group["streams"] += 1
+    for key in ("items_offered", "items_taken", "items_dropped", "wait_s"):
+        group[key] += stream[key]
+    group["wait_max_s"] = max(group["wait_max_s"], stream["wait_max_s"])
 
 
 class ErrorObject:
@@ -225,6 +248,10 @@ class Runtime:
         self.stream_items_reported = 0
         self.stream_items_inline = 0
         self.stream_items_promoted = 0
+        # Delivery clocks (`stream_delivery`) of the streams that have left
+        # `_streams`, by producing task's name. A stream stays in `_streams`
+        # until it retires: producer finished and items taken or abandoned.
+        self._streams_retired: dict[str, dict] = {}
         from ray_tpu._private.task_events import TaskEventBuffer
 
         self.task_events = TaskEventBuffer()
@@ -769,14 +796,74 @@ class Runtime:
         """Create the owner-side ObjectRefStream for a streaming task
         (reference: TaskManager ObjectRefStream, task_manager.h:100)."""
         from ray_tpu._private.streaming import ObjectRefGenerator, ObjectRefStream
+        from ray_tpu.util import tracing
 
-        stream = ObjectRefStream(carries_values=not consumer_is_peer)
+        span_id = tracing.task_span_id(spec.task_id)
+        stream = ObjectRefStream(
+            carries_values=not consumer_is_peer,
+            name=spec.name,
+            trace=(spec.trace_ctx[0] if spec.trace_ctx else span_id, span_id),
+            on_retire=functools.partial(self._retire_stream, spec.task_id),
+        )
         with self._lock:
             self._streams[spec.task_id] = stream
         gen = ObjectRefGenerator(stream, spec.task_id)
         # The completion object's lifetime rides on the generator handle.
         gen._completion_ref = completion_ref
+        # A consumer that drops the generator with items untaken: the
+        # stream retires all the same, once its producer has finished.
+        weakref.finalize(gen, stream.abandon).atexit = False
         return gen
+
+    def _retire_stream(self, task_id: TaskID, stream) -> None:
+        """Once a stream (`ObjectRefStream.on_retire`): fold its delivery
+        clock into the retired totals, drop it, and emit its one span,
+        `stream.deliver`, under the producing task's."""
+        from ray_tpu.util import tracing
+
+        delivery = stream.delivery()
+        with self._lock:
+            self._streams.pop(task_id, None)
+            _fold_delivery(
+                self._streams_retired.setdefault(stream.name, _no_delivery()),
+                delivery,
+            )
+        tracing.emit_span(
+            "stream.deliver",
+            stream.created_s,
+            time.time(),
+            parent=stream.trace,
+            attributes={
+                "producer": stream.name,
+                "items": delivery["items_taken"],
+                "wait_s": delivery["wait_s"],
+                "wait_max_s": delivery["wait_max_s"],
+            },
+        )
+
+    def stream_delivery(self) -> dict:
+        """How long streamed items waited for their consumers, by producing
+        task's name: `{streams, items_offered, items_taken, items_dropped,
+        wait_s, wait_max_s}` over every streaming generator this runtime
+        has registered, the live ones read where they stand and the
+        retired ones from the totals they left, so a stream counts the
+        same open as closed. `wait_s` sums, over the items taken, the time
+        from the thread that offered one to the thread that took it;
+        `items_offered - items_taken - items_dropped` items are waiting
+        now (dropped: left in a stream whose consumer let go of it).
+        For a snapshot: it takes the runtime's lock once and each live
+        stream's once, and is never called an item."""
+        with self._lock:
+            live = list(self._streams.values())
+            out = {
+                name: dict(totals)
+                for name, totals in self._streams_retired.items()
+            }
+        for stream in live:
+            _fold_delivery(
+                out.setdefault(stream.name, _no_delivery()), stream.delivery()
+            )
+        return out
 
     def report_stream_item(
         self,
@@ -856,8 +943,10 @@ class Runtime:
 
     def _finish_stream(self, spec: TaskSpec, result: TaskResult) -> None:
         with self._lock:
-            stream = self._streams.pop(spec.task_id, None)
-        if stream is None:
+            stream = self._streams.get(spec.task_id)
+        # The stream stays listed until it retires (`_retire_stream`), so
+        # its own lock picks the one path that finishes it.
+        if stream is None or not stream.claim_finish():
             return
         if result.exc is not None:
             # Failure before the generator produced (bad args, actor death):

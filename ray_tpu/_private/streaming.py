@@ -8,14 +8,22 @@ item (or the stream ends). Errors raised mid-generator are sealed into the
 failing item's slot, so the consumer raises exactly at that point. (A small
 item read in the producing runtime's process is not sealed until its ref
 escapes: `Runtime.report_stream_item`.)
+
+A stream times each item from the thread that offers it to the thread that
+takes it: `offer` keeps one `perf_counter` reading beside the ref and `next`,
+the only place an item leaves, charges the wait to the stream's own totals,
+both under the stream's own lock, which they hold anyway. Nothing shared by
+the process is touched an item; `Runtime.stream_delivery` sums the streams
+at a snapshot and a stream hands its totals over once, when it retires.
 """
 
 from __future__ import annotations
 
 import logging
 import threading
+import time
 from collections import deque
-from typing import Optional
+from typing import Callable, Optional
 
 logger = logging.getLogger(__name__)
 
@@ -28,13 +36,38 @@ class ObjectRefStream:
     `carries_values` says that the consumer reads the refs in the process
     that offers them, so a small item's ref may carry its value
     (`Runtime.report_stream_item`); a stream handed to a peer process, which
-    gets its items by id, does not."""
+    gets its items by id, does not.
 
-    def __init__(self, carries_values: bool = False):
+    `name` is the producing task's (what `Runtime.stream_delivery` groups
+    by) and `trace` the (trace id, span id) of that task's span. The
+    delivery clock: `items_offered`, `items_taken`, `wait_s` (the sum over
+    taken items of offer -> take) and `wait_max_s`. `on_retire(stream)`
+    is called once, from whichever thread ends the stream's life: the
+    producer has finished and the consumer has either taken every item or
+    dropped its generator (`abandon`)."""
+
+    def __init__(
+        self,
+        carries_values: bool = False,
+        name: str = "",
+        trace: Optional[tuple] = None,
+        on_retire: Optional[Callable[["ObjectRefStream"], None]] = None,
+    ):
         self.carries_values = carries_values
+        self.name = name
+        self.trace = trace
+        self.created_s = time.time()
+        self.items_offered = 0
+        self.items_taken = 0
+        self.wait_s = 0.0
+        self.wait_max_s = 0.0
+        self._on_retire = on_retire
         self._cv = threading.Condition()
-        self._items: deque = deque()
+        self._items: deque = deque()  # (ref, perf_counter reading at offer)
         self._done = False
+        self._finishing = False
+        self._abandoned = False
+        self._retired = False
         self._total: Optional[int] = None
         # One-shot callbacks of consumers that wait without a thread
         # (`on_ready`), called by the producer's thread.
@@ -42,10 +75,19 @@ class ObjectRefStream:
 
     def offer(self, ref) -> None:
         with self._cv:
-            self._items.append(ref)
+            self._items.append((ref, time.perf_counter()))
+            self.items_offered += 1
             self._cv.notify_all()
             waiters, self._waiters = self._waiters, []
         self._wake(waiters)
+
+    def claim_finish(self) -> bool:
+        """True for the one caller that may finish the stream (every path
+        that finalizes the producing task tries)."""
+        with self._cv:
+            claimed = not self._finishing
+            self._finishing = True
+        return claimed
 
     def finish(self, total: int) -> None:
         with self._cv:
@@ -53,7 +95,44 @@ class ObjectRefStream:
             self._total = total
             self._cv.notify_all()
             waiters, self._waiters = self._waiters, []
+            retire = self._ends_here_locked()
         self._wake(waiters)
+        if retire:
+            self._on_retire(self)
+
+    def abandon(self) -> None:
+        """The consumer's generator is gone: what it left is taken by
+        nobody."""
+        with self._cv:
+            self._abandoned = True
+            retire = self._ends_here_locked()
+        if retire:
+            self._on_retire(self)
+
+    def _ends_here_locked(self) -> bool:
+        """Whether the caller, who holds the lock, is the one to retire the
+        stream."""
+        if (
+            self._retired
+            or self._on_retire is None
+            or not self._done
+            or (self._items and not self._abandoned)
+        ):
+            return False
+        self._retired = True
+        return True
+
+    def delivery(self) -> dict:
+        """The delivery clock, read together; `items_dropped` are the items
+        no consumer will take."""
+        with self._cv:
+            return {
+                "items_offered": self.items_offered,
+                "items_taken": self.items_taken,
+                "items_dropped": len(self._items) if self._abandoned else 0,
+                "wait_s": self.wait_s,
+                "wait_max_s": self.wait_max_s,
+            }
 
     @staticmethod
     def _wake(waiters: list) -> None:
@@ -82,10 +161,10 @@ class ObjectRefStream:
     def next(self, timeout: Optional[float] = None):
         """Blocking pop; returns _SENTINEL when the stream is exhausted.
         timeout=None waits indefinitely (the producer task finishing always
-        wakes us via finish())."""
-        import time as _time
-
-        deadline = None if timeout is None else _time.monotonic() + timeout
+        wakes us via finish()). The one place an item leaves the stream,
+        for a thread parked here and for an event loop that asks with
+        timeout=0 after `on_ready` woke it: its wait is charged here."""
+        deadline = None if timeout is None else time.monotonic() + timeout
         with self._cv:
             while not self._items:
                 if self._done:
@@ -93,10 +172,19 @@ class ObjectRefStream:
                 if deadline is None:
                     self._cv.wait()
                 else:
-                    remaining = deadline - _time.monotonic()
+                    remaining = deadline - time.monotonic()
                     if remaining <= 0 or not self._cv.wait(remaining):
                         raise TimeoutError("ObjectRefStream.next timed out")
-            return self._items.popleft()
+            ref, offered = self._items.popleft()
+            waited = time.perf_counter() - offered
+            self.wait_s += waited
+            if waited > self.wait_max_s:
+                self.wait_max_s = waited
+            self.items_taken += 1
+            retire = self._ends_here_locked()
+        if retire:
+            self._on_retire(self)
+        return ref
 
 
 class ObjectRefGenerator:

@@ -290,6 +290,10 @@ class LLMEngine:
         )
         self._on_token: Dict[str, Callable[[int], None]] = {}
         self._on_finish: Dict[str, Callable[[Sequence], None]] = {}
+        # Tokens emitted since `llm_engine_generated_tokens` was last
+        # written (once a step, with the rest of the metric family; a step
+        # that raises leaves its count to the next one that returns).
+        self._step_tokens_emitted = 0
 
         # Engines share one registered metric per name (several engines can
         # coexist in-process, one per Serve app); each engine is its own
@@ -592,7 +596,9 @@ class LLMEngine:
         # program dispatch. The runner calls the hook where a program's
         # dispatch call has returned and nothing has been fetched yet:
         # the boundary between prepare and wait.
-        self._clock = StepPhaseClock()
+        self._clock = StepPhaseClock(
+            on_stall=self.flight_recorder.record_stall
+        )
         self.runner.on_dispatched = self._clock.dispatched
         # Host-gap apparatus, on the clock's readings: the moment the
         # previous decode/verify results became host-readable, the
@@ -1126,6 +1132,11 @@ class LLMEngine:
                 )
         for metric in self._metric_family:
             metric._ensure_registered()
+        if self._step_tokens_emitted:
+            self._tokens_generated.inc(
+                self._step_tokens_emitted, tags=self._metric_tags
+            )
+            self._step_tokens_emitted = 0
         preempted = self.scheduler.num_preemptions - preempted_before
         if preempted:
             self._preemptions.inc(preempted, tags=self._metric_tags)
@@ -1889,7 +1900,7 @@ class LLMEngine:
         while seq.emitted < len(seq.generated):
             token = seq.generated[seq.emitted]
             seq.emitted += 1
-            self._tokens_generated.inc(tags=self._metric_tags)
+            self._step_tokens_emitted += 1  # exported by _finish_step
             if cb is not None:
                 cb(token)
 
@@ -2172,13 +2183,46 @@ class LLMEngine:
 
 
 class _RequestState:
-    __slots__ = ("tokens", "done", "seq", "error")
+    """One request's way out of the engine: the queue its tokens change
+    thread on (step thread -> the thread that called `generate` or drives
+    `generate_stream`) and, with `instrument` on, that hand-over's clock.
+    One writer a field: `offered` is the step thread's, the `handoff_*`
+    the one consumer's, so neither takes a lock."""
 
-    def __init__(self):
+    __slots__ = (
+        "tokens", "done", "seq", "error",
+        "stamped", "offered", "handoff_s", "handoff_max_s", "handoff_tokens",
+    )
+
+    def __init__(self, stamped: bool):
         self.tokens: "queue.Queue" = queue.Queue()
         self.done = threading.Event()
         self.seq: Optional[Sequence] = None
         self.error: Optional[BaseException] = None
+        # Whether the queue holds (token, perf_counter reading at commit)
+        # and not bare tokens.
+        self.stamped = stamped
+        self.offered = 0
+        self.handoff_s = 0.0
+        self.handoff_max_s = 0.0
+        self.handoff_tokens = 0
+
+    def offer(self, token: int) -> None:
+        """`on_token` of a stamped request: the step thread's side."""
+        self.offered += 1
+        self.tokens.put((token, time.perf_counter()))
+
+    def take(self, item) -> int:
+        """The token of a queue item, its wait charged: the consumer's side."""
+        if not self.stamped:
+            return item
+        token, committed = item
+        waited = time.perf_counter() - committed
+        self.handoff_s += waited
+        if waited > self.handoff_max_s:
+            self.handoff_max_s = waited
+        self.handoff_tokens += 1
+        return token
 
 
 _STREAM_END = object()
@@ -2306,6 +2350,9 @@ class LLMServer:
         self._lock = _HandoffLock()
         self._work = threading.Condition(self._lock)
         self._requests: Dict[str, _RequestState] = {}
+        # The hand-over clocks of the requests that have left `_requests`.
+        self._handoff_retired_s = 0.0
+        self._handoff_retired_tokens = 0
         self._shutdown = False
         self._wedged = False
         self._consecutive_step_failures = 0
@@ -2538,7 +2585,8 @@ class LLMServer:
         request_id: Optional[str],
         deadline_s: Optional[float] = None,
     ) -> tuple[str, _RequestState]:
-        state = _RequestState()
+        # The commit's stamp rides `instrument`, as the step clock does.
+        state = _RequestState(stamped=self._engine._instrument)
 
         def on_finish(seq: Sequence) -> None:
             state.seq = seq
@@ -2566,11 +2614,14 @@ class LLMServer:
                 max_new_tokens=max_new_tokens,
                 eos_id=eos_id,
                 request_id=request_id,
-                on_token=state.tokens.put,
+                on_token=state.offer if state.stamped else state.tokens.put,
                 on_finish=on_finish,
                 deadline_s=deadline_s,
             )
             self._requests[rid] = state
+            trace = self._engine._req_traces.get(rid)
+            if trace is not None:
+                trace.egress = state
             self._work.notify_all()
         return rid, state
 
@@ -2624,7 +2675,7 @@ class LLMServer:
                 item = state.tokens.get_nowait()
                 if item is _STREAM_END:
                     break
-                token_ids.append(item)
+                token_ids.append(state.take(item))
             return {
                 "request_id": rid,
                 "token_ids": token_ids,
@@ -2633,7 +2684,7 @@ class LLMServer:
             }
         finally:
             with self._lock:
-                self._requests.pop(rid, None)
+                self._retire(rid)
 
     def generate_stream(
         self,
@@ -2691,7 +2742,7 @@ class LLMServer:
                     ) from None
                 if item is _STREAM_END:
                     break
-                yield item
+                yield state.take(item)
             if state.error is not None:
                 raise state.error
             if (
@@ -2709,31 +2760,85 @@ class LLMServer:
             # pool returns to steady state now. A finished request is no
             # longer active, so the abort is a no-op on the normal path.
             with self._lock:
-                self._requests.pop(rid, None)
+                self._retire(rid)
                 self._engine.abort(rid)
+
+    def _retire(self, rid: str) -> None:
+        """Drop a request's state, its hand-over clock kept. Caller holds
+        the lock."""
+        state = self._requests.pop(rid, None)
+        if state is not None:
+            self._handoff_retired_s += state.handoff_s
+            self._handoff_retired_tokens += state.handoff_tokens
 
     def abort(self, request_id: str) -> bool:
         with self._lock:
             return self._engine.abort(request_id)
 
     def metrics(self) -> dict:
+        delivery = self._stream_delivery()
         with self._lock:
-            return self._server_stats()
+            return self._server_stats(delivery)
 
-    def _server_stats(self) -> dict:
-        """The engine's stats and what only its server knows. Caller holds
-        the lock. The two stream counts are the hosting runtime's, of every
-        streaming generator in the process (`Runtime.report_stream_item`):
-        how many items were reported, and how many travelled with their refs
-        and not through the object store."""
+    @staticmethod
+    def _stream_delivery() -> dict:
+        """`Runtime.stream_delivery()` of the hosting runtime; no group in
+        a worker process, whose runtime proxy registers no stream. Read
+        before the server's lock is taken: while a snapshot holds it the
+        step thread stands still and the streams drain, so a backlog read
+        under it is the backlog's floor."""
+        from ray_tpu._private import runtime as runtime_mod
+
+        return getattr(runtime_mod._RUNTIME, "stream_delivery", dict)()
+
+    def _server_stats(self, groups: dict) -> dict:
+        """The engine's stats and what only its server knows; `groups` is
+        `_stream_delivery()`. Caller holds the lock. The two stream counts
+        are the hosting runtime's, of every streaming generator in the
+        process (`Runtime.report_stream_item`): how many items were
+        reported, and how many travelled with their refs and not through
+        the object store.
+
+        The way out, hand-over by hand-over (totals, flat, for a window's
+        difference): `egress_handoff_*`, commit -> the thread that called
+        `generate` or drives `generate_stream`, over live and retired
+        requests (0 with `instrument` off); `engine_stream_*`, this class's
+        `generate_stream` items from that thread to whoever iterates the
+        stream (under Serve, the replica's thread), and `stream_*`, the
+        same over every streaming generator of the process (under Serve:
+        that hop and replica -> proxy), both from
+        `Runtime.stream_delivery`; `egress_backlog_tokens`, a gauge: tokens
+        committed and not yet taken by the last consumer in this process,
+        wherever they wait (request queues and every live stream)."""
         from ray_tpu._private import runtime as runtime_mod
 
         stats = self._engine.stats()
         stats["wedged"] = self._wedged
         stats["consecutive_step_failures"] = self._consecutive_step_failures
+        runtime = runtime_mod._RUNTIME
         for key in ("stream_items_reported", "stream_items_inline"):
             # 0 in a worker process or with no runtime: nothing is inline there.
-            stats[key] = getattr(runtime_mod._RUNTIME, key, 0)
+            stats[key] = getattr(runtime, key, 0)
+        handoff_s = self._handoff_retired_s
+        handoff_tokens = self._handoff_retired_tokens
+        backlog = 0
+        for state in self._requests.values():
+            handoff_s += state.handoff_s
+            handoff_tokens += state.handoff_tokens
+            backlog += state.offered - state.handoff_tokens
+        stats["egress_handoff_s"] = handoff_s
+        stats["egress_handoff_tokens"] = handoff_tokens
+        own = groups.get(f"{type(self).__name__}.generate_stream", {})
+        stats["engine_stream_wait_s"] = own.get("wait_s", 0.0)
+        stats["engine_stream_items_taken"] = own.get("items_taken", 0)
+        stats["stream_wait_s"] = sum(g["wait_s"] for g in groups.values())
+        stats["stream_items_taken"] = sum(
+            g["items_taken"] for g in groups.values()
+        )
+        stats["egress_backlog_tokens"] = backlog + sum(
+            g["items_offered"] - g["items_taken"] - g["items_dropped"]
+            for g in groups.values()
+        )
         return stats
 
     def autoscaling_snapshot(self) -> dict:
@@ -2791,10 +2896,11 @@ class LLMServer:
         (the dashboard /api/llm panel polls this; three separate RPCs per
         engine per refresh would triple the scrape's exposure to a busy
         engine's lock)."""
+        delivery = self._stream_delivery()
         with self._lock:
             e = self._engine
             return {
-                "metrics": self._server_stats(),
+                "metrics": self._server_stats(delivery),
                 "dead_letters": e.dead_letters(),
                 "shed_requests": e.shed_requests(),
                 "flight_record": e.flight_recorder.snapshot(steps_limit),

@@ -29,8 +29,13 @@ Two pieces the engine hooks into (gated by EngineConfig.instrument):
     one perf_counter reading, and the same boundaries open and close
     `jax.profiler.TraceAnnotation`s (`llm.step`, `llm.step.<phase>`) so a
     profiler session shows the host phases beside the device's `XLA Ops`.
-    Beside the partition it keeps `host_exposed`: host time during which
-    the device had no program queued.
+    Around `prepare`, the phase that paces a host-bound step, it also
+    reads the step thread's own CPU clock: the seconds of `prepare` the
+    thread ran, and by difference the seconds it was off the CPU, waiting
+    for the interpreter or blocked in a call that released it. Beside the
+    partition it keeps `host_exposed`: host time during which the device
+    had no program queued; and it counts the steps that held the thread
+    over STALL_SECONDS outside `wait`.
 
   * CompileClock — process-wide totals of JAX's own compile events
     (tracing, lowering, the compile step, persistent-cache misses), so
@@ -43,16 +48,20 @@ output token, e2e — as the primary serving SLO metrics).
 
 from __future__ import annotations
 
+import gc
+import logging
 import threading
 import time
 import uuid
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 import jax.monitoring
 from jax.profiler import TraceAnnotation
 
 from ray_tpu.util import tracing
+
+logger = logging.getLogger(__name__)
 
 # Bucket rationale: requests cover ~1 ms (cache-hit prefill of a short
 # prompt on warm programs) to minutes (long decode under preemption), so
@@ -107,6 +116,7 @@ class RequestTrace:
         "prefills",
         "preempts",
         "error",
+        "egress",
     )
 
     def __init__(self, request_id: str, parent_ctx: Optional[tuple]):
@@ -127,6 +137,9 @@ class RequestTrace:
         self.prefills = 0
         self.preempts = 0
         self.error: Optional[str] = None
+        # Whoever hands this request's tokens on (LLMServer's request
+        # state): its `handoff_s` / `handoff_max_s` close the root span.
+        self.egress = None
 
     def _emit(self, name, start_s, end_s, attributes=None) -> None:
         tracing.emit_span(
@@ -220,6 +233,11 @@ class RequestTrace:
             attrs["ttft_s"] = self.first_token_s - self.submit_s
         if self.error is not None:
             attrs["error"] = self.error
+        if self.egress is not None:
+            # Commit -> the thread that streams the request, over the
+            # tokens taken so far (the last ones are taken after this).
+            attrs["handoff_s"] = self.egress.handoff_s
+            attrs["handoff_max_s"] = self.egress.handoff_max_s
         tracing.emit_span(
             "llm.request",
             self.submit_s,
@@ -238,11 +256,20 @@ class RequestTrace:
 # record), so the six sum to the wall time from one entry to the next.
 STEP_PHASES = ("schedule", "prepare", "wait", "commit", "other", "between")
 _ANNOTATED_PHASES = frozenset(("schedule", "prepare", "wait", "commit"))
+# A step that holds the step thread this long outside `wait` is a stall:
+# fifty to a hundred times a decode step's host work, and under the two
+# that are known (a full collection of the heap warm-up leaves, over a
+# second; one of 1.3 to 3.6 s with no collection in it).
+STALL_SECONDS = 0.25
+
+
+def _full_collections() -> int:
+    return gc.get_stats()[2]["collections"]
 
 
 class StepPhaseClock:
-    """Partition of the step loop's wall time, and the device's idle window
-    as the host sees it.
+    """Partition of the step loop's wall time, how much of `prepare` the
+    step thread ran, and the device's idle window as the host sees it.
 
     At every moment exactly one phase is current; `switch` reads
     `perf_counter` once, charges the time since the previous reading to the
@@ -251,6 +278,17 @@ class StepPhaseClock:
     None and nothing accumulates. Single writer: the thread that steps the
     engine.
 
+    Where `prepare` opens and where it closes, `switch` also reads the
+    calling thread's CPU clock (`thread_time`): `prepare_cpu` is what the
+    thread ran of `prepare`, and the rest of the phase it was off the CPU,
+    waiting for the interpreter behind another thread or blocked in a
+    call that released it. Only `prepare`, and not every boundary: on a
+    sandboxed host (gVisor, where the serving cells run) a reading of
+    that clock is a trapped system call of 6 to 11 us where
+    `perf_counter` is 0.09, and eight a step cost a 3 ms step 2 to 3%
+    (PERF.md, PR 37); the clock also advances in 10 ms ticks there, so
+    the seconds are a 100 Hz sample: sound over a window, not for a step.
+
     `host_exposed` is sampled where a program's dispatch call returns: the
     time since the device last had nothing left to run, as far as the host
     can know: the newest program it dispatched had become host-readable
@@ -258,29 +296,53 @@ class StepPhaseClock:
     dispatch made while a program is still out, as a chained async decode
     is, samples 0. At pipeline depth 0 the samples add up to the
     non-wait phases.
+
+    A stall is judged where a step returns, over the stretch since the
+    step before it returned (the `between` in front of a step counts as
+    the step's): more than STALL_SECONDS of it outside `wait` adds one to
+    `stall_steps` and hands `on_stall` the stretch by phase, what the
+    thread ran of its `prepare`, and whether a collection of the oldest
+    generation ran in it.
     """
 
-    def __init__(self):
+    def __init__(self, on_stall: Optional[Callable[[dict], None]] = None):
         self.totals: Dict[str, float] = dict.fromkeys(STEP_PHASES, 0.0)
+        self.prepare_cpu = 0.0  # seconds of `prepare` the step thread ran
         self.dispatch_steps = 0  # steps that dispatched a program
         self.dispatches = 0
         self.exposed_total = 0.0
         self.exposed_samples = 0
+        self.stall_steps = 0
+        self._on_stall = on_stall
         self._phase: Optional[str] = None
         self._t = 0.0
+        self._cpu = 0.0  # the thread's CPU clock where `prepare` opened
         self._annotation: Optional[TraceAnnotation] = None
         self._step_annotation: Optional[TraceAnnotation] = None
+        self._index = 0
+        self._batch = 0
         self._entry_t = 0.0
         self._entry_totals = self.totals
+        self._entry_prepare_cpu = 0.0
         self._dispatches_at_entry = 0
         self._ready_seq = 0  # newest dispatch known to have finished
         self._idle_since: Optional[float] = None
+        # Where the stretch a stall is judged over began, the step before's
+        # return: `between`'s total and the full collections then.
+        self._mark_between = 0.0
+        self._mark_collections = 0
 
     def switch(self, phase: Optional[str]) -> float:
         """Close the current phase and open `phase`; returns the reading."""
         now = time.perf_counter()
         if self._phase is not None:
             self.totals[self._phase] += now - self._t
+            if self._phase == "prepare":
+                cpu = time.thread_time()
+                self.prepare_cpu += cpu - self._cpu
+                self._cpu = cpu  # `prepare` may open again at once
+        if phase == "prepare" and self._phase != "prepare":
+            self._cpu = time.thread_time()
         if self._annotation is not None:
             self._annotation.__exit__(None, None, None)
             self._annotation = None
@@ -296,14 +358,20 @@ class StepPhaseClock:
             # The last step raised: close what it left open. The time
             # since then stays with the phase it was in.
             self.exit_step(live=True)
+        if self._phase is None:
+            # Out of an idle stretch, whose collections are no step's.
+            self._mark_collections = _full_collections()
+        self._index = index
+        self._batch = 0
         self._step_annotation = TraceAnnotation("llm.step", step=index)
         self._step_annotation.__enter__()
         self._entry_t = self.switch("schedule")
         self._entry_totals = dict(self.totals)
+        self._entry_prepare_cpu = self.prepare_cpu
         self._dispatches_at_entry = self.dispatches
 
     def exit_step(self, live: bool) -> None:
-        self.switch("between" if live else None)
+        now = self.switch("between" if live else None)
         if self.dispatches > self._dispatches_at_entry:
             self.dispatch_steps += 1
         if not live:
@@ -312,6 +380,41 @@ class StepPhaseClock:
         if self._step_annotation is not None:
             self._step_annotation.__exit__(None, None, None)
             self._step_annotation = None
+        self._judge_stall(now)
+
+    def _judge_stall(self, now: float) -> None:
+        """At a step's return: the stretch is the `between` that ran up to
+        the step's entry (nothing is added to it inside a step) and the
+        step itself."""
+        totals, entry = self.totals, self._entry_totals
+        between = totals["between"] - self._mark_between
+        held = now - self._entry_t + between - (totals["wait"] - entry["wait"])
+        collections = _full_collections()
+        if held > STALL_SECONDS:
+            self.stall_steps += 1
+            if self._on_stall is not None:
+                phases = {
+                    phase: round(totals[phase] - entry[phase], 6)
+                    for phase in STEP_PHASES
+                }
+                phases["between"] = round(between, 6)
+                self._on_stall(
+                    {
+                        "step": self._index,
+                        "batch_size": self._batch,
+                        "held_s": round(held, 6),
+                        "phases": phases,
+                        "prepare_cpu_s": round(
+                            self.prepare_cpu - self._entry_prepare_cpu, 6
+                        ),
+                        "full_collection": (
+                            collections != self._mark_collections
+                        ),
+                        "time": time.time(),
+                    }
+                )
+        self._mark_between = totals["between"]
+        self._mark_collections = collections
 
     def dispatched(self) -> None:
         """A program's dispatch call returned (GPTRunner's hook): prepare
@@ -341,7 +444,8 @@ class StepPhaseClock:
 
     def describe_decode(self, batch: int, context_tokens: int) -> None:
         """What the decode dispatch of this step asks the paged kernel to
-        read, on the step's annotation."""
+        read, on the step's annotation (the batch on a stall's record too)."""
+        self._batch = batch
         if self._step_annotation is not None:
             self._step_annotation.set_metadata(
                 batch=batch, context_tokens=context_tokens
@@ -349,10 +453,14 @@ class StepPhaseClock:
 
     def step_record(self) -> dict:
         """Seconds of the current step so far, by phase: the flight
-        record's `duration_s` and `phases`, which sum to it."""
+        record's `duration_s` and `phases`, which sum to it, and
+        `prepare_cpu_s`, the seconds of its `prepare` the step thread ran."""
         now = self.switch(self._phase)
         return {
             "duration_s": round(now - self._entry_t, 6),
+            "prepare_cpu_s": round(
+                self.prepare_cpu - self._entry_prepare_cpu, 6
+            ),
             "phases": {
                 phase: round(self.totals[phase] - self._entry_totals[phase], 6)
                 for phase in STEP_PHASES
@@ -362,8 +470,13 @@ class StepPhaseClock:
 
     def stats(self) -> dict:
         out = {f"step_{phase}_s": s for phase, s in self.totals.items()}
+        out["step_prepare_cpu_s"] = self.prepare_cpu
+        # A total, like the others, so that a reader divides a window's
+        # difference of it: prepare's seconds off the CPU.
+        out["step_prepare_offcpu_s"] = self.totals["prepare"] - self.prepare_cpu
         out["dispatch_steps"] = self.dispatch_steps
         out["host_exposed_total_s"] = self.exposed_total
+        out["stall_steps"] = self.stall_steps
         return out
 
 
@@ -482,9 +595,28 @@ class FlightRecorder:
         # operator will be asked to explain after the fact.
         self.sheds: deque = deque(maxlen=128)
         self.expiries: deque = deque(maxlen=128)
+        # Steps that held the step thread over STALL_SECONDS outside
+        # `wait` (StepPhaseClock): few, and each one is asked after.
+        self.stalls: deque = deque(maxlen=32)
 
     def record_step(self, record: dict) -> None:
         self.steps.append(record)
+
+    def record_stall(self, record: dict) -> None:
+        """One stalled step, as StepPhaseClock judged it: the stretch from
+        the step before's return to this one's by phase, the seconds of
+        its `prepare` the step thread ran, the decode batch, and whether a
+        collection of the oldest generation ran in it. Logged too: a
+        stall is rare, and whoever reads the log has no flight record."""
+        self.stalls.append(record)
+        logger.warning(
+            "llm step %d held the step thread %.3f s outside wait "
+            "(batch %d, full collection %s): %s, of prepare the thread "
+            "ran %.3f s",
+            record["step"], record["held_s"], record["batch_size"],
+            record["full_collection"], record["phases"],
+            record["prepare_cpu_s"],
+        )
 
     def record_compile(
         self,
@@ -584,4 +716,5 @@ class FlightRecorder:
             "failures": list(self.failures),
             "sheds": list(self.sheds),
             "expiries": list(self.expiries),
+            "stalls": list(self.stalls),
         }

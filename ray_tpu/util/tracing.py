@@ -135,7 +135,10 @@ class SpanBuffer:
     """Process-local bounded store of finished user spans."""
 
     def __init__(self, capacity: int = 10_000):
-        self._lock = threading.Lock()
+        # Reentrant: a span is also emitted from a finalizer (a stream
+        # whose generator was dropped retires there), and a collection can
+        # run one inside any allocation, this lock's holder's included.
+        self._lock = threading.RLock()
         self._spans: List[Span] = []
         self._capacity = capacity
 

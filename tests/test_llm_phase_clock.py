@@ -9,12 +9,23 @@
   * with instrument=False the decode loop reads no clock at all;
   * a jax.profiler session holds `llm.step*` annotations on a host plane;
   * set-up is on the same footing: warm-up and JAX's compile events in
-    stats(), and the split on every warm-up round of the flight record.
+    stats(), and the split on every warm-up round of the flight record;
+  * around `prepare` the step thread's own CPU clock beside the wall's:
+    prepare's CPU seconds never pass its wall seconds, are the thread's
+    `thread_time` over its prepare stretches and no other phase's, and
+    the clock is read nowhere else; threads that spin on the interpreter
+    beside it grow `step_prepare_offcpu_s` and not `step_prepare_cpu_s`; a
+    block in `wait` is not prepare's;
+  * a step that holds the thread over 0.25 s outside `wait` is counted in
+    `stall_steps` and kept in the flight record's `stalls`; a long `wait`
+    is not.
 """
 
+import gc
 import os
 import re
 import sys
+import threading
 import time
 import types
 
@@ -27,7 +38,11 @@ from ray_tpu.llm import EngineConfig, LLMEngine
 from ray_tpu.llm import engine as engine_module
 from ray_tpu.llm import observability as observability_module
 from ray_tpu.llm.engine import LLMServer
-from ray_tpu.llm.observability import STEP_PHASES
+from ray_tpu.llm.observability import (
+    STALL_SECONDS,
+    STEP_PHASES,
+    StepPhaseClock,
+)
 from ray_tpu.models.gpt import GPTConfig
 
 TINY = GPTConfig(
@@ -440,3 +455,196 @@ def test_a_step_that_raises_is_closed_by_the_next_entry():
     assert all(seconds >= 0.0 for seconds in spent.values())
     assert sum(spent.values()) <= wall
     assert eng.stats()["inflight_steps"] == 0
+
+
+@pytest.mark.parametrize("mode", (False, True), ids=("sync", "async"))
+def test_prepares_cpu_seconds_stay_under_its_wall_seconds(mode, monkeypatch):
+    eng = LLMEngine(TINY, EngineConfig(async_scheduling=mode, **BASE), seed=0)
+    prompts = ([5, 9, 11, 3, 7], [8, 2, 4, 6, 1, 3, 9, 9, 2, 5, 7])
+    eng.generate(list(prompts), max_new_tokens=3)  # compiles every shape
+    before = eng.stats()
+    for prompt in prompts:
+        eng.add_request(list(prompt), max_new_tokens=60)
+    # The CPU clock is a system call, and a dear one on a sandboxed host:
+    # it is read where prepare opens and where it closes, nowhere else.
+    reads = []
+    clock = eng._clock
+
+    def counted():
+        reads.append(clock._phase)
+        return time.thread_time()
+
+    monkeypatch.setattr(
+        observability_module,
+        "time",
+        types.SimpleNamespace(
+            perf_counter=time.perf_counter,
+            time=time.time,
+            thread_time=counted,
+        ),
+    )
+    dispatches = clock.dispatches
+    cpu0 = time.thread_time()
+    run_to_idle(eng)
+    thread_cpu = time.thread_time() - cpu0
+    monkeypatch.undo()
+    after = eng.stats()
+    steps = after["steps"] - before["steps"]
+    wall = window(before, after, *PHASE_KEYS)
+    cpu = after["step_prepare_cpu_s"] - before["step_prepare_cpu_s"]
+    # The two clocks are read one after the other at a boundary, and what
+    # runs between the readings (a young collection, at worst) is prepare's
+    # CPU and the next phase's wall.
+    assert 0.0 < cpu <= wall["step_prepare_s"] + 1e-3
+    assert cpu <= thread_cpu
+    # Read in pairs: once as prepare opens (from another phase), once as
+    # it closes; a step has one prepare stretch a program it dispatches.
+    opened = [phase for phase in reads if phase != "prepare"]
+    closed = [phase for phase in reads if phase == "prepare"]
+    assert steps - 2 <= len(opened) == len(closed)
+    assert len(closed) <= clock.dispatches - dispatches + 2
+    offcpu = after["step_prepare_offcpu_s"] - before["step_prepare_offcpu_s"]
+    assert offcpu == pytest.approx(wall["step_prepare_s"] - cpu, abs=1e-9)
+    assert [key for key in after if key.endswith("_cpu_s")] == [
+        "step_prepare_cpu_s"
+    ]
+    assert after["stall_steps"] == before["stall_steps"]
+    for record in eng.flight_recorder.snapshot()["steps"]:
+        assert (
+            0.0
+            <= record["prepare_cpu_s"]
+            <= record["phases"]["prepare"] + 1e-3
+        )
+
+
+def _prepare_stretch(clock: StepPhaseClock, loops: int) -> None:
+    """One step of fixed interpreter work in `prepare` and a block in
+    `wait`, as a decode step has them."""
+    clock.enter_step(0)
+    clock.switch("prepare")
+    total = 0
+    for i in range(loops):
+        total += i * i
+    clock.dispatched()
+    time.sleep(0.05)
+    clock.ready()
+    clock.exit_step(live=False)
+
+
+def test_threads_that_spin_beside_it_grow_prepares_offcpu_not_its_cpu():
+    """The step thread's wait for the interpreter, told from its own work:
+    the same loop costs the same CPU seconds alone and beside three threads
+    that never let go of the interpreter, and the wall seconds it loses to
+    them are `step_prepare_offcpu_s`. The block in `wait` is in neither."""
+    loops = 400_000
+    alone = StepPhaseClock()
+    _prepare_stretch(alone, loops)
+    stop = threading.Event()
+
+    def spin():
+        while not stop.is_set():
+            sum(range(1000))
+
+    spinners = [threading.Thread(target=spin) for _ in range(3)]
+    for thread in spinners:
+        thread.start()
+    crowded = StepPhaseClock()
+    try:
+        _prepare_stretch(crowded, loops)
+    finally:
+        stop.set()
+        for thread in spinners:
+            thread.join(timeout=30)
+    assert not any(thread.is_alive() for thread in spinners)
+    quiet, busy = alone.stats(), crowded.stats()
+    assert busy["step_prepare_cpu_s"] < 2.0 * quiet["step_prepare_cpu_s"] + 0.01
+    assert busy["step_prepare_offcpu_s"] > 3.0 * quiet["step_prepare_offcpu_s"]
+    assert busy["step_prepare_offcpu_s"] > 0.5 * busy["step_prepare_cpu_s"]
+    for stats in (quiet, busy):
+        # The sleep is `wait`'s, and not prepare's.
+        assert stats["step_wait_s"] >= 0.05
+        assert stats["step_prepare_offcpu_s"] == pytest.approx(
+            stats["step_prepare_s"] - stats["step_prepare_cpu_s"]
+        )
+    assert quiet["step_prepare_offcpu_s"] < 0.05 <= quiet["step_wait_s"]
+
+
+def _primed_engine():
+    """An engine one prefill into a request, decode steps from here, with
+    the stalls its first steps may have had (a step that compiles a program
+    is one) out of the record."""
+    eng = LLMEngine(TINY, EngineConfig(async_scheduling=False, **BASE), seed=0)
+    eng.generate([[5, 9, 11, 3, 7]], max_new_tokens=3)
+    eng.add_request([5, 9, 11, 3, 7], max_new_tokens=6)
+    eng.step()
+    eng._clock.stall_steps = 0
+    eng.flight_recorder.stalls.clear()
+    return eng
+
+
+def test_a_step_held_outside_wait_is_a_stall_with_its_phases():
+    eng = _primed_engine()
+    decode = eng.runner.decode
+    hold = STALL_SECONDS + 0.1
+
+    def slow_prepare(*args, **kwargs):
+        eng.runner.decode = decode
+        time.sleep(hold)  # before the dispatch: prepare's
+        return decode(*args, **kwargs)
+
+    assert eng.stats()["stall_steps"] == 0
+    stalled_step = eng.stats()["steps"]
+    eng.runner.decode = slow_prepare
+    gc.disable()  # no collection of its own accord in the stalled step
+    try:
+        eng.step()
+    finally:
+        gc.enable()
+    run_to_idle(eng)
+    assert eng.stats()["stall_steps"] == 1
+    (stall,) = eng.flight_recorder.snapshot()["stalls"]
+    assert stall["step"] == stalled_step
+    assert stall["batch_size"] == 1
+    assert set(stall["phases"]) == set(STEP_PHASES)
+    assert stall["phases"]["prepare"] >= hold
+    assert stall["held_s"] == pytest.approx(
+        sum(stall["phases"].values()) - stall["phases"]["wait"], abs=1e-5
+    )
+    # It slept: the thread was off the CPU, and no collection explains it.
+    assert stall["prepare_cpu_s"] < 0.5 * stall["phases"]["prepare"]
+    assert stall["full_collection"] is False
+
+
+def test_a_stall_says_whether_a_full_collection_ran_in_it():
+    eng = _primed_engine()
+    decode = eng.runner.decode
+
+    def collecting_prepare(*args, **kwargs):
+        eng.runner.decode = decode
+        gc.collect()
+        time.sleep(STALL_SECONDS + 0.05)
+        return decode(*args, **kwargs)
+
+    eng.runner.decode = collecting_prepare
+    run_to_idle(eng)
+    (stall,) = eng.flight_recorder.snapshot()["stalls"]
+    assert stall["full_collection"] is True
+
+
+def test_a_long_wait_is_not_a_stall():
+    eng = _primed_engine()
+    dispatched = eng.runner.on_dispatched
+
+    def slow_device():
+        dispatched()
+        time.sleep(STALL_SECONDS + 0.1)  # after the dispatch: wait's
+
+    before = eng.stats()
+    eng.runner.on_dispatched = slow_device
+    eng.step()
+    eng.runner.on_dispatched = dispatched
+    run_to_idle(eng)
+    after = eng.stats()
+    assert after["step_wait_s"] - before["step_wait_s"] >= STALL_SECONDS + 0.1
+    assert after["stall_steps"] == 0
+    assert eng.flight_recorder.snapshot()["stalls"] == []
