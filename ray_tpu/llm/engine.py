@@ -2048,18 +2048,7 @@ class LLMEngine:
             "chained_decode_dispatches": self._chained_dispatches,
             "pipeline_flushes": sum(self._pipeline_flushes.values()),
             "pipeline_flushes_by_cause": dict(self._pipeline_flushes),
-            "attention_shape": (
-                self.runner.attention_shape()
-                if hasattr(self.runner, "attention_shape")
-                else {
-                    "num_layers": self.model_config.num_layers,
-                    "num_heads": self.model_config.num_heads,
-                    "head_dim": self.model_config.head_dim,
-                    "kv_itemsize": np.dtype(
-                        self.runner.kv_cache_dtype
-                    ).itemsize,
-                }
-            ),
+            "attention_shape": self.runner.attention_shape(),
             # Whether a cached prefix can be shared on this model (not
             # with recurrent layers: nothing snapshots their state at a
             # block boundary), and, for such a model, its state slots,

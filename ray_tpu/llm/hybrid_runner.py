@@ -53,7 +53,7 @@ import numpy as np
 from ray_tpu._private.jax_setup import ensure_compile_cache
 from ray_tpu.llm.cache import kv_pool_bytes_sharded
 from ray_tpu.llm.config import EngineConfig
-from ray_tpu.llm.model_runner import bytes_by_device
+from ray_tpu.llm.model_runner import bytes_by_device, prefill_tiling
 from ray_tpu.ops.paged_flash import paged_attention_impl, resolve_paged_impl
 
 # The routing counts a decode step appends to its tokens, in this order.
@@ -430,21 +430,28 @@ class HybridRunner:
         }
 
     def attention_shape(self) -> dict:
-        """The K/V pools as the paged kernel reads them: of the one cache
-        class, or by class name where the model has several."""
+        """The K/V pools as the paged kernel reads them and how it tiles a
+        chunk: of the one cache class, or by class name where the model
+        has several."""
         cfg = self.model_config
         kinds = {cfg.cache_class_of(kind): kind for kind in cfg.layer_types if kind != MAMBA}
-        shapes = {
-            cls.name: {
+        shapes = {}
+        for i, cls in enumerate(self.classes):
+            if i not in kinds:
+                continue
+            query_heads = max(cfg.heads_of(kinds[i]), default=0)
+            shapes[cls.name] = {
                 "num_layers": cls.layers,
                 "num_heads": cfg.num_key_value_heads,
                 "head_dim": cfg.head_dim,
                 "kv_itemsize": np.dtype(self.kv_cache_dtype).itemsize,
-                "num_query_heads": max(cfg.heads_of(kinds[i]), default=0),
+                "num_query_heads": query_heads,
                 **({} if cls.horizon is None else {"horizon": cls.horizon}),
+                **prefill_tiling(
+                    self.engine_config, query_heads, cfg.num_key_value_heads,
+                    cfg.head_dim, cfg.dtype, self.kv_cache_dtype,
+                ),
             }
-            for i, cls in enumerate(self.classes) if i in kinds
-        }
         return shapes if len(self.classes) > 1 else next(iter(shapes.values()))
 
     def stats(self) -> dict:

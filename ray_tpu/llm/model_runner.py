@@ -65,6 +65,7 @@ from ray_tpu.models.gpt import (
 from ray_tpu.ops.attention import validate_tp_heads
 from ray_tpu.ops.paged_flash import (
     KV_SCALE_DTYPE,
+    q_tile,
     quantize_kv,
     resolve_paged_impl,
 )
@@ -407,6 +408,25 @@ def bytes_by_device(arrays) -> dict:
     return out
 
 
+def prefill_tiling(
+    ecfg: EngineConfig, heads: int, kv_heads: int, head_dim: int, dtype,
+    kv_dtype,
+) -> dict:
+    """How the paged kernel tiles the widest chunk the engine warms, from
+    the function the kernel asks: fed tokens a q tile, and the rows of one
+    product (the q tiles of the query heads a cached head serves, stacked;
+    one q tile where every query head has its own cached head)."""
+    tq = q_tile(
+        max(ecfg.chunk_widths()), heads, kv_heads, head_dim,
+        np.dtype(dtype).itemsize, ecfg.block_size, ecfg.max_blocks_per_seq,
+        np.dtype(kv_dtype).itemsize,
+    )
+    return {
+        "prefill_q_tile": tq,
+        "prefill_rows_per_product": heads // kv_heads * tq,
+    }
+
+
 def build_runner(model_config, engine_config: EngineConfig, params=None,
                  seed: int = 0):
     """The runner of `model_config`'s type: the class its `llm_runner`
@@ -618,6 +638,22 @@ class GPTRunner:
         if self.mesh is None:
             return None
         return str(self.k_cache.sharding.spec)
+
+    def attention_shape(self) -> dict:
+        """The K/V pools as the paged kernel reads them, and how it tiles
+        a chunk (a chip's own heads under tensor parallelism)."""
+        cfg = self.model_config
+        local_heads = cfg.num_heads // self.tensor_parallel_size
+        return {
+            "num_layers": cfg.num_layers,
+            "num_heads": cfg.num_heads,
+            "head_dim": cfg.head_dim,
+            "kv_itemsize": np.dtype(self.kv_cache_dtype).itemsize,
+            **prefill_tiling(
+                self.engine_config, local_heads, local_heads, cfg.head_dim,
+                cfg.dtype, self.kv_cache_dtype,
+            ),
+        }
 
     def kv_pool_bytes(self) -> dict:
         """Aggregate and per-shard bytes of both KV pools (+ scale tensors

@@ -36,10 +36,12 @@ Covers both program shapes ray_tpu.llm compiles, with the contraction
 chosen by shape. Decode (S == 1) lays q block-diagonally, [H, H*D], so one
 MXU product against the tile scores every head and one more weights V.
 Prefix-aware partial prefill and verify (S > 1: the fed tokens attend the
-cached prefix through the table and themselves causally) take one
-[tq, D] x [D, 128] product a head, heads walked a lane tile at a time in a
-loop the program traces once. `ops.paged_attention` is the correctness
-oracle; interpret mode on CPU runs the same code path in tests.
+cached prefix through the table and themselves causally) take one product a
+cached head: the q tiles of the query heads it serves stacked along the
+rows, [heads / kv_heads * tq, D] x [D, 128] (one head's [tq, D] where every
+query head has its own cached head), cached heads walked a lane tile at a
+time in a loop the program traces once. `ops.paged_attention` is the
+correctness oracle; interpret mode on CPU runs the same code path in tests.
 
 int8 KV cache rides on top: the cache pools store int8 with per-token,
 per-head scales (written by `quantize_kv` at scatter time — per-token
@@ -80,10 +82,13 @@ from ray_tpu.ops.flash_attention import _on_cpu
 _LANES = 128  # TPU lane width: min trailing dim for scratch tiles
 # VMEM one q tile's blocks and the cached K and V tiles may take together.
 # The compiler's default scoped limit on v5e is 16 MiB; the rest is left to
-# the kernel's own temporaries (float32 at "highest" matmul precision took
-# 4.4 MiB of them at 20 heads, measured on the chip). At 20 heads of 64 the
-# two-deep bf16 tiles are 1.25 MiB and a 128-token q tile the other 8.75.
-_Q_TILE_VMEM_BYTES = 10 * 1024 * 1024
+# the kernel's own temporaries. With the heads walked in a loop those are
+# one product's score and weight blocks, under 0.5 MiB: the call at 72
+# query heads over 8 cached heads of 128, whose blocks count 12.7 MiB here,
+# compiles for a v5e under a scoped limit of 13.5 MiB and not under 13
+# (chip-less, PR 38). At 20 heads of 64 the two-deep bf16 tiles are
+# 1.25 MiB and a 128-token q tile 8.75.
+_Q_TILE_VMEM_BYTES = 13 * 1024 * 1024
 
 # Storage dtype for the KV-cache scale tensors. bf16 keeps the scale
 # overhead at 2 bytes per (token, head) — f32 scales at block_size=8 would
@@ -117,17 +122,23 @@ def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 def _online_update(s, stats, weigh):
     """One streaming-softmax step: fold the score block `s` ([rows, cols])
     into the running (max, sum, accumulator) scratch `stats`. `weigh(p)`
-    returns the softmax weights' product with the block's value rows."""
+    returns the softmax weights' product with the block's value rows.
+    The scratch holds the same rows, [rows, ...] or split by query head
+    [heads, rows // heads, ...]: whole sublane tiles either way."""
     m_scr, l_scr, acc_scr = stats
-    m_prev = m_scr[:, 0:1]
+    rows = s.shape[0]
+    m_prev = m_scr[..., 0:1].reshape(rows, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     # Masked lanes hold NEG_INF: exp underflows to exactly 0.
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_new = l_scr[:, 0:1] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + weigh(p)
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+    l_new = l_scr[..., 0:1].reshape(rows, 1) * alpha + jnp.sum(
+        p, axis=1, keepdims=True
+    )
+    acc = acc_scr[...].reshape(rows, -1) * alpha + weigh(p)
+    acc_scr[...] = acc.reshape(acc_scr.shape)
+    for scr, new in ((m_scr, m_new), (l_scr, l_new)):
+        scr[...] = jnp.broadcast_to(new, (rows, scr.shape[-1])).reshape(scr.shape)
 
 
 def _dot(a, b, contract):
@@ -188,18 +199,24 @@ def _paged_kernel(
     of the accumulator are dropped at the end. Otherwise q / new-token K/V
     / out blocks are heads-leading [1, H, tq, D] (Mosaic tiles the last two
     dims, so a per-head [tq, D] view must not have the head dim between
-    them) and head h is a lane slice of the tile: one [tq, D] x [D, tokens]
-    product a head. int8 scales come gathered, [compute blocks, H, tokens]:
-    the layout the scores have.
+    them) and cached head h is a lane slice of the tile: one
+    [rows, D] x [D, tokens] product, one streaming-softmax step and one
+    weighted-V product a cached head. int8 scales come gathered,
+    [compute blocks, H, tokens]: the layout the scores have.
 
     Grouped-query attention (`kv_heads` < `heads`): the tile, the new K/V
     and the lanes hold `kv_heads` heads, each read by `heads // kv_heads`
     consecutive query heads. Decode's q then arrives already laid
     block-diagonally, [H, kv_heads * D] with row h in the lanes of cached
     head h // group, and its out leaves in that form (the caller keeps each
-    row's own lanes); elsewhere query head h reads lanes and new-token rows
-    of cached head h // group. With kv_heads == heads every branch below
-    is the one it was.
+    row's own lanes). Elsewhere the q tiles of a cached head's query heads
+    are consecutive in the heads-leading blocks and scratch, so they are
+    read, folded and written as one [heads // kv_heads * tq, ...] slab: the
+    rows of one product (a reshape of whole sublane tiles; tq is a multiple
+    of the dtype's sublane tile for every chunk width a grouped model
+    warms), each row masked by its own token's position. With
+    kv_heads == heads the slab is one head's [tq, ...] and every branch
+    below is the one it was.
 
     `window`: the query at position p sees the keys at p - window < j <= p
     (a fed token's position is context_lens[b] + its index). The walk of a
@@ -246,10 +263,24 @@ def _paged_kernel(
         group = 1
     shared = heads // kv_heads  # query heads a cached head serves
 
+    # A fed chunk's unit of work is a cached head: its `shared` query heads'
+    # q tiles stacked along the rows of one product, [shared * tq, D].
+    def of_head(h):
+        """Where cached head h's query heads lie on a heads-leading axis."""
+        return h if shared == 1 else pl.ds(h * shared, shared)
+
+    def stacked(x):  # [shared, tq, n] -> [shared * tq, n]; [tq, n] as it is
+        return x.reshape(-1, x.shape[-1])
+
+    def under_each_head(x):  # [tq, n] -> [shared * tq, n]: x a query head
+        if shared == 1:
+            return x
+        return stacked(jnp.broadcast_to(x[None], (shared, *x.shape)))
+
     def head_stats(h):
         if batched_heads:
             return m_scr, l_scr, acc_scr
-        return m_scr.at[h], l_scr.at[h], acc_scr.at[h]
+        return tuple(scr.at[of_head(h)] for scr in (m_scr, l_scr, acc_scr))
 
     def block_diagonal_q():  # [H, H*D] float32: row h holds head h's q
         if shared > 1:
@@ -268,11 +299,13 @@ def _paged_kernel(
         t_ids = c * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
         live = t_ids < ctx
         if window is not None:
-            # Row r of the tile is the query at ctx + qi * tq + r (decode's
-            # rows are the heads of the one query at ctx).
+            # Row r of a query head is the query at ctx + qi * tq + r
+            # (decode's rows are the heads of the one query at ctx).
             q_pos = ctx + qi * tq + jax.lax.broadcasted_iota(
                 jnp.int32, (1 if batched_heads else tq, 1), 0
             )
+            if not batched_heads:
+                q_pos = under_each_head(q_pos)
             live = live & (t_ids + window > q_pos)
 
         def fold(h, s, heads_here, v):
@@ -306,10 +339,10 @@ def _paged_kernel(
             v = rows(v_ref, lo, group * d).astype(compute_dtype)
             for i in range(group):
                 h, lanes = g * group + i, slice(i * d, (i + 1) * d)
-                for r in range(shared):
-                    hq = h if shared == 1 else h * shared + r
-                    s = _dot(q_ref[0, hq], k[:, lanes], ((1,), (1,)))  # [tq, tile]
-                    fold(hq, s, 1, v[:, lanes])
+                s = _dot(
+                    stacked(q_ref[0, of_head(h)]), k[:, lanes], ((1,), (1,))
+                )  # [shared * tq, tile]
+                fold(h, s, 1, v[:, lanes])
 
         _each(kv_heads // group, head_group, looped)
 
@@ -432,10 +465,13 @@ def _paged_kernel(
             )
             return
 
-        def head(h):
-            hk = h if shared == 1 else h // shared
-            s = _dot(q_ref[0, h], nk_ref[0, hk], ((1,), (1,)))  # [tq, tq]
-            rows = qi * tq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        def head(h):  # a cached head and the query heads it serves
+            s = _dot(
+                stacked(q_ref[0, of_head(h)]), nk_ref[0, h], ((1,), (1,))
+            )  # [shared * tq, tq]
+            rows = under_each_head(
+                qi * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, tq), 0)
+            )
             cols = j * tq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             visible = rows >= cols
             if window is not None:
@@ -443,11 +479,11 @@ def _paged_kernel(
             _online_update(
                 jnp.where(visible, s, NEG_INF), head_stats(h),
                 lambda p: _dot(  # new tokens are never quantized
-                    p.astype(compute_dtype), nv_ref[0, hk], ((1,), (0,))
+                    p.astype(compute_dtype), nv_ref[0, h], ((1,), (0,))
                 ),
             )
 
-        _each(heads, head)
+        _each(kv_heads, head)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
@@ -456,7 +492,7 @@ def _paged_kernel(
         # l == 0 hygiene.
         def normalized(stats):
             _, l_scr, acc_scr = stats
-            l = l_scr[:, 0:1]
+            l = l_scr[..., 0:1]
             return jnp.where(
                 l == 0.0, 0.0, acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
             )
@@ -470,9 +506,9 @@ def _paged_kernel(
             return
 
         def head(h):
-            o_ref[0, h] = normalized(head_stats(h)).astype(o_ref.dtype)
+            o_ref[0, of_head(h)] = normalized(head_stats(h)).astype(o_ref.dtype)
 
-        _each(heads, head)
+        _each(kv_heads, head)
 
 
 _TILE_TOKENS = 128  # cached tokens a compute block covers: whole lanes
@@ -482,15 +518,39 @@ def _whole_lanes(n: int) -> int:
     return -(-n // _LANES) * _LANES
 
 
-def _q_tile(
-    s_len: int, heads: int, head_dim: int, itemsize: int, kv_vmem_bytes: int
+def _compute_blocks(block_size: int, table_blocks: int) -> Tuple[int, int, int]:
+    """A table's compute blocks: as many table entries as make _TILE_TOKENS
+    cached tokens (the whole table, where that is shorter) -> (entries a
+    compute block, its tokens, compute blocks a table)."""
+    entries = min(max(1, _TILE_TOKENS // block_size), table_blocks)
+    return entries, entries * block_size, -(-table_blocks // entries)
+
+
+def q_tile(
+    s_len: int, heads: int, kv_heads: int, head_dim: int, itemsize: int,
+    block_size: int, table_blocks: int, kv_itemsize: int,
 ) -> int:
-    """Fed tokens per q tile: the largest tile whose blocks fit
-    _Q_TILE_VMEM_BYTES beside the `kv_vmem_bytes` of the cached K and V (the
-    two-deep tiles, or a slot's gathered context) — q, new K, new V
-    and out double-buffered in the compute dtype plus the float32 running
-    max, sum and accumulator, every [tile, D] slab padded to whole lanes."""
-    row_bytes = heads * _whole_lanes(head_dim) * (4 * 2 * itemsize + 3 * 4)
+    """Fed tokens per q tile of an `s_len`-token chunk, as
+    `paged_flash_attention` tiles it: the largest tile whose blocks fit
+    _Q_TILE_VMEM_BYTES beside the cached K and V (the two-deep tiles the
+    kernel copies, or a slot's gathered context and, for int8 pools of
+    `kv_itemsize` 1, its scales) — q and out at `heads`, new K and new V at
+    `kv_heads`, double-buffered in the compute dtype, plus the float32
+    running max, sum and accumulator at `heads`, every [tile, D] slab
+    padded to whole lanes. One product of the fed-chunk branch has
+    `heads // kv_heads` times as many rows."""
+    _, tile, n_tiles = _compute_blocks(block_size, table_blocks)
+    if (kv_heads * head_dim) % _LANES == 0:
+        kv_vmem_bytes = 2 * 2 * tile * kv_heads * head_dim * kv_itemsize
+    else:
+        kv_vmem_bytes = (
+            2 * 2 * n_tiles * tile * _whole_lanes(kv_heads * head_dim) * kv_itemsize
+        )
+    if kv_itemsize == 1:
+        kv_vmem_bytes += 2 * 2 * n_tiles * heads * _whole_lanes(tile) * 4
+    row_bytes = _whole_lanes(head_dim) * (
+        heads * (2 * 2 * itemsize + 3 * 4) + kv_heads * 2 * 2 * itemsize
+    )
     room = _Q_TILE_VMEM_BYTES - kv_vmem_bytes
     tile = next((t for t in (128, 64, 32) if t * row_bytes <= room), 16)
     return min(s_len, tile)
@@ -583,11 +643,7 @@ def paged_flash_attention(
     # epilogue by XLA): no per-score-element scale pass inside.
     q = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
 
-    # A compute block is as many table entries as make _TILE_TOKENS cached
-    # tokens (the whole table, where that is shorter).
-    entries = min(max(1, _TILE_TOKENS // bs), nb)
-    tile = entries * bs
-    n_tiles = -(-nb // entries)
+    entries, tile, n_tiles = _compute_blocks(bs, nb)
     # The kernel copies blocks out of a pool itself only in whole lanes.
     # Mosaic refuses to slice a narrower or ragged minor axis in HBM (a
     # chip's 5 heads of 64 under tp = 4; every scale pool): XLA gathers the
@@ -605,7 +661,6 @@ def paged_flash_attention(
             lambda bi, qi, j, tables_ref, lens_ref: (bi, 0, 0, 0),
         )
 
-    itemsize = k_cache.dtype.itemsize
     if kernel_copies:
         # The pools go in whole, as stored: the copies' addresses choose
         # the layer and the block, so XLA neither slices a layer out of a
@@ -618,12 +673,10 @@ def paged_flash_attention(
             pltpu.SemaphoreType.DMA((2, 2)),   # (K | V, tile)
             tile_buf, tile_buf,
         ]
-        kv_vmem_bytes = 2 * 2 * tile * hkv * d * itemsize
     else:
         kv_specs = [slot_tiles(tile, hkv * d)] * 2
         kv_operands = [gathered(k_cache), gathered(v_cache)]
         copy_scratch = []
-        kv_vmem_bytes = 2 * 2 * n_tiles * tile * _whole_lanes(hkv * d) * itemsize
     if quantized:
         # Tokens on the lanes, as the scores have them.
         kv_specs += [slot_tiles(h, tile)] * 2
@@ -631,13 +684,14 @@ def paged_flash_attention(
             gathered(scale).transpose(0, 1, 3, 2).astype(jnp.float32)
             for scale in (k_scale, v_scale)
         ]
-        kv_vmem_bytes += 2 * 2 * n_tiles * h * _whole_lanes(tile) * 4
 
     # The fed tokens are tiled over S so VMEM holds one [H, tq, D] tile of
     # q / new K / new V / out and its statistics, whatever the bucket. A
     # length that is not a whole number of tiles is zero-padded: padded key
     # columns sit above every real row's diagonal, padded q rows are cut.
-    tq = _q_tile(s_len, h, d, q.dtype.itemsize, kv_vmem_bytes)
+    tq = q_tile(
+        s_len, h, hkv, d, q.dtype.itemsize, bs, nb, k_cache.dtype.itemsize
+    )
     nq = -(-s_len // tq)
     pad = nq * tq - s_len
     # One fed token a slot (decode) takes every head in one product: q, new

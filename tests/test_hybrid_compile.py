@@ -8,6 +8,12 @@ both K/V pools and every state pool fit one chip beside the program's
 temporaries with room for the widest prefill chunk (14.4 GB of the chip's
 16.9: the 2,048-token chunk needs 1.1 GB more than decode), and the donated
 pools alias, so no step copies a pool. About ten seconds: not slow.
+
+And the paged kernel fed Laguna's widest chunk at the widths its cell runs
+(3,584 tokens at 72 query heads over 8 cached heads of 128 under a window
+of 512, and at 48 over 8 without; blocks of 16, tables of 896): a cached
+head's query heads are the rows of one product, 576 and 384 of them, and
+the q tile's blocks stay under the compiler's scoped VMEM limit.
 """
 
 import os
@@ -21,7 +27,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.llm import hybrid_runner as hr
+from ray_tpu.llm.config import EngineConfig
 from ray_tpu.models import granite_hybrid as gh
+from ray_tpu.models import laguna
+from ray_tpu.ops.paged_flash import paged_flash_attention
 
 SLOTS, TABLE, BLOCK, BLOCKS = 32, 400, 16, 16384
 
@@ -80,3 +89,53 @@ def test_real_width_decode_program_fits_a_v5e(chip, monkeypatch):
     assert text.count('custom_call_target="tpu_custom_call"') == 1  # the paged kernel
     scopes = set(hr.scopes_of(text).values())
     assert {"llm.mixer.mamba.update", "llm.moe.routed", "llm.mixer.attention"} <= scopes
+
+
+# layers 0-11 of Laguna-S-2.1 and its cell's geometry (benchmark/configs)
+LAGUNA = laguna.LagunaConfig(
+    layer_types=laguna.LAGUNA_PERIOD * 3,
+    num_attention_heads_per_layer=(48, 72, 72, 72) * 3,
+    mlp_layer_types=(laguna.DENSE,) + (laguna.SPARSE,) * 11,
+    experts_held=tuple(range(32)),
+)
+LAGUNA_ENGINE = EngineConfig(
+    block_size=16, num_blocks=13312, max_blocks_per_seq=896, max_decode_slots=48,
+    prefill_buckets=(256, 1024, 2048, 3584),
+)
+
+
+@pytest.mark.parametrize(
+    "cls,layers,blocks,q_tile,rows",
+    [("window", 9, 1858, 64, 576), ("full", 3, 13312, 64, 384)],
+)
+def test_lagunas_widest_chunk_stacks_a_cached_heads_query_heads(
+    chip, cls, layers, blocks, q_tile, rows
+):
+    # What `stats()["attention_shape"]` would say, without the 9.7 GB of
+    # parameters a runner makes: the method reads these four fields.
+    runner = object.__new__(hr.HybridRunner)
+    runner.model_config, runner.engine_config = LAGUNA, LAGUNA_ENGINE
+    runner.classes, runner.kv_cache_dtype = LAGUNA.cache_classes, LAGUNA.dtype
+    shape = runner.attention_shape()[cls]
+    assert (shape["num_layers"], shape["num_heads"]) == (layers, 8)
+    assert shape["prefill_q_tile"] == q_tile
+    assert shape["prefill_rows_per_product"] == rows
+    heads, chunk = shape["num_query_heads"], max(LAGUNA_ENGINE.chunk_widths())
+    assert (chunk, rows) == (3584, heads // 8 * q_tile)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def fn(q, k_cache, v_cache, tables, lens, new_k, new_v):
+        return paged_flash_attention(
+            q, k_cache, v_cache, tables, lens, new_k=new_k, new_v=new_v,
+            layer=layers - 1, num_kv_heads=8, interpret=False,
+            **({} if "horizon" not in shape else {"window": shape["horizon"]}),
+        )
+
+    pool, fed = sds((layers, blocks, 16, 8 * 128)), sds((1, chunk, 8, 128))
+    compiled = jax.jit(fn).lower(
+        sds((1, chunk, heads, 128)), pool, pool, sds((1, 896), jnp.int32),
+        sds((1,), jnp.int32), fed, fed,
+    ).compile()  # raises where the blocks pass the scoped VMEM limit
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1

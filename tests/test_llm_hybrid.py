@@ -270,6 +270,9 @@ def test_counters(params, observed, depth):
     assert stats["attention_shape"] == {
         "num_layers": 1, "num_heads": 2, "head_dim": 16, "kv_itemsize": 4,
         "num_query_heads": 4,
+        # The widest chunk is one q tile of 16; a cached head's two query
+        # heads are the rows of one product.
+        "prefill_q_tile": 16, "prefill_rows_per_product": 32,
     }
 
 

@@ -286,10 +286,14 @@ def test_counters(params, observed, depth):
         "weight_itemsize": 4,
     }
     assert stats["attention_shape"] == {
+        # The widest chunk is one q tile of 16; a cached head's two or
+        # three query heads are the rows of one product.
         "full": {"num_layers": 2, "num_heads": 2, "head_dim": 16,
-                 "kv_itemsize": 4, "num_query_heads": 4},
+                 "kv_itemsize": 4, "num_query_heads": 4,
+                 "prefill_q_tile": 16, "prefill_rows_per_product": 32},
         "window": {"num_layers": 3, "num_heads": 2, "head_dim": 16,
-                   "kv_itemsize": 4, "num_query_heads": 6, "horizon": WINDOW},
+                   "kv_itemsize": 4, "num_query_heads": 6, "horizon": WINDOW,
+                   "prefill_q_tile": 16, "prefill_rows_per_product": 48},
     }
     classes = stats["cache_classes"]
     assert classes["full"] == {

@@ -267,6 +267,9 @@ def test_decode_work_counters_follow_the_batches(mode):
     assert stats["decode_context_tokens"] == 5 + 6 + 7 + 8 + 9 * overshoot
     assert stats["attention_shape"] == {
         "num_layers": 2, "num_heads": 4, "head_dim": 16, "kv_itemsize": 4,
+        # The widest warmed chunk (32 tokens) is one q tile, and a head
+        # with a cached head of its own is one product's rows.
+        "prefill_q_tile": 32, "prefill_rows_per_product": 32,
     }
 
 

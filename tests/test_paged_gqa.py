@@ -25,34 +25,74 @@ import pytest
 
 from ray_tpu.llm.model_runner import _StepPrograms
 from ray_tpu.models.gpt import GPTConfig
+from ray_tpu.ops import paged_flash
 from ray_tpu.ops.attention import paged_attention
 from ray_tpu.ops.paged_flash import paged_flash_attention
 
 TOLERANCE = 2e-6
 
 
-def _case(b, s, hq, hkv, d, bs=16, nb=4, layers=2, seed=0):
+def _case(b, s, hq, hkv, d, bs=16, nb=4, layers=2, seed=0, lens=None):
     rng = np.random.RandomState(seed)
     n = 1 + b * nb
     f32 = lambda *shape: jnp.asarray(rng.randn(*shape), jnp.float32)  # noqa: E731
     q, nk, nv = f32(b, s, hq, d), f32(b, s, hkv, d), f32(b, s, hkv, d)
     kc, vc = f32(layers, n, bs, hkv * d), f32(layers, n, bs, hkv * d)
     tables = jnp.asarray(1 + np.arange(b * nb).reshape(b, nb), jnp.int32)
-    lens = jnp.asarray(rng.randint(0, nb * bs - s, b), jnp.int32).at[0].set(0)
-    return (q, kc, vc, tables, lens), dict(new_k=nk, new_v=nv, layer=1, sm_scale=1 / 64)
+    if lens is None:
+        lens = jnp.asarray(rng.randint(0, nb * bs - s, b), jnp.int32).at[0].set(0)
+    return (
+        (q, kc, vc, tables, jnp.asarray(lens, jnp.int32)),
+        dict(new_k=nk, new_v=nv, layer=1, sm_scale=1 / 64),
+    )
 
 
 # (d) the kernel against the XLA path at num_kv_heads = num_heads and
 # num_heads / 4, one fed token a slot (decode) and several (a chunk).
-@pytest.mark.parametrize("head_dim", [64, 128])
-@pytest.mark.parametrize("fed", [1, 8, 24], ids=["decode", "chunk8", "chunk24"])
-@pytest.mark.parametrize("kv_heads", [8, 2], ids=["mha", "gqa4"])
-def test_kernel_matches_the_xla_path(kv_heads, fed, head_dim):
-    args, kwargs = _case(3, fed, 8, kv_heads, head_dim, seed=fed + kv_heads)
+AS_BEFORE = [
+    pytest.param(8, kv_heads, fed, head_dim, None, None,
+                 id=f"{name}-{'decode' if fed == 1 else f'chunk{fed}'}-{head_dim}")
+    for head_dim in (64, 128)
+    for fed in (1, 8, 24)
+    for kv_heads, name in ((8, "mha"), (2, "gqa4"))
+]
+# A fed chunk stacks a cached head's query heads along the rows of one
+# product: the groups of Laguna's sliding and full layers and of granite's
+# (9, 6, 4) at heads of 128, in q tiles of 16 tokens (no VMEM to spare), so
+# 40 tokens are two and a half tiles and 48 are three. Slot 0 is a padded
+# slot: nothing fed, nothing cached, a table of null blocks. The others
+# start at context 0, end inside a compute block (128 tokens) and at the
+# edge of one.
+STACKED = [
+    pytest.param(2 * group, 2, fed, 128, (0, 0, 168, 128), 16,
+                 id=f"gqa{group}-chunk{fed}-tiles-of-16")
+    for group in (9, 6, 4)
+    for fed in (40, 48)
+]
+
+
+@pytest.mark.parametrize("heads,kv_heads,fed,head_dim,contexts,q_tile", AS_BEFORE + STACKED)
+def test_kernel_matches_the_xla_path(
+    monkeypatch, heads, kv_heads, fed, head_dim, contexts, q_tile
+):
+    if q_tile is not None:
+        monkeypatch.setattr(paged_flash, "_Q_TILE_VMEM_BYTES", 0)
+        assert paged_flash.q_tile(fed, heads, kv_heads, head_dim, 4, 16, 16, 4) == q_tile
+    slots = 3 if contexts is None else len(contexts)
+    args, kwargs = _case(
+        slots, fed, heads, kv_heads, head_dim, seed=fed + kv_heads, lens=contexts,
+        nb=4 if contexts is None else 16,
+    )
+    if contexts is not None:  # the padded slot
+        q, kc, vc, tables, lens = args
+        args = (q.at[0].set(0), kc, vc, tables.at[0].set(0), lens)
+        kwargs.update({k: kwargs[k].at[0].set(0) for k in ("new_k", "new_v")})
     want = paged_attention(*args, **kwargs)
     got = paged_flash_attention(*args, **kwargs, num_kv_heads=kv_heads)
-    assert got.shape == want.shape == (3, fed, 8, head_dim)
+    assert got.shape == want.shape == (slots, fed, heads, head_dim)
     assert float(jnp.abs(got - want).max()) < TOLERANCE
+    if contexts is not None:
+        assert not np.asarray(got[0]).any()  # exact zeros
 
 
 def test_one_cached_head_serves_every_query_head():
@@ -61,9 +101,14 @@ def test_one_cached_head_serves_every_query_head():
     assert float(jnp.abs(got - paged_attention(*args, **kwargs)).max()) < TOLERANCE
 
 
-def test_grouped_query_is_multi_head_over_repeated_heads():
+@pytest.mark.parametrize("impl", ["xla", "kernel"])
+def test_grouped_query_is_multi_head_over_repeated_heads(impl):
+    """The kernel, fed a chunk, takes a cached head's four query heads as
+    the rows of one product and a head of its own as one q tile: row by
+    row the same arithmetic, so the same bits."""
     (q, kc, vc, tables, lens), kwargs = _case(2, 8, 8, 2, 64, seed=3)
-    grouped = paged_attention(q, kc, vc, tables, lens, **kwargs)
+    op = paged_attention if impl == "xla" else paged_flash_attention
+    grouped = op(q, kc, vc, tables, lens, **kwargs)
 
     def repeat(pool):  # [L, N, bs, 2 * d] -> [L, N, bs, 8 * d]
         heads = pool.reshape(pool.shape[:3] + (2, 64))
@@ -72,7 +117,9 @@ def test_grouped_query_is_multi_head_over_repeated_heads():
     kwargs["new_k"], kwargs["new_v"] = (
         jnp.repeat(kwargs[k], 4, axis=2) for k in ("new_k", "new_v")
     )
-    full = paged_attention(q, repeat(kc), repeat(vc), tables, lens, **kwargs)
+    full = op(q, repeat(kc), repeat(vc), tables, lens, **kwargs)
+    if impl == "kernel":
+        assert np.array_equal(np.asarray(grouped), np.asarray(full))
     assert float(jnp.abs(grouped - full).max()) < TOLERANCE
 
 

@@ -9,16 +9,20 @@ it unmasked would be off by orders of magnitude. Windows start inside a
 compute block (128 cached tokens), at its edge and across several, for
 decode (one fed token a slot) and for chunks (several, one or more q tiles),
 at 6 and at 4 query heads over 2 cached ones (the groups of Laguna's sliding
-and full layers, 9 and 6 over 8, in small).
+and full layers, 9 and 6 over 8, in small) and, for chunks, at 18 and 12
+(those groups themselves).
 
 Tolerance: 3e-6 absolute on outputs of order 1, float32 throughout; the
 implementations differ from the dense softmax in the order of sums only.
 
 The pin: sha256 of the lowered text (StableHLO, without the result names) of
 the toy step programs of GPT-2 and granite, both attention implementations,
-recorded on the parent commit of the PR that added `window` (e83482d). A
+recorded on the parent commit of the PR that added `window` (e83482d); the
+two chunk programs of granite under the kernel were re-recorded at PR 38,
+which changed what a grouped model's chunk traces and nothing else here. A
 change that alters what those callers trace shows here; a deliberate one
-re-records the table (the helper prints it: `python tests/test_paged_window.py`).
+re-records the table (the helper prints it:
+`PYTHONPATH=.:tests python tests/test_paged_window.py`).
 """
 
 import hashlib
@@ -99,6 +103,9 @@ CHUNKS = [
     (48, [0, 129, 336], 20),
     (160, [0, 64, 200], 130),       # two q tiles: the second's horizon is higher
     (160, [224], 16),               # new-token tiles wholly outside the window
+    # A q tile and a half under a window that crosses the q tiles and two
+    # compute blocks, the blocks below it null.
+    (200, [0, 300], 150),
 ]
 
 
@@ -112,7 +119,10 @@ def test_decode_inside_the_window(contexts, window, heads):
     assert np.abs(np.asarray(kernel) - want).max() < TOLERANCE
 
 
-@pytest.mark.parametrize("heads", [6, 4])
+# A chunk stacks the query heads of a cached head along the rows of one
+# product, each row masked by its own token's position: Laguna's own groups
+# (9 and 6) beside the small ones.
+@pytest.mark.parametrize("heads", [18, 12, 6, 4])
 @pytest.mark.parametrize("s_len,contexts,window", CHUNKS)
 def test_chunk_inside_the_window(s_len, contexts, window, heads):
     args, kw, want = case(s_len, contexts, window, heads)
@@ -166,8 +176,10 @@ PINS = {
     "gpt.decode.pallas": "df37fa95058d4865",
     "gpt.suffix.pallas": "9a5618e5e93e45f8",
     "granite.jit__decode_step.None.pallas": "ee633a9917e56c8b",
-    "granite.jit__prefill_step.16.pallas": "89ede5d8d5b5e790",
-    "granite.jit__prefill_suffix_step.16.pallas": "59dd149fb5f0f45f",
+    # Re-taken at PR 38 (on 562fae4 + that PR's kernel): a fed chunk of a
+    # grouped model takes a cached head's query heads in one product.
+    "granite.jit__prefill_step.16.pallas": "29d02d613c48855d",
+    "granite.jit__prefill_suffix_step.16.pallas": "36e94c46d86210d5",
 }
 
 
