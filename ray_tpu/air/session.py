@@ -62,7 +62,7 @@ class _Session:
             # Close the round just before the rendezvous so its record
             # rides this report; the put's blocking time is attributed to
             # the NEXT round's `report` phase (it is that round's start).
-            item["profile"] = profiler.end_round()
+            item["profile"] = profiler.end_round(experts=item["metrics"].get("experts"))
             t0 = time.perf_counter()
             self.result_queue.put(item)
             profiler.add("report", time.perf_counter() - t0)

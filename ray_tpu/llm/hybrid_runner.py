@@ -42,7 +42,6 @@ same length so that a step can be chained on the last one's output.
 from __future__ import annotations
 
 import importlib
-import re
 import threading
 from typing import Callable, Dict, Optional, Sequence
 
@@ -55,12 +54,11 @@ from ray_tpu.llm.cache import kv_pool_bytes_sharded
 from ray_tpu.llm.config import EngineConfig
 from ray_tpu.llm.model_runner import bytes_by_device, prefill_tiling
 from ray_tpu.ops.paged_flash import paged_attention_impl, resolve_paged_impl
+from ray_tpu.util.device_report import scopes_of  # noqa: F401  (also the name tests know it by)
 
 # The routing counts a decode step appends to its tokens, in this order.
 DECODE_COUNTS = ("held", "absent", "touched", "load_max")
 MAMBA = "mamba"
-_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?(?P<name>[^\s=]+) = ")
-_OP_NAME = re.compile(r'op_name="(?P<path>[^"]*)"')
 
 
 def model_of(cfg):
@@ -73,29 +71,6 @@ def visible_pairs(offset: int, tokens: int, horizon: int) -> int:
     sees in one layer of a class with `horizon`: the query at position p
     sees min(p + 1, horizon) keys."""
     return sum(min(p + 1, horizon) for p in range(offset, offset + tokens))
-
-
-def scopes_of(hlo_text: str) -> Dict[str, str]:
-    """HLO instruction name -> the innermost part of a layer (a
-    `jax.named_scope` whose name starts with "llm.": the model's SCOPES) its
-    `op_name` metadata passes through, for the instructions that have one. A fusion
-    carries its root's metadata, so an operation fused across two parts
-    counts to its root's."""
-    out: Dict[str, str] = {}
-    for line in hlo_text.splitlines():
-        found = _INSTRUCTION.match(line)
-        path = _OP_NAME.search(line)
-        if not found or not path:
-            continue
-        inside = [part for part in path["path"].split("/") if part.startswith("llm.")]
-        if inside:
-            out[found["name"]] = inside[-1]
-        elif path["path"].startswith("ragged-dot"):
-            # XLA:TPU expands a ragged dot into a kernel and its metadata
-            # call under a name of their own, without the scope; these
-            # programs have no ragged dot but the routed experts'.
-            out[found["name"]] = "llm.moe.routed"
-    return out
 
 
 class _HybridPrograms:

@@ -63,6 +63,7 @@ from ray_tpu.models.gpt import (
     serving_params,
 )
 from ray_tpu.ops.attention import validate_tp_heads
+from ray_tpu.util.device_report import bytes_by_device  # noqa: F401  (hybrid_runner's too)
 from ray_tpu.ops.paged_flash import (
     KV_SCALE_DTYPE,
     q_tile,
@@ -395,17 +396,6 @@ def _step_programs(
             )
             _PROGRAM_CACHE[key] = programs
     return programs
-
-
-def bytes_by_device(arrays) -> dict:
-    """Bytes a device holds of `arrays` (`addressable_shards`, so a
-    replicated leaf counts on every chip that holds it)."""
-    out: dict = {}
-    for array in arrays:
-        for shard in array.addressable_shards:
-            key = f"{shard.device.platform}:{shard.device.id}"
-            out[key] = out.get(key, 0) + int(shard.data.nbytes)
-    return out
 
 
 def prefill_tiling(

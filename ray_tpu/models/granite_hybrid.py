@@ -56,7 +56,7 @@ from ray_tpu.ops.ssd import ssd_chunked_scan, ssm_decode_update
 
 MAMBA, ATTENTION = "mamba", "attention"
 # The parts of a layer a trace's time is split by
-# (`hybrid_runner.scopes_of`), and the scope of an attention layer's
+# (`ray_tpu.util.device_report.scopes_of`), and the scope of an attention layer's
 # projections and of attention alone: one here.
 SCOPES = (
     "llm.mixer.mamba.proj", "llm.mixer.mamba.scan", "llm.mixer.mamba.update",

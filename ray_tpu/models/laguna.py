@@ -45,7 +45,6 @@ Not imported by `ray_tpu` or `ray_tpu.models`: import this module by name.
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -54,7 +53,11 @@ import numpy as np
 
 from ray_tpu.llm.cache import CacheClass
 from ray_tpu.models import parts
-from ray_tpu.models.parts import num_params  # noqa: F401  (the runner's name for it)
+from ray_tpu.models.parts import (  # noqa: F401  (names the runner and tests know)
+    num_params,
+    rope_frequencies,
+    rotate,
+)
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
@@ -73,7 +76,7 @@ LAGUNA_S_ROPE = {
     },
 }
 # The parts of a layer a trace's time is split by
-# (`hybrid_runner.scopes_of`), and per kind of attention layer the scope of
+# (`ray_tpu.util.device_report.scopes_of`), and per kind of attention layer the scope of
 # its projections (q, k, v, rotation, gate, output) and of attention alone.
 SCOPES = (
     "llm.mixer.attention.proj", "llm.mixer.attention.full",
@@ -84,12 +87,6 @@ ATTENTION_SCOPES = {
     FULL: ("llm.mixer.attention.proj", "llm.mixer.attention.full"),
     SLIDING: ("llm.mixer.attention.proj", "llm.mixer.attention.window"),
 }
-
-
-def _frozen(tree):
-    if isinstance(tree, dict):
-        return tuple(sorted((k, _frozen(v)) for k, v in tree.items()))
-    return tree
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,7 +104,7 @@ class LagunaConfig:
     mlp_layer_types: Tuple[str, ...] = (DENSE,) + (SPARSE,) * 47
     num_key_value_heads: int = 8
     head_dim: int = 128
-    rope_parameters: Any = _frozen(LAGUNA_S_ROPE)
+    rope_parameters: Any = parts.frozen(LAGUNA_S_ROPE)
     sliding_window: int = 512
     num_experts: int = 256
     num_experts_per_tok: int = 10
@@ -131,7 +128,7 @@ class LagunaConfig:
 
     def __post_init__(self):
         if isinstance(self.rope_parameters, dict):
-            object.__setattr__(self, "rope_parameters", _frozen(self.rope_parameters))
+            object.__setattr__(self, "rope_parameters", parts.frozen(self.rope_parameters))
         for name in ("layer_types", "num_attention_heads_per_layer",
                      "mlp_layer_types", "experts_held"):
             object.__setattr__(self, name, tuple(getattr(self, name)))
@@ -222,53 +219,9 @@ def expert_shape(cfg: LagunaConfig) -> Dict[str, int]:
 # ---------------- rotary positions ----------------
 
 
-def rope_frequencies(rope: Dict[str, Any], rotated: int) -> Tuple[np.ndarray, float]:
-    """(inverse frequencies [rotated / 2] float32, the factor cos and sin
-    are multiplied by) of one kind of layer. "default": base^(-2i/d).
-    "yarn", as Hugging Face's `_compute_yarn_parameters` over the rotated
-    dimension d: with f_i = base^(2i/d) and dim(n) = d ln(L / (2 pi n)) /
-    (2 ln base) for the original length L, low = floor(dim(beta_fast)),
-    high = ceil(dim(beta_slow)) clipped to [0, d - 1], ramp_i =
-    clip((i - low) / (high - low), 0, 1): (1 - ramp_i) / f_i + ramp_i /
-    (factor f_i)."""
-    base = float(rope["rope_theta"])
-    f = base ** (np.arange(0, rotated, 2, dtype=np.float64) / rotated)
-    kind = rope.get("rope_type", "default")
-    if kind == "default":
-        return (1.0 / f).astype(np.float32), 1.0
-    if kind != "yarn":
-        raise ValueError(f"rope_type {kind!r} is not implemented")
-    factor, original = float(rope["factor"]), rope["original_max_position_embeddings"]
-
-    def dim(rotations):
-        return rotated * math.log(original / (rotations * 2 * math.pi)) / (2 * math.log(base))
-
-    low = max(math.floor(dim(rope["beta_fast"])), 0)
-    high = min(math.ceil(dim(rope["beta_slow"])), rotated - 1)
-    if low == high:
-        high += 0.001
-    ramp = np.clip((np.arange(rotated // 2, dtype=np.float64) - low) / (high - low), 0, 1)
-    inv = (1 - ramp) / f + ramp / (factor * f)
-    scale = rope.get("attention_factor")
-    if scale is None:  # the default of a config that names none
-        scale = 0.1 * math.log(factor) + 1.0
-    return inv.astype(np.float32), float(scale)
-
-
 def rotary_tables(cfg: LagunaConfig, kind: str, positions):
     """cos and sin [..., rotated / 2] float32 at `positions` [...]."""
-    inv, scale = rope_frequencies(cfg.rope(kind), cfg.rotary_dim(kind))
-    angles = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv)
-    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
-
-
-def rotate(x, cos, sin):
-    """x [..., H, d] float32 with its first 2 * cos.shape[-1] dimensions
-    rotated in pairs (i, i + half), the rest passed through."""
-    half = cos.shape[-1]
-    x1, x2, rest = x[..., :half], x[..., half : 2 * half], x[..., 2 * half :]
-    c, s = cos[..., None, :], sin[..., None, :]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s, rest], axis=-1)
+    return parts.rotary_tables(cfg.rope(kind), cfg.rotary_dim(kind), positions)
 
 
 # ---------------- parameters ----------------
@@ -312,20 +265,7 @@ def init_params(cfg: LagunaConfig, seed: int) -> Dict[str, Any]:
     for the norms. The head is untied, so the embedding needs no smaller
     scale to keep the input token from deciding every logit
     (`granite_hybrid.init_params`)."""
-    leaves, tree = jax.tree_util.tree_flatten_with_path(
-        _leaf_shapes(cfg), is_leaf=lambda v: isinstance(v, tuple)
-    )
-    base = jax.random.PRNGKey(seed)
-    made = []
-    for index, (path, shape) in enumerate(leaves):
-        if path[-1].key.startswith("norm"):
-            leaf = jnp.ones(shape, cfg.param_dtype)
-        else:
-            leaf = parts.normal(
-                jax.random.fold_in(base, index), shape, cfg.param_dtype, 0.02
-            )
-        made.append(leaf)
-    return jax.tree_util.tree_unflatten(tree, made)
+    return parts.seeded_tree(_leaf_shapes(cfg), seed, cfg.param_dtype)
 
 
 # ---------------- the parts of a layer ----------------

@@ -1,8 +1,10 @@
 """The parts of a layer that the models served by `ray_tpu.llm.hybrid_runner`
-share (`granite_hybrid`, `laguna`): RMS norm, the matrix product in the
-compute dtype with float32 accumulation, the gated MLP, the routed experts
-beside a shared expert with the routing's counts, the embedding and the
-head, and the seeded normal leaf. Pure functions; a model's configuration
+share (`granite_hybrid`, `laguna`) with the one that is trained
+(`mellum`): RMS norm, the matrix product in the compute dtype with float32
+accumulation, rotary positions (default and YaRN frequencies), the gated
+MLP, the routed experts with the routing's counts, beside a shared expert
+where the model has one, the embedding and the head, and the seeded normal
+leaf. Pure functions; a model's configuration
 is read by attribute.
 
 Not imported by `ray_tpu` or `ray_tpu.models`: import this module by name.
@@ -11,10 +13,12 @@ Not imported by `ray_tpu` or `ray_tpu.models`: import this module by name.
 from __future__ import annotations
 
 import functools
-from typing import Sequence
+import math
+from typing import Any, Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.ops.grouped_experts import route, routed_dense, routed_grouped
 
@@ -23,6 +27,34 @@ from ray_tpu.ops.grouped_experts import route, routed_dense, routed_grouped
 def normal(key, shape, dtype, std):
     """A normal(std) leaf made on the device in `dtype`."""
     return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+
+
+def frozen(tree):
+    """A nested dict as sorted tuples, so that a configuration that holds
+    one (`rope_parameters`) hashes."""
+    if isinstance(tree, dict):
+        return tuple(sorted((k, frozen(v)) for k, v in tree.items()))
+    return tree
+
+
+def seeded_tree(shapes, seed: int, dtype, std_of=lambda name: 0.02):
+    """A tree of seeded weights from a tree of shapes (tuples), made leaf by
+    leaf on the device in `dtype` (a float32 tree of a serving size does not
+    fit a chip beside its bfloat16 copy): ones for a leaf whose name starts
+    with "norm", else normal(`std_of(name)`), the key folded from the
+    leaf's place in the tree."""
+    leaves, tree = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=lambda v: isinstance(v, tuple)
+    )
+    base = jax.random.PRNGKey(seed)
+    made = []
+    for index, (path, shape) in enumerate(leaves):
+        name = path[-1].key
+        if name.startswith("norm"):
+            made.append(jnp.ones(shape, dtype))
+        else:
+            made.append(normal(jax.random.fold_in(base, index), shape, dtype, std_of(name)))
+    return jax.tree_util.tree_unflatten(tree, made)
 
 
 def num_params(params) -> int:
@@ -55,6 +87,62 @@ def matmul(x, w, dtype):
     )
 
 
+# ---------------- rotary positions ----------------
+
+
+def rope_frequencies(rope: Dict[str, Any], rotated: int) -> Tuple[np.ndarray, float]:
+    """(inverse frequencies [rotated / 2] float32, the factor cos and sin
+    are multiplied by) of one kind of layer. "default": base^(-2i/d).
+    "yarn", as Hugging Face's `_compute_yarn_parameters` over the rotated
+    dimension d: with f_i = base^(2i/d) and dim(n) = d ln(L / (2 pi n)) /
+    (2 ln base) for the original length L, low = floor(dim(beta_fast)),
+    high = ceil(dim(beta_slow)) clipped to [0, d - 1], ramp_i =
+    clip((i - low) / (high - low), 0, 1): (1 - ramp_i) / f_i + ramp_i /
+    (factor f_i)."""
+    base = float(rope["rope_theta"])
+    f = base ** (np.arange(0, rotated, 2, dtype=np.float64) / rotated)
+    kind = rope.get("rope_type", "default")
+    if kind == "default":
+        return (1.0 / f).astype(np.float32), 1.0
+    if kind != "yarn":
+        raise ValueError(f"rope_type {kind!r} is not implemented")
+    factor, original = float(rope["factor"]), rope["original_max_position_embeddings"]
+
+    def dim(rotations):
+        return rotated * math.log(original / (rotations * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(dim(rope["beta_fast"])), 0)
+    high = min(math.ceil(dim(rope["beta_slow"])), rotated - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(rotated // 2, dtype=np.float64) - low) / (high - low), 0, 1)
+    inv = (1 - ramp) / f + ramp / (factor * f)
+    scale = rope.get("attention_factor")
+    if scale is None:  # the default of a config that names none
+        scale = 0.1 * math.log(factor) + 1.0
+    return inv.astype(np.float32), float(scale)
+
+
+def rotary_tables(rope: Dict[str, Any], rotated: int, positions):
+    """cos and sin [..., rotated / 2] float32 at `positions` [...] of one
+    kind of layer."""
+    inv, scale = rope_frequencies(rope, rotated)
+    angles = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv)
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+
+
+def rotate(x, cos, sin):
+    """x [..., H, d] float32 with its first 2 * cos.shape[-1] dimensions
+    rotated in pairs (i, i + half), the rest passed through."""
+    half = cos.shape[-1]
+    x1, x2, rest = x[..., :half], x[..., half : 2 * half], x[..., 2 * half :]
+    c, s = cos[..., None, :], sin[..., None, :]
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s, rest], axis=-1)
+
+
+# ---------------- the MLPs ----------------
+
+
 def gated_mlp(x, w_in, w_out, dtype):
     """w_out (silu(g) * u), [g, u] = w_in x; w_in [D, 2F], w_out [F, D]."""
     g, u = jnp.split(matmul(x, w_in, dtype), 2, axis=-1)
@@ -62,10 +150,13 @@ def gated_mlp(x, w_in, w_out, dtype):
 
 
 def experts(cfg, p, x, *, grouped: bool, valid=None):
-    """routed(x) + shared(x) for x [T, D], float32, and the routing's
+    """routed(x) + shared(x) for x [T, D], float32 (routed(x) alone for a
+    model without a shared expert: no `shared_in` among the layer's
+    parameters), and the routing's
     counts over the tokens `valid` marks (all, where None): assignments to
     experts held here and to absent ones, held experts that a token
-    reached, and the fullest held expert's load. The router's rule is the
+    reached, the fullest held expert's load, and every held expert's
+    (`load` [held]). The router's rule is the
     configuration's (`router_score` and, where it scales the gates,
     `routed_scaling_factor`; `ray_tpu.ops.grouped_experts.route`)."""
     held = cfg.local_of()
@@ -83,6 +174,7 @@ def experts(cfg, p, x, *, grouped: bool, valid=None):
         counts = {
             "held": jnp.sum(local >= 0), "absent": jnp.sum(local == -1),
             "touched": jnp.sum(load > 0), "load_max": jnp.max(load),
+            "load": load,
         }
     xc = x.astype(cfg.dtype)
     w_in, w_out = p["experts_in"].astype(cfg.dtype), p["experts_out"].astype(cfg.dtype)
@@ -91,6 +183,8 @@ def experts(cfg, p, x, *, grouped: bool, valid=None):
             routed = routed_grouped(xc, ids, gates, held, w_in, w_out, valid)
         else:
             routed = routed_dense(xc, ids, gates, held, w_in, w_out)
+    if "shared_in" not in p:
+        return routed, counts
     with jax.named_scope("llm.moe.shared"):
         shared = gated_mlp(x, p["shared_in"], p["shared_out"], cfg.dtype)
     return routed + shared, counts

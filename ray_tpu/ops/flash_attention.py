@@ -29,6 +29,23 @@ recompute). Q arrives at every kernel prescaled by sm_scale (folded into
 surrounding XLA ops), removing the per-element scale passes; dQ is
 rescaled once on its [block, d] output tile.
 
+Grouped queries and a sliding window (`flash_attention`'s `window`; the
+group is q.shape[2] // k.shape[2]): K and V stay at their own head count
+in HBM. The forward and the dQ kernel run one program a QUERY head and
+read the key head `row // group`; the dK/dV kernel runs one program a KEY
+head and its sequential axis walks the group's query heads one after the
+other ([B*Hq, S, D] folded is [B*Hkv, group*S, D] without a copy, so
+"the group's rows stacked along the q axis" and "accumulate over the
+group's heads" are the same addressing). Under a window the query at i
+sees i - window < j <= i, and the grids shrink to the band: the
+sequential axis has only as many steps as blocks can meet one block's band
+(2 of 8 at S=8192, window 1024, blocks of 1024), counted from the
+diagonal's block, so blocks outside the band cost neither a product, nor a
+copy, nor a grid step. That index is a function of the program ids alone.
+A step before the sequence's start clamps to block 0 and is skipped. At
+window None and group 1 every kernel and index map traces as it did
+before either existed (tests/test_flash_window_gqa.py pins the jaxprs).
+
 Net-new vs the reference (no attention kernels exist in Ray); design follows
 the standard flash-attention blockwise algorithm (PAPERS.md) and the Pallas TPU
 guide's scratch/when/dimension-semantics idioms.
@@ -50,9 +67,55 @@ from ray_tpu.ops.attention import NEG_INF, mha_reference
 _LANES = 128  # TPU lane width: min trailing dim for scratch tiles
 
 
+def _seen(q_ids, k_ids, window: Optional[int]):
+    """The causal mask, and the window's lower bound where there is one."""
+    if window is None:
+        return q_ids >= k_ids
+    return (q_ids >= k_ids) & (k_ids > q_ids - window)
+
+
+def _band_k_block(qi, step, block_q: int, block_k: int, steps: int):
+    """Under a window: the key block that `step` of q block `qi` reads, the
+    last of `steps` being the diagonal's. Negative before the sequence's
+    start (the index maps clamp it, the kernels skip it)."""
+    return (qi * block_q + block_q - 1) // block_k - (steps - 1) + step
+
+
+def _band_q_block(ki, step, block_q: int, block_k: int):
+    """Under a window: the q block that `step` of key block `ki` reads, the
+    first being the diagonal's. Past the sequence's end where the band
+    leaves it (clamped and skipped alike)."""
+    return (ki * block_k) // block_q + step
+
+
+def _in_band(qi, ki, block_q: int, block_k: int, window: int):
+    """Whether q block `qi` sees anything of key block `ki`, given that the
+    block is not above the diagonal: its last key is inside the first
+    query's window, and the block exists."""
+    return (ki >= 0) & (ki * block_k + block_k - 1 > qi * block_q - window)
+
+
+def _band_steps(window: int, block_q: int, block_k: int, num_q: int, num_k: int):
+    """(key blocks that can meet one q block's band, q blocks that can meet
+    one key block's): the extents of the kernels' sequential axes under a
+    window."""
+    k_steps = max(
+        (i * block_q + block_q - 1) // block_k
+        - max((i * block_q - window + 1) // block_k, 0) + 1
+        for i in range(num_q)
+    )
+    q_steps = max(
+        min((j * block_k + block_k + window - 2) // block_q, num_q - 1)
+        - (j * block_k) // block_q + 1
+        for j in range(num_k)
+    )
+    return k_steps, q_steps
+
+
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch, l_scratch, acc_scratch,
-    *, causal: bool, block_q: int, block_k: int, num_k: int
+    *, causal: bool, block_q: int, block_k: int, num_k: int,
+    window: Optional[int] = None,
 ):
     ki = pl.program_id(2)
     qi = pl.program_id(1)
@@ -63,9 +126,15 @@ def _fwd_kernel(
         l_scratch[:] = jnp.zeros_like(l_scratch)
         acc_scratch[:] = jnp.zeros_like(acc_scratch)
 
-    # Blocks entirely above the causal diagonal contribute nothing: skip
-    # their compute (their copies still run — see module docstring).
-    needed = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
+    step = ki
+    if window is None:
+        # Blocks entirely above the causal diagonal contribute nothing: skip
+        # their compute (their copies still run — see module docstring).
+        needed = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
+    else:
+        # `num_k` steps ending at the diagonal's block (module docstring).
+        ki = _band_k_block(qi, step, block_q, block_k, num_k)
+        needed = _in_band(qi, ki, block_q, block_k, window)
 
     @pl.when(needed)
     def _body():
@@ -80,8 +149,12 @@ def _fwd_kernel(
         if causal:
             q_ids = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             k_ids = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
+            s = jnp.where(_seen(q_ids, k_ids, window), s, NEG_INF)
 
+        # Under a window a row may see nothing of an early block: its
+        # maximum stays NEG_INF and p reads 1 there, and the diagonal's
+        # block, which comes last and holds the row's own key, wipes that
+        # with alpha == 0.
         m_prev = m_scratch[:, 0:1]  # [block_q, 1] broadcast column
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -97,7 +170,7 @@ def _fwd_kernel(
         m_scratch[:] = jnp.broadcast_to(m_new, m_scratch.shape)
         l_scratch[:] = jnp.broadcast_to(l_new, l_scratch.shape)
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(step == num_k - 1)
     def _finalize():
         l = l_scratch[:, 0:1]
         l = jnp.where(l == 0.0, 1.0, l)
@@ -112,10 +185,13 @@ def _fwd_kernel(
 def _flash_fwd_pallas(
     q: jax.Array, k: jax.Array, v: jax.Array,
     causal: bool, block_q: int, block_k: int, interpret: bool,
+    window: Optional[int] = None,
 ):
-    """q,k,v: [BH, S, D], q prescaled by sm_scale. Returns (out, lse)."""
+    """q [B*Hq, S, D] prescaled by sm_scale, k and v [B*Hkv, S, D]. Returns
+    (out, lse)."""
     bh, s_q, d = q.shape
     s_k = k.shape[1]
+    kv_row = _kv_row(bh // k.shape[0])
     block_q = min(block_q, s_q)
     block_k = min(block_k, s_k)
     if s_q % block_q or s_k % block_k:
@@ -125,14 +201,21 @@ def _flash_fwd_pallas(
         )
     num_q = s_q // block_q
     num_k = s_k // block_k
+    if window is None:
+        kv_map = lambda b, i, j: (kv_row(b), j, 0)
+    else:
+        num_k, _ = _band_steps(window, block_q, block_k, num_q, num_k)
+        kv_map = lambda b, i, j: (
+            kv_row(b), jnp.maximum(_band_k_block(i, j, block_q, block_k, num_k), 0), 0
+        )
     kernel = functools.partial(
         _fwd_kernel,
         causal=causal,
         block_q=block_q,
         block_k=block_k,
         num_k=num_k,
+        window=window,
     )
-    kv_map = lambda b, i, j: (b, j, 0)
     return pl.pallas_call(
         kernel,
         grid=(bh, num_q, num_k),
@@ -161,6 +244,13 @@ def _flash_fwd_pallas(
     )(q, k, v)
 
 
+def _kv_row(group: int):
+    """Folded query row [B*Hq] -> the folded K/V row [B*Hkv] it reads."""
+    if group == 1:
+        return lambda b: b
+    return lambda b: b // group
+
+
 def _on_cpu() -> bool:
     return jax.devices()[0].platform == "cpu"
 
@@ -180,7 +270,8 @@ def _pick_block(s: int) -> int:
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_scratch,
-    *, sm_scale: float, causal: bool, block_q: int, block_k: int, num_k: int
+    *, sm_scale: float, causal: bool, block_q: int, block_k: int, num_k: int,
+    window: Optional[int] = None,
 ):
     ki = pl.program_id(2)
 
@@ -189,8 +280,13 @@ def _dq_kernel(
         acc_scratch[:] = jnp.zeros_like(acc_scratch)
 
     qi = pl.program_id(1)
-    # Causal: k blocks entirely above the diagonal contribute nothing.
-    needed = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
+    step = ki
+    if window is None:
+        # Causal: k blocks entirely above the diagonal contribute nothing.
+        needed = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
+    else:
+        ki = _band_k_block(qi, step, block_q, block_k, num_k)
+        needed = _in_band(qi, ki, block_q, block_k, window)
 
     @pl.when(needed)
     def _body():
@@ -204,7 +300,7 @@ def _dq_kernel(
         if causal:
             q_ids = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             k_ids = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
+            s = jnp.where(_seen(q_ids, k_ids, window), s, NEG_INF)
         p = jnp.exp(s - lse_ref[0, 0][:, None])  # [bq, bk] f32
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -215,7 +311,7 @@ def _dq_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(step == num_k - 1)
     def _finalize():
         # sm_scale applied once on the [block_q, d] tile rather than per
         # S×S element.
@@ -225,8 +321,12 @@ def _dq_kernel(
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_scratch, dv_scratch,
-    *, sm_scale: float, causal: bool, block_q: int, block_k: int, num_q: int
+    *, sm_scale: float, causal: bool, block_q: int, block_k: int, num_q: int,
+    window: Optional[int] = None, group: int = 1, q_blocks: int = 0,
 ):
+    """One program a key head and key block; the sequential axis walks the
+    `group` query heads of the key head, `num_q` steps each (`q_blocks`, of
+    the sequence's, under a window: `num_q` is then the band's steps)."""
     qi = pl.program_id(2)
 
     @pl.when(qi == 0)
@@ -235,7 +335,14 @@ def _dkv_kernel(
         dv_scratch[:] = jnp.zeros_like(dv_scratch)
 
     ki = pl.program_id(1)
-    needed = (not causal) or (qi * block_q + block_q - 1 >= ki * block_k)
+    step = qi
+    if group > 1:
+        qi = step % num_q
+    if window is None:
+        needed = (not causal) or (qi * block_q + block_q - 1 >= ki * block_k)
+    else:
+        qi = _band_q_block(ki, qi, block_q, block_k)
+        needed = (qi < q_blocks) & _in_band(qi, ki, block_q, block_k, window)
 
     @pl.when(needed)
     def _body():
@@ -249,7 +356,7 @@ def _dkv_kernel(
         if causal:
             q_ids = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             k_ids = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
+            s = jnp.where(_seen(q_ids, k_ids, window), s, NEG_INF)
         p = jnp.exp(s - lse_ref[0, 0][:, None])  # [bq, bk]
         # dV += P^T @ dO
         dv_scratch[:] = dv_scratch[:] + jax.lax.dot_general(
@@ -266,7 +373,7 @@ def _dkv_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(step == group * num_q - 1)
     def _finalize():
         dk_ref[0] = dk_scratch[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
@@ -347,24 +454,45 @@ def _flash_bwd_fused_pallas(q, k, v, o, do, lse, sm_scale, causal, interpret):
 
 
 def _flash_bwd_pallas(
-    q, k, v, do, lse, delta, sm_scale, causal, block_q, block_k, interpret
+    q, k, v, do, lse, delta, sm_scale, causal, block_q, block_k, interpret,
+    window=None,
 ):
-    """All inputs [BH, S, D] / [BH, 8, S]; returns (dq, dk, dv)."""
+    """q, do [B*Hq, S, D], k, v [B*Hkv, S, D], lse, delta [B*Hq, 8, S];
+    returns (dq, dk, dv), dk and dv summed over a key head's query heads."""
     bh, s_q, d = q.shape
     s_k = k.shape[1]
+    group = bh // k.shape[0]
+    kv_row = _kv_row(group)
     block_q = min(block_q, s_q)
     block_k = min(block_k, s_k)
     num_q = s_q // block_q
     num_k = s_k // block_k
-    kv_map = lambda b, i, j: (b, j, 0)
-    q_map = lambda b, j, i: (b, i, 0)
-    qrow_map = lambda b, j, i: (b, 0, i)
+    if window is None:
+        k_steps, q_steps = num_k, num_q
+        kv_map = lambda b, i, j: (kv_row(b), j, 0)
+        q_block = lambda j, i: i
+    else:
+        k_steps, q_steps = _band_steps(window, block_q, block_k, num_q, num_k)
+        kv_map = lambda b, i, j: (
+            kv_row(b), jnp.maximum(_band_k_block(i, j, block_q, block_k, k_steps), 0), 0
+        )
+        q_block = lambda j, i: jnp.minimum(
+            _band_q_block(j, i, block_q, block_k), num_q - 1
+        )
+    if group == 1:
+        q_of = lambda b, j, i: (b, q_block(j, i))
+    else:
+        # Step i of a key head's program: query head i // q_steps of its
+        # group, that head's step i % q_steps.
+        q_of = lambda b, j, i: (b * group + i // q_steps, q_block(j, i % q_steps))
+    q_map = lambda b, j, i: (*q_of(b, j, i), 0)
+    qrow_map = lambda b, j, i: (q_of(b, j, i)[0], 0, q_of(b, j, i)[1])
     dq = pl.pallas_call(
         functools.partial(
             _dq_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, num_k=num_k,
+            block_q=block_q, block_k=block_k, num_k=k_steps, window=window,
         ),
-        grid=(bh, num_q, num_k),
+        grid=(bh, num_q, k_steps),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_map),
@@ -384,9 +512,10 @@ def _flash_bwd_pallas(
     dk, dv = pl.pallas_call(
         functools.partial(
             _dkv_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, num_q=num_q,
+            block_q=block_q, block_k=block_k, num_q=q_steps, window=window,
+            group=group, q_blocks=num_q,
         ),
-        grid=(bh, num_k, num_q),
+        grid=(k.shape[0], num_k, group * q_steps),
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_map),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
@@ -400,8 +529,8 @@ def _flash_bwd_pallas(
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s_k, d), v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -616,10 +745,10 @@ def flash_attention_packed(
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
 )
-def _flash_attention(q, k, v, sm_scale, causal, block_q, block_k):
-    return _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k)[0]
+def _flash_attention(q, k, v, sm_scale, causal, block_q, block_k, window=None):
+    return _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, window)[0]
 
 
 def _fold_heads(x):
@@ -632,7 +761,7 @@ def _unfold_heads(x, b, h):
     return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
-def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
+def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, window=None):
     b, s, h, d = q.shape
     # Prescale q once on the [B,S,H,D] tensor (XLA fuses this into the
     # producing matmul's epilogue in real models): every kernel then skips
@@ -640,7 +769,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
     q = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
     q_f, k_f, v_f = _fold_heads(q), _fold_heads(k), _fold_heads(v)
     out_f, lse = _flash_fwd_pallas(
-        q_f, k_f, v_f, causal, block_q, block_k, interpret=_on_cpu()
+        q_f, k_f, v_f, causal, block_q, block_k, interpret=_on_cpu(), window=window
     )
     out = _unfold_heads(out_f, b, h)
     # Residuals stay in kernel layout (q_f prescaled): the backward reads
@@ -648,17 +777,18 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
     return out, (q_f, k_f, v_f, out_f, lse[:, 0, :])
 
 
-def _flash_bwd(sm_scale, causal, block_q, block_k, residuals, do):
+def _flash_bwd(sm_scale, causal, block_q, block_k, window, residuals, do):
     """Flash backward using the forward's per-row logsumexp — no S×S logits
     are ever materialized. Single-block sequences take the fused one-kernel
-    path; longer ones the two-kernel (dQ over k-blocks; dK/dV over q-blocks)
-    scheme."""
+    path (as many key heads as query heads, no window); longer ones the
+    two-kernel (dQ over k-blocks; dK/dV over q-blocks) scheme."""
     q_f, k_f, v_f, out_f, lse = residuals
     b, _, h, _ = do.shape
     do_f = _fold_heads(do)
     pad8 = lambda x: jnp.broadcast_to(x[:, None, :], (x.shape[0], 8, x.shape[1]))
     s_len = q_f.shape[1]
-    if min(block_q, s_len) == s_len == k_f.shape[1] == min(block_k, s_len):
+    plain = window is None and k_f.shape[0] == q_f.shape[0]
+    if plain and min(block_q, s_len) == s_len == k_f.shape[1] == min(block_k, s_len):
         dq, dk, dv = _flash_bwd_fused_pallas(
             q_f, k_f, v_f, out_f, do_f, pad8(lse),
             sm_scale, causal, interpret=_on_cpu(),
@@ -670,12 +800,13 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, residuals, do):
         )
         dq, dk, dv = _flash_bwd_pallas(
             q_f, k_f, v_f, do_f, pad8(lse), pad8(delta),
-            sm_scale, causal, block_q, block_k, interpret=_on_cpu(),
+            sm_scale, causal, block_q, block_k, interpret=_on_cpu(), window=window,
         )
+    kv_heads = k_f.shape[0] // b
     return (
         _unfold_heads(dq, b, h),
-        _unfold_heads(dk, b, h),
-        _unfold_heads(dv, b, h),
+        _unfold_heads(dk, b, kv_heads),
+        _unfold_heads(dv, b, kv_heads),
     )
 
 
@@ -691,8 +822,12 @@ def flash_attention(
     sm_scale: Optional[float] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
-    """Flash attention. q,k,v: [B, S, H, D] → [B, S, H, D].
+    """Flash attention. q [B, S, Hq, D], k and v [B, S, Hkv, D] with Hq a
+    multiple of Hkv (query head j reads key head j // (Hq // Hkv)) →
+    [B, S, Hq, D]. With `window` (causal only) the query at i sees the
+    keys i - window < j <= i.
 
     Runs the Pallas kernels (interpret mode on CPU so tests exercise the
     same code path). Differentiable via dedicated Pallas backward kernels.
@@ -709,7 +844,11 @@ def flash_attention(
         block_q = _pick_block(q.shape[1])
     if block_k is None:
         block_k = _pick_block(k.shape[1])
-    return _flash_attention(q, k, v, sm_scale, causal, block_q, block_k)
+    if q.shape[2] % k.shape[2] or k.shape != v.shape:
+        raise ValueError(f"query heads {q.shape[2]} over key heads {k.shape[2]}")
+    if window is not None and (not causal or window < 1 or q.shape[1] != k.shape[1]):
+        raise ValueError("a window needs causal self-attention and at least one key")
+    return _flash_attention(q, k, v, sm_scale, causal, block_q, block_k, window)
 
 
 def attention(

@@ -30,6 +30,7 @@ from ray_tpu.train.jax_trainer import (
     prepare_batch,
     prepare_params,
     prepare_step,
+    step_device_report,
 )
 from ray_tpu.train.worker_group import WorkerGroup
 from ray_tpu.train.observability import (
@@ -72,4 +73,5 @@ __all__ = [
     "restore_train_state",
     "save_sharded",
     "save_train_state",
+    "step_device_report",
 ]

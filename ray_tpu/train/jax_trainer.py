@@ -100,7 +100,38 @@ def prepare_step(step_fn: Callable, donate_argnums=(0,)) -> Callable:
                 jax.block_until_ready(out)
         return out
 
+    instrumented_step.jitted = jitted  # for `step_device_report`
     return instrumented_step
+
+
+def step_device_report(step: Callable, *args) -> dict:
+    """What a prepared step holds and is made of, the training counterpart
+    of the serving runners' `device_report()`: for the callable
+    `prepare_step` returned and the arguments a call takes (the parameters
+    first; arrays, which are not consumed: the step is lowered and compiled
+    again, a read where the compile cache has it, and never run),
+
+      - `op_scopes`: {program name: {HLO instruction: the part of a layer
+        it was traced under}} (`ray_tpu.util.device_report.scopes_of`, forward
+        and backward instructions alike), which splits a trace's device
+        time by part;
+      - `param_bytes_by_device`: bytes of the first argument a device;
+      - `step_argument_bytes`, `step_temp_bytes`: XLA's account of the
+        program's arguments and of its scratch space."""
+    import jax
+
+    from ray_tpu.util.device_report import bytes_by_device, scopes_of
+
+    jitted = getattr(step, "jitted", step)
+    compiled = jitted.lower(*args).compile()
+    memory = compiled.memory_analysis()
+    name = "jit_" + getattr(jitted, "__name__", "step")
+    return {
+        "op_scopes": {name: scopes_of(compiled.as_text())},
+        "param_bytes_by_device": bytes_by_device(jax.tree_util.tree_leaves(args[0])),
+        "step_argument_bytes": int(memory.argument_size_in_bytes),
+        "step_temp_bytes": int(memory.temp_size_in_bytes),
+    }
 
 
 def report_from_rank0(metrics: dict, checkpoint=None) -> None:

@@ -19,6 +19,14 @@ gated by ``TrainConfig.instrument`` the way the engine plane is gated by
       - ``checkpoint`` ``Checkpoint.from_dict`` / ``save_sharded`` /
                        ``save_train_state``
 
+    A report that carries the routed experts' counts of its round
+    (``train.report({"loss": ..., "experts": {...}})``, the counts a
+    model such as ``ray_tpu.models.mellum`` returns beside its loss,
+    summed over the steps since the last report) leaves them on the
+    round's record; the driver sums them into ``TrainRunRecord.report()``
+    and the ``train_expert_assignments`` / ``train_expert_load_max``
+    counters.
+
     Rounds land in a bounded per-worker ring (``RayTrainWorker.
     profile_records`` → ``WorkerGroup.profile_records``) AND ride each
     report to the driver, so the trainer aggregates without extra RPCs.
@@ -98,7 +106,22 @@ def _train_metrics():
         "Rank-rounds flagged as stragglers, by dominant phase",
         tag_keys=("phase",),
     )
-    return h_round, h_report, h_sps, c_straggler
+    c_assignments = get_or_create(
+        Counter,
+        "train_expert_assignments",
+        "Token-to-expert assignments a routed-experts model reported with "
+        "its loss, by where the chosen expert lives: held by the reporting "
+        "rank, or absent (held by another chip)",
+        tag_keys=("where",),
+    )
+    c_load_max = get_or_create(
+        Counter,
+        "train_expert_load_max",
+        "The fullest held expert's tokens, summed over the layers and steps "
+        "of the reports (over train_expert_assignments{where=held} / experts "
+        "held: how unevenly the router loads them)",
+    )
+    return h_round, h_report, h_sps, c_straggler, c_assignments, c_load_max
 
 
 def round_span_id(fit_span_id: str, round_idx: int) -> str:
@@ -142,6 +165,30 @@ def batch_rows(batch: Any) -> int:
         return len(batch)
     except Exception:
         return 0
+
+
+EXPERT_COUNTS = ("held", "absent", "touched", "load_max")
+
+
+def expert_counts(experts: dict) -> dict:
+    """A report's ``experts`` entry as plain numbers: the scalar counts as
+    ints, ``load`` (tokens a held expert) as a list."""
+    out = {k: int(experts.get(k, 0)) for k in EXPERT_COUNTS}
+    if experts.get("load") is not None:
+        out["load"] = [int(v) for v in experts["load"]]
+    return out
+
+
+def add_expert_counts(total: Optional[dict], counts: dict) -> dict:
+    """`total` + `counts`, field by field (None: nothing yet)."""
+    if total is None:
+        return dict(counts, load=list(counts.get("load", [])))
+    out = {k: total[k] + counts[k] for k in EXPERT_COUNTS}
+    mine, theirs = total.get("load", []), counts.get("load", [])
+    out["load"] = (
+        [a + b for a, b in zip(mine, theirs)] if len(mine) == len(theirs) else []
+    )
+    return out
 
 
 class StepProfiler:
@@ -220,10 +267,14 @@ class StepProfiler:
                 best = stage
         return best[0] if best else None
 
-    def end_round(self) -> dict:
+    def end_round(self, experts: Optional[dict] = None) -> dict:
         """Close the current round (called by ``session.report`` just
         before the rendezvous put), record it, emit its worker spans, and
-        return the record so it can ride the report to the driver."""
+        return the record so it can ride the report to the driver.
+        `experts` is what the report says of its routed experts (the
+        routing's counts summed over the round's steps: ``held``,
+        ``absent``, ``touched``, ``load_max``, ``load`` an expert): kept on
+        the record as plain numbers."""
         now_p = time.perf_counter()
         now_ts = time.time()
         duration = now_p - self._round_start
@@ -237,6 +288,8 @@ class StepProfiler:
             "data_blame": self._data_blame() if phases["data_wait"] else None,
             "time": now_ts,
         }
+        if experts and isinstance(experts, dict):
+            record["experts"] = expert_counts(experts)
         self.records.append(record)
         if self.trace is not None:
             self._emit_round_spans(record, now_ts - duration, now_ts)
@@ -365,6 +418,10 @@ class TrainRunRecord:
         # dicts keep the per-round loop allocation-free.
         self._metrics = _train_metrics()
         self._phase_tags = {p: {"phase": p} for p in TRAIN_PHASES}
+        self._where_tags = {w: {"where": w} for w in ("held", "absent")}
+        # The routed experts' counts over every rank and round that
+        # reported them (None: a model without, or a loop that sends none).
+        self.experts: Optional[dict] = None
 
     # -- per-round ----------------------------------------------------------
 
@@ -379,7 +436,7 @@ class TrainRunRecord:
         """Fold one rendezvous round's per-rank records in: histograms,
         min/median/max per phase across ranks, straggler flags, and the
         ``train.round`` span the workers' round spans hang under."""
-        h_round, h_report, h_sps, c_straggler = self._metrics
+        h_round, h_report, h_sps, c_straggler, c_assignments, c_load_max = self._metrics
         profiles = [p for p in profiles if p]
         round_wall = max(end_ts - start_ts, 1e-9)
         for record in profiles:
@@ -392,6 +449,14 @@ class TrainRunRecord:
         self.samples_total += samples
         if samples:
             h_sps.observe(samples / round_wall)
+
+        for record in profiles:
+            experts = record.get("experts")
+            if experts:
+                c_assignments.inc(float(experts["held"]), self._where_tags["held"])
+                c_assignments.inc(float(experts["absent"]), self._where_tags["absent"])
+                c_load_max.inc(float(experts["load_max"]))
+                self.experts = add_expert_counts(self.experts, experts)
 
         stragglers = self._detect_stragglers(round_idx, profiles)
         for s in stragglers:
@@ -522,6 +587,7 @@ class TrainRunRecord:
             "samples_total": self.samples_total,
             "straggler_rounds": self.straggler_rounds,
             "stragglers": list(self.stragglers),
+            "experts": self.experts,
             "phase_stats": {
                 p: _min_median_max(list(vs))
                 for p, vs in self._phase_values.items()
