@@ -182,7 +182,8 @@ class _HybridPrograms:
     ):
         """tokens [1, S_bucket] (0-padded past true_len) of the sequence in
         state slot `slot`, at positions offset.. -> (pools, [next token,
-        held assignments]). block_table holds a [nb] table a cache class."""
+        held assignments, the sorted rows the grouped experts walked for
+        them]). block_table holds a [nb] table a cache class."""
         cfg, model = self.cfg, self.model
         k_cache, v_cache = list(k_cache), list(v_cache)
         sb = tokens.shape[1]
@@ -224,7 +225,9 @@ class _HybridPrograms:
             k_cache[cls] = k_cache[cls].at[at].set(k[0].reshape(sb, -1))
             v_cache[cls] = v_cache[cls].at[at].set(v[0].reshape(sb, -1))
         logits = model.head(cfg, params, h[true_len - 1])
-        out = jnp.stack([self._sample(logits), counts["held"]]).astype(jnp.int32)
+        out = jnp.stack(
+            [self._sample(logits), counts["held"], counts["walked"]]
+        ).astype(jnp.int32)
         return (tuple(k_cache), tuple(v_cache), tuple(conv), tuple(ssm)), out
 
     def _prefill_step(
@@ -344,7 +347,7 @@ class HybridRunner:
         names = [
             "decode_expert_assignments", "decode_expert_assignments_absent",
             "decode_experts_touched", "decode_expert_load_max",
-            "prefill_expert_assignments",
+            "prefill_expert_assignments", "prefill_expert_rows_walked",
         ]
         if self.recurrent:
             names = ["decode_state_bytes", *names, "prefill_scan_tokens"]
@@ -536,8 +539,9 @@ class HybridRunner:
         self._set_pools(pools)
         self._dispatched()
         self._count_transfer(arrays_in, out)
-        token, held = (int(v) for v in np.asarray(out))
+        token, held, walked = (int(v) for v in np.asarray(out))
         self.counters["prefill_expert_assignments"] += held
+        self.counters["prefill_expert_rows_walked"] += walked
         if self.recurrent:
             self.counters["prefill_scan_tokens"] += n
         if self.horizon is not None:

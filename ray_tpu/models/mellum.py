@@ -92,8 +92,9 @@ EMBEDDING_STD = 1.0
 # the targets, under which the load stays even for a window's steps (call 8).
 HEAD_STD = 0.02 / 16
 # The routing's counts `loss_and_counts` returns, summed over layers;
-# `load` is [experts held], the rest scalars.
-COUNTS = ("held", "absent", "touched", "load_max", "load")
+# `load` is [experts held], the rest scalars (`walked`: the sorted rows the
+# grouped experts visited for the `held` ones).
+COUNTS = ("held", "absent", "touched", "load_max", "walked", "load")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -20,7 +20,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.ops.grouped_experts import route, routed_dense, routed_grouped
+from ray_tpu.ops.grouped_experts import (
+    route, routed_dense, routed_grouped, rows_walked,
+)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3))
@@ -156,7 +158,9 @@ def experts(cfg, p, x, *, grouped: bool, valid=None):
     counts over the tokens `valid` marks (all, where None): assignments to
     experts held here and to absent ones, held experts that a token
     reached, the fullest held expert's load, and every held expert's
-    (`load` [held]). The router's rule is the
+    (`load` [held]); where `grouped`, also the sorted rows the grouped
+    path visits for them (`walked`, at least `held` and at most `held +
+    absent`: `ray_tpu.ops.grouped_experts.rows_walked`). The router's rule is the
     configuration's (`router_score` and, where it scales the gates,
     `routed_scaling_factor`; `ray_tpu.ops.grouped_experts.route`)."""
     held = cfg.local_of()
@@ -176,6 +180,10 @@ def experts(cfg, p, x, *, grouped: bool, valid=None):
             "touched": jnp.sum(load > 0), "load_max": jnp.max(load),
             "load": load,
         }
+        if grouped:
+            counts["walked"] = rows_walked(
+                counts["held"], ids.size, len(cfg.experts_held) / held.shape[0]
+            )
     xc = x.astype(cfg.dtype)
     w_in, w_out = p["experts_in"].astype(cfg.dtype), p["experts_out"].astype(cfg.dtype)
     with jax.named_scope("llm.moe.routed"):

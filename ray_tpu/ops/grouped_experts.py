@@ -14,11 +14,19 @@ same logits alone and among others.
 Two forms of the same sum. `routed_dense` sends every token through every
 held expert and weighs by the gate (nought where the token did not choose
 it): for the few tokens of a decode step, whose time is reading the
-experts' matrices whatever is computed. `routed_grouped` sorts the held
-assignments by expert and takes two grouped products
-(`jax.lax.ragged_dot`, a grouped-matmul kernel on the TPU), so the work is
-that of the assignments made: for a prompt's chunk, and for a training
-step, where it carries its own backward.
+experts' matrices whatever is computed. `routed_grouped` sorts the
+assignments by expert, the ones no expert here serves last, and works on a
+prefix of that order that holds every held one: the rows are gathered,
+multiplied (grouped products, Pallas kernels whose grid ends at the last
+group's last tile), gated and summed back a token over that prefix alone, so
+the work is that of the assignments this chip holds: for a prompt's chunk,
+and for a training step, where it carries its own backward.
+
+The prefix is one of a short ladder of static lengths (`ladder`), the first
+a quarter over the share of the experts held here, each next one four times
+as long, the last every row: the rung is chosen on the device from the held
+count (`lax.switch`), so any imbalance is exact and dropless, and
+`rows_walked` says which rung a count takes.
 
 An expert is `w_out (silu(g) * u)`, `[g, u] = w_in x`; w_in [E, D, 2F],
 w_out [E, F, D].
@@ -26,10 +34,15 @@ w_out [E, F, D].
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops.grouped_matmul import (
+    matrices_by_group, round_up, row_tile, rows_by_group, summed_by_token, walk,
+)
 
 
 def route(
@@ -82,44 +95,115 @@ def routed_dense(
     )
 
 
-def _sorted_by_expert(ids, local_of, valid, held: int):
+def _on_cpu() -> bool:
+    return jax.default_backend() == "cpu"
+
+
+def ladder(rows: int, share: float) -> Tuple[int, ...]:
+    """The prefix lengths `routed_grouped` may walk of `rows` sorted
+    assignments where `share` of the experts are held: whole row tiles; the
+    first a quarter over the held share, each next one four times as long,
+    the last every row. Short, because a rung is a copy of the program:
+    its eight kernels are traced again for every one (a second of set-up a
+    rung of the Mellum step on the chip's host, PR 41)."""
+    tile = row_tile(rows)
+    every = round_up(rows, tile)
+    rungs, rung = [], round_up(max(int(1.25 * share * rows), 1), tile)
+    while rung < every:
+        rungs.append(rung)
+        rung *= 4
+    return tuple(rungs) + (every,)
+
+
+def _rung_of(held, rungs: Tuple[int, ...]):
+    """The first rung that takes `held` rows (the last takes any)."""
+    return sum((held > rung).astype(jnp.int32) for rung in rungs[:-1])
+
+
+def rows_walked(held, rows: int, share: float):
+    """Rows of the sorted order that `routed_grouped` visits when `held`
+    of its `rows` assignments are served here: the rung, at most `rows`."""
+    rungs = ladder(rows, share)
+    lengths = jnp.minimum(jnp.asarray(rungs, jnp.int32), rows)
+    return lengths[_rung_of(held, rungs)]
+
+
+def _sorted_by_expert(ids, gates, local_of, valid, held: int):
     """The assignments numbered choice-major (choice * T + token) and sorted
-    by held expert, absent ones last: (local [k, T], -1 where no expert
-    here serves the choice; order [k * T]; sizes [held])."""
+    by held expert, absent ones last: (order [k * T rounded up to a row
+    tile], the padding numbered past the last assignment; each sorted
+    row's gate; sizes [held]). One sort carries the numbers and the gates:
+    a gather of as many scalars costs as much again."""
     local = jnp.where(valid[:, None], local_of[ids], -1).T  # [k, T]
     key = jnp.where(local >= 0, local, held).reshape(-1)  # absent last
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-    return local, order, sizes
-
-
-def _grouped_forward(x, ids, gates, local_of, w_in, w_out, valid):
-    t_len, k = ids.shape
-    held = w_in.shape[0]
-    # Sorted back, the products are [k, T, D] and the sum over a token's
-    # choices runs over the leading axis. ([T, k, D] would put k = 10 on the
-    # second-minor axis, which the TPU pads to its tile and copies: 3 ms a
-    # layer of a 2,048-token chunk, more than both products; chip run,
-    # PR 32.)
-    local, order, sizes = _sorted_by_expert(ids, local_of, valid, held)
-    rows = x[order % t_len]  # [k * T, D]
-    # The products round to the operands' type on the way out (float32
-    # inside): of a chunk's 20,480 rows by 4,096 a float32 copy is a third
-    # of a gigabyte, written, sorted back and read again in every layer.
-    h = jax.lax.ragged_dot(rows, w_in, sizes, preferred_element_type=x.dtype)
-    act = _gated(h.astype(jnp.float32)).astype(x.dtype)
-    y = jax.lax.ragged_dot(act, w_out, sizes, preferred_element_type=x.dtype)
-    # Back in the order of (choice, token), where the gates are. A choice
-    # no expert here serves sorted past the last group: whatever the
-    # product left in its row is not part of the sum.
-    y = y[jnp.argsort(order)].reshape(k, t_len, -1)
-    weight = jnp.where(local >= 0, gates.T, 0.0)
-    out = jnp.sum(
-        jnp.where(local[..., None] >= 0, y.astype(jnp.float32), 0.0)
-        * weight[..., None],
-        axis=0,
+    rows = key.shape[0]
+    _, order, gate = jax.lax.sort(
+        (key, jnp.arange(rows, dtype=jnp.int32), gates.T.reshape(-1)),
+        num_keys=1, is_stable=True,
     )
-    return out, (x, gates, w_in, w_out, local, order, sizes, h)
+    sizes = jnp.sum(
+        key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :], axis=0,
+        dtype=jnp.int32,
+    )
+    padding = round_up(rows, row_tile(rows)) - rows
+    order = jnp.concatenate([order, rows + jnp.arange(padding, dtype=jnp.int32)])
+    return order, jnp.pad(gate, (0, padding)), sizes
+
+
+def _prefix(order, gate, held, t_len: int, p: int):
+    """Of the first `p` sorted rows, the first `held` of them served here:
+    the token each belongs to, whether an expert here serves it, and its
+    gate, nought where none does."""
+    here = jnp.arange(p, dtype=jnp.int32) < held
+    return order[:p] % t_len, here, jnp.where(here, gate[:p], 0.0)
+
+
+def _summed(rows, token, here, held, t_len: int, k: int, *, interpret):
+    """[t_len, D] float32: the held rows summed a token."""
+    key, by_token = jax.lax.sort(
+        (jnp.where(here, token, t_len), jnp.arange(token.shape[0], dtype=jnp.int32)),
+        num_keys=1,
+    )
+    return summed_by_token(
+        rows[by_token], key, held, t_len=t_len, most=k, interpret=interpret
+    )
+
+
+def _forward_rung(p, k, interpret, x, w_in, w_out, order, gate, held, at):
+    t_len = x.shape[0]
+    token, here, gate = _prefix(order, gate, held, t_len, p)
+    # The products round to the operands' type on the way out (float32
+    # inside): of a chunk's rows by 4,096 a float32 copy is a third of a
+    # gigabyte, written and read again in every layer.
+    h = rows_by_group(x[token], w_in, at, x.dtype, interpret=interpret)
+    # The gate goes in before the second product, so what comes out of it
+    # is summed a token as it is.
+    act = jnp.where(
+        here[:, None], _gated(h.astype(jnp.float32)) * gate[:, None], 0.0
+    ).astype(x.dtype)
+    y = rows_by_group(act, w_out, at, x.dtype, interpret=interpret)
+    out = _summed(y, token, here, held, t_len, k, interpret=interpret)
+    # h for the backward, as long as the longest rung's: where nothing is
+    # differentiated XLA drops it from the branches (a chunk program's
+    # compiled text holds no such pad: PR 41).
+    return out, jnp.pad(h, ((0, order.shape[0] - p), (0, 0)))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _forward(x, ids, gates, local_of, w_in, w_out, valid, *, interpret):
+    """(out, what the backward needs). One traced function a shape, with
+    and without a backward, so a program traces it and lowers its kernels
+    once however many layers call it."""
+    held = w_in.shape[0]
+    order, gate, sizes = _sorted_by_expert(ids, gates, local_of, valid, held)
+    rungs = ladder(ids.size, held / local_of.shape[0])
+    total = jnp.sum(sizes)
+    out, h = jax.lax.switch(
+        _rung_of(total, rungs),
+        [functools.partial(_forward_rung, p, ids.shape[1], interpret) for p in rungs],
+        x, w_in, w_out, order, gate, total, walk(sizes, order.shape[0]),
+    )
+    return out, (x, gates, local_of, w_in, w_out, order, gate, sizes, h)
 
 
 @jax.custom_vjp
@@ -128,70 +212,83 @@ def routed_grouped(
     w_in: jax.Array, w_out: jax.Array, valid: jax.Array,
 ) -> jax.Array:
     """As `routed_dense`, by grouped products over the assignments sorted
-    by expert. `valid` [T] marks the real tokens: a bucket's padding is
+    by expert, of which only a prefix that holds every held one is touched
+    (`ladder`). `valid` [T] marks the real tokens: a bucket's padding is
     routed nowhere. Differentiable in x, gates, w_in and w_out, with no
     capacity either way: the backward is two more grouped products for the
     rows and two whose contracting axis is the ragged one for the
-    matrices."""
+    matrices, over the same prefix."""
     return _grouped_forward(x, ids, gates, local_of, w_in, w_out, valid)[0]
 
 
-def _grouped_backward(residuals, d_out):
+def _grouped_forward(x, ids, gates, local_of, w_in, w_out, valid):
+    return _forward(x, ids, gates, local_of, w_in, w_out, valid, interpret=_on_cpu())
+
+
+def _backward_rung(
+    p, interpret, x, gates, w_in, w_out, order, gate, held, h, d_out, at
+):
     """d_out [T, D] float32. With r a sorted row of token t(r), choice
     c(r), expert e(r), gate g(r) (nought where absent), a = silu(h_g) h_u:
         du = d_out[t(r)] W2_e^T            d gate = a . du
         dW2_e = sum_r (g a)^T d_out[t(r)]  da = g du
         dh = da * d(gated)/dh              dW1_e = sum_r x[t(r)]^T dh
         dx[t] = sum over r of token t of dh W1_e^T."""
-    x, gates, w_in, w_out, local, order, sizes, h = residuals
-    k, t_len = local.shape
+    t_len, k = gates.shape
     dtype = x.dtype
-    token = order % t_len
-    here = (local >= 0).reshape(-1)[order]  # sorted: the held rows first
-    g = jnp.where(here, gates.T.reshape(-1)[order], 0.0)[:, None]  # [k * T, 1]
+    token, here, gate = _prefix(order, gate, held, t_len, p)
     rows, d_rows = x[token], d_out.astype(dtype)[token]
     # Past the last group the forward's product left anything in h.
-    h32 = jnp.where(here[:, None], h.astype(jnp.float32), 0.0)
+    h32 = jnp.where(here[:, None], h[:p].astype(jnp.float32), 0.0)
     act = _gated(h32)
-    du = jax.lax.ragged_dot(
-        d_rows, w_out.swapaxes(1, 2), sizes, preferred_element_type=jnp.float32
+    du = rows_by_group(
+        d_rows, w_out, at, jnp.float32, transposed=True, interpret=interpret
     )
     du = jnp.where(here[:, None], du, 0.0)  # past the last group: anything
     d_gate = jnp.sum(act * du, axis=-1)
-    ragged_rows = jax.lax.RaggedDotDimensionNumbers(
-        dot_dimension_numbers=(((0,), (0,)), ((), ())),
-        lhs_ragged_dimensions=[0], rhs_group_dimensions=[],
-    )
-    d_w_out = jax.lax.ragged_dot_general(
-        (act * g).astype(dtype), d_rows, sizes, ragged_rows,
-        preferred_element_type=jnp.float32,
+    d_w_out = matrices_by_group(
+        (act * gate[:, None]).astype(dtype), d_rows, at, w_out.dtype,
+        interpret=interpret,
     )
     gate_half, up_half = jnp.split(h32, 2, axis=-1)
     sig = jax.nn.sigmoid(gate_half)
-    da = du * g
+    da = du * gate[:, None]
     dh = jnp.concatenate(
         [da * up_half * sig * (1.0 + gate_half * (1.0 - sig)), da * gate_half * sig],
         axis=-1,
     ).astype(dtype)
-    d_w_in = jax.lax.ragged_dot_general(
-        rows, dh, sizes, ragged_rows, preferred_element_type=jnp.float32
+    d_w_in = matrices_by_group(rows, dh, at, w_in.dtype, interpret=interpret)
+    d_sorted = rows_by_group(dh, w_in, at, dtype, transposed=True, interpret=interpret)
+    d_x = _summed(d_sorted, token, here, held, t_len, k, interpret=interpret)
+    # Back where the gates are, (choice, token): a row's number is its place.
+    d_gates = jnp.zeros((k * t_len,), d_gate.dtype).at[order[:p]].set(
+        d_gate, mode="drop", unique_indices=True
     )
-    d_sorted = jax.lax.ragged_dot(
-        dh, w_in.swapaxes(1, 2), sizes, preferred_element_type=dtype
-    )
-    back = jnp.argsort(order)
-    d_x = jnp.sum(
-        jnp.where(
-            local[..., None] >= 0,
-            d_sorted[back].reshape(k, t_len, -1).astype(jnp.float32), 0.0,
-        ),
-        axis=0,
-    )
-    d_gates = d_gate[back].reshape(k, t_len).T
     return (
-        d_x.astype(dtype), None, d_gates.astype(gates.dtype), None,
-        d_w_in.astype(w_in.dtype), d_w_out.astype(w_out.dtype), None,
+        d_x.astype(dtype), d_gates.reshape(k, t_len).T.astype(gates.dtype),
+        d_w_in, d_w_out,
     )
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _backward(
+    x, gates, local_of, w_in, w_out, order, gate, sizes, h, d_out, *, interpret
+):
+    rungs = ladder(gates.size, w_in.shape[0] / local_of.shape[0])
+    total = jnp.sum(sizes)
+    return jax.lax.switch(
+        _rung_of(total, rungs),
+        [functools.partial(_backward_rung, p, interpret) for p in rungs],
+        x, gates, w_in, w_out, order, gate, total, h, d_out,
+        walk(sizes, order.shape[0]),
+    )
+
+
+def _grouped_backward(residuals, d_out):
+    d_x, d_gates, d_w_in, d_w_out = _backward(
+        *residuals, d_out, interpret=_on_cpu()
+    )
+    return d_x, None, d_gates, None, d_w_in, d_w_out, None
 
 
 routed_grouped.defvjp(_grouped_forward, _grouped_backward)

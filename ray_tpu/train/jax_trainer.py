@@ -72,26 +72,64 @@ def prepare_batch(batch: Any) -> Any:
     return out
 
 
+def _committed_to_mesh(tree: Any) -> Any:
+    """`tree` with the array leaves no one placed (a `jax.jit(tx.init)`'s
+    optimizer state lands uncommitted on the default device) committed to the
+    session mesh, replicated: where `prepare_params` puts a parameter no
+    rule shards, and where a step puts what it returns. The same device, so
+    nothing is copied on a one-device mesh."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    replicated = NamedSharding(session.get_mesh(), PartitionSpec())
+
+    def commit(leaf):
+        if isinstance(leaf, jax.Array) and not leaf.committed:
+            return jax.device_put(leaf, replicated)
+        return leaf
+
+    return jax.tree_util.tree_map(commit, tree)
+
+
 def prepare_step(step_fn: Callable, donate_argnums=(0,)) -> Callable:
     """jit the train step; shardings propagate from the (already-sharded)
     inputs, XLA inserts the gradient collectives. Under an instrumented
     session each call is timed into the `compute` phase and bounded by
     block_until_ready — otherwise async dispatch would bill device time to
-    whatever host code touches the result next."""
+    whatever host code touches the result next.
+
+    A step's donated arguments are what it returns, one call later. The
+    first call's are placed as those will be (`_committed_to_mesh`), so the
+    second call finds the first call's executable: a state that arrives
+    uncommitted has another cache key than the committed one the step
+    hands back, and the step was traced, lowered and read from the compile
+    cache twice at set-up. On a mesh of several devices that covers the
+    leaves nobody placed (adam's count); what `jax.jit(tx.init)` placed
+    there stays where it is."""
     import jax
 
     from ray_tpu.train.observability import current_profiler
 
     jitted = jax.jit(step_fn, donate_argnums=donate_argnums)
     # The session's profiler is fixed for the loop's lifetime, so decide
-    # once at prepare time: uninstrumented (or driver-side) callers get the
-    # jit callable itself — full jit API (.lower, .clear_cache), zero
-    # per-call overhead.
-    profiler = current_profiler()
-    if profiler is None:
+    # once at prepare time: driver-side callers get the jit callable itself
+    # — full jit API (.lower, .clear_cache), zero per-call overhead.
+    if session._get_session() is None:
         return jitted
+    profiler = current_profiler()
+    donated = {donate_argnums} if isinstance(donate_argnums, int) else set(donate_argnums)
+    placed = False  # the first call's donated arguments, yet
 
-    def instrumented_step(*args, **kwargs):
+    def step(*args, **kwargs):
+        nonlocal placed
+        if not placed:
+            placed = True
+            args = tuple(
+                _committed_to_mesh(arg) if i in donated else arg
+                for i, arg in enumerate(args)
+            )
+        if profiler is None:
+            return jitted(*args, **kwargs)
         # `train.compute` is the phase's annotation; the wait inside it
         # tells the dispatch from the blocked host on a device trace.
         with profiler.phase("compute"):
@@ -100,8 +138,8 @@ def prepare_step(step_fn: Callable, donate_argnums=(0,)) -> Callable:
                 jax.block_until_ready(out)
         return out
 
-    instrumented_step.jitted = jitted  # for `step_device_report`
-    return instrumented_step
+    step.jitted = jitted  # for `step_device_report`, `.lower`, `.clear_cache`
+    return step
 
 
 def step_device_report(step: Callable, *args) -> dict:

@@ -111,7 +111,9 @@ def _train_metrics():
         "train_expert_assignments",
         "Token-to-expert assignments a routed-experts model reported with "
         "its loss, by where the chosen expert lives: held by the reporting "
-        "rank, or absent (held by another chip)",
+        "rank, or absent (held by another chip); and, where the report "
+        "says so, the sorted rows the grouped experts walked for the held "
+        "ones (walked / (held + absent): the share of every row's work done)",
         tag_keys=("where",),
     )
     c_load_max = get_or_create(
@@ -172,8 +174,11 @@ EXPERT_COUNTS = ("held", "absent", "touched", "load_max")
 
 def expert_counts(experts: dict) -> dict:
     """A report's ``experts`` entry as plain numbers: the scalar counts as
-    ints, ``load`` (tokens a held expert) as a list."""
+    ints (``walked`` where the report has it), ``load`` (tokens a held
+    expert) as a list."""
     out = {k: int(experts.get(k, 0)) for k in EXPERT_COUNTS}
+    if experts.get("walked") is not None:
+        out["walked"] = int(experts["walked"])
     if experts.get("load") is not None:
         out["load"] = [int(v) for v in experts["load"]]
     return out
@@ -184,6 +189,8 @@ def add_expert_counts(total: Optional[dict], counts: dict) -> dict:
     if total is None:
         return dict(counts, load=list(counts.get("load", [])))
     out = {k: total[k] + counts[k] for k in EXPERT_COUNTS}
+    if "walked" in total and "walked" in counts:
+        out["walked"] = total["walked"] + counts["walked"]
     mine, theirs = total.get("load", []), counts.get("load", [])
     out["load"] = (
         [a + b for a, b in zip(mine, theirs)] if len(mine) == len(theirs) else []
@@ -273,7 +280,8 @@ class StepProfiler:
         return the record so it can ride the report to the driver.
         `experts` is what the report says of its routed experts (the
         routing's counts summed over the round's steps: ``held``,
-        ``absent``, ``touched``, ``load_max``, ``load`` an expert): kept on
+        ``absent``, ``touched``, ``load_max``, ``load`` an expert, and
+        ``walked`` where the step counts it): kept on
         the record as plain numbers."""
         now_p = time.perf_counter()
         now_ts = time.time()
@@ -418,7 +426,7 @@ class TrainRunRecord:
         # dicts keep the per-round loop allocation-free.
         self._metrics = _train_metrics()
         self._phase_tags = {p: {"phase": p} for p in TRAIN_PHASES}
-        self._where_tags = {w: {"where": w} for w in ("held", "absent")}
+        self._where_tags = {w: {"where": w} for w in ("held", "absent", "walked")}
         # The routed experts' counts over every rank and round that
         # reported them (None: a model without, or a loop that sends none).
         self.experts: Optional[dict] = None
@@ -455,6 +463,8 @@ class TrainRunRecord:
             if experts:
                 c_assignments.inc(float(experts["held"]), self._where_tags["held"])
                 c_assignments.inc(float(experts["absent"]), self._where_tags["absent"])
+                if "walked" in experts:
+                    c_assignments.inc(float(experts["walked"]), self._where_tags["walked"])
                 c_load_max.inc(float(experts["load_max"]))
                 self.experts = add_expert_counts(self.experts, experts)
 

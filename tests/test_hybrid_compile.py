@@ -14,9 +14,17 @@ And the paged kernel fed Laguna's widest chunk at the widths its cell runs
 of 512, and at 48 over 8 without; blocks of 16, tables of 896): a cached
 head's query heads are the rows of one product, 576 and 384 of them, and
 the q tile's blocks stay under the compiler's scoped VMEM limit.
+
+And one chunk program of each at the cell's widths with the grouped experts'
+own kernels in it (PR 41): granite's 2,048-token and Laguna's 1,024-token
+starting chunk compile, fit beside the pools, keep them in place, name every
+instruction of the grouped path `llm.moe.routed`, and hand Mosaic the
+experts' kernels once a rung of the ladder, not once a layer.
 """
 
+import gc
 import os
+import re
 import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
@@ -30,6 +38,7 @@ from ray_tpu.llm import hybrid_runner as hr
 from ray_tpu.llm.config import EngineConfig
 from ray_tpu.models import granite_hybrid as gh
 from ray_tpu.models import laguna
+from ray_tpu.ops import grouped_experts
 from ray_tpu.ops.paged_flash import paged_flash_attention
 
 SLOTS, TABLE, BLOCK, BLOCKS = 32, 400, 16, 16384
@@ -52,6 +61,15 @@ def chip():
     yield SingleDeviceSharding(topology.devices[0])
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _leave_a_small_heap():
+    """What this file traced goes when it is done: the worker that ran it
+    runs other files after, and some of them time a full `gc.collect()`."""
+    yield
+    jax.clear_caches()
+    gc.collect()
 
 
 def test_real_width_decode_program_fits_a_v5e(chip, monkeypatch):
@@ -139,3 +157,71 @@ def test_lagunas_widest_chunk_stacks_a_cached_heads_query_heads(
         sds((1,), jnp.int32), fed, fed,
     ).compile()  # raises where the blocks pass the scoped VMEM limit
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+def _grouped_path_is_routed(text):
+    """Every instruction `routed_grouped` traced, its kernels among them,
+    is timed as the routed experts; how many of them are Mosaic kernels."""
+    scopes = hr.scopes_of(text)
+    routed = [
+        line for line in text.splitlines()
+        if re.search(r"jit\((_forward|rows_by_group|summed_by_token)\)", line)
+    ]
+    named = [re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line) for line in routed]
+    assert routed and all(named)
+    off = [m[1] for m in named if scopes.get(m[1]) != "llm.moe.routed"]
+    assert not off, off[:5]
+    return sum('custom_call_target="tpu_custom_call"' in line for line in routed)
+
+
+@pytest.mark.parametrize("model", ["granite", "laguna"])
+def test_a_chunk_program_with_the_grouped_kernels_fits_a_v5e(chip, monkeypatch, model):
+    monkeypatch.setattr(sys.modules["ray_tpu.ops.paged_flash"], "_on_cpu", lambda: False)
+    monkeypatch.setattr(grouped_experts, "_on_cpu", lambda: False)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    if model == "granite":
+        cfg = gh.GraniteHybridConfig(experts_held=tuple(range(36)))
+        shapes, chunk, expert_layers = gh._leaf_shapes(cfg), 2048, 10
+        pools = [sds((1, BLOCKS, BLOCK, 8 * 128))]
+        conv = tuple(sds((SLOTS, 3, cfg.conv_dim)) for _ in range(9))
+        ssm = tuple(sds((SLOTS, 128, 64, 128), jnp.float32) for _ in range(9))
+        tables, limit = (i32(TABLE),), 14.4e9
+        rungs = grouped_experts.ladder(chunk * 10, 36 / 72)
+        assert rungs == (12800, 20480)
+    else:
+        cfg, chunk, expert_layers = LAGUNA, 1024, 11
+        shapes = laguna._leaf_shapes(cfg)
+        pools = [sds((3, 13312, 16, 8 * 128)), sds((9, 1858, 16, 8 * 128))]
+        conv = ssm = ()
+        tables, limit = (i32(896), i32(896)), 15.2e9
+        rungs = grouped_experts.ladder(chunk * 10, 32 / 256)
+        assert rungs == (1792, 7168, 10240)
+    params = jax.tree_util.tree_map(
+        sds, shapes, is_leaf=lambda v: isinstance(v, tuple)
+    )
+    programs = hr._HybridPrograms(cfg, BLOCK, "pallas")
+    lowered = programs.prefill_fn.lower(
+        params, tuple(pools), tuple(pools), conv, ssm, i32(1, chunk), tables,
+        i32(), i32(),
+    )
+    # Handed to Mosaic: the paged kernel an attention layer, and the grouped
+    # experts' three once a rung however many layers call them.
+    attention = sum(kind != hr.MAMBA for kind in cfg.layer_types)
+    kernels = lowered.as_text().count("tpu_custom_call")
+    assert kernels == attention + 3 * len(rungs), kernels
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * sum(
+        2 * pool.size for pool in pools
+    )  # every K/V pool updated in place
+    held = (
+        memory.argument_size_in_bytes + memory.temp_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    )
+    assert held < limit, held
+    # In the compiled program every layer has its own copy of each rung's.
+    assert _grouped_path_is_routed(compiled.as_text()) == 3 * len(rungs) * expert_layers

@@ -6,8 +6,10 @@ adamw over float32 masters), compiled for a described TPU v5e without one
 parameters and optimizer state as shapes only, nothing runs. What it
 proves: Mosaic takes the flash kernels at 32 query heads over 4 key heads of
 128 under a window of 1,024 at 8,192 tokens, forward and backward, and
-without the window; XLA:TPU takes the grouped products and the two whose
-contracting axis is the ragged one; arguments and temporaries fit the
+without the window, and the grouped experts' own kernels (a row by its
+group's matrix, a group's rows by its rows, a token's rows summed) at every
+rung of the ladder, under `llm.moe.routed`, lowered once a rung and not once
+a layer; arguments and temporaries fit the
 chip's 16.9 GB at the cell's `sequences_per_step`; and the donated
 parameters and optimizer state alias, so no step copies them.
 """
@@ -18,12 +20,15 @@ import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.models import mellum
+from ray_tpu.ops import grouped_experts
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.util.device_report import scopes_of
 
@@ -67,6 +72,7 @@ def _leave_a_small_heap():
 def on_tpu(monkeypatch):
     # The flash kernels choose interpret mode from the backend, the CPU here.
     monkeypatch.setattr(sys.modules["ray_tpu.ops.flash_attention"], "_on_cpu", lambda: False)
+    monkeypatch.setattr(grouped_experts, "_on_cpu", lambda: False)
 
 
 @pytest.mark.parametrize("window", [1024, None])
@@ -99,9 +105,19 @@ def test_train_step_fits_a_v5e_and_updates_in_place(chip, on_tpu):
         jax.eval_shape(tx.init, params),
     )
     tokens = jax.ShapeDtypeStruct((SEQUENCES, TOKENS), jnp.int32, sharding=chip)
-    compiled = jax.jit(
+    lowered = jax.jit(
         mellum.train_step(CELL, tx), donate_argnums=(0, 1)
-    ).lower(params, state, tokens).compile()
+    ).lower(params, state, tokens)
+    # Kernel bodies Mosaic is handed: the flash kernels a layer (16), and the
+    # grouped experts' once a rung whatever the layers: three forward and
+    # five backward at each of the ladder's two rungs, and a forward kernel
+    # or two again where a transformation (the recomputed layer) lowers its
+    # own copy. A kernel lowered a layer would be 64 more.
+    rungs = grouped_experts.ladder(SEQUENCES * TOKENS * 8, 16 / 64)
+    assert rungs == (40960, 131072)
+    kernels = lowered.as_text().count("tpu_custom_call")
+    assert 16 + 8 * len(rungs) <= kernels <= 16 + 11 * len(rungs), kernels
+    compiled = lowered.compile()
     memory = compiled.memory_analysis()
     masters = 4 * 595_153_152
     assert memory.alias_size_in_bytes >= 3 * masters  # weights and both moments
@@ -116,3 +132,16 @@ def test_train_step_fits_a_v5e_and_updates_in_place(chip, on_tpu):
     # three kernels a layer and again the forward one where a layer is recomputed
     assert text.count('custom_call_target="tpu_custom_call"') >= 12
     assert set(mellum.SCOPES) <= set(scopes_of(text).values())
+    # Every instruction of the grouped path, its kernels among them, forward
+    # and backward, is timed as the routed experts.
+    scopes = scopes_of(text)
+    routed = [
+        line for line in text.splitlines()
+        if re.search(r"jit\((_forward|_backward|rows_by_group|matrices_by_group|summed_by_token)\)", line)
+    ]
+    named = [re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line) for line in routed]
+    assert len(routed) > 200 and all(named)
+    off = [m[1] for m in named if scopes.get(m[1]) != "llm.moe.routed"]
+    assert not off, off[:5]
+    ours = [line for line in routed if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(ours) >= 4 * (3 + 3 + 5)  # a layer: forward, recomputed, backward

@@ -171,15 +171,19 @@ PINS = {
     "gpt.decode.reference": "e93e16a7b7e88d9b",
     "gpt.suffix.reference": "8ccadbc3c2bcfd51",
     "granite.jit__decode_step.None.reference": "0a804be3d6cfb9a2",
-    "granite.jit__prefill_step.16.reference": "e2ec22f789911746",
-    "granite.jit__prefill_suffix_step.16.reference": "61568b64430187b8",
+    # The four chunk programs re-taken at PR 41 (on e75c747 + that PR's
+    # grouped experts): `routed_grouped` walks a rung of the sorted rows
+    # through `ops/grouped_matmul.py`'s kernels, and a chunk returns the
+    # rows walked beside the held assignments.
+    "granite.jit__prefill_step.16.reference": "d5dea66b81d7fec6",
+    "granite.jit__prefill_suffix_step.16.reference": "fc2f0b11e8caa50e",
     "gpt.decode.pallas": "df37fa95058d4865",
     "gpt.suffix.pallas": "9a5618e5e93e45f8",
     "granite.jit__decode_step.None.pallas": "ee633a9917e56c8b",
-    # Re-taken at PR 38 (on 562fae4 + that PR's kernel): a fed chunk of a
-    # grouped model takes a cached head's query heads in one product.
-    "granite.jit__prefill_step.16.pallas": "29d02d613c48855d",
-    "granite.jit__prefill_suffix_step.16.pallas": "36e94c46d86210d5",
+    # (Re-taken at PR 38 too: a fed chunk of a grouped model takes a cached
+    # head's query heads in one product.)
+    "granite.jit__prefill_step.16.pallas": "2867823fa685056b",
+    "granite.jit__prefill_suffix_step.16.pallas": "6bb01919de69c2b5",
 }
 
 

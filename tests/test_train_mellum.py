@@ -3,9 +3,12 @@
 the loss falls, and the routing's counts a report carries arrive in
 `train.report`'s history, in the step profiler's round record, in
 `TrainRunRecord.report()` and in the `train_*` metric family, with
-`held + absent == tokens x choices x layers`. And the training side's
-device report: the scope map of the prepared step, backward instructions
-under the scope their forward was traced in.
+`held + absent == tokens x choices x layers`, and beside them the sorted
+rows the grouped experts walked for the held ones (`walked`). And the
+training side's device report: the scope map of the prepared step, backward
+instructions under the scope their forward was traced in. And a prepared
+step called as the benchmark's runners call it holds one executable: it is
+lowered once at set-up, not again when it first meets its own outputs.
 """
 
 import gc
@@ -25,7 +28,7 @@ from ray_tpu.util import metrics
 from ray_tpu.util.device_report import scopes_of
 
 STEPS, EVERY, BATCH, SEQ = 12, 4, 8, 64  # 8: the test mesh is 8 CPU devices, data-parallel
-SCALARS = ("held", "absent", "touched", "load_max")
+SCALARS = ("held", "absent", "touched", "load_max", "walked")
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -121,11 +124,76 @@ def test_counts_are_summed_in_the_run_report(fitted):
     ).tolist()
 
 
-@pytest.mark.parametrize("where", ["held", "absent"])
+@pytest.mark.parametrize("where", ["held", "absent", "walked"])
 def test_counts_reach_the_metric_family(fitted, where):
     _, result, _, counters = fitted
     series = counters["train_expert_assignments"]
     assert series[(("where", where),)] == result.train_report["experts"][where]
+
+
+def test_walked_lies_between_the_held_rows_and_all_of_them(fitted):
+    from ray_tpu.ops.grouped_experts import ladder
+
+    cfg, result, _, _ = fitted
+    rungs = ladder(
+        BATCH * SEQ * cfg.num_experts_per_tok, len(cfg.experts_held) / cfg.num_experts
+    )
+    calls = EVERY * cfg.num_layers  # of the grouped experts, a report
+    for reported in result.metrics_history:
+        experts = reported["experts"]
+        assert experts["held"] <= experts["walked"] <= experts["held"] + experts["absent"]
+        # every call walked one rung of its ladder
+        assert calls * rungs[0] <= experts["walked"] <= calls * rungs[-1]
+    assert result.train_report["experts"]["walked"] == sum(
+        m["experts"]["walked"] for m in result.metrics_history
+    )
+
+
+@pytest.mark.parametrize("devices", [1, 8])
+def test_a_prepared_step_is_lowered_once(devices):
+    """From `prepare_params` and `jax.jit(tx.init)(params)`, as the
+    benchmark's runners call it: the optimizer state arrives uncommitted,
+    the step hands back a committed one, and the second call must find the
+    first call's executable (on the parent it held two: the step was traced,
+    lowered and read from the compile cache twice at set-up). On one device,
+    and on the test mesh's eight, data-parallel, as found."""
+    import ray_tpu
+    from ray_tpu.air import session
+    from ray_tpu.parallel import MeshSpec
+
+    cfg = toy_config()
+    seen = {}
+
+    def loop(_config):
+        if devices == 1:
+            session._require_session().context.mesh = MeshSpec().build(jax.devices()[:1])
+        assert train.get_mesh().devices.size == devices
+        params = train.prepare_params(mellum.init_params(cfg, 0))
+        tx = optax.adamw(3e-3)
+        opt_state = jax.jit(tx.init)(params)
+        seen["uncommitted"] = sum(
+            not leaf.committed for leaf in jax.tree_util.tree_leaves(opt_state)
+        )
+        step = train.prepare_step(mellum.train_step(cfg, tx), donate_argnums=(0, 1))
+        tokens = np.random.RandomState(0).randint(
+            0, cfg.rows_held, size=(BATCH, SEQ)
+        ).astype(np.int32)
+        held = []
+        for _ in range(3):
+            params, opt_state, loss, _ = step(params, opt_state, train.prepare_batch(tokens))
+            held.append(step.jitted._cache_size())
+        seen["held"], seen["loss"] = held, float(loss)
+
+    ray_tpu.init(num_cpus=4)
+    try:
+        result = JaxTrainer(
+            loop, scaling_config=ScalingConfig(num_workers=1, cpus_per_worker=1)
+        ).fit()
+    finally:
+        ray_tpu.shutdown()
+    assert result.error is None, result.error
+    assert seen["uncommitted"] > 0  # else this proves nothing
+    assert seen["held"] == [1, 1, 1] and np.isfinite(seen["loss"])
 
 
 def test_load_max_reaches_the_metric_family(fitted):
@@ -156,6 +224,9 @@ def test_a_report_without_counts_leaves_none():
     assert record["experts"] == {
         "held": 3, "absent": 5, "touched": 0, "load_max": 0, "load": [1, 2],
     }
+    # `walked` rides where the step counts it, and only there.
+    record = profiler.end_round(experts={"held": 3, "absent": 5, "walked": jnp.int32(4)})
+    assert record["experts"]["walked"] == 4
 
 
 @pytest.mark.parametrize(
