@@ -46,7 +46,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 NULL_BLOCK = 0
 
@@ -137,6 +137,26 @@ class CacheClass:
     name: str
     layers: int
     horizon: Optional[int] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class RecurrentKind:
+    """One kind of recurrent layer a model module declares
+    (`recurrent_kinds(cfg)`, by the kind's name in `layer_types`): the
+    arrays a state slot keeps for one such layer, as (name, shape, dtype),
+    and the layer's two functions, `prefill(cfg, p, u, *arrays, length)`
+    for a chunk of one sequence that starts from `arrays` (zeros at a
+    sequence's start) and `decode(cfg, p, u, *arrays)` for one token a lane
+    from the lanes' `arrays` ([lanes, *shape]); both return the mixer's
+    output and then the arrays as the step leaves them. `scan_scope` and
+    `update_scope` are the named scopes under which the runner writes them
+    back: the kind's own, a chunk's and a decode step's."""
+
+    arrays: Tuple[Tuple[str, Tuple[int, ...], Any], ...]
+    prefill: Callable
+    decode: Callable
+    scan_scope: str
+    update_scope: str
 
 
 def window_class_of(model_config) -> Optional[CacheClass]:

@@ -1,15 +1,16 @@
 """Jitted step programs for a model whose layers declare a kind and the
-state each keeps: `mamba` (a recurrent state a sequence), `attention` /
-`full_attention` (K and V of every position, paged) and
-`sliding_attention` (K and V of the last `horizon` positions, paged in a
-cache class of its own). The model is a module of pure functions over a
-plain tree that its configuration names (`llm_model`:
-`ray_tpu.models.granite_hybrid`, `ray_tpu.models.laguna`); what this runner
-reads of it is `init_params`, `embed`, `head`, `run_layers`,
-`attention_qkv` / `attention_out`, `ATTENTION_SCOPES`,
-`expert_shape` (and `recurrent_shape`), and of its configuration
-`layer_types`, `cache_classes` / `cache_class_of`, `heads_of`,
-`attention_scale`, `num_key_value_heads`, `head_dim`.
+state each keeps: a recurrent kind (arrays a sequence, which the model
+declares: `cache.RecurrentKind`), `attention` / `full_attention` (K and V
+of every position, paged) and `sliding_attention` (K and V of the last
+`horizon` positions, paged in a cache class of its own). The model is a
+module of pure functions over a plain tree that its configuration names
+(`llm_model`: `ray_tpu.models.granite_hybrid`, `ray_tpu.models.laguna`,
+`ray_tpu.models.olmo_hybrid`); what this runner reads of it is
+`init_params`, `embed`, `head`, `run_layers`, `attention_qkv` /
+`attention_out`, `ATTENTION_SCOPES`, and where it has them
+`recurrent_kinds` / `recurrent_shape` and `expert_shape`, and of its
+configuration `layer_types`, `cache_classes` / `cache_class_of`,
+`heads_of`, `attention_scale`, `num_key_value_heads`, `head_dim`.
 
 The same three program shapes `model_runner` compiles, under the same
 names: one decode program over all decode lanes, and for every prefill
@@ -18,9 +19,10 @@ cached context) and one that continues it (`_prefill_suffix_step`: the
 chunk starts from the slot's state and attends the cached context through
 the block tables). A cache class has one K and one V pool over its
 attention layers ([layers of the class, N of the class, bs, kv heads * head
-size]) and a block table a sequence; every Mamba layer has a state pool
-[slots, H, P, N] float32 and a convolution-tail pool [slots, d_conv - 1,
-conv_dim], one array a layer. All are donated through every step. A layer
+size]) and a block table a sequence; every recurrent layer has a pool
+[slots, *shape] for each array its kind declares (granite's Mamba-2: a
+float32 state and a convolution tail), one array a layer. All are donated
+through every step. A layer
 of a class with a horizon attends through `paged_attention_impl(...,
 window=horizon)`: the entries of its table below the window are null and
 never read.
@@ -33,14 +35,17 @@ length 0: idle, or a sequence still prefilling) as they were. Nothing
 gathers or scatters a state. A slot is never cleared: the program that
 starts a sequence does not read it.
 
-The decode program returns the sampled tokens and the step's routing
-counts in one int32 vector, so the engine reads both in the one fetch it
-makes anyway, one step behind at pipeline depth 1; its token input has the
-same length so that a step can be chained on the last one's output.
+The decode program of a model with routed experts (one that publishes
+`expert_shape`) returns the sampled tokens and the step's routing counts in
+one int32 vector, so the engine reads both in the one fetch it makes
+anyway, one step behind at pipeline depth 1; its token input has the same
+length so that a step can be chained on the last one's output. A dense
+model's returns the tokens alone and has no routing counters.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib
 import threading
 from typing import Callable, Dict, Optional, Sequence
@@ -58,12 +63,28 @@ from ray_tpu.util.device_report import scopes_of  # noqa: F401  (also the name t
 
 # The routing counts a decode step appends to its tokens, in this order.
 DECODE_COUNTS = ("held", "absent", "touched", "load_max")
-MAMBA = "mamba"
 
 
 def model_of(cfg):
     """The module of pure functions `cfg` names (`llm_model`)."""
     return importlib.import_module(type(cfg).llm_model)
+
+
+def recurrent_kinds(cfg) -> dict:
+    """kind -> `cache.RecurrentKind` of the recurrent kinds the model of
+    `cfg` declares ({}: every layer keeps its memory in the paged cache)."""
+    declare = getattr(model_of(cfg), "recurrent_kinds", None)
+    return {} if declare is None else declare(cfg)
+
+
+def state_layout(cfg) -> list:
+    """The state pools of `cfg`'s model, in the order the programs take
+    them: (kind, layers of the kind, (name, shape, dtype)) an array a
+    recurrent kind declares."""
+    return [
+        (kind, cfg.layer_types.count(kind), array)
+        for kind, spec in recurrent_kinds(cfg).items() for array in spec.arrays
+    ]
 
 
 def visible_pairs(offset: int, tokens: int, horizon: int) -> int:
@@ -81,7 +102,15 @@ class _HybridPrograms:
         self.model = model_of(cfg)
         self.block_size = block_size
         self.attn_impl = attn_impl
-        donated = (1, 2, 3, 4)
+        self.routed = hasattr(self.model, "expert_shape")
+        self.recurrent = recurrent_kinds(cfg)
+        # Where in the state pools a kind's arrays are (`state_layout`).
+        kinds = [kind for kind, _, _ in state_layout(cfg)]
+        self.state_at = {
+            kind: [j for j, k in enumerate(kinds) if k == kind]
+            for kind in self.recurrent
+        }
+        donated = (1, 2, 3)
         self.decode_fn = jax.jit(self._decode_step, donate_argnums=donated)
         self.prefill_fn = jax.jit(self._prefill_step, donate_argnums=donated)
         self.prefill_suffix_fn = jax.jit(
@@ -117,35 +146,36 @@ class _HybridPrograms:
         with jax.named_scope(projections):
             return model.attention_out(cfg, kind, p, u, out)
 
-    def _mixers(self, mamba, attend) -> dict:
+    def _mixers(self, recur, attend) -> dict:
         """`run_layers`' mixers by the kinds of layer the model has."""
         return {
-            kind: mamba if kind == MAMBA else (
-                lambda i, p, u, kind=kind: attend(kind, i, p, u)
-            )
+            kind: functools.partial(recur if kind in self.recurrent else attend, kind)
             for kind in dict.fromkeys(self.cfg.layer_types)
         }
 
     def _decode_step(
-        self, params, k_cache, v_cache, conv, ssm, tokens, positions,
+        self, params, k_cache, v_cache, state, tokens, positions,
         block_tables, context_lens,
     ):
         """One token for every lane that decodes. tokens [B + counts] (the
         last step's output or the host's; the first B are read), the rest
         [B] / a [B, nb] table a cache class -> (pools, [B tokens, counts]).
-        k_cache and v_cache are a pool a cache class."""
+        k_cache and v_cache are a pool a cache class, state a pool a layer
+        for each array of `state_layout`."""
         cfg, model = self.cfg, self.model
         k_cache, v_cache = list(k_cache), list(v_cache)
         b = positions.shape[0]
         live = context_lens > 0
-        conv, ssm = list(conv), list(ssm)
+        state = [list(pools) for pools in state]
         new: dict = {}
 
-        def mamba(i, p, u):
-            out, tail, state = model.mamba_decode(cfg, p, u, conv[i], ssm[i])
-            with jax.named_scope("llm.mixer.mamba.update"):
-                conv[i] = jnp.where(live[:, None, None], tail, conv[i])
-                ssm[i] = jnp.where(live[:, None, None, None], state, ssm[i])
+        def recur(kind, i, p, u):
+            spec, at = self.recurrent[kind], self.state_at[kind]
+            out, *after = spec.decode(cfg, p, u, *(state[j][i] for j in at))
+            with jax.named_scope(spec.update_scope):
+                for j, array in zip(at, after):
+                    lanes = jnp.expand_dims(live, tuple(range(1, array.ndim)))
+                    state[j][i] = jnp.where(lanes, array, state[j][i])
             return out
 
         def attend(kind, i, p, u):
@@ -156,7 +186,7 @@ class _HybridPrograms:
 
         h, counts = model.run_layers(
             cfg, params, model.embed(cfg, params, tokens[:b]),
-            self._mixers(mamba, attend), grouped=False, valid=live,
+            self._mixers(recur, attend), grouped=False, valid=live,
         )
         # Each lane's new K/V at its own position; an idle lane's table is
         # all null, so it lands in block 0.
@@ -169,39 +199,42 @@ class _HybridPrograms:
             at = (layer, block_ids[cls], offsets)
             k_cache[cls] = k_cache[cls].at[at].set(k.reshape(b, -1))
             v_cache[cls] = v_cache[cls].at[at].set(v.reshape(b, -1))
-        next_tokens = self._sample(model.head(cfg, params, h))
-        out = jnp.concatenate([
-            next_tokens.astype(jnp.int32),
-            jnp.stack([counts[k] for k in DECODE_COUNTS]).astype(jnp.int32),
-        ])
-        return (tuple(k_cache), tuple(v_cache), tuple(conv), tuple(ssm)), out
+        out = self._sample(model.head(cfg, params, h)).astype(jnp.int32)
+        if self.routed:
+            out = jnp.concatenate([
+                out, jnp.stack([counts[k] for k in DECODE_COUNTS]).astype(jnp.int32),
+            ])
+        return (tuple(k_cache), tuple(v_cache), tuple(map(tuple, state))), out
 
     def _chunk(
-        self, params, k_cache, v_cache, conv, ssm, tokens, block_table,
+        self, params, k_cache, v_cache, state, tokens, block_table,
         offset, true_len, slot, fresh: bool,
     ):
         """tokens [1, S_bucket] (0-padded past true_len) of the sequence in
         state slot `slot`, at positions offset.. -> (pools, [next token,
-        held assignments, the sorted rows the grouped experts walked for
-        them]). block_table holds a [nb] table a cache class."""
+        and of a model with routed experts the held assignments and the
+        sorted rows the grouped experts walked for them]). block_table
+        holds a [nb] table a cache class."""
         cfg, model = self.cfg, self.model
         k_cache, v_cache = list(k_cache), list(v_cache)
         sb = tokens.shape[1]
         lane = jnp.arange(sb)
         valid = lane < true_len
         positions = jnp.where(valid, offset + lane, 0)
-        conv, ssm = list(conv), list(ssm)
+        state = [list(pools) for pools in state]
         new: dict = {}
 
-        def mamba(i, p, u):
+        def recur(kind, i, p, u):
+            spec, at = self.recurrent[kind], self.state_at[kind]
             if fresh:
-                tail, state = jnp.zeros_like(conv[i][0]), jnp.zeros_like(ssm[i][0])
+                before = [jnp.zeros_like(state[j][i][0]) for j in at]
             else:
-                tail, state = conv[i][slot], ssm[i][slot]
-            out, tail, state = model.mamba_prefill(cfg, p, u, tail, state, true_len)
-            with jax.named_scope("llm.mixer.mamba.scan"):
-                conv[i] = conv[i].at[slot].set(tail.astype(conv[i].dtype))
-                ssm[i] = ssm[i].at[slot].set(state)
+                before = [state[j][i][slot] for j in at]
+            out, *after = spec.prefill(cfg, p, u, *before, true_len)
+            with jax.named_scope(spec.scan_scope):
+                for j, array in zip(at, after):
+                    pool = state[j][i]
+                    state[j][i] = pool.at[slot].set(array.astype(pool.dtype))
             return out
 
         def attend(kind, i, p, u):
@@ -213,7 +246,7 @@ class _HybridPrograms:
 
         h, counts = model.run_layers(
             cfg, params, model.embed(cfg, params, tokens[0]),
-            self._mixers(mamba, attend), grouped=True, valid=valid,
+            self._mixers(recur, attend), grouped=True, valid=valid,
         )
         bs = self.block_size
         block_ids = [
@@ -225,26 +258,27 @@ class _HybridPrograms:
             k_cache[cls] = k_cache[cls].at[at].set(k[0].reshape(sb, -1))
             v_cache[cls] = v_cache[cls].at[at].set(v[0].reshape(sb, -1))
         logits = model.head(cfg, params, h[true_len - 1])
-        out = jnp.stack(
-            [self._sample(logits), counts["held"], counts["walked"]]
-        ).astype(jnp.int32)
-        return (tuple(k_cache), tuple(v_cache), tuple(conv), tuple(ssm)), out
+        out = [self._sample(logits)]
+        if self.routed:
+            out += [counts["held"], counts["walked"]]
+        out = jnp.stack(out).astype(jnp.int32)
+        return (tuple(k_cache), tuple(v_cache), tuple(map(tuple, state))), out
 
     def _prefill_step(
-        self, params, k_cache, v_cache, conv, ssm, tokens, block_table,
+        self, params, k_cache, v_cache, state, tokens, block_table,
         true_len, slot,
     ):
         return self._chunk(
-            params, k_cache, v_cache, conv, ssm, tokens, block_table,
+            params, k_cache, v_cache, state, tokens, block_table,
             jnp.int32(0), true_len, slot, fresh=True,
         )
 
     def _prefill_suffix_step(
-        self, params, k_cache, v_cache, conv, ssm, tokens, block_table,
+        self, params, k_cache, v_cache, state, tokens, block_table,
         offset, true_len, slot,
     ):
         return self._chunk(
-            params, k_cache, v_cache, conv, ssm, tokens, block_table,
+            params, k_cache, v_cache, state, tokens, block_table,
             offset, true_len, slot, fresh=False,
         )
 
@@ -325,30 +359,24 @@ class HybridRunner:
             for _ in range(2)
         )
         # One state slot a decode lane: a running sequence holds a lane
-        # from admission on, prefilling or decoding.
+        # from admission on, prefilling or decoding. The pools are what the
+        # model's recurrent kinds declare, an array a layer.
         self.recurrent = bool(cfg.recurrent_state)
+        self.routed = self._programs.routed
         self.state_slots = slots = ecfg.max_decode_slots
-        mamba_layers = cfg.mamba_layers if self.recurrent else 0
-        self.conv = tuple(
-            jnp.zeros((slots, cfg.mamba_d_conv - 1, cfg.conv_dim), cfg.dtype)
-            for _ in range(mamba_layers)
-        )
-        self.ssm = tuple(
-            jnp.zeros(
-                (slots, cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state),
-                jnp.float32,
-            )
-            for _ in range(mamba_layers)
+        self.state = tuple(
+            tuple(jnp.zeros((slots, *shape), dtype) for _ in range(layers))
+            for _, layers, (_, shape, dtype) in state_layout(cfg)
         )
         self.state_slot_bytes = sum(
-            int(pool.nbytes) for pool in self.conv + self.ssm
+            int(pool.nbytes) for pool in jax.tree_util.tree_leaves(self.state)
         ) // slots
         # Routing and state traffic, cumulative (stats()).
         names = [
             "decode_expert_assignments", "decode_expert_assignments_absent",
             "decode_experts_touched", "decode_expert_load_max",
             "prefill_expert_assignments", "prefill_expert_rows_walked",
-        ]
+        ] if self.routed else []
         if self.recurrent:
             names = ["decode_state_bytes", *names, "prefill_scan_tokens"]
         # The horizon of the window class, where the model has one: what a
@@ -363,11 +391,16 @@ class HybridRunner:
     # ---------------- pools ----------------
 
     @property
+    def _tail(self) -> int:
+        """The routing counts behind a decode result's tokens."""
+        return len(DECODE_COUNTS) if self.routed else 0
+
+    @property
     def _pools(self):
-        return (self.k_cache, self.v_cache, self.conv, self.ssm)
+        return (self.k_cache, self.v_cache, self.state)
 
     def _set_pools(self, pools) -> None:
-        self.k_cache, self.v_cache, self.conv, self.ssm = pools
+        self.k_cache, self.v_cache, self.state = pools
 
     def _dispatched(self) -> None:
         if self.on_dispatched is not None:
@@ -410,9 +443,13 @@ class HybridRunner:
     def attention_shape(self) -> dict:
         """The K/V pools as the paged kernel reads them and how it tiles a
         chunk: of the one cache class, or by class name where the model
-        has several."""
+        has several or asks for it (`ATTENTION_SHAPE_BY_CLASS`)."""
         cfg = self.model_config
-        kinds = {cfg.cache_class_of(kind): kind for kind in cfg.layer_types if kind != MAMBA}
+        recurrent = recurrent_kinds(cfg)
+        kinds = {
+            cfg.cache_class_of(kind): kind
+            for kind in cfg.layer_types if kind not in recurrent
+        }
         shapes = {}
         for i, cls in enumerate(self.classes):
             if i not in kinds:
@@ -430,31 +467,29 @@ class HybridRunner:
                     cfg.head_dim, cfg.dtype, self.kv_cache_dtype,
                 ),
             }
-        return shapes if len(self.classes) > 1 else next(iter(shapes.values()))
+        by_class = len(self.classes) > 1 or getattr(
+            model_of(cfg), "ATTENTION_SHAPE_BY_CLASS", False
+        )
+        return shapes if by_class else next(iter(shapes.values()))
 
     def stats(self) -> dict:
         """The counters and shapes the engine's `stats()` carries for a
-        model with routed experts and, where it has them, recurrent
-        layers."""
+        model with routed experts, with recurrent layers, or both: each
+        where the model has them."""
         cfg, model = self.model_config, self.model
         recurrent = {
             "state_slots": self.state_slots,
             "state_slot_bytes": self.state_slot_bytes,
             "state_pool_bytes": self.state_slot_bytes * self.state_slots,
-            "recurrent_shape": {
-                **model.recurrent_shape(cfg),
-                "state_itemsize": 4,
-                "conv_itemsize": np.dtype(cfg.dtype).itemsize,
-            },
+            "recurrent_shape": model.recurrent_shape(cfg),
         } if self.recurrent else {}
-        return {
-            **self.counters,
-            **recurrent,
+        routed = {
             "expert_shape": {
                 **model.expert_shape(cfg),
                 "weight_itemsize": np.dtype(cfg.param_dtype).itemsize,
             },
-        }
+        } if self.routed else {}
+        return {**self.counters, **recurrent, **routed}
 
     # ---------------- programs ----------------
 
@@ -469,7 +504,7 @@ class HybridRunner:
         i32 = self._i32
         per_class = len(self.classes)
         yield "jit__decode_step", None, self._programs.decode_fn.lower(
-            self.params, *self._pools, i32(slots + len(DECODE_COUNTS)),
+            self.params, *self._pools, i32(slots + self._tail),
             i32(slots), (i32(slots, nb),) * per_class, i32(slots),
         )
         tables = (i32(nb),) * per_class
@@ -539,9 +574,10 @@ class HybridRunner:
         self._set_pools(pools)
         self._dispatched()
         self._count_transfer(arrays_in, out)
-        token, held, walked = (int(v) for v in np.asarray(out))
-        self.counters["prefill_expert_assignments"] += held
-        self.counters["prefill_expert_rows_walked"] += walked
+        token, *routing = (int(v) for v in np.asarray(out))
+        if self.routed:
+            self.counters["prefill_expert_assignments"] += routing[0]
+            self.counters["prefill_expert_rows_walked"] += routing[1]
         if self.recurrent:
             self.counters["prefill_scan_tokens"] += n
         if self.horizon is not None:
@@ -593,13 +629,14 @@ class HybridRunner:
         """As `GPTRunner.decode`: dispatch one decode over the lanes without
         waiting. On a model with recurrent layers lane i is state slot i.
         `window_tables` are the lanes' tables in the window class, where
-        the model has one. The result (and a chained `tokens`) is
-        [lanes + len(DECODE_COUNTS)]: the sampled tokens, then the step's
-        routing counts, which `count_routing` takes once fetched."""
+        the model has one. The result (and a chained `tokens`) is the
+        sampled tokens [lanes] and then, of a model with routed experts, the
+        step's routing counts (DECODE_COUNTS), which `count_routing` takes
+        once fetched."""
         chained = isinstance(tokens, jax.Array)
         if not chained:
             tokens = jnp.asarray(
-                np.concatenate([tokens, np.zeros(len(DECODE_COUNTS), np.int32)])
+                np.concatenate([tokens, np.zeros(self._tail, np.int32)])
             )
         tables = (block_tables,) if window_tables is None else (
             block_tables, window_tables
@@ -626,7 +663,10 @@ class HybridRunner:
         return out
 
     def count_routing(self, fetched: np.ndarray) -> None:
-        """Add a fetched decode result's routing counts (its tail)."""
+        """Add a fetched decode result's routing counts (its tail; a dense
+        model's result has none)."""
+        if not self.routed:
+            return
         held, absent, touched, load_max = (
             int(v) for v in fetched[-len(DECODE_COUNTS):]
         )

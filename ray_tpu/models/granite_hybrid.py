@@ -41,11 +41,12 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.llm.cache import CacheClass
+from ray_tpu.llm.cache import CacheClass, RecurrentKind
 from ray_tpu.models import parts
 from ray_tpu.models.parts import (  # noqa: F401  (this module's names for them)
     experts,
     gated_mlp as _gated_mlp,
+    inverse_softplus as _inverse_softplus,
     matmul as _matmul,
     normal as _normal,
     num_params,
@@ -185,6 +186,24 @@ def recurrent_shape(cfg: GraniteHybridConfig) -> Dict[str, int]:
         "conv_width": cfg.mamba_d_conv,
         "conv_dim": cfg.conv_dim,
         "chunk_size": cfg.mamba_chunk_size,
+        "state_itemsize": 4,
+        "conv_itemsize": jnp.dtype(cfg.dtype).itemsize,
+    }
+
+
+def recurrent_kinds(cfg: GraniteHybridConfig) -> Dict[str, RecurrentKind]:
+    """What a state slot keeps for one Mamba layer, and the layer's two
+    functions: the runner makes the pools and calls them."""
+    return {
+        MAMBA: RecurrentKind(
+            arrays=(
+                ("conv", (cfg.mamba_d_conv - 1, cfg.conv_dim), cfg.dtype),
+                ("ssm", (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state),
+                 jnp.float32),
+            ),
+            prefill=mamba_prefill, decode=mamba_decode,
+            scan_scope="llm.mixer.mamba.scan", update_scope="llm.mixer.mamba.update",
+        ),
     }
 
 
@@ -230,11 +249,6 @@ def _leaf_shapes(cfg: GraniteHybridConfig) -> Dict[str, Any]:
             "shared_out": (cfg.shared_intermediate_size, d),
         })
     return {"wte": (cfg.vocab_size, d), "norm_f": (d,), "layers": layers}
-
-
-@jax.jit
-def _inverse_softplus(dt):
-    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 def init_params(cfg: GraniteHybridConfig, seed: int) -> Dict[str, Any]:

@@ -1,8 +1,9 @@
 """The parts of a layer that the models served by `ray_tpu.llm.hybrid_runner`
-share (`granite_hybrid`, `laguna`) with the one that is trained
-(`mellum`): RMS norm, the matrix product in the compute dtype with float32
-accumulation, rotary positions (default and YaRN frequencies), the gated
-MLP, the routed experts with the routing's counts, beside a shared expert
+share (`granite_hybrid`, `laguna`, `olmo_hybrid`) with the one that is
+trained (`mellum`): RMS norm, the L2 norm, QK-norm over a whole projection,
+the block with its norms on the sub-layers' outputs, the matrix product in
+the compute dtype with float32 accumulation, rotary positions (default and
+YaRN frequencies), the gated MLP, the routed experts with the routing's counts, beside a shared expert
 where the model has one, the embedding and the head, and the seeded normal
 leaf. Pure functions; a model's configuration
 is read by attribute.
@@ -81,6 +82,32 @@ def rms_norm(x, weight, eps):
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return (y * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def l2_norm(x, eps=1e-6):
+    """x [..., d] float32 over its last axis' length (flash-linear-attention's
+    `l2norm`: the eps is under the root)."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def qk_norm(q, k, q_weight, k_weight, eps):
+    """Olmo 2's QK-norm: an RMS norm over the WHOLE query and key
+    projections [..., heads * head size], before they are cut into heads."""
+    return rms_norm(q, q_weight, eps), rms_norm(k, k_weight, eps)
+
+
+def output_norm_block(h, mixer, mlp, norm1, norm2, eps, dtype):
+    """Olmo 2's reordered norm, a layer on the residual rows h: the norm is
+    on each sub-layer's OUTPUT, `h + norm1(mixer(h))` and then `h +
+    norm2(mlp(h))`; the sub-layers read the stream as it is. Sums in
+    float32, the stream kept in `dtype`."""
+    h = (h.astype(jnp.float32) + rms_norm(mixer(h), norm1, eps)).astype(dtype)
+    return (h.astype(jnp.float32) + rms_norm(mlp(h), norm2, eps)).astype(dtype)
+
+
+@jax.jit
+def inverse_softplus(dt):
+    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 def matmul(x, w, dtype):

@@ -20,6 +20,14 @@ own kernels in it (PR 41): granite's 2,048-token and Laguna's 1,024-token
 starting chunk compile, fit beside the pools, keep them in place, name every
 instruction of the grouped path `llm.moe.routed`, and hand Mosaic the
 experts' kernels once a rung of the ladder, not once a layer.
+
+And Olmo Hybrid's decode program and widest chunk at the widths its cell
+runs (PR 42: layers 0-15, 64 lanes, 4,608 blocks of 16, 200-block tables):
+the paged kernel at 30 cached heads of 128 with one query head each, the
+packed float32 state pools [64, 15, 96, 384] and every K/V pool updated in
+place, no temporary of a state pool's size (a `repeat` over the packed axis
+once cost 1.9 GB of them), and 14.8 / 15.0 GB held of the chip's 16.9
+(the chip's allocator reads 14.84 GB at its peak: 0.15 GB under this analysis).
 """
 
 import gc
@@ -38,6 +46,7 @@ from ray_tpu.llm import hybrid_runner as hr
 from ray_tpu.llm.config import EngineConfig
 from ray_tpu.models import granite_hybrid as gh
 from ray_tpu.models import laguna
+from ray_tpu.models import olmo_hybrid as oh
 from ray_tpu.ops import grouped_experts
 from ray_tpu.ops.paged_flash import paged_flash_attention
 
@@ -92,7 +101,7 @@ def test_real_width_decode_program_fits_a_v5e(chip, monkeypatch):
     ssm = tuple(sds((SLOTS, 128, 64, 128), jnp.float32) for _ in range(9))
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
     compiled = programs.decode_fn.lower(
-        params, (kv,), (kv,), conv, ssm, i32(SLOTS + len(hr.DECODE_COUNTS)),
+        params, (kv,), (kv,), (conv, ssm), i32(SLOTS + len(hr.DECODE_COUNTS)),
         i32(SLOTS), (i32(SLOTS, TABLE),), i32(SLOTS),
     ).compile()
     memory = compiled.memory_analysis()
@@ -189,6 +198,7 @@ def test_a_chunk_program_with_the_grouped_kernels_fits_a_v5e(chip, monkeypatch, 
         pools = [sds((1, BLOCKS, BLOCK, 8 * 128))]
         conv = tuple(sds((SLOTS, 3, cfg.conv_dim)) for _ in range(9))
         ssm = tuple(sds((SLOTS, 128, 64, 128), jnp.float32) for _ in range(9))
+        state = (conv, ssm)
         tables, limit = (i32(TABLE),), 14.4e9
         rungs = grouped_experts.ladder(chunk * 10, 36 / 72)
         assert rungs == (12800, 20480)
@@ -196,7 +206,7 @@ def test_a_chunk_program_with_the_grouped_kernels_fits_a_v5e(chip, monkeypatch, 
         cfg, chunk, expert_layers = LAGUNA, 1024, 11
         shapes = laguna._leaf_shapes(cfg)
         pools = [sds((3, 13312, 16, 8 * 128)), sds((9, 1858, 16, 8 * 128))]
-        conv = ssm = ()
+        state = ()
         tables, limit = (i32(896), i32(896)), 15.2e9
         rungs = grouped_experts.ladder(chunk * 10, 32 / 256)
         assert rungs == (1792, 7168, 10240)
@@ -205,12 +215,12 @@ def test_a_chunk_program_with_the_grouped_kernels_fits_a_v5e(chip, monkeypatch, 
     )
     programs = hr._HybridPrograms(cfg, BLOCK, "pallas")
     lowered = programs.prefill_fn.lower(
-        params, tuple(pools), tuple(pools), conv, ssm, i32(1, chunk), tables,
+        params, tuple(pools), tuple(pools), state, i32(1, chunk), tables,
         i32(), i32(),
     )
     # Handed to Mosaic: the paged kernel an attention layer, and the grouped
     # experts' three once a rung however many layers call them.
-    attention = sum(kind != hr.MAMBA for kind in cfg.layer_types)
+    attention = sum(kind != gh.MAMBA for kind in cfg.layer_types)
     kernels = lowered.as_text().count("tpu_custom_call")
     assert kernels == attention + 3 * len(rungs), kernels
     compiled = lowered.compile()
@@ -225,3 +235,56 @@ def test_a_chunk_program_with_the_grouped_kernels_fits_a_v5e(chip, monkeypatch, 
     assert held < limit, held
     # In the compiled program every layer has its own copy of each rung's.
     assert _grouped_path_is_routed(compiled.as_text()) == 3 * len(rungs) * expert_layers
+
+
+@pytest.mark.parametrize("program,held_limit,temp_limit", [
+    ("decode", 14.85e9, 0.4e9), ("chunk2048", 15.1e9, 0.65e9),
+])
+def test_olmo_hybrids_programs_fit_a_v5e_with_the_state_in_place(
+    chip, monkeypatch, program, held_limit, temp_limit
+):
+    monkeypatch.setattr(sys.modules["ray_tpu.ops.paged_flash"], "_on_cpu", lambda: False)
+    slots, table, blocks = 64, 200, 4608
+    cfg = oh.OlmoHybridConfig(layer_types=oh.OLMO_HYBRID_PERIOD * 4)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    params = jax.tree_util.tree_map(
+        sds, oh._leaf_shapes(cfg), is_leaf=lambda v: isinstance(v, tuple)
+    )
+    assert 2 * sum(x.size for x in jax.tree_util.tree_leaves(params)) == 2 * 4_100_788_944
+    kv = sds((4, blocks, BLOCK, 30 * 128))
+    state = tuple(
+        tuple(sds((slots, *shape), dtype) for _ in range(layers))
+        for _, layers, (_, shape, dtype) in hr.state_layout(cfg)
+    )
+    assert [pools[0].shape for pools in state] == [(64, 3 * 11520), (64, 15, 96, 384)]
+    programs = hr._HybridPrograms(cfg, BLOCK, "pallas")
+    if program == "decode":
+        lowered = programs.decode_fn.lower(
+            params, (kv,), (kv,), state, i32(slots), i32(slots), (i32(slots, table),),
+            i32(slots),
+        )
+    else:
+        lowered = programs.prefill_suffix_fn.lower(
+            params, (kv,), (kv,), state, i32(1, 2048), (i32(table),), i32(), i32(), i32(),
+        )
+    assert lowered.as_text().count("tpu_custom_call") == 4  # the paged kernel a full layer
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    pools = 2 * kv.size * 2 + sum(
+        x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(state)
+    )
+    assert pools == 2 * 4 * blocks * BLOCK * 3840 * 2 + slots * 12 * 2_280_960
+    assert memory.alias_size_in_bytes >= pools  # every pool updated in place
+    assert memory.temp_size_in_bytes < temp_limit, memory.temp_size_in_bytes
+    held = (
+        memory.argument_size_in_bytes + memory.temp_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    )
+    assert held < held_limit, held
+    scopes = set(hr.scopes_of(compiled.as_text()).values())
+    mine = "llm.mixer.gdn.update" if program == "decode" else "llm.mixer.gdn.scan"
+    assert {mine, "llm.mixer.gdn.proj", "llm.mixer.attention.full", "llm.mlp"} <= scopes

@@ -69,9 +69,21 @@ STACKED = [
     for group in (9, 6, 4)
     for fed in (40, 48)
 ]
+# Olmo Hybrid's full layers: as many cached heads as query heads, 30 of
+# 128, so the hybrid runner's pool [layers, N, 16, 3840] and a decode step
+# whose block-diagonal product has one row a cached head; a chunk in q tiles
+# of 16 (two and a half of them) beside the padded slot.
+ONE_QUERY_HEAD_A_CACHED_HEAD = [
+    pytest.param(30, 30, 1, 128, None, None, id="mha30-decode-128"),
+    pytest.param(30, 30, 1, 128, (0, 37, 168, 128), None, id="mha30-decode-128-padded-slot"),
+    pytest.param(30, 30, 40, 128, (0, 0, 168, 128), 16, id="mha30-chunk40-tiles-of-16"),
+]
 
 
-@pytest.mark.parametrize("heads,kv_heads,fed,head_dim,contexts,q_tile", AS_BEFORE + STACKED)
+@pytest.mark.parametrize(
+    "heads,kv_heads,fed,head_dim,contexts,q_tile",
+    AS_BEFORE + STACKED + ONE_QUERY_HEAD_A_CACHED_HEAD,
+)
 def test_kernel_matches_the_xla_path(
     monkeypatch, heads, kv_heads, fed, head_dim, contexts, q_tile
 ):
