@@ -146,17 +146,18 @@ class RecurrentKind:
     arrays a state slot keeps for one such layer, as (name, shape, dtype),
     and the layer's two functions, `prefill(cfg, p, u, *arrays, length)`
     for a chunk of one sequence that starts from `arrays` (zeros at a
-    sequence's start) and `decode(cfg, p, u, *arrays)` for one token a lane
-    from the lanes' `arrays` ([lanes, *shape]); both return the mixer's
-    output and then the arrays as the step leaves them. `scan_scope` and
-    `update_scope` are the named scopes under which the runner writes them
-    back: the kind's own, a chunk's and a decode step's."""
+    sequence's start) and `decode(cfg, p, u, *arrays, live)` for one token a
+    lane from the lanes' `arrays` ([lanes, *shape]); both return the mixer's
+    output and then the arrays as the step leaves them, which for `decode`
+    means a lane that is not `live` ([lanes] bool) keeps its own: the runner
+    puts what it returns in the pools as it is, so a kind can update its
+    state in place. `scan_scope` is the named scope under which the runner
+    writes a chunk's arrays back to the slot: the kind's own."""
 
     arrays: Tuple[Tuple[str, Tuple[int, ...], Any], ...]
     prefill: Callable
     decode: Callable
     scan_scope: str
-    update_scope: str
 
 
 def window_class_of(model_config) -> Optional[CacheClass]:

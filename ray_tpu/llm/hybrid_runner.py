@@ -30,10 +30,12 @@ never read.
 On a model with recurrent layers a sequence owns one state slot for as
 long as it runs (the scheduler hands them out), and its slot is its lane
 in the decode batch: the decode program updates the state pools in place,
-lane for lane, and leaves the lanes that do not decode this step (context
-length 0: idle, or a sequence still prefilling) as they were. Nothing
-gathers or scatters a state. A slot is never cleared: the program that
-starts a sequence does not read it.
+lane for lane, and the lanes that do not decode this step (context length
+0: idle, or a sequence still prefilling) stay as they were: a kind's
+`decode` is handed which lanes are live and returns the pools' arrays, and
+the runner selects nothing over them. Nothing gathers or scatters a state.
+A slot is never cleared: the program that starts a sequence does not read
+it.
 
 The decode program of a model with routed experts (one that publishes
 `expert_shape`) returns the sampled tokens and the step's routing counts in
@@ -171,11 +173,9 @@ class _HybridPrograms:
 
         def recur(kind, i, p, u):
             spec, at = self.recurrent[kind], self.state_at[kind]
-            out, *after = spec.decode(cfg, p, u, *(state[j][i] for j in at))
-            with jax.named_scope(spec.update_scope):
-                for j, array in zip(at, after):
-                    lanes = jnp.expand_dims(live, tuple(range(1, array.ndim)))
-                    state[j][i] = jnp.where(lanes, array, state[j][i])
+            out, *after = spec.decode(cfg, p, u, *(state[j][i] for j in at), live)
+            for j, array in zip(at, after):
+                state[j][i] = array
             return out
 
         def attend(kind, i, p, u):

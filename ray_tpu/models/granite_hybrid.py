@@ -202,7 +202,7 @@ def recurrent_kinds(cfg: GraniteHybridConfig) -> Dict[str, RecurrentKind]:
                  jnp.float32),
             ),
             prefill=mamba_prefill, decode=mamba_decode,
-            scan_scope="llm.mixer.mamba.scan", update_scope="llm.mixer.mamba.update",
+            scan_scope="llm.mixer.mamba.scan",
         ),
     }
 
@@ -348,9 +348,10 @@ def mamba_prefill(cfg, p, u, conv_tail, ssm, length):
     return out, new_tail, new_ssm
 
 
-def mamba_decode(cfg, p, u, conv_tail, ssm):
+def mamba_decode(cfg, p, u, conv_tail, ssm, live):
     """One token for each of a batch of sequences. u [B, D], conv_tail
-    [B, d_conv - 1, conv_dim], ssm [B, H, P, N]."""
+    [B, d_conv - 1, conv_dim], ssm [B, H, P, N]; a lane that is not `live`
+    [B] keeps its tail and state."""
     z, xbc, dt = _mamba_split(cfg, p, u)
     with jax.named_scope("llm.mixer.mamba.update"):
         window = jnp.concatenate(
@@ -366,7 +367,10 @@ def mamba_decode(cfg, p, u, conv_tail, ssm):
         y = _mamba_finish(cfg, p, y, x, z)
     with jax.named_scope("llm.mixer.mamba.proj"):
         out = _matmul(y, p["out_proj"], cfg.dtype)
-    return out, window[:, 1:], new_ssm
+    with jax.named_scope("llm.mixer.mamba.update"):
+        new_tail = parts.where_live(live, window[:, 1:], conv_tail)
+        new_ssm = parts.where_live(live, new_ssm, ssm)
+    return out, new_tail, new_ssm
 
 
 def attention_qkv(cfg, kind, p, u, positions=None):

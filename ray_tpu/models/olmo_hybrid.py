@@ -196,7 +196,7 @@ def recurrent_kinds(cfg: OlmoHybridConfig) -> Dict[str, RecurrentKind]:
                            PACK * cfg.linear_value_head_dim), jnp.float32),
             ),
             prefill=gdn_prefill, decode=gdn_decode,
-            scan_scope="llm.mixer.gdn.scan", update_scope="llm.mixer.gdn.update",
+            scan_scope="llm.mixer.gdn.scan",
         ),
     }
 
@@ -336,9 +336,10 @@ def gdn_prefill(cfg, p, u, conv_tail, state, length):
     return out, new_tail.reshape(-1), new_state
 
 
-def gdn_decode(cfg, p, u, conv_tail, state):
+def gdn_decode(cfg, p, u, conv_tail, state, live):
     """One token for each of a batch of sequences. u [B, D], conv_tail
-    [B, (taps - 1) * conv_dim], state [B, H / 2, K, 2 V]."""
+    [B, (taps - 1) * conv_dim], state [B, H / 2, K, 2 V]; a lane that is not
+    `live` [B] keeps its tail and state."""
     qkv, beta, g, gate = _gdn_project(cfg, p, u)
     taps = cfg.linear_conv_kernel_dim
     with jax.named_scope("llm.mixer.gdn.proj"):
@@ -354,11 +355,14 @@ def gdn_decode(cfg, p, u, conv_tail, state):
         )
         q, k, v = _gdn_heads(cfg, conv)
     with jax.named_scope("llm.mixer.gdn.update"):
-        o, new_state = gated_delta_update(q, k, v, g, beta, state)
+        o, new_state = gated_delta_update(q, k, v, g, beta, state, live)
         y = _gdn_finish(cfg, p, o, gate)
+        new_tail = parts.where_live(
+            live, window[:, 1:].reshape(u.shape[0], -1), conv_tail
+        )
     with jax.named_scope("llm.mixer.gdn.proj"):
         out = parts.matmul(y, p["o"], cfg.dtype)
-    return out, window[:, 1:].reshape(u.shape[0], -1), new_state
+    return out, new_tail, new_state
 
 
 def attention_qkv(cfg, kind, p, u, positions=None):

@@ -105,6 +105,13 @@ def output_norm_block(h, mixer, mlp, norm1, norm2, eps, dtype):
     return (h.astype(jnp.float32) + rms_norm(mlp(h), norm2, eps)).astype(dtype)
 
 
+def where_live(live, new, old):
+    """`new` [B, ...] for the lanes that decode this step (`live` [B]) and
+    `old` for the rest: what a recurrent kind's decode returns of an array
+    its slots keep."""
+    return jnp.where(jnp.expand_dims(live, tuple(range(1, new.ndim))), new, old)
+
+
 @jax.jit
 def inverse_softplus(dt):
     return dt + jnp.log(-jnp.expm1(-dt))

@@ -30,10 +30,13 @@ padding never reaches the state. Every exponent is a difference of running
 sums that is at most zero, so a decay near 0 underflows to 0 and nothing
 divides. `gated_delta_update` is the one-token recurrence over a batch of
 states in one pass over the state: both read-outs are taken from the OLD
-state (`o = a S^T q + (k . q) d` with `d = beta (v - a S^T k)`), so the
-state is read for the read-outs and for `S' = a S + k (outer) d` and
-written once. It is elementwise and bound by reading and writing the
-states.
+state (`o = a S^T q + (k . q) d` with `d = beta (v - a S^T k)`), and a tile
+of all key rows and any value columns needs nothing outside itself for its
+columns' read-outs, its `d`, its `o` and its new values. So it is one Pallas
+kernel (interpreted on the CPU) that holds such tiles in VMEM: the state is
+read once and written once, in place, at the rate of a copy. A reduction
+that XLA would have to finish before the write could start made it read the
+state twice.
 
 A slot's state is kept PACKED, `[H / 2, K, 2 V]`: heads 2p and 2p + 1 side
 by side in the last axis. At V = 192 a float32 `[K, V]` tile pads its rows
@@ -47,13 +50,24 @@ whatever `dtype` the matrix products take their operands in.
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.scipy.linalg import solve_triangular
 
+from ray_tpu.ops.flash_attention import _on_cpu
+
 PACK = 2  # heads side by side in a packed state's last axis
+# Head pairs a step of the update kernel's grid holds at most. On a v5e 3, 5
+# and 15 pairs of [96, 384] float32 all run at the rate of a copy through
+# the same blocks (0.44 ms for a layer's [64, 15, 96, 384], 640 GB/s: PR 43);
+# 5 are 0.7 MB a block, 2.9 MB with the state in and out and the pipeline's
+# second buffers, and a third of 15's body to trace and compile.
+_PAIR_BLOCK = 5
 
 
 def pack_state(state: jax.Array) -> jax.Array:
@@ -148,15 +162,90 @@ def gated_delta_chunked(
     return o[:t_len], pack_state(state)
 
 
-def _side_by_side(x: jax.Array, width: int) -> jax.Array:
-    """x [B, H, ...] (a scalar, or a [K] column, a head) -> [B, H / 2, ...,
-    2 width]: each of a pair's over its half of a packed state's last axis.
-    A select between two broadcasts, which fuses into what reads it; a
-    repeat's reshape would be written out at the state's size."""
-    b, h = x.shape[:2]
-    pairs = x.reshape((b, h // PACK, PACK) + x.shape[2:])
-    first = jnp.arange(PACK * width) < width
-    return jnp.where(first, pairs[:, :, 0, ..., None], pairs[:, :, 1, ..., None])
+def _update_kernel(live_ref, cols_ref, rows_ref, state_ref, o_ref, new_ref):
+    """One lane's block of P head pairs. cols [K, 4 P]: k of a pair's two
+    heads, then q of them, as columns over the key rows; rows [4, P, 2 V]:
+    a, beta, v and k . q, each head's over its half; state [P, K, 2 V], read
+    from VMEM for the read-outs and again for the write."""
+    pairs, k_dim, width = state_ref.shape[1:]
+    alive = live_ref[pl.program_id(0)] != 0
+    first = jax.lax.broadcasted_iota(jnp.int32, (k_dim, width), 1) < width // PACK
+    cols = cols_ref[0, 0]
+
+    def column(j):  # a pair's two columns, each over its half
+        return jnp.where(
+            first,
+            jnp.broadcast_to(cols[:, j : j + 1], (k_dim, width)),
+            jnp.broadcast_to(cols[:, j + 1 : j + 2], (k_dim, width)),
+        )
+
+    for p in range(pairs):
+        old = state_ref[0, p]
+        k, q = column(2 * PACK * p), column(2 * PACK * p + PACK)
+        a, beta, v, kq = (rows_ref[0, 0, r, p : p + 1, :] for r in range(4))
+        d = beta * (v - a * jnp.sum(old * k, axis=0, keepdims=True))
+        o_ref[0, 0, p : p + 1, :] = (
+            a * jnp.sum(old * q, axis=0, keepdims=True) + kq * d
+        )
+        new_ref[0, p] = jnp.where(alive, a * old + k * d, old)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _update(q, k, v, g, beta, state, live, *, interpret):
+    """One traced function a shape: a program traces the kernel's body and
+    lowers it once however many layers call it. What XLA makes for the
+    kernel is small, [B, H] rows and columns: nothing of the state's size."""
+    b, h, v_dim = v.shape
+    k_dim, half, width = k.shape[-1], h // PACK, PACK * v_dim
+    block = max(p for p in range(1, _PAIR_BLOCK + 1) if half % p == 0)
+    groups = half // block
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+
+    def halves(x):  # [B, H], a scalar a head -> each over its half, [B, H / 2, 2 V]
+        return jnp.repeat(x, v_dim, axis=1).reshape(b, half, width)
+
+    rows = jnp.stack(
+        [
+            halves(jnp.exp(g.astype(jnp.float32))), halves(beta.astype(jnp.float32)),
+            v.reshape(b, half, width), halves(jnp.sum(k * q, axis=-1)),
+        ],
+        axis=1,
+    ).reshape(b, 4, groups, block, width)
+    # A pair's k, k, q, q: [B, groups, 4 P, K], then columns over the key rows.
+    cols = jnp.stack(
+        [x.reshape(b, groups, block, PACK, k_dim) for x in (k, q)], axis=3
+    ).reshape(b, groups, 2 * PACK * block, k_dim)
+
+    def spec(*block_shape):  # of lane i, its j-th group of pairs
+        rest = (0,) * (len(block_shape) - 2)
+        return pl.BlockSpec(block_shape, lambda i, j, live: (i, j) + rest)
+
+    o, new = pl.pallas_call(
+        _update_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, groups),
+            in_specs=[
+                spec(1, 1, k_dim, 2 * PACK * block),
+                spec(1, 1, 4, block, width),
+                spec(1, block, k_dim, width),
+            ],
+            out_specs=[spec(1, 1, block, width), spec(1, block, k_dim, width)],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, groups, block, width), jnp.float32),
+            jax.ShapeDtypeStruct(state.shape, jnp.float32),
+        ],
+        input_output_aliases={3: 1},  # the states, in place
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")
+        ),
+        interpret=interpret,
+    )(
+        live.astype(jnp.int32), jnp.swapaxes(cols, -1, -2),
+        jnp.swapaxes(rows, 1, 2), state,
+    )
+    return o.reshape(b, h, v_dim), new
 
 
 def gated_delta_update(
@@ -166,23 +255,12 @@ def gated_delta_update(
     g: jax.Array,
     beta: jax.Array,
     state: jax.Array,
+    live: jax.Array,
 ) -> Tuple[jax.Array, jax.Array]:
     """One token a sequence: q and k [B, H, K], v [B, H, V], g and beta
-    [B, H], state [B, H / 2, K, 2 V] float32 (packed) -> (o [B, H, V]
-    float32, the new states). Multiplies and sums, not matrix products: the
-    MXU would round the float32 state to its input type."""
-    b, h, v_dim = v.shape
-    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
-    a = jnp.exp(g.astype(jnp.float32))
-    beta = beta.astype(jnp.float32)
-
-    def read(x):  # S^T x of the old state, [B, H, V]
-        return jnp.sum(state * _side_by_side(x, v_dim), axis=-2).reshape(b, h, v_dim)
-
-    d = beta[..., None] * (v - a[..., None] * read(k))
-    o = a[..., None] * read(q) + jnp.sum(k * q, axis=-1, keepdims=True) * d
-    new = (
-        _side_by_side(a, v_dim)[:, :, None, :] * state
-        + _side_by_side(k, v_dim) * d.reshape(b, h // PACK, 1, PACK * v_dim)
-    )
-    return o, new
+    [B, H], state [B, H / 2, K, 2 V] float32 (packed), live [B] bool ->
+    (o [B, H, V] float32, the new states; a lane that is not live keeps its
+    state, bit for bit, and its o means nothing). Multiplies and sums, not
+    matrix products: the MXU would round the float32 state to its input
+    type."""
+    return _update(q, k, v, g, beta, state, live, interpret=_on_cpu())

@@ -4,7 +4,9 @@ layer, routed experts of which half are held, a chunk that does not divide
 most lengths) at widths the CPU runs in milliseconds, in float32 so that a
 comparison with the float32 reference can be tight."""
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.models import granite_hybrid as gh
 
@@ -24,6 +26,30 @@ def toy_config(experts_held=(0, 1, 2, 3), **changes):
     )
     fields.update(changes)
     return gh.GraniteHybridConfig(**fields)
+
+
+def assert_idle_lanes_keep_their_state(runner, pools_expected):
+    """One decode step of `runner` (a `HybridRunner` of four lanes and tables
+    of twelve blocks) over pools of noise with lanes 1 and 3 idle (context
+    length 0): every array a state slot keeps is theirs bit for bit after it,
+    and the decoding lanes' moved. The kind's decode sees to it; the runner
+    puts what it returns in the pools."""
+    rng = np.random.RandomState(0)
+    runner.state = jax.tree_util.tree_map(
+        lambda pool: jnp.asarray(rng.standard_normal(pool.shape), pool.dtype),
+        runner.state,
+    )
+    before = jax.tree_util.tree_map(np.asarray, runner.state)
+    lens = np.array([3, 0, 5, 0], np.int32)
+    runner.decode(
+        np.arange(1, 5, dtype=np.int32), lens.copy(), np.zeros((4, 12), np.int32), lens
+    )
+    after = jax.tree_util.tree_map(np.asarray, runner.state)
+    pools = list(zip(*map(jax.tree_util.tree_leaves, (before, after))))
+    assert len(pools) == pools_expected
+    for was, now in pools:
+        np.testing.assert_array_equal(now[lens == 0], was[lens == 0])
+        assert all((now[lane] != was[lane]).any() for lane in np.flatnonzero(lens))
 
 
 def held_params(params, cfg_all, held):

@@ -1,5 +1,6 @@
 """`ray_tpu.ops.gated_delta` against the recurrence it is the chunked and
-the one-pass form of, written here token by token in float32:
+the one-pass form of (the second a Pallas kernel, interpreted on the CPU),
+written here token by token in float32:
 
     S <- a S;  d = beta (v - S^T k);  S <- S + k (outer) d;  o = S^T q
 
@@ -47,7 +48,8 @@ def recurrence(q, k, v, g, beta, state):
     return o, state
 
 
-def inputs(t_len, seed, decay="mixed"):
+def inputs(t_len, seed, decay="mixed", dims=(H, K, V)):
+    H, K, V = dims
     rng = np.random.default_rng(seed)
     q = rng.standard_normal((t_len, H, K)).astype(np.float32)
     k = rng.standard_normal((t_len, H, K)).astype(np.float32)
@@ -127,14 +129,27 @@ def test_padded_positions_leave_the_state_alone(length):
         np.testing.assert_array_equal(unpack_state(packed), state)
 
 
+# Lanes and (H, K, V): a toy, and Olmo Hybrid's slot, [15, 96, 384] packed.
+UPDATE_SHAPES = {"toy": (5, (H, K, V)), "real": (2, (30, 96, 192))}
+
+
 @pytest.mark.parametrize("decay", ["mixed", "near_one", "near_zero"])
-def test_one_pass_update_is_the_two_step_recurrence(decay):
-    lanes = 5
-    q, k, v, g, beta, _ = inputs(lanes, 3, decay)
+@pytest.mark.parametrize("shape", list(UPDATE_SHAPES))
+@pytest.mark.parametrize("idle", [-1, 1])  # -1: every lane is live
+def test_one_pass_update_is_the_two_step_recurrence(decay, shape, idle):
+    """The kernel (interpreted here) against the recurrence, lane by lane;
+    a lane that is not live keeps its state bit for bit, whatever its o."""
+    lanes, dims = UPDATE_SHAPES[shape]
+    q, k, v, g, beta, _ = inputs(lanes, 3, decay, dims)
     rng = np.random.default_rng(4)
-    states = jnp.asarray(rng.standard_normal((lanes, H, K, V)).astype(np.float32))
-    o, new = jax.jit(gated_delta_update)(q, k, v, g, beta, pack_state(states))
+    states = jnp.asarray(rng.standard_normal((lanes, *dims)).astype(np.float32))
+    live = jnp.arange(lanes) != idle
+    o, new = jax.jit(gated_delta_update)(q, k, v, g, beta, pack_state(states), live)
+    assert o.shape == v.shape and o.dtype == new.dtype == jnp.float32
     for lane in range(lanes):
+        if lane == idle:
+            np.testing.assert_array_equal(unpack_state(new)[lane], states[lane])
+            continue
         want_o, want_s = recurrence(
             *(x[lane][None] for x in (q, k, v, g, beta)), states[lane]
         )
@@ -159,7 +174,8 @@ def test_update_after_chunks_is_the_recurrence():
     close(o, want_o[cut:prompt])
     for t in range(prompt, t_len):
         o_t, s = gated_delta_update(
-            q[t][None], k[t][None], v[t][None], g[t][None], beta[t][None], s[None]
+            q[t][None], k[t][None], v[t][None], g[t][None], beta[t][None], s[None],
+            jnp.ones((1,), bool),
         )
         s = s[0]
         close(o_t[0], want_o[t])

@@ -28,6 +28,11 @@ packed float32 state pools [64, 15, 96, 384] and every K/V pool updated in
 place, no temporary of a state pool's size (a `repeat` over the packed axis
 once cost 1.9 GB of them), and 14.8 / 15.0 GB held of the chip's 16.9
 (the chip's allocator reads 14.84 GB at its peak: 0.15 GB under this analysis).
+Since PR 43 the decode program's update is a Pallas kernel a layer
+(`ops/gated_delta.py`): Mosaic takes it at that shape, it is lowered once
+for its twelve callers, each call sits under `llm.mixer.gdn.update`, and no
+XLA operation outside the calls reads or writes an array of a state pool's
+shape.
 """
 
 import gc
@@ -47,7 +52,7 @@ from ray_tpu.llm.config import EngineConfig
 from ray_tpu.models import granite_hybrid as gh
 from ray_tpu.models import laguna
 from ray_tpu.models import olmo_hybrid as oh
-from ray_tpu.ops import grouped_experts
+from ray_tpu.ops import gated_delta, grouped_experts
 from ray_tpu.ops.paged_flash import paged_flash_attention
 
 SLOTS, TABLE, BLOCK, BLOCKS = 32, 400, 16, 16384
@@ -244,6 +249,7 @@ def test_olmo_hybrids_programs_fit_a_v5e_with_the_state_in_place(
     chip, monkeypatch, program, held_limit, temp_limit
 ):
     monkeypatch.setattr(sys.modules["ray_tpu.ops.paged_flash"], "_on_cpu", lambda: False)
+    monkeypatch.setattr(gated_delta, "_on_cpu", lambda: False)
     slots, table, blocks = 64, 200, 4608
     cfg = oh.OlmoHybridConfig(layer_types=oh.OLMO_HYBRID_PERIOD * 4)
 
@@ -271,7 +277,10 @@ def test_olmo_hybrids_programs_fit_a_v5e_with_the_state_in_place(
         lowered = programs.prefill_suffix_fn.lower(
             params, (kv,), (kv,), state, i32(1, 2048), (i32(table),), i32(), i32(), i32(),
         )
-    assert lowered.as_text().count("tpu_custom_call") == 4  # the paged kernel a full layer
+    # Handed to Mosaic: the paged kernel a full layer, and in the decode
+    # program the delta rule's update once for its twelve layers.
+    update = program == "decode"
+    assert lowered.as_text().count("tpu_custom_call") == 4 + update
     compiled = lowered.compile()
     memory = compiled.memory_analysis()
     pools = 2 * kv.size * 2 + sum(
@@ -285,6 +294,33 @@ def test_olmo_hybrids_programs_fit_a_v5e_with_the_state_in_place(
         + memory.output_size_in_bytes - memory.alias_size_in_bytes
     )
     assert held < held_limit, held
-    scopes = set(hr.scopes_of(compiled.as_text()).values())
-    mine = "llm.mixer.gdn.update" if program == "decode" else "llm.mixer.gdn.scan"
-    assert {mine, "llm.mixer.gdn.proj", "llm.mixer.attention.full", "llm.mlp"} <= scopes
+    text = compiled.as_text()
+    scopes = hr.scopes_of(text)
+    mine = "llm.mixer.gdn.update" if update else "llm.mixer.gdn.scan"
+    assert {
+        mine, "llm.mixer.gdn.proj", "llm.mixer.attention.full", "llm.mlp"
+    } <= set(scopes.values())
+    kernels = [
+        re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line)[1]
+        for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    by_scope = [scopes.get(name) for name in kernels]
+    assert by_scope.count("llm.mixer.attention.full") == 4
+    if not update:
+        assert len(kernels) == 4
+        return
+    # A layer's update is one kernel under the update's scope, and it alone
+    # touches the layer's states: they pass from the program's parameters
+    # through the kernel to its results, and no fusion reads or writes an
+    # array of their shape (a second pass over them, the runner's select).
+    assert len(kernels) == 16 and by_scope.count("llm.mixer.gdn.update") == 12
+    touching = [
+        line for line in text.splitlines()
+        if "f32[64,15,96,384]" in line.split(" = ", 1)[-1]
+        and not line.startswith(("HloModule", "ENTRY"))
+    ]
+    assert touching
+    passing = re.compile(r" (parameter|get-tuple-element|bitcast|tuple|custom-call)\(")
+    off = [line.strip()[:160] for line in touching if not passing.search(line)]
+    assert not off, off[:3]
+    assert sum(" custom-call(" in line for line in touching) == 12

@@ -19,6 +19,7 @@ recurrent state kept in bfloat16 by ten times the tolerance
 """
 
 import functools
+import gc
 
 import jax
 import jax.numpy as jnp
@@ -31,11 +32,21 @@ from ray_tpu.llm.engine import LLMEngine
 from ray_tpu.models import granite_hybrid as gh
 from ray_tpu.models import granite_hybrid_reference as ref
 
-from hybrid_toy import toy_config
+from hybrid_toy import assert_idle_lanes_keep_their_state, toy_config
 
 TOLERANCE = 2e-8
 PAD = 96  # the reference runs every sequence at one padded length
 CFG = toy_config()
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _leave_a_small_heap():
+    """What this file traced goes when it is done: the worker that ran it
+    runs other files after, and some of them time a full `gc.collect()`."""
+    yield
+    _reference.cache_clear()
+    jax.clear_caches()
+    gc.collect()
 
 
 @pytest.fixture(scope="module")
@@ -189,6 +200,11 @@ def test_a_request_alone_and_among_others(params, observed, depth):
         if row in alone:
             assert float(np.abs(got[0] - alone[row]).max()) < TOLERANCE
     assert_matches_reference(params, prompts, outputs, rows)
+
+
+def test_a_decode_step_leaves_an_idle_lanes_state_alone(params):
+    runner = LLMEngine(CFG, engine_config(), params=params).runner
+    assert_idle_lanes_keep_their_state(runner, 2 * CFG.layer_types.count(gh.MAMBA))
 
 
 @DEPTHS

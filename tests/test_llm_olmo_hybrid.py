@@ -26,6 +26,7 @@ from ray_tpu.llm.engine import LLMEngine
 from ray_tpu.models import olmo_hybrid as oh
 from ray_tpu.models import olmo_hybrid_reference as ref
 
+from hybrid_toy import assert_idle_lanes_keep_their_state
 from olmo_toy import toy_config
 
 TOLERANCE = 2e-5
@@ -195,6 +196,11 @@ def test_lanes_join_and_leave_around_a_request(params, observed, depth):
         if row in alone:
             assert float(np.abs(got[0] - alone[row]).max()) < TOLERANCE
     assert_matches_reference(params, prompts, outputs, rows)
+
+
+def test_a_decode_step_leaves_an_idle_lanes_state_alone(params):
+    runner = LLMEngine(CFG, engine_config(), params=params).runner
+    assert_idle_lanes_keep_their_state(runner, 2 * CFG.layer_types.count(oh.LINEAR))
 
 
 def test_a_preempted_sequence_is_prefilled_again(params, observed):

@@ -106,7 +106,9 @@ def test_decode_steps_after_a_chunk_are_the_sequence(params):
     out, tail, state = oh.gdn_prefill(CFG, p, u[:13], *empty, 13)
     np.testing.assert_allclose(out, want[:13], atol=1e-6)
     for t in range(13, 24):
-        out, tail, state = oh.gdn_decode(CFG, p, u[t][None], tail[None], state[None])
+        out, tail, state = oh.gdn_decode(
+            CFG, p, u[t][None], tail[None], state[None], jnp.ones((1,), bool)
+        )
         tail, state = tail[0], state[0]
         np.testing.assert_allclose(out[0], want[t], atol=1e-6)
 
