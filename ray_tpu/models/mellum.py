@@ -35,7 +35,12 @@ Parameters are held in `param_dtype` (float32 masters for training), matrix
 products take `dtype` operands (bfloat16) and accumulate in float32,
 rotation, router and softmax are float32. `remat` recomputes a layer in the
 backward pass (`jax.checkpoint` a layer), which is what lets an 8,192-token
-step fit beside the optimizer's state; it changes no result.
+step fit beside the optimizer's state; it changes no result. A recomputed
+layer keeps its input and the two arrays its flash kernel's own backward
+reads, the kernel's output and the rows' log-sum-exp
+(`flash_attention.RESIDUAL_NAMES`), so the forward kernel runs once a step
+and not twice: 2 x tokens x query heads x head_dim bytes in bfloat16 and
+4 x tokens x query heads, 136 MB a layer at two sequences of 8,192.
 
 Not imported by `ray_tpu` or `ray_tpu.models`: import this module by name.
 """
@@ -51,7 +56,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import parts
 from ray_tpu.models.parts import num_params  # noqa: F401  (as the other models name it)
-from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.flash_attention import RESIDUAL_NAMES, flash_attention
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 MELLUM_PERIOD = (SLIDING, SLIDING, SLIDING, FULL)
@@ -101,7 +106,9 @@ COUNTS = ("held", "absent", "touched", "load_max", "walked", "load")
 class MellumConfig:
     """Keys as the published config.json names them, plus `experts_held`
     (which of a layer's routed experts this chip holds), `vocab_rows` (the
-    first and one past the last row of the vocabulary it holds), `remat`,
+    first and one past the last row of the vocabulary it holds), `remat`
+    (a layer's forward is taken again in the backward pass, all but its
+    flash kernel, whose output and log-sum-exp are kept: the module's note),
     `hold_router` (`train_step` leaves the routers' weights as they are)
     and the types. `intermediate_size` is published and unused: no layer
     is dense."""
@@ -249,9 +256,12 @@ def hidden(cfg: MellumConfig, params, tokens):
     positions = jnp.arange(tokens.shape[1])
     h = parts.embed(params["wte"], tokens, cfg.dtype)
     totals = None
+    keep = jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES)
     for kind, p in zip(cfg.layer_types, params["layers"]):
         run = functools.partial(layer, cfg, kind)
-        h, counts = (jax.checkpoint(run) if cfg.remat else run)(p, h, positions)
+        if cfg.remat:
+            run = jax.checkpoint(run, policy=keep)
+        h, counts = run(p, h, positions)
         totals = parts.add_counts(totals, counts)
     return h, totals
 

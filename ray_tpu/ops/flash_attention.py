@@ -59,12 +59,20 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.attention import NEG_INF, mha_reference
 
 _LANES = 128  # TPU lane width: min trailing dim for scratch tiles
+# What `flash_attention`'s backward reads of its forward kernel: the output in
+# kernel layout and the rows' log-sum-exp, under these names. A caller that
+# recomputes a layer asks for them (`models/mellum.py`:
+# `jax.checkpoint(..., policy=save_only_these_names(*RESIDUAL_NAMES))`) and
+# the forward kernel then runs once; where no policy asks, a name is an
+# identity and lowers to nothing.
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
 def _seen(q_ids, k_ids, window: Optional[int]):
@@ -771,10 +779,12 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, window=None):
     out_f, lse = _flash_fwd_pallas(
         q_f, k_f, v_f, causal, block_q, block_k, interpret=_on_cpu(), window=window
     )
+    out_f = checkpoint_name(out_f, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse[:, 0, :], RESIDUAL_NAMES[1])
     out = _unfold_heads(out_f, b, h)
     # Residuals stay in kernel layout (q_f prescaled): the backward reads
     # them directly instead of paying the fold transposes a second time.
-    return out, (q_f, k_f, v_f, out_f, lse[:, 0, :])
+    return out, (q_f, k_f, v_f, out_f, lse)
 
 
 def _flash_bwd(sm_scale, causal, block_q, block_k, window, residuals, do):

@@ -3,7 +3,10 @@
 heads than query heads): forward, dQ, dK and dV against dense masked
 attention with K and V repeated, and the pins: at `window=None` and one
 query head a key head the kernels' jaxprs and the GPT train step's lowered
-program are what they were before either existed.
+program are what they were before either existed. And the names the forward
+gives its two residuals (`RESIDUAL_NAMES`): a checkpoint whose policy asks
+for them runs the forward kernel once and gives the same gradients, and
+where no policy asks they lower to nothing.
 
 `PYTHONPATH=. python tests/test_flash_window_gqa.py` prints the table to
 re-record after a DELIBERATE change to what those programs trace.
@@ -20,7 +23,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops.attention import mha_reference
-from ray_tpu.ops.flash_attention import _band_steps, flash_attention
+from ray_tpu.ops.flash_attention import RESIDUAL_NAMES, _band_steps, flash_attention
 
 BLOCK = 64
 WINDOWS = {"no_window": None, "inside_a_block": 24, "across_blocks": 100}
@@ -131,7 +134,8 @@ CASES = {  # sequence, block, causal, head size
 }
 
 
-def _flash_jaxprs(name, heads=4, window=None):
+def _flash_case(name, heads=4, window=None):
+    """(the call, the gradient of its sum, the shapes both take) of a case."""
     s, block, causal, d = CASES[name]
     x = jax.ShapeDtypeStruct((2, s, 4, d), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((2, s, heads, d), jnp.bfloat16)
@@ -144,10 +148,24 @@ def _flash_jaxprs(name, heads=4, window=None):
     grad = jax.grad(
         lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(), argnums=(0, 1, 2)
     )
+    return attend, grad, (x, kv, kv)
+
+
+def _flash_jaxprs(name, **change):
+    attend, grad, shapes = _flash_case(name, **change)
     return {
-        "forward": jax.make_jaxpr(attend)(x, kv, kv),
-        "grad": jax.make_jaxpr(grad)(x, kv, kv),
+        "forward": jax.make_jaxpr(attend)(*shapes),
+        "grad": jax.make_jaxpr(grad)(*shapes),
     }
+
+
+def _flash_grad_text(name, **change):
+    """The lowered text of the jitted gradient, under no checkpoint: what a
+    caller that asks for no name runs."""
+    _, grad, shapes = _flash_case(name, **change)
+    text = jax.jit(grad).lower(*shapes).as_text()
+    # A private function's symbol ends in a counter of the lowering's own.
+    return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
 
 
 def _gpt_train_step_text():
@@ -182,18 +200,28 @@ def _gpt_train_step_text():
     ).as_text()
 
 
-# Taken on commit fc42792 (PR 38), the parent of the PR that added `window`
-# and the key heads' own count, with the functions above.
+# The jaxprs were taken on commit fc42792 (PR 38), the parent of the PR that
+# added `window` and the key heads' own count, and taken again at PR 44,
+# which names the forward's output and log-sum-exp: two `name` equations a
+# call and no operation, which is what the `.lowered` lines of the flash
+# calls hold: they are commit 6a6da49's (PR 43, before the names).
 PINNED = {
-    "causal_blocks.forward": "a827a1823d2dce7a",
-    "causal_blocks.grad": "48f5bb5eae224cb6",
-    "causal_one_block.forward": "2b196a986c923ed1",
-    "causal_one_block.grad": "c902dfb66b67c725",
-    "full_blocks.forward": "dfd90d1e5dc04636",
-    "full_blocks.grad": "c943bf42dfaf4770",
-    "causal_default_blocks_d128.forward": "0f905c4616dd343b",
-    "causal_default_blocks_d128.grad": "4238c7811bf81026",
+    "causal_blocks.forward": "dbbc75b6ed5e1e4b",
+    "causal_blocks.grad": "9fdf073ea302f9ab",
+    "causal_one_block.forward": "eda48acf9700ec9f",
+    "causal_one_block.grad": "e722674885b9b7d1",
+    "full_blocks.forward": "f3b50f3db4a1c040",
+    "full_blocks.grad": "1802f1f01d28ad8c",
+    "causal_default_blocks_d128.forward": "b45ee1c45e3f018b",
+    "causal_default_blocks_d128.grad": "5d6e89dfa6c8c254",
     "gpt_train_step.lowered": "88d7b9d8031c969f",
+}
+LOWERED = {  # case, the call's other arguments, the digest
+    "causal_blocks": ("causal_blocks", {}, "335338f90c8d14d4"),
+    "causal_one_block": ("causal_one_block", {}, "092880edcf8a0fc7"),
+    "full_blocks": ("full_blocks", {}, "32349f34627383fc"),
+    "causal_default_blocks_d128": ("causal_default_blocks_d128", {}, "46aa9eeb8e160450"),
+    "window_and_group": ("causal_blocks", {"heads": 2, "window": 100}, "82aefb032dad7db7"),
 }
 
 
@@ -207,6 +235,12 @@ def test_gpt_train_step_lowers_as_before():
     assert _digest(_gpt_train_step_text()) == PINNED["gpt_train_step.lowered"]
 
 
+@pytest.mark.parametrize("name", list(LOWERED))
+def test_with_no_policy_the_names_lower_to_nothing(name):
+    case, arguments, want = LOWERED[name]
+    assert _digest(_flash_grad_text(case, **arguments)) == want
+
+
 @pytest.mark.parametrize("change", [{"heads": 2}, {"window": 100}])
 def test_the_digest_sees_a_group_and_a_window(change):
     """The pins are no constants: either argument changes every kernel."""
@@ -214,8 +248,45 @@ def test_the_digest_sees_a_group_and_a_window(change):
         assert _digest(jaxpr) != PINNED["causal_blocks." + which]
 
 
+# ---------------- a checkpoint that asks for the named residuals ----------------
+
+
+@pytest.mark.parametrize("window", [None, 100], ids=["full", "window"])
+def test_a_checkpoint_that_keeps_the_names_runs_the_forward_kernel_once(window):
+    """Four query heads over two key heads, blocks of 64 over 256 keys: the
+    gradients of the unwrapped call to the bit, and three kernels (forward,
+    dQ, dK/dV) where the bare checkpoint takes the forward one again."""
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    q = jax.random.normal(keys[0], (2, 256, 4, 16), jnp.float32)
+    k, v = (jax.random.normal(key, (2, 256, 2, 16), jnp.float32) for key in keys[1:3])
+    weight = jax.random.normal(keys[3], q.shape, jnp.float32)
+
+    def total(q, k, v):
+        out = flash_attention(
+            q, k, v, causal=True, window=window, block_q=BLOCK, block_k=BLOCK
+        )
+        return jnp.sum(out * weight)
+
+    def grad(wrap):
+        return jax.grad(wrap(total), argnums=(0, 1, 2))
+
+    def kernels(wrap):
+        return len(re.findall(r"pallas_call\[", str(jax.make_jaxpr(grad(wrap))(q, k, v))))
+
+    keep = jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES)
+    kept = functools.partial(jax.checkpoint, policy=keep)
+    assert kernels(lambda f: f) == 3
+    assert kernels(jax.checkpoint) == 4
+    assert kernels(kept) == 3
+    for mine, theirs in zip(grad(kept)(q, k, v), grad(lambda f: f)(q, k, v)):
+        assert float(jnp.linalg.norm(theirs)) > 0
+        np.testing.assert_array_equal(mine, theirs)
+
+
 if __name__ == "__main__":
     for case in CASES:
         for which, jaxpr in _flash_jaxprs(case).items():
             print(f'    "{case}.{which}": "{_digest(jaxpr)}",')
     print(f'    "gpt_train_step.lowered": "{_digest(_gpt_train_step_text())}",')
+    for name, (case, arguments, _) in LOWERED.items():
+        print(f'    "{name}": ("{case}", {arguments}, "{_digest(_flash_grad_text(case, **arguments))}"),')

@@ -1,5 +1,6 @@
 """`ray_tpu.models.mellum` (flash kernels interpreted, grouped experts with
-their own backward, a layer recomputed in the backward pass) against
+their own backward, a layer recomputed in the backward pass all but its
+flash kernel, whose two residuals it keeps) against
 `ray_tpu.models.mellum_reference` (dense masked attention, a loop over a
 token's chosen experts, `jax.grad` of the plain loss), in float32 at the toy
 widths of `tests/mellum_toy.py`: logits, loss and every kind of gradient, a
@@ -140,6 +141,85 @@ def test_recomputing_a_layer_changes_nothing():
         jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(found["grads"])
     ):
         np.testing.assert_allclose(mine, theirs, rtol=1e-5, atol=1e-8)
+
+
+# ---------------- what a recomputed layer keeps ----------------
+
+
+def flash_kernels(jaxpr, inside=False):
+    """The `pallas_call`s under the attention scopes, the grouped experts'
+    own kernels left out."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        stack = str(eqn.source_info.name_stack)
+        here = inside or any(scope in stack for scope in mellum.ATTENTION_SCOPE.values())
+        found += here and eqn.primitive.name == "pallas_call"
+        found += sum(flash_kernels(sub, here) for sub in jax.core.jaxprs_in_params(eqn.params))
+    return found
+
+
+def _ask_for_no_name(patch):
+    """`hidden`'s checkpoint with no policy: every value of a layer is
+    taken again in the backward pass, as before the names."""
+    patch.setattr(jax.checkpoint_policies, "save_only_these_names", lambda *names: None)
+
+
+def _loss_of(cfg, tokens):
+    return lambda p: mellum.loss_and_counts(cfg, p, tokens)[0]
+
+
+def _saved(cfg, params, tokens, capsys):
+    """The lines `print_saved_residuals` gives for values computed on the
+    way (no argument, no constant)."""
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda p: jnp.sum(mellum.hidden(cfg, p, tokens)[0]), params
+    )
+    lines = capsys.readouterr().out.splitlines()
+    return [l for l in lines if "from the argument" not in l and "from a constant" not in l]
+
+
+def test_a_recomputed_layer_keeps_its_input_and_the_flash_residuals(capsys):
+    found = case()
+    cfg, layers = found["cfg"], found["cfg"].num_layers
+    lines = _saved(cfg, found["params"], found["tokens"], capsys)
+    flash = [l for l in lines if "flash_attention.py" in l]
+    # heads folded into the batch: [2 x 8, 64, 16] and its rows' log-sum-exp
+    assert sum(l.startswith("f32[16,64,16] ") for l in flash) == layers
+    assert sum(l.startswith("f32[16,64] named 'flash_lse'") for l in flash) == layers
+    assert len(flash) == 2 * layers
+    inputs = [l for l in lines if l.startswith("f32[2,64,64] ")]
+    assert len(inputs) == layers and sum("(layer)" in l for l in inputs) == layers - 1
+    # and nothing else of a layer: what is left is the positions and the
+    # embedding's index, made before the first one
+    rest = [l for l in lines if l not in flash and l not in inputs]
+    assert len(rest) == 2 and all("(hidden)" in l or "(embed)" in l for l in rest), rest
+    grad = jax.make_jaxpr(jax.grad(_loss_of(cfg, found["tokens"])))(found["params"])
+    assert flash_kernels(grad.jaxpr) == 3 * layers  # forward, dQ, dK/dV
+
+
+def test_a_bare_checkpoint_keeps_the_input_alone_and_runs_the_forward_twice(
+    monkeypatch, capsys
+):
+    found = case()
+    _ask_for_no_name(monkeypatch)
+    cfg, layers = found["cfg"], found["cfg"].num_layers
+    lines = _saved(cfg, found["params"], found["tokens"], capsys)
+    assert not [l for l in lines if "flash_attention.py" in l]
+    grad = jax.make_jaxpr(jax.grad(_loss_of(cfg, found["tokens"])))(found["params"])
+    assert flash_kernels(grad.jaxpr) == 4 * layers
+
+
+def test_keeping_the_flash_residuals_changes_no_bit_of_a_gradient(monkeypatch):
+    found = case()
+    with monkeypatch.context() as bare, jax.default_matmul_precision("highest"):
+        _ask_for_no_name(bare)
+        want = jax.grad(_loss_of(found["cfg"], found["tokens"]))(found["params"])
+    paths = jax.tree_util.tree_leaves_with_path(want)
+    assert len(paths) == 3 + 9 * found["cfg"].num_layers
+    for (path, theirs), mine in zip(paths, jax.tree_util.tree_leaves(found["grads"])):
+        assert float(jnp.linalg.norm(theirs)) > 0, path
+        np.testing.assert_array_equal(mine, theirs, err_msg=jax.tree_util.keystr(path))
 
 
 # ---------------- the shares of a four-chip host ----------------
