@@ -119,10 +119,8 @@ function renderLLM(engines){
         ` · <span class=bad>shed ${m.shed_requests??0}</span>`+
         ` · expired ${m.expired_requests??0}`+
         (m.fabric_timeouts?` · fabric timeouts ${m.fabric_timeouts}`:''):'')+
-      (m.async_scheduling?` · <b>async</b> host gap `+
-        `${m.host_gap_mean_s==null?'—':(1e6*m.host_gap_mean_s).toFixed(0)+'µs'} mean`+
-        ((e.latency_percentiles?.host_gap_s?.p50)!=null?
-          ` / ${(1e6*e.latency_percentiles.host_gap_s.p50).toFixed(0)}µs p50`:'')+
+      (m.async_scheduling?` · <b>async</b> host exposed `+
+        `${m.dispatch_steps?(1e6*m.host_exposed_total_s/m.dispatch_steps).toFixed(0)+'µs':'—'} a step`+
         ` · inflight ${m.inflight_steps}`:'')+`</p>`+
       (m.kv_fabric&&m.kv_fabric!=='off'?
         `<p style="font-size:.8rem">kv fabric <b class=mono>${esc(m.kv_fabric)}</b>`+
@@ -136,9 +134,9 @@ function renderLLM(engines){
       `<tr><td>${s.step}</td><td>${esc(s.phase)}${s.chained?'⤳':''}</td><td>${s.batch_size}</td>`+
       `<td>${s.tokens_in}/${s.tokens_out}</td><td>${s.cache_hit_tokens}</td>`+
       `<td>${s.preempted}</td><td>${(1e3*s.duration_s).toFixed(1)}ms</td>`+
-      `<td>${s.host_gap_s==null?'—':(1e6*s.host_gap_s).toFixed(0)+'µs'}</td></tr>`).join('');
+      `<td>${s.host_exposed_s==null?'—':(1e6*s.host_exposed_s).toFixed(0)+'µs'}</td></tr>`).join('');
     const stepTable=steps?`<table><tr><th>step</th><th>phase</th><th>batch</th>`+
-      `<th>tok in/out</th><th>cache hits</th><th>preempt</th><th>dur</th><th>gap</th></tr>${steps}</table>`:'';
+      `<th>tok in/out</th><th>cache hits</th><th>preempt</th><th>dur</th><th>exposed</th></tr>${steps}</table>`:'';
     const compiles=(fr.compile_events||[]).map(c=>
       `${esc(c.program)}[${c.bucket}] ${c.compile_s.toFixed(2)}s`).join(' · ');
     const fails=(fr.failures||[]).slice(-5).map(f=>
@@ -376,7 +374,6 @@ def _llm_latency_percentiles(engine_id) -> dict:
         ("tpot_s", "llm_request_time_per_output_token_seconds"),
         ("queue_s", "llm_request_queue_time_seconds"),
         ("e2e_s", "llm_request_e2e_seconds"),
-        ("host_gap_s", "llm_engine_step_host_gap_seconds"),
     ):
         try:
             out[label] = {

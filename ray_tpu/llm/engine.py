@@ -42,7 +42,6 @@ from ray_tpu.llm.cache import (
 from ray_tpu.llm.config import EngineConfig
 from ray_tpu.llm.model_runner import build_runner
 from ray_tpu.llm.observability import (
-    HOST_GAP_SECONDS_BOUNDARIES,
     PER_TOKEN_SECONDS_BOUNDARIES,
     REQUEST_SECONDS_BOUNDARIES,
     STEP_SECONDS_BOUNDARIES,
@@ -479,20 +478,6 @@ class LLMEngine:
             boundaries=STEP_SECONDS_BOUNDARIES,
             tag_keys=("engine", "phase", "attn_impl", "chunk"),
         )
-        self._h_host_gap = get_or_create(
-            Histogram,
-            "llm_engine_step_host_gap_seconds",
-            "Host time between consecutive decode/verify device "
-            "dispatches: how long the previous decode's results had been "
-            "sitting on host before the next DECODE program was queued. "
-            "A prefill chunk dispatched in between keeps the device busy "
-            "and is counted all the same; the device's idle window is "
-            "stats() host_exposed_total_s. A chained async dispatch "
-            "issued BEFORE the previous step's results were fetched "
-            "records 0.",
-            boundaries=HOST_GAP_SECONDS_BOUNDARIES,
-            tag_keys=("engine",),
-        )
         # Which paged-attention implementation the runner resolved (pallas
         # fused kernel vs XLA reference): tagged onto the step histograms
         # and per-step flight records so the observability plane can
@@ -600,18 +585,8 @@ class LLMEngine:
             on_stall=self.flight_recorder.record_stall
         )
         self.runner.on_dispatched = self._clock.dispatched
-        # Host-gap apparatus, on the clock's readings: the moment the
-        # previous decode/verify results became host-readable, the
-        # per-step gap/commit fields the flight record carries, and the
-        # cumulative aggregates stats() exposes. The gap runs to the next
-        # DECODE dispatch, over any prefill chunk in between; what the
-        # device idles is the clock's host_exposed.
-        self._last_ready_t: Optional[float] = None
-        self._step_gap: Optional[float] = None
+        # The commits of the current step, for its flight record.
         self._step_commits: List[dict] = []
-        self._host_gap_total = 0.0
-        self._host_gap_count = 0
-        self._host_gap_last: Optional[float] = None
         # What the decode dispatches asked the paged kernel to read: a
         # reader divides the kernel's device time by these.
         self._decode_dispatches = 0
@@ -668,7 +643,7 @@ class LLMEngine:
             self._preemptions, self._prefix_hits, self._tokens_generated,
             self._dead_letter_count, self._shed_count, self._expired_count,
             self._h_ttft, self._h_tpot,
-            self._h_queue, self._h_e2e, self._h_step, self._h_host_gap,
+            self._h_queue, self._h_e2e, self._h_step,
         )
         if self._spec is not None:
             self._metric_family += (
@@ -1025,7 +1000,6 @@ class LLMEngine:
         # operator is staring at the recorder.
         t_step = time.time() if instrument else 0.0
         bytes_before = self._host_transfer_bytes() if instrument else 0
-        self._step_gap = None
         self._step_commits = []
 
         # Deadline sweep BEFORE admission and before the chain attempt: a
@@ -1090,10 +1064,6 @@ class LLMEngine:
                         # Depth 0; and speculation at any depth, whose
                         # acceptance is value-dependent: commit now.
                         self._commit_head(follows_dispatch=True)
-            else:
-                # No decode this step: the next dispatch follows an idle
-                # stretch, not host scheduling work — don't count it as gap.
-                self._last_ready_t = None
         return self._finish_step(
             t_step=t_step, bytes_before=bytes_before,
             preempted_before=preempted_before, plans=plans,
@@ -1204,13 +1174,12 @@ class LLMEngine:
                 "queue_depth": len(self.scheduler.waiting),
                 "time": t_step,
                 # Which dispatch each commit of this step belongs to (its
-                # own at depth 0), and host_gap_s from the previous
-                # decode's results to this step's decode dispatch, over
-                # any prefill chunk in between.
+                # own at depth 0).
                 "commits": self._step_commits,
-                "host_gap_s": self._step_gap,
-                # duration_s and the measured phase seconds that sum to
-                # it (observability.ledger reads its columns from these).
+                # duration_s, the measured phase seconds that sum to it
+                # (observability.ledger reads its columns from these) and
+                # host_exposed_s, this step's share of stats()
+                # host_exposed_total_s.
                 **clock.step_record(),
             }
             if self._pipeline_depth:
@@ -1404,12 +1373,11 @@ class LLMEngine:
             block_tables[i, : len(seq.block_table)] = seq.block_table
             context_lens[i] = seq.num_cached
             true_lens[i] = 1 + len(props)
-        self._note_dispatch(pipelined=False)
         out = self.runner.verify(
             tokens, block_tables, context_lens, true_lens
         )
         if clock is not None:
-            self._last_ready_t = clock.ready()
+            clock.ready()
         proposed = accepted = emitted = 0
         for i, (seq, props) in enumerate(zip(decoding, plans)):
             # Per-sequence commit section; nothing mutates before the
@@ -1479,29 +1447,6 @@ class LLMEngine:
         }
 
     # ---------------- decode: dispatch and commit ----------------
-
-    def _note_dispatch(self, pipelined: bool) -> None:
-        """Host-gap sample at a decode/verify device dispatch: how long
-        the previous decode/verify results had been host-readable before
-        this one was queued. Not the device's idle window: a prefill
-        chunk in between runs on the device and is counted all the same
-        (that window is the clock's host_exposed). A chained async
-        dispatch is issued BEFORE the previous step's results are even
-        fetched, so it records exactly 0 (the gap definition's clamp:
-        the dispatch beat the fetch)."""
-        if not self._instrument:
-            return
-        if pipelined:
-            gap = 0.0
-        else:
-            if self._last_ready_t is None:
-                return  # first dispatch / post-idle: no previous step
-            gap = max(0.0, time.perf_counter() - self._last_ready_t)
-        self._step_gap = gap
-        self._host_gap_total += gap
-        self._host_gap_count += 1
-        self._host_gap_last = gap
-        self._h_host_gap.observe(gap, tags=self._metric_tags)
 
     def _still_decoding(self, seq: Sequence, rid: str) -> bool:
         """Whether a dispatched slot still holds its running request."""
@@ -1609,7 +1554,6 @@ class LLMEngine:
         self._decode_context_tokens += context_tokens
         if clock is not None:
             clock.describe_decode(len(seqs), context_tokens)
-        self._note_dispatch(pipelined=bool(ahead))
         if window is not None:
             self._decode_window_tokens += window_tokens
         tokens_dev = self.runner.decode(
@@ -1660,9 +1604,9 @@ class LLMEngine:
                 # The step's routing counts ride the same fetch.
                 self.runner.count_routing(rec.tokens_host)
             if clock is not None:
-                # The tokens are on host: everything until the next
-                # dispatch is host-side gap.
-                self._last_ready_t = clock.ready(rec.clock_seq)
+                # The tokens are on host: the device is idle from now
+                # if nothing newer is out.
+                clock.ready(rec.clock_seq)
         next_tokens = rec.tokens_host
         committed = 0
         while rec.commit_idx < len(rec.seqs):
@@ -2016,26 +1960,15 @@ class LLMEngine:
             "host_transfer_bytes": self._host_transfer_bytes(),
             "steps": self._steps,
             "decode_tokens": self._decode_tokens,
-            # The pipeline's depth (EngineConfig.async_scheduling) + the
-            # host-gap apparatus: mean/last host time between consecutive
-            # decode dispatches (0 for a chained dispatch — it beat the
-            # previous step's fetch), and how many records are
-            # dispatched-but-uncommitted right now.
+            # The pipeline's depth (EngineConfig.async_scheduling), and
+            # how many records are dispatched-but-uncommitted right now.
             "async_scheduling": bool(self._pipeline_depth),
             "inflight_steps": len(self._inflight),
-            "host_gap_samples": self._host_gap_count,
-            "host_gap_total_s": self._host_gap_total,
-            "host_gap_mean_s": (
-                self._host_gap_total / self._host_gap_count
-                if self._host_gap_count
-                else None
-            ),
-            "host_gap_last_s": self._host_gap_last,
             # The step loop's phase clock: seconds in each phase (they
             # sum to the wall time from the first instrumented step's
             # entry on, idle stretches left out), steps that dispatched
             # a program, and host_exposed, host time during which the
-            # device had nothing queued (what host_gap was taken for).
+            # device had nothing queued.
             **self._clock.stats(),
             # What the decode dispatches asked the paged kernel to read
             # (sum of context_lens, in every layer), and the shape that
@@ -2909,9 +2842,6 @@ class LLMServer:
                     ),
                     "llm_request_e2e_seconds": e._h_e2e.snapshot(
                         e._metric_tags
-                    ),
-                    "llm_engine_step_host_gap_seconds": (
-                        e._h_host_gap.snapshot(e._metric_tags)
                     ),
                 },
             }

@@ -81,20 +81,6 @@ STEP_SECONDS_BOUNDARIES = [
     0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
     0.5, 1.0, 2.5,
 ]
-# Host gap between consecutive decode/verify dispatches: from the previous
-# decode's results being host-readable to the next DECODE dispatch. It is
-# not the device's idle window: a prefill chunk that runs in between keeps
-# the device busy for its whole program and is counted here all the same
-# (PR 22 read 24.9 ms a step where the device idled 7). The device's idle
-# window as the host sees it is StepPhaseClock's `host_exposed`
-# (stats()["host_exposed_total_s"]), sampled at every program's dispatch.
-# A chained async dispatch issued before the previous step's results were
-# even fetched records 0, so the ladder starts at 10 µs and the first
-# bucket is the "pipelined" bucket.
-HOST_GAP_SECONDS_BOUNDARIES = [
-    0.00001, 0.000025, 0.00005, 0.0001, 0.00025, 0.0005, 0.001,
-    0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
-]
 
 
 class RequestTrace:
@@ -324,6 +310,7 @@ class StepPhaseClock:
         self._entry_t = 0.0
         self._entry_totals = self.totals
         self._entry_prepare_cpu = 0.0
+        self._entry_exposed = 0.0
         self._dispatches_at_entry = 0
         self._ready_seq = 0  # newest dispatch known to have finished
         self._idle_since: Optional[float] = None
@@ -368,6 +355,7 @@ class StepPhaseClock:
         self._entry_t = self.switch("schedule")
         self._entry_totals = dict(self.totals)
         self._entry_prepare_cpu = self.prepare_cpu
+        self._entry_exposed = self.exposed_total
         self._dispatches_at_entry = self.dispatches
 
     def exit_step(self, live: bool) -> None:
@@ -432,7 +420,7 @@ class StepPhaseClock:
             self.exposed_samples += 1
         self.dispatches += 1
 
-    def ready(self, seq: Optional[int] = None) -> float:
+    def ready(self, seq: Optional[int] = None) -> None:
         """The results of dispatch `seq` (the newest when None) are on the
         host: commit begins, and the device is idle from now if nothing
         newer is out."""
@@ -440,7 +428,6 @@ class StepPhaseClock:
         self._ready_seq = max(self._ready_seq, seq or self.dispatches)
         if self._ready_seq == self.dispatches:
             self._idle_since = now
-        return now
 
     def describe_decode(self, batch: int, context_tokens: int) -> None:
         """What the decode dispatch of this step asks the paged kernel to
@@ -453,13 +440,17 @@ class StepPhaseClock:
 
     def step_record(self) -> dict:
         """Seconds of the current step so far, by phase: the flight
-        record's `duration_s` and `phases`, which sum to it, and
-        `prepare_cpu_s`, the seconds of its `prepare` the step thread ran."""
+        record's `duration_s` and `phases`, which sum to it,
+        `prepare_cpu_s`, the seconds of its `prepare` the step thread ran,
+        and `host_exposed_s`, the step's share of `exposed_total`."""
         now = self.switch(self._phase)
         return {
             "duration_s": round(now - self._entry_t, 6),
             "prepare_cpu_s": round(
                 self.prepare_cpu - self._entry_prepare_cpu, 6
+            ),
+            "host_exposed_s": round(
+                self.exposed_total - self._entry_exposed, 6
             ),
             "phases": {
                 phase: round(self.totals[phase] - self._entry_totals[phase], 6)

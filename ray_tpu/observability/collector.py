@@ -40,7 +40,6 @@ FLEET_HISTOGRAMS = (
     "llm_request_time_per_output_token_seconds",
     "llm_request_queue_time_seconds",
     "llm_request_e2e_seconds",
-    "llm_engine_step_host_gap_seconds",
 )
 
 

@@ -30,10 +30,13 @@ Columns (the partition):
 
 Overlay (NOT part of the partition — do not add it to the sum):
 
-- ``host_gap_s``  — the gap the engine measures from the previous
-                    decode's results to this step's decode dispatch. It
-                    straddles the step boundary and any prefill chunk in
-                    between, so it overlaps the columns.
+- ``host_exposed_s`` — the step's share of the phase clock's
+                    `host_exposed`: at each program's dispatch, the time
+                    since the device had nothing queued, as the host
+                    sees it. It runs across the non-wait columns (and
+                    the `between` before the step), so it overlaps them;
+                    a chained dispatch samples 0, so it reads 0 where the
+                    device runs dry under one (PERF.md section 7.9a).
 
 `replica_ledger` sums step ledgers over a flight-record ring and adds a
 ``loop_s`` column for the wall-clock span not covered by any step record
@@ -66,11 +69,11 @@ def step_ledger(record: dict) -> dict:
     """One flight-record step's `duration_s` by LEDGER_COLUMNS, from the
     step's measured `phases` (they sum to the duration; what rounding or
     a record without phases leaves over lands in `other_s`), plus the
-    `host_gap_s` overlay."""
+    `host_exposed_s` overlay."""
     duration = float(record.get("duration_s") or 0.0)
     out = {col: 0.0 for col in LEDGER_COLUMNS}
     out["duration_s"] = duration
-    out["host_gap_s"] = float(record.get("host_gap_s") or 0.0)
+    out["host_exposed_s"] = float(record.get("host_exposed_s") or 0.0)
     if record.get("phase") == "idle":
         out["idle_s"] = duration
         return out
@@ -113,19 +116,19 @@ def replica_ledger(
             "fractions": {},
             "ledger_sum_s": 0.0,
             "coverage": None,
-            "host_gap_s": 0.0,
+            "host_exposed_s": 0.0,
             "committed_tokens": 0,
             "goodput_tokens_per_s": 0.0,
             "mfu": None,
         }
 
-    host_gap = 0.0
+    host_exposed = 0.0
     duration_total = 0.0
     for record in steps:
         step = step_ledger(record)
         for col in LEDGER_COLUMNS:
             columns[col] += step[col]
-        host_gap += step["host_gap_s"]
+        host_exposed += step["host_exposed_s"]
         duration_total += step["duration_s"]
 
     # Replica wall = wall-clock span from the first recorded step's start
@@ -157,7 +160,7 @@ def replica_ledger(
         "ledger_sum_s": ledger_sum,
         # ledger_sum / wall — the ~100% acceptance number.
         "coverage": ledger_sum / wall,
-        "host_gap_s": host_gap,
+        "host_exposed_s": host_exposed,
         "committed_tokens": tokens,
         "goodput_tokens_per_s": goodput,
         "mfu": mfu_estimate(model_params, goodput, peak_flops_per_s),

@@ -133,7 +133,7 @@ def test_step_ledger_partitions_duration_exactly():
             "commit": 0.010, "other": 0.010,
         },
         "commits": [{"tokens": 4}],
-        "host_gap_s": 0.002,
+        "host_exposed_s": 0.002,
     }
     led = step_ledger(rec)
     assert led["idle_s"] == 0.0
@@ -145,9 +145,9 @@ def test_step_ledger_partitions_duration_exactly():
     assert led["commit_s"] == pytest.approx(0.010)
     assert led["other_s"] == pytest.approx(0.010)
     assert sum(led[c] for c in LEDGER_COLUMNS) == pytest.approx(0.100)
-    # host_gap is an OVERLAY (straddles step boundaries), never part of
-    # the partition sum.
-    assert led["host_gap_s"] == pytest.approx(0.002)
+    # host_exposed is an OVERLAY (it runs across the non-wait columns),
+    # never part of the partition sum.
+    assert led["host_exposed_s"] == pytest.approx(0.002)
 
 
 def test_step_ledger_idle_and_unphased_steps():
@@ -191,7 +191,7 @@ def test_replica_ledger_covers_wall_and_estimates_mfu():
                 "phases": {"schedule": 0.005, "prepare": 0.005,
                            "wait": 0.07, "commit": 0.01, "other": 0.01},
                 "commits": [{"tokens": 4}],
-                "host_gap_s": None,
+                "host_exposed_s": None,
             }
         )
     led = replica_ledger(steps, model_params=1000, peak_flops_per_s=1e6)

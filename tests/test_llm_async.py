@@ -17,9 +17,8 @@ one step behind via `copy_to_host_async`. These tests pin the contract:
     fixed seed, whose greedy stream first emits its EOS mid-stream;
   * the steady decode path allocates NO fresh host input buffers per step
     (preallocated, reused, asserted by allocation count) in either mode;
-  * per-step dispatch/commit timestamps land in the flight record and the
-    llm_engine_step_host_gap_seconds histogram + stats() counters expose
-    the host gap, with chained dispatches recording exactly 0;
+  * per-step dispatch/commit timestamps land in the flight record, whose
+    host_exposed_s is the step's share of stats() host_exposed_total_s;
   * async ON is the default (PR 31); async_scheduling=False leaves sync
     records free of async keys and counts nothing;
   * stats() counts the chained dispatches and the flushes by cause, and
@@ -119,8 +118,7 @@ def run_modes(model_cfg, prompts, n_new, repeat=False, **overrides):
 def test_async_greedy_matches_sync_and_reference():
     """Base acceptance: mixed prompt/output lengths, async on vs off vs
     the unbatched ground truth — and the async run really pipelined
-    (chained dispatches in the flight record, host gap of exactly 0 on
-    every chained step)."""
+    (chained dispatches in the flight record)."""
     prompts = random_prompts((5, 11, 3, 17), seed=2)
     sync, async_, eng = run_modes(TINY, prompts, 8, **BASE)
     assert async_ == sync
@@ -130,7 +128,6 @@ def test_async_greedy_matches_sync_and_reference():
     steps = eng.flight_recorder.snapshot()["steps"]
     chained = [s for s in steps if s.get("chained")]
     assert len(chained) >= 4, "depth 1 never chained a dispatch"
-    assert all(s["host_gap_s"] == 0.0 for s in chained)
     assert all(s["loop"] == "async" for s in chained)
 
 
@@ -356,33 +353,43 @@ def test_steady_decode_allocates_no_fresh_host_buffers(mode):
         eng.step()
 
 
-# ---------------- host-gap metrics + flight record ----------------
+# ---------------- host_exposed + flight record ----------------
 
 
-def test_host_gap_metrics_and_flight_record_surfaces():
-    """Satellite: per-step dispatch/commit timestamps in the flight
-    record, the llm_engine_step_host_gap_seconds histogram queryable via
-    the same helper the dashboard panel uses, and the stats() counters —
-    chained dispatches record a gap of exactly 0, sync dispatches a
-    positive gap."""
-    from ray_tpu.util.metrics import histogram_percentile
+def test_host_exposed_and_flight_record_surfaces():
+    """Per-step dispatch/commit timestamps in the flight record, and one
+    account of host time: a step record's host_exposed_s is its share of
+    stats() host_exposed_total_s (the ring's sum is the window's
+    difference), sync steps record a positive share, and nothing of the
+    old decode-to-decode gap is left in stats(), in a step record or in
+    the metric registry. (That a chained dispatch samples 0 is
+    test_llm_phase_clock's, on a pure decode loop: a chunk's synchronous
+    fetch leaves the device idle before the next chained dispatch.)"""
+    from ray_tpu.util import metrics
 
-    gaps = {}
+    exposed = {}
     for mode in (False, True):
         eng = LLMEngine(
             TINY, EngineConfig(async_scheduling=mode, **BASE), seed=0
         )
+        before = eng.stats()
         eng.generate(random_prompts((5, 9), seed=3), max_new_tokens=8)
         stats = eng.stats()
-        assert stats["host_gap_samples"] > 0
-        assert stats["host_gap_mean_s"] is not None
-        assert stats["host_gap_last_s"] is not None
-        gaps[mode] = stats
-        steps = [
-            s
-            for s in eng.flight_recorder.snapshot()["steps"]
-            if s.get("commits")
-        ]
+        ring = eng.flight_recorder.snapshot()["steps"]
+        assert len(ring) == stats["steps"] - before["steps"]
+        exposed[mode] = (
+            stats["host_exposed_total_s"] - before["host_exposed_total_s"]
+        )
+        assert exposed[mode] > 0.0
+        # Each record is rounded to the microsecond.
+        assert sum(s["host_exposed_s"] for s in ring) == pytest.approx(
+            exposed[mode], abs=1e-6 * len(ring)
+        )
+        assert not [key for key in stats if "host_gap" in key]
+        assert not [key for s in ring for key in s if "host_gap" in key]
+        with pytest.raises(KeyError):
+            metrics.histogram_snapshot("llm_engine_step_host_gap_seconds")
+        steps = [s for s in ring if s.get("commits")]
         assert steps
         for s in steps:
             # Every step that committed also dispatched a decode batch;
@@ -395,30 +402,20 @@ def test_host_gap_metrics_and_flight_record_surfaces():
                 assert "time" in c and "tokens" in c
         if mode:
             assert any(s.get("chained") for s in steps)
-            assert all(
-                s["host_gap_s"] == 0.0 for s in steps if s.get("chained")
-            )
-            p50 = histogram_percentile(
-                "llm_engine_step_host_gap_seconds",
-                50.0,
-                {"engine": stats["engine_id"]},
-            )
-            assert p50 is not None and p50 >= 0.0
         else:
             assert all("loop" not in s for s in steps)
-            measured = [
-                s["host_gap_s"] for s in steps
-                if s["host_gap_s"] is not None
-            ]
-            assert measured and all(g > 0.0 for g in measured)
-    # Sync pays a real host gap every decode step; async's mean (chained
-    # steps pinned at 0) must come in below it on the same workload.
-    assert gaps[True]["host_gap_mean_s"] < gaps[False]["host_gap_mean_s"]
+            # Depth 0: every decode dispatch after the first follows a
+            # fetch, with the device idle in between.
+            assert all(s["host_exposed_s"] > 0.0 for s in steps[1:])
+    # Sync leaves the device idle before every decode dispatch; async's
+    # chained dispatches sample 0, so its total comes in below on the
+    # same workload.
+    assert exposed[True] < exposed[False]
 
 
-def test_dashboard_percentiles_include_host_gap():
-    """The dashboard panel's percentile helper reads the host-gap series
-    alongside the SLO trio (null-safe before any observation)."""
+def test_dashboard_percentiles_are_the_request_histograms():
+    """The dashboard panel's percentile helper reads the four request
+    histograms and nothing else (null-safe before any observation)."""
     from ray_tpu.dashboard.head import _llm_latency_percentiles
 
     eng = LLMEngine(
@@ -426,11 +423,11 @@ def test_dashboard_percentiles_include_host_gap():
     )
     eng.generate(random_prompts((6,), seed=4), max_new_tokens=6)
     out = _llm_latency_percentiles(eng.stats()["engine_id"])
-    assert "host_gap_s" in out
-    assert out["host_gap_s"]["p50"] is not None
-    assert _llm_latency_percentiles("no-such-engine")["host_gap_s"] == {
-        "p50": None, "p99": None,
-    }
+    assert list(out) == ["ttft_s", "tpot_s", "queue_s", "e2e_s"]
+    assert all(series["p50"] is not None for series in out.values())
+    assert _llm_latency_percentiles("no-such-engine") == dict.fromkeys(
+        out, {"p50": None, "p99": None}
+    )
 
 
 def test_async_off_is_default_and_records_unchanged():

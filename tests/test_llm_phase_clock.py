@@ -117,9 +117,9 @@ def test_prefill_chunk_between_decodes_is_wait_not_exposed(mode):
     """One stream decoding, a second prompt arrives: its chunk program
     runs between two decode batches (at depth 1, behind the chained
     decode of its own step). The device is busy for the chunk's whole
-    run, so that time is `wait`; at depth 0 the old host gap (previous
-    decode ready -> next decode dispatch) counts it as host time, at
-    depth 1 the chained dispatch beat the fetch and the gap reads 0."""
+    run, so that time is `wait` and not `host_exposed`, in the window's
+    total and in the step's own record; at depth 1 the chained dispatch
+    beat the fetch and the step's exposed reads 0."""
     chunk_s = 0.5  # far above a loaded host's own work in one step
     eng = LLMEngine(
         TINY, EngineConfig(async_scheduling=mode, **BASE), seed=0
@@ -154,9 +154,7 @@ def test_prefill_chunk_between_decodes_is_wait_not_exposed(mode):
     for _ in range(4):
         eng.step()  # prefilled and decoding
     eng.add_request(late, max_new_tokens=4)
-    keys = PHASE_KEYS + (
-        "host_exposed_total_s", "host_gap_total_s", "host_gap_samples",
-    )
+    keys = PHASE_KEYS + ("host_exposed_total_s",)
     before = eng.stats()
     dispatches, samples = eng._clock.dispatches, eng._clock.exposed_samples
     eng.step()  # the chunk, then the decode batch
@@ -168,16 +166,12 @@ def test_prefill_chunk_between_decodes_is_wait_not_exposed(mode):
     assert delta["step_wait_s"] >= chunk_s
     assert record["phases"]["wait"] >= chunk_s
     assert delta["host_exposed_total_s"] < chunk_s / 2
-    assert delta["host_gap_samples"] == 1
+    assert record["host_exposed_s"] == pytest.approx(
+        delta["host_exposed_total_s"], abs=1e-6
+    )
     if mode:
         assert record["chained"]
-        assert delta["host_gap_total_s"] == 0.0
-        assert record["host_gap_s"] == 0.0
-    else:
-        # The fault of the old gap, kept under its name until the
-        # benchmark retires the metric that reads it.
-        assert delta["host_gap_total_s"] >= chunk_s
-        assert record["host_gap_s"] >= chunk_s
+        assert record["host_exposed_s"] == 0.0
     while eng.has_work():
         eng.step()
 
@@ -197,7 +191,7 @@ def test_chained_async_dispatch_samples_zero_exposed():
             delta = window(before, eng.stats(), "host_exposed_total_s")
             assert eng._clock.exposed_samples - samples == 1
             assert delta["host_exposed_total_s"] == 0.0
-            assert record["host_gap_s"] == 0.0
+            assert record["host_exposed_s"] == 0.0
     assert chained >= 10
     assert eng.stats()["inflight_steps"] == 0
 
@@ -236,7 +230,7 @@ def test_instrument_off_reads_no_clock_in_the_decode_loop(mode, monkeypatch):
     stats = eng.stats()
     assert all(stats[key] == 0.0 for key in PHASE_KEYS)
     assert eng._clock.exposed_samples == 0 and eng._clock.dispatches == 0
-    assert stats["host_gap_samples"] == 0
+    assert stats["host_exposed_total_s"] == 0.0
     assert stats["dispatch_steps"] == 0
     # No listener is registered for an uninstrumented engine, so stats()
     # has no jax_* totals to show.

@@ -355,12 +355,24 @@ def test_parallel_iterator_from_iterators(ray_start_regular):
 def _tables_hold(runtime, ref) -> bool:
     """Whether the store or the reference counter has an entry for the id
     (read through `_id`: taking `ref.id` is itself an escape)."""
-    oid = ref._id
+    return bool(_held(runtime, lambda oid: oid == ref._id))
+
+
+def _held(runtime, mine) -> list:
+    """The ids among `mine` (a predicate on an id) that the reference
+    counter or the store still has an entry for.
+
+    What a test made, and not "the tables are empty": a worker that ran
+    Serve's files before still has their routers' and controllers' threads
+    (`router-*`, `serve-reconcile`; 26 and 4 of them in the driver's run),
+    which long-poll whichever runtime is current, so a dozen of their refs
+    come and go in both tables for as long as the process lives. One
+    collection there takes 0.11 s; it was never the collector."""
     with runtime.refcount._lock:
-        counted = oid in runtime.refcount._refs
+        counted = [oid for oid in runtime.refcount._refs if mine(oid)]
     with runtime.store._lock:
-        stored = oid in runtime.store._entries
-    return counted or stored
+        stored = [oid for oid in runtime.store._entries if mine(oid)]
+    return counted + stored
 
 
 def _wait_until(predicate, timeout_s=10.0):
@@ -518,17 +530,22 @@ def test_small_stream_item_is_promoted_once_when_it_escapes(
     assert runtime.stream_items_promoted == 1
     assert not _tables_hold(runtime, refs[0])
     assert not _tables_hold(runtime, refs[2])
-    # ... and collected like one when its handles die.
-    oid = ref._id
+    # ... and collected like one when its handles die: one collection for
+    # what the escape left in cycles, then nothing is held for the stream's
+    # ids (the task that took the ref gives its count back on its own
+    # thread, a moment after its result is readable).
+    oid, made = ref._id, ref.task_id()
     del ref, refs
-    _wait_until(lambda: (gc.collect(), runtime.refcount.num_tracked())[1] == 0)
-    _wait_until(lambda: not runtime.store._entries)
+    gc.collect()
+    _wait_until(lambda: not _held(runtime, lambda o: o.task_id == made))
     assert runtime.refcount.counts(oid) == (0, 0)
+    assert not runtime.store.contains(oid)
 
 
 def test_small_stream_items_leave_nothing_behind(ray_start_regular):
     """1,000 items consumed and dropped, some freed, some never read: the
-    reference counter's and the store's tables end up empty."""
+    reference counter's and the store's tables end up with nothing of the
+    stream's: no item, not its completion object, no output set."""
     import gc
 
     from ray_tpu.exceptions import ObjectFreedError
@@ -552,14 +569,16 @@ def test_small_stream_items_leave_nothing_behind(ray_start_regular):
         elif i % 2:
             total += ray_tpu.get(ref)
         # else: dies unread
+    made = ref.task_id()
     del ref
     assert total == sum(i for i in range(1000) if i % 2 and i % 100 != 7)
     assert runtime.stream_items_inline == 1000
     assert runtime.stream_items_promoted == 10
-    del stream  # the completion object's handle
-    _wait_until(lambda: (gc.collect(), runtime.refcount.num_tracked())[1] == 0)
-    _wait_until(lambda: not runtime.store._entries)
-    assert not runtime.refcount._task_outputs
+    assert _held(runtime, lambda o: o.task_id == made)  # the completion object
+    del stream  # its handle
+    gc.collect()
+    _wait_until(lambda: not _held(runtime, lambda o: o.task_id == made))
+    assert made not in runtime.refcount._task_outputs
 
 
 def test_error_and_large_stream_items_are_sealed(ray_start_regular):
@@ -602,9 +621,12 @@ def test_stream_item_holding_a_ref_keeps_it_alive(ray_start_regular):
     (ref,) = list(gen.remote())
     gc.collect()
     assert not _tables_hold(runtime, ref)
+    inner = ray_tpu.get(ref)["inner"].id
+    assert runtime.refcount.counts(inner)[0] >= 1
     assert ray_tpu.get(ray_tpu.get(ref)["inner"]) == "kept"
     del ref
-    _wait_until(lambda: (gc.collect(), runtime.refcount.num_tracked())[1] == 0)
+    gc.collect()
+    _wait_until(lambda: not _held(runtime, lambda o: o == inner))
 
 
 def test_concurrent_streams_stay_off_the_shared_tables(ray_start_regular):

@@ -184,8 +184,11 @@ def test_asha_end_to_end_kills_bad_trials(ray_start_regular):
         for i in range(20):
             session.report({"acc": config["quality"] * (i + 1) / 20.0})
 
-    # Strong trials first: they populate each rung before the weak ones
-    # arrive, so the weak trials meet a meaningful cutoff deterministically.
+    # Strong trials first, one trial at a time: the two strong ones have
+    # populated every rung before a weak one reports, so each weak trial
+    # arrives at its first rung as the lowest of three and is cut there.
+    # With all four at once which trial reaches a rung first is the
+    # interpreter's choice, and in ascending order nothing is ever cut.
     results = tune.run(
         train_fn,
         config={"quality": tune.grid_search([1.0, 0.9, 0.2, 0.1])},
@@ -194,6 +197,7 @@ def test_asha_end_to_end_kills_bad_trials(ray_start_regular):
         scheduler=AsyncHyperBandScheduler(
             metric="acc", mode="max", grace_period=2, reduction_factor=2, max_t=20
         ),
+        max_concurrent_trials=1,
     )
     iters = {
         r.metrics.get("training_iteration", 0): r.metrics.get("acc") for r in results
