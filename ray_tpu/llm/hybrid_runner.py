@@ -5,12 +5,23 @@ of every position, paged) and `sliding_attention` (K and V of the last
 `horizon` positions, paged in a cache class of its own). The model is a
 module of pure functions over a plain tree that its configuration names
 (`llm_model`: `ray_tpu.models.granite_hybrid`, `ray_tpu.models.laguna`,
-`ray_tpu.models.olmo_hybrid`); what this runner reads of it is
-`init_params`, `embed`, `head`, `run_layers`, `attention_qkv` /
-`attention_out`, `ATTENTION_SCOPES`, and where it has them
-`recurrent_kinds` / `recurrent_shape` and `expert_shape`, and of its
-configuration `layer_types`, `cache_classes` / `cache_class_of`,
-`heads_of`, `attention_scale`, `num_key_value_heads`, `head_dim`.
+`ray_tpu.models.olmo_hybrid`, `ray_tpu.models.falcon_h1`); what this
+runner reads of it is `init_params`, `embed`, `head`, `run_layers`,
+`attention_qkv` / `attention_out`, `ATTENTION_SCOPES`, and where it has
+them `recurrent_kinds` / `recurrent_shape`, `expert_shape` and
+`layer_mixers`, and of its configuration `layer_types`, `cache_classes` /
+`cache_class_of`, `heads_of`, `attention_scale`, `num_key_value_heads`,
+`head_dim`.
+
+A layer kind holds one mixer, itself, unless the model says otherwise
+(`layer_mixers(cfg) -> {kind: (mixer, ...)}`: a Falcon-H1 layer runs a
+recurrent mixer and full attention on the same normed input and adds
+them). What keeps memory is the mixer: a recurrent mixer has a state pool
+for every layer that holds it, a cached one a layer of its cache class's
+pools, both counted over the layers that hold the mixer, and `run_layers`
+is handed one function a mixer, `mixers[mixer](i, p, u)` for the i-th
+layer that holds it. A layer with two mixers writes its state pool and its
+K/V blocks in the one program, at the same layer index.
 
 The same three program shapes `model_runner` compiles, under the same
 names: one decode program over all decode lanes, and for every prefill
@@ -79,13 +90,37 @@ def recurrent_kinds(cfg) -> dict:
     return {} if declare is None else declare(cfg)
 
 
+def layer_mixers(cfg) -> dict:
+    """layer kind -> the mixers a layer of that kind holds, for the kinds
+    in `cfg.layer_types`: what the model declares (`layer_mixers`), else
+    the kind itself."""
+    declare = getattr(model_of(cfg), "layer_mixers", None)
+    declared = {} if declare is None else declare(cfg)
+    return {
+        kind: tuple(declared.get(kind, (kind,)))
+        for kind in dict.fromkeys(cfg.layer_types)
+    }
+
+
+def layers_holding(cfg) -> dict:
+    """mixer -> how many of the model's layers hold it, every mixer of
+    every kind, in the order the kinds first appear."""
+    held = layer_mixers(cfg)
+    count: dict = {}
+    for kind in cfg.layer_types:
+        for mixer in held[kind]:
+            count[mixer] = count.get(mixer, 0) + 1
+    return count
+
+
 def state_layout(cfg) -> list:
     """The state pools of `cfg`'s model, in the order the programs take
-    them: (kind, layers of the kind, (name, shape, dtype)) an array a
+    them: (mixer, layers that hold it, (name, shape, dtype)) an array a
     recurrent kind declares."""
+    holding = layers_holding(cfg)
     return [
-        (kind, cfg.layer_types.count(kind), array)
-        for kind, spec in recurrent_kinds(cfg).items() for array in spec.arrays
+        (mixer, holding[mixer], array)
+        for mixer, spec in recurrent_kinds(cfg).items() for array in spec.arrays
     ]
 
 
@@ -106,7 +141,8 @@ class _HybridPrograms:
         self.attn_impl = attn_impl
         self.routed = hasattr(self.model, "expert_shape")
         self.recurrent = recurrent_kinds(cfg)
-        # Where in the state pools a kind's arrays are (`state_layout`).
+        # Where in the state pools a recurrent mixer's arrays are
+        # (`state_layout`).
         kinds = [kind for kind, _, _ in state_layout(cfg)]
         self.state_at = {
             kind: [j for j, k in enumerate(kinds) if k == kind]
@@ -129,8 +165,8 @@ class _HybridPrograms:
         """q of u (tokens at `positions`) against the cached context of the
         kind's cache class, as far as the class's horizon lets it see, and
         the new tokens' own K/V (kept in `new` for the scatter). u
-        [B, S, D], positions [B, S]; `layer` counts the layers of the
-        kind, which are the class's."""
+        [B, S, D], positions [B, S]; `kind` is the mixer and `layer` counts
+        the layers that hold it, which are the class's."""
         cfg, model = self.cfg, self.model
         cls = cfg.cache_class_of(kind)
         horizon = cfg.cache_classes[cls].horizon
@@ -149,10 +185,11 @@ class _HybridPrograms:
             return model.attention_out(cfg, kind, p, u, out)
 
     def _mixers(self, recur, attend) -> dict:
-        """`run_layers`' mixers by the kinds of layer the model has."""
+        """`run_layers`' mixers: one function for every mixer a layer of
+        the model holds, the recurrent path or the cached one."""
         return {
-            kind: functools.partial(recur if kind in self.recurrent else attend, kind)
-            for kind in dict.fromkeys(self.cfg.layer_types)
+            mixer: functools.partial(recur if mixer in self.recurrent else attend, mixer)
+            for mixer in layers_holding(self.cfg)
         }
 
     def _decode_step(
@@ -345,6 +382,15 @@ class HybridRunner:
         # engine's `num_blocks`; a class with a horizon is sized from the
         # lanes, the horizon and the chunk in flight.
         self.classes = cfg.cache_classes
+        held = [0] * len(self.classes)
+        for mixer, layers in layers_holding(cfg).items():
+            if mixer not in self._programs.recurrent:
+                held[cfg.cache_class_of(mixer)] += layers
+        if held != [cls.layers for cls in self.classes]:
+            raise ValueError(
+                f"cache classes {self.classes} against {held} layers that hold "
+                "a cached mixer"
+            )
         self.class_blocks = tuple(
             ecfg.num_blocks if cls.horizon is None
             else ecfg.window_class_blocks(cls.horizon)
@@ -447,8 +493,8 @@ class HybridRunner:
         cfg = self.model_config
         recurrent = recurrent_kinds(cfg)
         kinds = {
-            cfg.cache_class_of(kind): kind
-            for kind in cfg.layer_types if kind not in recurrent
+            cfg.cache_class_of(mixer): mixer
+            for mixer in layers_holding(cfg) if mixer not in recurrent
         }
         shapes = {}
         for i, cls in enumerate(self.classes):
@@ -489,7 +535,15 @@ class HybridRunner:
                 "weight_itemsize": np.dtype(cfg.param_dtype).itemsize,
             },
         } if self.routed else {}
-        return {**self.counters, **recurrent, **routed}
+        return {
+            **self.counters, **recurrent, **routed,
+            "layer_mixers": {k: list(v) for k, v in layer_mixers(cfg).items()},
+            "head_shape": {
+                "vocab_size": cfg.vocab_size,
+                "hidden_size": cfg.hidden_size,
+                "weight_itemsize": np.dtype(cfg.param_dtype).itemsize,
+            },
+        }
 
     # ---------------- programs ----------------
 

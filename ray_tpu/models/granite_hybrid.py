@@ -112,8 +112,8 @@ class GraniteHybridConfig:
     router_score = "chosen"
 
     def __post_init__(self):
-        if self.mamba_n_groups != 1:
-            raise ValueError("mamba_n_groups other than 1 is not implemented")
+        if self.mamba_n_heads % self.mamba_n_groups:
+            raise ValueError("mamba heads must divide into the groups of B and C")
         if self.mamba_n_heads * self.mamba_d_head != self.mamba_expand * self.hidden_size:
             raise ValueError("mamba heads x head size must be expand x hidden")
         if self.num_attention_heads % self.num_key_value_heads:
@@ -315,11 +315,9 @@ def _mamba_finish(cfg, p, y, x, z):
 
 
 def _xbc_parts(cfg, xbc):
-    x, b, c = jnp.split(
-        xbc, [cfg.d_inner, cfg.d_inner + cfg.mamba_d_state], axis=-1
+    return parts.split_xbc(
+        xbc, cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_n_groups, cfg.mamba_d_state
     )
-    heads = x.shape[:-1] + (cfg.mamba_n_heads, cfg.mamba_d_head)
-    return x.reshape(heads), b, c
 
 
 def mamba_prefill(cfg, p, u, conv_tail, ssm, length):
@@ -362,14 +360,13 @@ def mamba_decode(cfg, p, u, conv_tail, ssm, live):
         ) + p["conv_b"].astype(jnp.float32)
         x, b, c = _xbc_parts(cfg, jax.nn.silu(conv).astype(cfg.dtype))
         y, new_ssm = ssm_decode_update(
-            x, dt, -jnp.exp(p["A_log"].astype(jnp.float32)), b, c, ssm
+            x, dt, -jnp.exp(p["A_log"].astype(jnp.float32)), b, c, ssm, live
         )
         y = _mamba_finish(cfg, p, y, x, z)
     with jax.named_scope("llm.mixer.mamba.proj"):
         out = _matmul(y, p["out_proj"], cfg.dtype)
     with jax.named_scope("llm.mixer.mamba.update"):
         new_tail = parts.where_live(live, window[:, 1:], conv_tail)
-        new_ssm = parts.where_live(live, new_ssm, ssm)
     return out, new_tail, new_ssm
 
 

@@ -1,6 +1,6 @@
 """The parts of a layer that the models served by `ray_tpu.llm.hybrid_runner`
-share (`granite_hybrid`, `laguna`, `olmo_hybrid`) with the one that is
-trained (`mellum`): RMS norm, the L2 norm, QK-norm over a whole projection,
+share (`granite_hybrid`, `laguna`, `olmo_hybrid`, `falcon_h1`) with the one
+that is trained (`mellum`): RMS norm (whole and by group), the L2 norm, QK-norm over a whole projection,
 the block with its norms on the sub-layers' outputs, the matrix product in
 the compute dtype with float32 accumulation, rotary positions (default and
 YaRN frequencies), the gated MLP, the routed experts with the routing's counts, beside a shared expert
@@ -82,6 +82,27 @@ def rms_norm(x, weight, eps):
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return (y * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def rms_norm_by_group(x, weight, eps, groups: int):
+    """Mamba-2's gated norm where the inner width is normalised a group of
+    B and C: x [..., W] in `groups` runs of W / groups channels, each under
+    its own mean square; `weight` [W]."""
+    x32 = x.astype(jnp.float32).reshape(x.shape[:-1] + (groups, -1))
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y.reshape(x.shape) * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def split_xbc(xbc, heads: int, head_dim: int, groups: int, state: int):
+    """What Mamba-2's convolution leaves, [..., H * P + 2 * G * N], cut into
+    x [..., H, P] and a group's B and C [..., G, N]."""
+    inner, grouped = heads * head_dim, groups * state
+    x, b, c = jnp.split(xbc, [inner, inner + grouped], axis=-1)
+    lead = xbc.shape[:-1]
+    return (
+        x.reshape(lead + (heads, head_dim)), b.reshape(lead + (groups, state)),
+        c.reshape(lead + (groups, state)),
+    )
 
 
 def l2_norm(x, eps=1e-6):
@@ -179,10 +200,16 @@ def rotate(x, cos, sin):
 # ---------------- the MLPs ----------------
 
 
-def gated_mlp(x, w_in, w_out, dtype):
-    """w_out (silu(g) * u), [g, u] = w_in x; w_in [D, 2F], w_out [F, D]."""
+def gated_mlp(x, w_in, w_out, dtype, gate_multiplier=None, out_multiplier=None):
+    """w_out (silu(g) * u), [g, u] = w_in x; w_in [D, 2F], w_out [F, D].
+    Where the model has them (Falcon-H1's `mlp_multipliers`), g times
+    `gate_multiplier` before the silu and the result times
+    `out_multiplier`, both on the float32 products."""
     g, u = jnp.split(matmul(x, w_in, dtype), 2, axis=-1)
-    return matmul(jax.nn.silu(g) * u, w_out, dtype)
+    if gate_multiplier is not None:
+        g = g * gate_multiplier
+    out = matmul(jax.nn.silu(g) * u, w_out, dtype)
+    return out if out_multiplier is None else out * out_multiplier
 
 
 def experts(cfg, p, x, *, grouped: bool, valid=None):

@@ -150,7 +150,7 @@ def test_a_token_does_not_depend_on_the_batch(weights):
 
 def test_config_refuses_what_it_cannot_run():
     with pytest.raises(ValueError):
-        toy_config(mamba_n_groups=2)
+        toy_config(mamba_n_groups=3)  # 8 heads do not divide into 3 groups
     with pytest.raises(ValueError):
         toy_config(experts_held=(0, 0))
     with pytest.raises(ValueError):

@@ -33,6 +33,16 @@ Since PR 43 the decode program's update is a Pallas kernel a layer
 for its twelve callers, each call sits under `llm.mixer.gdn.update`, and no
 XLA operation outside the calls reads or writes an array of a state pool's
 shape.
+
+And Falcon-H1's decode program and widest chunk at the widths its cell runs
+(PR 46: layers 0-5, 96 lanes, 7,424 blocks of 16, 176-block tables): every
+layer has BOTH a state pool [96, 32, 128, 256] float32 with its flat tail
+and a layer of the K/V pools [6, 7424, 16, 512], all updated in place by the
+one program; the paged kernel at 5 query heads a cached head of 128; the
+grouped scan and update lowered as plain XLA with no temporary of a state
+pool's size; the head of 261,120 rows (decode's logits are 100 MB); 14.61 /
+14.97 GB held of the chip's 16.91 (the chip's allocator reads 14.87 GB at
+its peak: 0.10 GB under this analysis).
 """
 
 import gc
@@ -49,6 +59,7 @@ from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.llm import hybrid_runner as hr
 from ray_tpu.llm.config import EngineConfig
+from ray_tpu.models import falcon_h1 as fh
 from ray_tpu.models import granite_hybrid as gh
 from ray_tpu.models import laguna
 from ray_tpu.models import olmo_hybrid as oh
@@ -324,3 +335,68 @@ def test_olmo_hybrids_programs_fit_a_v5e_with_the_state_in_place(
     off = [line.strip()[:160] for line in touching if not passing.search(line)]
     assert not off, off[:3]
     assert sum(" custom-call(" in line for line in touching) == 12
+
+
+@pytest.mark.parametrize("program,held_limit,temp_limit", [
+    ("decode", 14.7e9, 0.3e9), ("chunk2048", 15.05e9, 0.7e9),
+])
+def test_falcon_h1s_programs_fit_a_v5e_with_both_memories_in_place(
+    chip, monkeypatch, program, held_limit, temp_limit
+):
+    monkeypatch.setattr(sys.modules["ray_tpu.ops.paged_flash"], "_on_cpu", lambda: False)
+    slots, table, blocks, layers = 96, 176, 7424, 6
+    cfg = fh.FalconH1Config(num_hidden_layers=layers)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    params = jax.tree_util.tree_map(
+        sds, fh._leaf_shapes(cfg), is_leaf=lambda v: isinstance(v, tuple)
+    )
+    assert 2 * sum(x.size for x in jax.tree_util.tree_leaves(params)) == 10_509_188_224
+    kv = sds((layers, blocks, BLOCK, 4 * 128))
+    state = tuple(
+        tuple(sds((slots, *shape), dtype) for _ in range(count))
+        for _, count, (_, shape, dtype) in hr.state_layout(cfg)
+    )
+    assert [(len(pools), pools[0].shape) for pools in state] == [
+        (6, (96, 3 * 5120)), (6, (96, 32, 128, 256)),
+    ]
+    programs = hr._HybridPrograms(cfg, BLOCK, "pallas")
+    if program == "decode":
+        lowered = programs.decode_fn.lower(
+            params, (kv,), (kv,), state, i32(slots), i32(slots), (i32(slots, table),),
+            i32(slots),
+        )
+    else:
+        lowered = programs.prefill_suffix_fn.lower(
+            params, (kv,), (kv,), state, i32(1, 2048), (i32(table),), i32(), i32(), i32(),
+        )
+    # Handed to Mosaic: the paged kernel, once a layer.
+    assert lowered.as_text().count("tpu_custom_call") == layers
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    pools = 2 * kv.size * 2 + sum(
+        x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(state)
+    )
+    assert pools == 2 * layers * blocks * BLOCK * 512 * 2 + slots * 25_350_144
+    assert memory.alias_size_in_bytes >= pools  # every pool updated in place
+    assert memory.temp_size_in_bytes < temp_limit, memory.temp_size_in_bytes
+    held = (
+        memory.argument_size_in_bytes + memory.temp_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    )
+    assert held < held_limit, held
+    text = compiled.as_text()
+    scopes = hr.scopes_of(text)
+    mine = "llm.mixer.mamba.update" if program == "decode" else "llm.mixer.mamba.scan"
+    assert {
+        mine, "llm.mixer.mamba.proj", "llm.mixer.attention.proj",
+        "llm.mixer.attention.full", "llm.mlp", "llm.head",
+    } <= set(scopes.values())
+    kernels = [
+        re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line)[1]
+        for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    assert [scopes.get(name) for name in kernels] == ["llm.mixer.attention.full"] * layers

@@ -78,11 +78,21 @@ ONE_QUERY_HEAD_A_CACHED_HEAD = [
     pytest.param(30, 30, 1, 128, (0, 37, 168, 128), None, id="mha30-decode-128-padded-slot"),
     pytest.param(30, 30, 40, 128, (0, 0, 168, 128), 16, id="mha30-chunk40-tiles-of-16"),
 ]
+# Falcon-H1's attention branch: 20 query heads over 4 cached heads of 128,
+# so decode's block-diagonal product has rows of 5 (no power of two, and the
+# one group size between granite's 4 and Laguna's 6) and a fed chunk stacks
+# five q tiles a cached head; pools [layers, N, 16, 512].
+FIVE_QUERY_HEADS_A_CACHED_HEAD = [
+    pytest.param(20, 4, 1, 128, None, None, id="gqa5-decode-128"),
+    pytest.param(20, 4, 1, 128, (0, 37, 168, 128), None, id="gqa5-decode-128-padded-slot"),
+    pytest.param(20, 4, 40, 128, (0, 0, 168, 128), 16, id="gqa5-chunk40-tiles-of-16"),
+    pytest.param(20, 4, 48, 128, (0, 0, 168, 128), 16, id="gqa5-chunk48-tiles-of-16"),
+]
 
 
 @pytest.mark.parametrize(
     "heads,kv_heads,fed,head_dim,contexts,q_tile",
-    AS_BEFORE + STACKED + ONE_QUERY_HEAD_A_CACHED_HEAD,
+    AS_BEFORE + STACKED + ONE_QUERY_HEAD_A_CACHED_HEAD + FIVE_QUERY_HEADS_A_CACHED_HEAD,
 )
 def test_kernel_matches_the_xla_path(
     monkeypatch, heads, kv_heads, fed, head_dim, contexts, q_tile

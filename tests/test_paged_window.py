@@ -170,20 +170,24 @@ def test_refusals():
 PINS = {
     "gpt.decode.reference": "e93e16a7b7e88d9b",
     "gpt.suffix.reference": "8ccadbc3c2bcfd51",
-    "granite.jit__decode_step.None.reference": "0a804be3d6cfb9a2",
+    # All six of granite's re-taken at PR 46: `ops/ssd.py` takes B and C with
+    # a group axis (granite's one group among them) and its one-token update
+    # keeps an idle lane's state itself. GPT-2's four and Laguna's
+    # (tests/test_grouped_experts_grad.py) held through the runner's change.
+    "granite.jit__decode_step.None.reference": "0324a3fa67ee642e",
     # The four chunk programs re-taken at PR 41 (on e75c747 + that PR's
     # grouped experts): `routed_grouped` walks a rung of the sorted rows
     # through `ops/grouped_matmul.py`'s kernels, and a chunk returns the
     # rows walked beside the held assignments.
-    "granite.jit__prefill_step.16.reference": "d5dea66b81d7fec6",
-    "granite.jit__prefill_suffix_step.16.reference": "fc2f0b11e8caa50e",
+    "granite.jit__prefill_step.16.reference": "43e9b6ef479d2988",
+    "granite.jit__prefill_suffix_step.16.reference": "7b078394829e7da7",
     "gpt.decode.pallas": "df37fa95058d4865",
     "gpt.suffix.pallas": "9a5618e5e93e45f8",
-    "granite.jit__decode_step.None.pallas": "ee633a9917e56c8b",
+    "granite.jit__decode_step.None.pallas": "b02c8e99ab95bb2f",
     # (Re-taken at PR 38 too: a fed chunk of a grouped model takes a cached
     # head's query heads in one product.)
-    "granite.jit__prefill_step.16.pallas": "2867823fa685056b",
-    "granite.jit__prefill_suffix_step.16.pallas": "6bb01919de69c2b5",
+    "granite.jit__prefill_step.16.pallas": "ce6134a559c9a8ba",
+    "granite.jit__prefill_suffix_step.16.pallas": "0bcce8fc740f00f8",
 }
 
 
