@@ -158,7 +158,9 @@ def _maybe_consume_stream(
     return TaskResult(value=i)
 
 
-async def _consume_async_stream(spec: TaskSpec, agen) -> TaskResult:
+async def _consume_async_stream(
+    spec: TaskSpec, agen, should_abort: Optional[Callable] = None
+) -> TaskResult:
     """Async-generator variant of _maybe_consume_stream for async actors."""
     from ray_tpu._private.runtime import get_runtime
 
@@ -166,7 +168,9 @@ async def _consume_async_stream(spec: TaskSpec, agen) -> TaskResult:
     i = 0
     try:
         async for item in agen:
-            if _stream_cancel_requested(spec.task_id):
+            if (should_abort is not None and should_abort()) or (
+                _stream_cancel_requested(spec.task_id)
+            ):
                 await agen.aclose()
                 break
             runtime.report_stream_item(spec, i, value=item)
@@ -278,12 +282,25 @@ class NodeEngine:
         self._pool.shutdown(wait=False, cancel_futures=True)
 
 
+def is_async_actor_class(cls: type) -> bool:
+    """Whether a class's actor runs its methods on an event loop: it has a
+    coroutine function or an async generator function (the reference's rule:
+    an actor whose only `async def` is a streaming generator is async too,
+    and run threaded its stream would deliver the generator object as its
+    one item)."""
+    return any(
+        inspect.iscoroutinefunction(m) or inspect.isasyncgenfunction(m)
+        for _, m in inspect.getmembers(cls, predicate=inspect.isfunction)
+    )
+
+
 class ActorExecutor:
     """Executes one actor's creation task and method calls.
 
     Mode selection (matches the reference's rules, _raylet.pyx:3769 +
     transport/concurrency_group_manager.h):
-      * class has any `async def` method  → asyncio loop thread, up to
+      * class has any `async def` method (coroutine or async generator)
+                                          → asyncio loop thread, up to
         max_concurrency concurrent coroutines;
       * max_concurrency > 1               → thread pool (threaded actor);
       * otherwise                         → single thread, strict submission
@@ -302,12 +319,7 @@ class ActorExecutor:
         self.death_reason = ""
         self._inbox: "queue.Queue[Optional[TaskSpec]]" = queue.Queue()
         self._lock = threading.Lock()
-        self._is_async = any(
-            inspect.iscoroutinefunction(m)
-            for _, m in inspect.getmembers(
-                creation_spec.func, predicate=inspect.isfunction
-            )
-        )
+        self._is_async = is_async_actor_class(creation_spec.func)
         self.max_concurrency = max(1, creation_spec.max_concurrency)
         self._thread: Optional[threading.Thread] = None
         self._loop: Optional[asyncio.AbstractEventLoop] = None
@@ -435,7 +447,9 @@ class ActorExecutor:
                     with env:
                         if inspect.isasyncgenfunction(method) and spec.streaming:
                             result = await _consume_async_stream(
-                                spec, method(*args, **kwargs)
+                                spec,
+                                method(*args, **kwargs),
+                                should_abort=lambda: self.dead,
                             )
                         else:
                             if inspect.iscoroutinefunction(method):

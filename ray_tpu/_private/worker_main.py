@@ -800,11 +800,9 @@ class Worker:
         self._send_done(spec, result)
 
     def _setup_actor_concurrency(self, cls: type, max_concurrency: int) -> None:
-        is_async = any(
-            inspect.iscoroutinefunction(m)
-            for _, m in inspect.getmembers(cls, predicate=inspect.isfunction)
-        )
-        if is_async:
+        from ray_tpu._private.engine import is_async_actor_class
+
+        if is_async_actor_class(cls):
             import asyncio
 
             self._actor_sem = asyncio.Semaphore(max(1, max_concurrency))

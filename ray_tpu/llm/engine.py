@@ -20,12 +20,15 @@ standard Prometheus registry / tracing.traces() / flight_record().
 
 from __future__ import annotations
 
+import asyncio
+import contextvars
+import functools
 import hashlib
-import queue
 import threading
 import time
 import uuid
 from collections import deque
+from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Deque, Dict, List, Optional
 
 import numpy as np
@@ -289,6 +292,10 @@ class LLMEngine:
         )
         self._on_token: Dict[str, Callable[[int], None]] = {}
         self._on_finish: Dict[str, Callable[[Sequence], None]] = {}
+        # Called where a decode's emission ends (`_commit_head`), before
+        # the step goes on to its admissions and its chunks, whose
+        # synchronous fetch the committed tokens need not wait out.
+        self.on_commit: Optional[Callable[[], None]] = None
         # Tokens emitted since `llm_engine_generated_tokens` was last
         # written (once a step, with the rest of the metric family; a step
         # that raises leaves its count to the next one that returns).
@@ -1636,6 +1643,8 @@ class LLMEngine:
         self._current_rid = None
         self._attribution_step = None
         self._inflight.popleft()
+        if self.on_commit is not None:
+            self.on_commit()
         self._decode_tokens += committed
         self._decode_slot_steps += ecfg.max_decode_slots
         self._step_commits.append(
@@ -2105,49 +2114,133 @@ class LLMEngine:
 
 
 class _RequestState:
-    """One request's way out of the engine: the queue its tokens change
-    thread on (step thread -> the thread that called `generate` or drives
-    `generate_stream`) and, with `instrument` on, that hand-over's clock.
-    One writer a field: `offered` is the step thread's, the `handoff_*`
-    the one consumer's, so neither takes a lock."""
+    """One request's way out of the engine: where its tokens change thread
+    (step thread -> the event loop on which `generate` or
+    `generate_stream` was called) and, with `instrument` on, that
+    hand-over's clock. The emitting side (the step thread, or whoever
+    holds the server's lock) files nothing here itself: `offer` and
+    `finish` put `(state, item, stamp)` on the server's outbox, which goes
+    to the loop where a commit ends (`LLMServer._flush`), and there `_deliver`
+    appends to `items` and wakes the request's coroutine. One writer a
+    field: `offered`, `finished`, `seq` and `error` are the emitting
+    side's; `items`, `waiter`, `ended`, `late` and the `handoff_*` the
+    loop's, so neither takes a lock."""
 
     __slots__ = (
-        "tokens", "done", "seq", "error",
+        "loop", "emit", "per_token", "items", "waiter", "ended", "late",
+        "finished", "seq", "error",
         "stamped", "offered", "handoff_s", "handoff_max_s", "handoff_tokens",
     )
 
-    def __init__(self, stamped: bool):
-        self.tokens: "queue.Queue" = queue.Queue()
-        self.done = threading.Event()
+    def __init__(self, loop, emit: Callable, per_token: bool, stamped: bool):
+        self.loop = loop
+        self.emit = emit
+        # Whether a token wakes the coroutine (a stream) or only the end
+        # does (the blocking call gathers its tokens then).
+        self.per_token = per_token
+        self.items: Deque[tuple] = deque()
+        self.waiter: Optional[asyncio.Future] = None
+        self.ended = False
+        self.late = False
+        self.finished = False
         self.seq: Optional[Sequence] = None
         self.error: Optional[BaseException] = None
-        # Whether the queue holds (token, perf_counter reading at commit)
-        # and not bare tokens.
+        # Whether an item's stamp is the perf_counter reading at commit
+        # and not 0.0.
         self.stamped = stamped
         self.offered = 0
         self.handoff_s = 0.0
         self.handoff_max_s = 0.0
         self.handoff_tokens = 0
 
-    def offer(self, token: int) -> None:
-        """`on_token` of a stamped request: the step thread's side."""
-        self.offered += 1
-        self.tokens.put((token, time.perf_counter()))
+    # ---- the emitting side ----
 
-    def take(self, item) -> int:
-        """The token of a queue item, its wait charged: the consumer's side."""
-        if not self.stamped:
-            return item
-        token, committed = item
-        waited = time.perf_counter() - committed
-        self.handoff_s += waited
-        if waited > self.handoff_max_s:
-            self.handoff_max_s = waited
-        self.handoff_tokens += 1
+    def offer(self, token: int) -> None:
+        """`on_token`."""
+        if self.stamped:
+            self.offered += 1
+            self.emit(self, token, time.perf_counter())
+        else:
+            self.emit(self, token, 0.0)
+
+    def finish(self, seq: Optional[Sequence] = None) -> None:
+        """`on_finish`, and the end of a request that ends without one
+        (`error` set first): once, behind the request's last token."""
+        if self.finished:
+            return
+        self.finished = True
+        self.seq = seq
+        self.emit(self, _STREAM_END, 0.0)
+
+    # ---- the loop's side ----
+
+    def take(self, entry: tuple) -> int:
+        """The token of an entry of `items`, its wait charged."""
+        token, committed = entry
+        if self.stamped:
+            waited = time.perf_counter() - committed
+            self.handoff_s += waited
+            if waited > self.handoff_max_s:
+                self.handoff_max_s = waited
+            self.handoff_tokens += 1
         return token
+
+    def wake(self) -> None:
+        waiter = self.waiter
+        if waiter is not None and not waiter.done():
+            waiter.set_result(None)
+
+    def expire(self) -> None:
+        """The deadline's timer, one a request."""
+        self.late = True
+        self.wake()
+
+    async def wait(self, idle_s: Optional[float] = None) -> None:
+        """Parks the request's coroutine until `_deliver` has something
+        for it, its deadline has passed or `idle_s` seconds have: the
+        caller tells which by what it finds. No timer a token unless the
+        request asked for an idle time-out."""
+        if self.late:
+            return
+        self.waiter = self.loop.create_future()
+        idle = (
+            None if idle_s is None else self.loop.call_later(idle_s, self.wake)
+        )
+        try:
+            await self.waiter
+        finally:
+            self.waiter = None
+            if idle is not None:
+                idle.cancel()
 
 
 _STREAM_END = object()
+
+
+def _deliver(batch: List[tuple]) -> None:
+    """One flush of the server's outbox, on the loop its requests were
+    submitted on: each item filed with its request, whose coroutine is
+    woken through a future, with no lock and no thread."""
+    for state, item, stamp in batch:
+        state.items.append((item, stamp))
+        if item is _STREAM_END:
+            state.ended = True
+        elif not state.per_token:
+            continue
+        state.wake()
+
+
+def _off_loop(method: Callable) -> Callable:
+    """A call of `LLMServer`'s that takes the server's lock. A step holds
+    that lock for its whole length and the actor's event loop serves every
+    stream, so nothing may wait for the lock on the loop: the call is a
+    coroutine that runs `method` on the server's pool."""
+
+    @functools.wraps(method)
+    async def call(self, *args, **kwargs):
+        return await self._in_pool(method, self, *args, **kwargs)
+
+    return call
 
 
 class _HandoffLock:
@@ -2192,12 +2285,20 @@ class _HandoffLock:
 
 
 class LLMServer:
-    """Engine actor: background step loop + blocking / streaming generate.
+    """Engine actor: a background step thread, and `generate` /
+    `generate_stream` as coroutines of the actor's event loop.
 
-    Deploy with `ray_tpu.remote(LLMServer).options(max_concurrency=N)` so
-    concurrent generate calls overlap; they are continuous-batched inside
-    the one engine. `generate_stream` is a generator method — call it with
-    `.options(num_returns="streaming")` on the actor handle.
+    An async actor (`ray_tpu.remote(LLMServer).options(max_concurrency=N)`,
+    N a bound on coroutines): concurrent calls are continuous-batched
+    inside the one engine and none holds a thread, live or waiting for a
+    lane. `generate_stream` is an async generator: call it with
+    `.options(num_returns="streaming")` on the actor handle. The step
+    thread hands a decode's tokens to the loop in one
+    `call_soon_threadsafe` where their commit ends (`_flush`); every call
+    that takes the server's lock leaves the loop for it (`_off_loop`). In
+    process, call them from a coroutine
+    (`asyncio.run(server.generate(...))`): a request is served on the loop
+    it was submitted on.
     """
 
     def __init__(
@@ -2272,6 +2373,19 @@ class LLMServer:
         self._lock = _HandoffLock()
         self._work = threading.Condition(self._lock)
         self._requests: Dict[str, _RequestState] = {}
+        # What was emitted since the last flush, a list an event loop (the
+        # actor has one; in-process callers may each bring their own), and
+        # the flushes made: `call_soon_threadsafe` calls.
+        self._outbox: Dict[asyncio.AbstractEventLoop, List[tuple]] = {}
+        self._handoffs = 0
+        self._engine.on_commit = self._flush
+        # Where the calls that take the lock wait for it (`_in_pool`): a
+        # pool of the server's own, since the actor runtime's loop takes
+        # its next call through the loop's default executor and must not
+        # queue behind admissions that wait out a step.
+        self._pool = ThreadPoolExecutor(
+            max_workers=16, thread_name_prefix="llm-engine-call"
+        )
         # The hand-over clocks of the requests that have left `_requests`.
         self._handoff_retired_s = 0.0
         self._handoff_retired_tokens = 0
@@ -2452,7 +2566,7 @@ class LLMServer:
                         # set BEFORE fail_request fires its finish callback
                         # so the caller never sees a clean finish.
                         state = self._requests.get(culprit)
-                        if state is not None and not state.done.is_set():
+                        if state is not None and not state.finished:
                             state.error = PoisonRequestError(
                                 request_id=culprit, cause=exc
                             )
@@ -2489,32 +2603,73 @@ class LLMServer:
                     self._wedged = True
                     self._shutdown = True
                     self._engine.close_traces(exc)
-                    for state in self._requests.values():
-                        if not state.done.is_set():
-                            state.error = exc
-                            state.tokens.put(_STREAM_END)
-                            state.done.set()
+                    self._end_all(exc)
                     import traceback
 
                     traceback.print_exc()
                     return
+                finally:
+                    # A decode's tokens left where its commit ended
+                    # (`on_commit`); what the step emitted since (a
+                    # prompt's first token, the ends of streams, errors)
+                    # leaves here.
+                    self._flush()
+
+    def _end_all(self, exc: BaseException) -> None:
+        """The wedge's and the shutdown's broadcast: every live request
+        ends with `exc`, behind the tokens it was already given. Caller
+        holds the lock and flushes."""
+        for state in self._requests.values():
+            if not state.finished:
+                state.error = exc
+                state.finish()
+
+    def _emit(self, state: _RequestState, item, stamp: float) -> None:
+        """`_RequestState.emit`. Caller holds the lock."""
+        batch = self._outbox.get(state.loop)
+        if batch is None:
+            batch = self._outbox[state.loop] = []
+        batch.append((state, item, stamp))
+
+    def _flush(self) -> None:
+        """Everything emitted since the last flush goes to its event loop
+        in one `call_soon_threadsafe` (one a loop: the actor has exactly
+        one), so a decode's commit wakes one thread however many streams
+        it fed. Caller holds the lock, and flushes before releasing it
+        whatever it did that may have ended a request (a step, an abort,
+        the broadcasts)."""
+        if not self._outbox:
+            return
+        outbox, self._outbox = self._outbox, {}
+        for loop, batch in outbox.items():
+            try:
+                loop.call_soon_threadsafe(_deliver, batch)
+            except RuntimeError:
+                # The loop is closed: the actor died under its step
+                # thread, and nobody is left to serve. Drop the batch and
+                # stop stepping.
+                self._shutdown = True
+                continue
+            self._handoffs += 1
+
+    def _in_pool(self, fn: Callable, *args, **kwargs):
+        """Awaitable: `fn` on the server's pool, in the caller's context
+        (the trace parent `add_request` captures is the calling
+        coroutine's, not whatever the pool's thread ran last)."""
+        call = functools.partial(
+            contextvars.copy_context().run, fn, *args, **kwargs
+        )
+        return asyncio.get_running_loop().run_in_executor(self._pool, call)
 
     def _submit(
         self,
+        state: _RequestState,
         prompt_ids: List[int],
         max_new_tokens: Optional[int],
         eos_id: Optional[int],
         request_id: Optional[str],
         deadline_s: Optional[float] = None,
-    ) -> tuple[str, _RequestState]:
-        # The commit's stamp rides `instrument`, as the step clock does.
-        state = _RequestState(stamped=self._engine._instrument)
-
-        def on_finish(seq: Sequence) -> None:
-            state.seq = seq
-            state.tokens.put(_STREAM_END)
-            state.done.set()
-
+    ) -> str:
         with self._work:
             if self._shutdown or not self._thread.is_alive():
                 raise RuntimeError(
@@ -2536,8 +2691,8 @@ class LLMServer:
                 max_new_tokens=max_new_tokens,
                 eos_id=eos_id,
                 request_id=request_id,
-                on_token=state.offer if state.stamped else state.tokens.put,
-                on_finish=on_finish,
+                on_token=state.offer,
+                on_finish=state.finish,
                 deadline_s=deadline_s,
             )
             self._requests[rid] = state
@@ -2545,11 +2700,47 @@ class LLMServer:
             if trace is not None:
                 trace.egress = state
             self._work.notify_all()
-        return rid, state
+        return rid
+
+    async def _admit(
+        self,
+        per_token: bool,
+        prompt_ids: List[int],
+        max_new_tokens: Optional[int],
+        eos_id: Optional[int],
+        request_id: Optional[str],
+        timeout_s: Optional[float],
+    ) -> tuple:
+        """Submit a request from the running loop: its id, its state and
+        its deadline's timer (None without a deadline), which `_release`
+        takes back."""
+        loop = asyncio.get_running_loop()
+        # The commit's stamp rides `instrument`, as the step clock does.
+        state = _RequestState(
+            loop, self._emit, per_token, stamped=self._engine._instrument
+        )
+        deadline = (
+            time.monotonic() + timeout_s if timeout_s is not None else None
+        )
+        rid = await self._in_pool(
+            self._submit, state, prompt_ids, max_new_tokens, eos_id,
+            request_id, deadline,
+        )
+        expiry = (
+            None if timeout_s is None
+            else loop.call_later(timeout_s, state.expire)
+        )
+        return rid, state, expiry
+
+    async def _release(self, rid: str, expiry) -> None:
+        """A request's coroutine is done with it, however it ended."""
+        if expiry is not None:
+            expiry.cancel()
+        await self._in_pool(self._retire, rid)
 
     # ---------------- public API ----------------
 
-    def generate(
+    async def generate(
         self,
         prompt_ids: List[int],
         max_new_tokens: Optional[int] = None,
@@ -2557,26 +2748,25 @@ class LLMServer:
         request_id: Optional[str] = None,
         timeout_s: float = 120.0,
     ) -> dict:
-        """Blocking generation. `timeout_s` is the request's END-TO-END
+        """The whole generation. `timeout_s` is the request's END-TO-END
         deadline: it bounds this call's wait AND rides into the engine as
         an absolute monotonic deadline, so a request that cannot finish in
         time is dropped from the queue before its prefill ever runs (or
         aborted mid-decode with its blocks reclaimed) instead of decoding
         for a caller that already gave up. Either side tripping first
         raises TimeoutError."""
-        deadline = (
-            time.monotonic() + timeout_s if timeout_s is not None else None
-        )
-        rid, state = self._submit(
-            prompt_ids, max_new_tokens, eos_id, request_id, deadline
+        rid, state, expiry = await self._admit(
+            False, prompt_ids, max_new_tokens, eos_id, request_id, timeout_s
         )
         try:
-            if not state.done.wait(timeout=timeout_s):
+            if not state.ended:
+                await state.wait()
+            if not state.ended:
                 # The request may have finished in the instant between the
-                # wait expiring and the abort landing; only a successful
+                # timer firing and the abort landing; only a successful
                 # abort (it was still queued/running) is a real timeout —
                 # otherwise fall through and deliver the completed result.
-                if self.abort(rid) or not state.done.is_set():
+                if await self.abort(rid) or not state.ended:
                     raise TimeoutError(
                         f"generation {rid} timed out after {timeout_s}s"
                     )
@@ -2587,17 +2777,16 @@ class LLMServer:
                 and state.seq.finish_reason == FINISH_EXPIRED
             ):
                 # The ENGINE enforced the deadline (queued expiry or
-                # mid-decode abort) before this thread's own wait tripped:
+                # mid-decode abort) before this call's own timer fired:
                 # same contract, same error.
                 raise TimeoutError(
                     f"generation {rid} exceeded its {timeout_s}s deadline"
                 )
-            token_ids = []
-            while True:
-                item = state.tokens.get_nowait()
-                if item is _STREAM_END:
-                    break
-                token_ids.append(state.take(item))
+            token_ids = [
+                state.take(entry)
+                for entry in state.items
+                if entry[0] is not _STREAM_END
+            ]
             return {
                 "request_id": rid,
                 "token_ids": token_ids,
@@ -2605,10 +2794,9 @@ class LLMServer:
                 "num_preemptions": state.seq.num_preemptions if state.seq else 0,
             }
         finally:
-            with self._lock:
-                self._retire(rid)
+            await self._release(rid, expiry)
 
-    def generate_stream(
+    async def generate_stream(
         self,
         prompt_ids: List[int],
         max_new_tokens: Optional[int] = None,
@@ -2628,43 +2816,30 @@ class LLMServer:
         `stream_idle_timeout_s` (optional) additionally bounds the gap
         between consecutive tokens — the old `timeout_s` semantics for
         callers that want a liveness check tighter than the deadline."""
-        deadline = (
-            time.monotonic() + timeout_s if timeout_s is not None else None
+        rid, state, expiry = await self._admit(
+            True, prompt_ids, max_new_tokens, eos_id, request_id, timeout_s
         )
-        rid, state = self._submit(
-            prompt_ids, max_new_tokens, eos_id, request_id, deadline
-        )
+        items = state.items
         try:
             while True:
-                wait = stream_idle_timeout_s
-                if deadline is not None:
-                    remaining = deadline - time.monotonic()
-                    wait = (
-                        remaining
-                        if wait is None
-                        else min(wait, remaining)
-                    )
-                if wait is not None and wait < 0.0:
-                    wait = 0.0  # Queue.get rejects negative timeouts
-                try:
-                    item = state.tokens.get(timeout=wait)
-                except queue.Empty:
-                    self.abort(rid)
-                    if (
-                        deadline is not None
-                        and time.monotonic() >= deadline
-                    ):
+                if not items:
+                    await state.wait(stream_idle_timeout_s)
+                    if not items:
+                        # A timer woke it: the deadline's or the gap's.
+                        await self.abort(rid)
+                        if state.late:
+                            raise TimeoutError(
+                                f"generation {rid} exceeded its {timeout_s}s "
+                                "deadline"
+                            )
                         raise TimeoutError(
-                            f"generation {rid} exceeded its {timeout_s}s "
-                            "deadline"
-                        ) from None
-                    raise TimeoutError(
-                        f"generation {rid} produced no token for "
-                        f"{stream_idle_timeout_s}s"
-                    ) from None
-                if item is _STREAM_END:
+                            f"generation {rid} produced no token for "
+                            f"{stream_idle_timeout_s}s"
+                        )
+                entry = items.popleft()
+                if entry[0] is _STREAM_END:
                     break
-                yield state.take(item)
+                yield state.take(entry)
             if state.error is not None:
                 raise state.error
             if (
@@ -2675,28 +2850,36 @@ class LLMServer:
                     f"generation {rid} exceeded its {timeout_s}s deadline"
                 )
         finally:
-            # Closed before exhaustion (consumer disconnected / stream task
+            # Closed before exhaustion (consumer disconnected / stream
             # cancelled → GeneratorExit at the yield): the request is still
             # occupying KV blocks (and, with speculation=draft, mirror
-            # blocks) to generate tokens nobody will read — abort it so the
-            # pool returns to steady state now. A finished request is no
-            # longer active, so the abort is a no-op on the normal path.
-            with self._lock:
-                self._retire(rid)
-                self._engine.abort(rid)
+            # blocks) to generate tokens nobody will read — `_retire`'s
+            # abort returns the pool to steady state now. A finished
+            # request is no longer active, so the abort is a no-op on the
+            # normal path.
+            await self._release(rid, expiry)
 
     def _retire(self, rid: str) -> None:
-        """Drop a request's state, its hand-over clock kept. Caller holds
-        the lock."""
-        state = self._requests.pop(rid, None)
-        if state is not None:
-            self._handoff_retired_s += state.handoff_s
-            self._handoff_retired_tokens += state.handoff_tokens
+        """Drop a request's state, its hand-over clock kept, and abort
+        what is left of it in the engine."""
+        with self._lock:
+            state = self._requests.pop(rid, None)
+            if state is not None:
+                self._handoff_retired_s += state.handoff_s
+                self._handoff_retired_tokens += state.handoff_tokens
+                # Its end has no reader any more.
+                state.finished = True
+            self._engine.abort(rid)
 
+    @_off_loop
     def abort(self, request_id: str) -> bool:
         with self._lock:
-            return self._engine.abort(request_id)
+            try:
+                return self._engine.abort(request_id)
+            finally:
+                self._flush()
 
+    @_off_loop
     def metrics(self) -> dict:
         delivery = self._stream_delivery()
         with self._lock:
@@ -2722,10 +2905,13 @@ class LLMServer:
         the object store.
 
         The way out, hand-over by hand-over (totals, flat, for a window's
-        difference): `egress_handoff_*`, commit -> the thread that called
-        `generate` or drives `generate_stream`, over live and retired
-        requests (0 with `instrument` off); `engine_stream_*`, this class's
-        `generate_stream` items from that thread to whoever iterates the
+        difference): `egress_handoff_*`, commit -> the event loop on which
+        `generate` or `generate_stream` runs, over live and retired
+        requests (0 with `instrument` off), and `egress_handoffs`, the
+        `call_soon_threadsafe` calls that carried them (tokens over
+        hand-overs is the live lanes where a step feeds many streams);
+        `engine_stream_*`, this class's
+        `generate_stream` items from that loop to whoever iterates the
         stream (under Serve, the replica's thread), and `stream_*`, the
         same over every streaming generator of the process (under Serve:
         that hop and replica -> proxy), both from
@@ -2750,6 +2936,7 @@ class LLMServer:
             backlog += state.offered - state.handoff_tokens
         stats["egress_handoff_s"] = handoff_s
         stats["egress_handoff_tokens"] = handoff_tokens
+        stats["egress_handoffs"] = self._handoffs
         own = groups.get(f"{type(self).__name__}.generate_stream", {})
         stats["engine_stream_wait_s"] = own.get("wait_s", 0.0)
         stats["engine_stream_items_taken"] = own.get("items_taken", 0)
@@ -2763,6 +2950,7 @@ class LLMServer:
         )
         return stats
 
+    @_off_loop
     def autoscaling_snapshot(self) -> dict:
         """Compact SLO signal bundle for the serve controller's
         LLMAutoscalingPolicy: the engine's queue-time and TTFT histogram
@@ -2790,12 +2978,14 @@ class LLMServer:
                 "ttft": e._h_ttft.snapshot(e._metric_tags),
             }
 
+    @_off_loop
     def dead_letters(self) -> List[dict]:
         """Records of requests failed in isolation after poisoning an
         engine step (id, prompt hash, error, step), oldest first."""
         with self._lock:
             return self._engine.dead_letters()
 
+    @_off_loop
     def shed_requests(self) -> List[dict]:
         """Records of submissions rejected by bounded admission or dead
         on arrival (id, reason, queue depth, retry-after hint), oldest
@@ -2803,6 +2993,7 @@ class LLMServer:
         with self._lock:
             return self._engine.shed_requests()
 
+    @_off_loop
     def flight_record(self, steps_limit: Optional[int] = None) -> dict:
         """The engine flight recorder: bounded rings of per-step records
         (phase, batch size, tokens, buckets, cache hits, preemptions,
@@ -2811,6 +3002,7 @@ class LLMServer:
         with self._lock:
             return self._engine.flight_recorder.snapshot(steps_limit)
 
+    @_off_loop
     def observability_snapshot(
         self, steps_limit: Optional[int] = None
     ) -> dict:
@@ -2846,6 +3038,7 @@ class LLMServer:
                 },
             }
 
+    @_off_loop
     def device_report(self) -> dict:
         """Bytes of weights and KV pools per device, and the compiled
         decode program's memory, kernels and collectives
@@ -2854,12 +3047,14 @@ class LLMServer:
         with self._lock:
             return self._engine.runner.device_report()
 
+    @_off_loop
     def reset_prefix_cache(self) -> None:
         """Drop all cached-but-unreferenced KV blocks (e.g. after swapping
         the served params, whose cached activations would be stale)."""
         with self._lock:
             self._engine.allocator.reset_prefix_cache()
 
+    @_off_loop
     def flush_kv_fabric(self) -> int:
         """Demote the engine's cached-but-unreferenced KV blocks into the
         fabric (the drain path's cache preservation — called by the
@@ -2868,6 +3063,7 @@ class LLMServer:
         with self._lock:
             return self._engine.flush_kv_fabric()
 
+    @_off_loop
     def num_pending(self) -> int:
         with self._lock:
             return len(self._engine.scheduler.waiting) + len(
@@ -2880,26 +3076,24 @@ class LLMServer:
         # bucket compile) and make the controller churn healthy replicas
         return self._thread.is_alive() and not self._wedged
 
+    @_off_loop
     def shutdown(self) -> None:
-        # Preserve the prefix cache across the actor's death: flush the
-        # evictable keyed blocks into the fabric (no-op without one)
-        # before the step loop stops. Best effort — shutdown proceeds
-        # regardless.
-        try:
-            self.flush_kv_fabric()
-        except Exception:
-            pass
         with self._work:
+            # Preserve the prefix cache across the actor's death: flush the
+            # evictable keyed blocks into the fabric (no-op without one)
+            # before the step loop stops. Best effort — shutdown proceeds
+            # regardless.
+            try:
+                self._engine.flush_kv_fabric()
+            except Exception:
+                pass
             self._shutdown = True
             # Fail in-flight requests promptly instead of leaving their
             # callers to run out their full wait timeout.
             exc = RuntimeError("LLM engine shut down with requests in flight")
             if self._requests:
                 self._engine.close_traces(exc)
-            for state in self._requests.values():
-                if not state.done.is_set():
-                    state.error = exc
-                    state.tokens.put(_STREAM_END)
-                    state.done.set()
+            self._end_all(exc)
+            self._flush()
             self._work.notify_all()
         self._thread.join(timeout=10.0)

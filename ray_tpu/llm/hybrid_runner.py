@@ -640,6 +640,16 @@ class HybridRunner:
             )
         return token
 
+    @staticmethod
+    def _on_device(tables) -> tuple:
+        """The int32 tables of a call, transferred. Through a list: a
+        transfer releases the interpreter, and a tuple that `tuple(<a
+        generator>)` is still building must be referred to by nobody else
+        when it is resized, which `gc.get_objects()` in another thread (the
+        benchmark's harness calls it as its window closes) breaks with a
+        SystemError in this one (chip run, PR 47)."""
+        return tuple([jnp.asarray(t, jnp.int32) for t in tables])
+
     def prefill(
         self, token_ids: Sequence[int], block_ids: Sequence[int],
         state_slot: int = 0, window_ids: Sequence[int] = (),
@@ -651,7 +661,7 @@ class HybridRunner:
         tokens, tables = self._padded(token_ids), self._tables(block_ids, window_ids)
         pools, out = self._programs.prefill_fn(
             self.params, *self._pools, jnp.asarray(tokens),
-            tuple(jnp.asarray(t) for t in tables),
+            self._on_device(tables),
             jnp.int32(len(token_ids)), jnp.int32(state_slot),
         )
         return self._chunk_done(pools, out, (tokens, *tables), len(token_ids))
@@ -665,7 +675,7 @@ class HybridRunner:
         tokens, tables = self._padded(token_ids), self._tables(block_ids, window_ids)
         pools, out = self._programs.prefill_suffix_fn(
             self.params, *self._pools, jnp.asarray(tokens),
-            tuple(jnp.asarray(t) for t in tables),
+            self._on_device(tables),
             jnp.int32(offset), jnp.int32(len(token_ids)), jnp.int32(state_slot),
         )
         return self._chunk_done(
@@ -698,7 +708,7 @@ class HybridRunner:
         pools, out = self._programs.decode_fn(
             self.params, *self._pools, tokens,
             jnp.asarray(positions.copy(), jnp.int32),
-            tuple(jnp.asarray(t.copy(), jnp.int32) for t in tables),
+            self._on_device([t.copy() for t in tables]),
             jnp.asarray(context_lens.copy(), jnp.int32),
         )
         self._set_pools(pools)

@@ -19,8 +19,11 @@ from ray_tpu.llm.engine import LLMServer
 from ray_tpu.models.gpt import GPTConfig
 
 
-# Threads of the engine actor beyond `max(32, 2 x lanes)`.
-CONTROL_THREADS = 4
+# The engine actor is an async actor: its `max_concurrency` bounds the
+# coroutines on its event loop (requests live or waiting for a lane, and
+# the control calls beside them), none of which holds a thread. The
+# reference's default for an async actor.
+ENGINE_MAX_CONCURRENCY = 1000
 
 
 def get_or_create_engine_actor(
@@ -29,7 +32,7 @@ def get_or_create_engine_actor(
     engine_config: Optional[EngineConfig] = None,
     params=None,
     seed: int = 0,
-    max_concurrency: int = 32,
+    max_concurrency: int = ENGINE_MAX_CONCURRENCY,
     draft_params=None,
 ):
     """Named engine actor shared by every ingress replica. With
@@ -131,26 +134,9 @@ class LLMIngress:
         self._owns_engine = bool(engine_per_replica)
         if self._owns_engine:
             engine_name = f"{engine_name}-{uuid.uuid4().hex[:8]}"
-        # A generate call holds one of the engine actor's threads for as
-        # long as its request lives, so the actor's concurrency caps what
-        # the engine can hold, running and waiting together: at the default
-        # of 32 an engine with 32 decode lanes never has a request waiting
-        # for the lane that frees (chip runs, PR 32: occupancy 96.8 ->
-        # 98.9%, completed tokens/s +2.2%). Room for a queue as deep as the
-        # lanes; 32 up to 16 lanes, as before. And a few threads over for
-        # the calls that are not requests (metrics, snapshots, the flight
-        # record): with every thread held by a request they waited for one
-        # to complete, half a minute where an answer is a thousand tokens
-        # long and as many callers as threads are waiting (chip run, PR 35).
-        # Nothing reserves them: they are free only while the callers
-        # number at most `max(32, 2 x lanes)`; beyond that requests take
-        # them too: control calls want a thread group of their own, which
-        # the actor runtime does not have.
-        slots = (engine_config or EngineConfig()).max_decode_slots
         self._engine = get_or_create_engine_actor(
             engine_name, model_config, engine_config, params=params,
-            seed=seed, max_concurrency=max(32, 2 * slots) + CONTROL_THREADS,
-            draft_params=draft_params,
+            seed=seed, draft_params=draft_params,
         )
         self._as_snapshot: Optional[dict] = None
         self._as_snapshot_t = 0.0

@@ -127,6 +127,32 @@ def test_async_actor(ray_start_regular):
     assert elapsed < 0.35
 
 
+def test_an_actor_whose_only_async_def_is_a_generator_is_async(
+    ray_start_regular,
+):
+    """Run threaded, its stream's one item would be the async generator
+    object; it runs on a loop, where concurrent streams interleave."""
+
+    @ray_tpu.remote
+    class Ticker:
+        async def ticks(self, n):
+            import asyncio
+
+            for i in range(n):
+                await asyncio.sleep(0.02)
+                yield i
+
+    t = Ticker.options(max_concurrency=4).remote()
+    start = time.monotonic()
+    streams = [
+        t.ticks.options(num_returns="streaming").remote(5) for _ in range(4)
+    ]
+    got = [[ray_tpu.get(ref) for ref in stream] for stream in streams]
+    assert got == [[0, 1, 2, 3, 4]] * 4
+    # Four streams of five 20 ms ticks side by side, not end to end (0.4 s).
+    assert time.monotonic() - start < 0.35
+
+
 def test_threaded_actor_concurrency(ray_start_regular):
     @ray_tpu.remote
     class Slow:

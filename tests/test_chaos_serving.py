@@ -35,6 +35,7 @@ from ray_tpu.exceptions import (
 )
 from ray_tpu.llm import EngineConfig, LLMEngine, LLMServer
 from ray_tpu.models.gpt import GPT, GPTConfig
+from llm_in_process import in_process
 
 pytestmark = pytest.mark.chaos
 
@@ -126,7 +127,7 @@ def test_poisoned_prefill_fails_only_that_request():
         match="poison-me",
         exc_factory=lambda: RuntimeError("cosmic ray in prefill"),
     )
-    server = LLMServer(TINY, ECFG, seed=0, warmup=False)
+    server = in_process(LLMServer(TINY, ECFG, seed=0, warmup=False))
     jobs = [(f"ok-{i}", p, n_new) for i, p in enumerate(prompts)]
     jobs.append(("poison-me", random_prompts((9,), seed=3)[0], n_new))
     results = _concurrent_generates(server, jobs)
@@ -173,7 +174,7 @@ def test_poisoned_decode_fails_only_that_request():
         nth=3,  # fail on its 3rd decode iteration, mid-stream
         exc_factory=lambda: RuntimeError("decode bitflip"),
     )
-    server = LLMServer(TINY, ECFG, seed=0, warmup=False)
+    server = in_process(LLMServer(TINY, ECFG, seed=0, warmup=False))
     jobs = [
         ("ok-0", prompts[0], 10),
         ("poison-me", prompts[1], 10),
@@ -243,7 +244,7 @@ def test_engine_wedges_after_k_consecutive_failing_steps():
     # Steps 1-2 succeed (tokens flow), then every step fails
     # unattributably: step 3 retries, step 4 wedges (K=2).
     fi.inject("llm.step", nth=3, times=None, message="engine meltdown")
-    server = LLMServer(TINY, ecfg, seed=0, warmup=False)
+    server = in_process(LLMServer(TINY, ecfg, seed=0, warmup=False))
     prompts = random_prompts((5, 7), seed=5)
 
     stream_tokens = []
@@ -277,7 +278,7 @@ def test_unattributable_failure_below_threshold_recovers():
     """A transient unattributable step failure (fails twice, then stops) is
     retried in place: no dead letters, no wedge, token-identical output."""
     fi.inject("llm.step", nth=2, times=2, message="transient glitch")
-    server = LLMServer(TINY, ECFG, seed=0, warmup=False)
+    server = in_process(LLMServer(TINY, ECFG, seed=0, warmup=False))
     prompt = random_prompts((6,), seed=6)[0]
     out = server.generate(prompt, max_new_tokens=8, timeout_s=60.0)
     model = GPT(TINY)
@@ -309,7 +310,7 @@ def test_verify_fault_dead_letters_only_culprit_releases_draft_blocks():
         match="poison-me",
         exc_factory=lambda: RuntimeError("verify bitflip"),
     )
-    server = LLMServer(TINY, ecfg, seed=0, warmup=False)
+    server = in_process(LLMServer(TINY, ecfg, seed=0, warmup=False))
     prompts = random_prompts((7, 6), seed=4)
     jobs = [
         ("ok-0", prompts[0], 10),
@@ -369,7 +370,7 @@ def test_poisoned_chunk_dead_letters_only_culprit_releases_all_blocks():
         nth=2,  # fail on its SECOND chunk: mid-prompt, blocks held
         exc_factory=lambda: RuntimeError("cosmic ray mid-chunk"),
     )
-    server = LLMServer(TINY, ecfg, seed=0, warmup=False)
+    server = in_process(LLMServer(TINY, ecfg, seed=0, warmup=False))
     prompts = random_prompts((7, 6), seed=4)
     poison_prompt = random_prompts((40,), seed=12)[0]  # 3 chunks of 16
     jobs = [

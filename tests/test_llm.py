@@ -30,6 +30,7 @@ from ray_tpu.llm import (
 )
 from ray_tpu.models.gpt import GPT, GPTConfig
 from ray_tpu.ops import mha_reference, paged_attention
+from llm_in_process import in_process
 
 
 TINY = GPTConfig(
@@ -650,14 +651,14 @@ def test_llm_server_warmup_respects_admission_limits():
     """Regression: init-time warmup must shape its requests to pass the
     engine's own admission validation for any valid config (custom buckets
     smaller than max_model_len used to crash the replica at deploy)."""
-    server = LLMServer(
+    server = in_process(LLMServer(
         TINY,
         EngineConfig(
             block_size=8, num_blocks=64, max_blocks_per_seq=16,
             prefill_buckets=(8, 16),
         ),
         warmup=True,
-    )
+    ))
     out = server.generate([1, 2, 3], max_new_tokens=4)
     assert len(out["token_ids"]) == 4
     server.shutdown()

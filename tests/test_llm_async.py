@@ -34,6 +34,7 @@ import ray_tpu
 from ray_tpu.llm import EngineConfig, KVFabricConfig, LLMEngine
 from ray_tpu.llm.engine import FLUSH_CAUSES
 from ray_tpu.models.gpt import GPT, GPTConfig
+from llm_in_process import in_process
 
 
 TINY = GPTConfig(
@@ -656,7 +657,9 @@ def test_server_lock_serves_a_waiter_before_the_thread_that_released_it():
     assert not lock._is_owned()
     with lock:
         assert lock._is_owned()
-    server = LLMServer(TINY, EngineConfig(**BASE), seed=0, warmup=False)
+    server = in_process(
+        LLMServer(TINY, EngineConfig(**BASE), seed=0, warmup=False)
+    )
     try:
         assert isinstance(server._lock, _HandoffLock)
     finally:

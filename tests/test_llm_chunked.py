@@ -30,6 +30,7 @@ from ray_tpu.llm import (
     Sequence,
 )
 from ray_tpu.models.gpt import GPT, GPTConfig
+from llm_in_process import in_process
 
 TINY = GPTConfig(
     vocab_size=128,
@@ -367,7 +368,7 @@ def test_warmup_without_prefix_caching_still_compiles_chunk_programs():
         max_blocks_per_seq=8, enable_prefix_caching=False,
         max_prefill_tokens_per_step=16,
     )
-    server = LLMServer(TINY, cfg, seed=0, warmup=True)
+    server = in_process(LLMServer(TINY, cfg, seed=0, warmup=True))
     programs = {
         (c["program"], c["bucket"])
         for c in server.flight_record()["compile_events"]

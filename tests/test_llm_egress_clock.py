@@ -6,12 +6,13 @@ the request) and reports the hand-overs after it (`Runtime.stream_delivery`).
     tokens and is back at 0 once the stream is drained; live and retired
     requests are one total;
   * the blocking `generate` is counted too;
-  * with `instrument=False` the queue holds bare tokens and the fields read 0;
+  * with `instrument=False` an item's stamp is 0.0 and the fields read 0;
   * under Serve a token changes thread three times: `llm.request` carries
     `handoff_s`, each stream leaves one `stream.deliver` span in the request's
     trace, and `metrics()` splits the streams' wait by hop.
 """
 
+import asyncio
 import time
 
 import pytest
@@ -24,6 +25,7 @@ from ray_tpu.llm import EngineConfig
 from ray_tpu.llm.engine import _STREAM_END, LLMServer
 from ray_tpu.models.gpt import GPTConfig
 from ray_tpu.util import tracing
+from llm_in_process import in_process
 
 TINY = GPTConfig(
     vocab_size=128,
@@ -68,7 +70,9 @@ def _wait_idle(server, timeout=60.0):
 
 @pytest.fixture
 def server():
-    made = LLMServer(TINY, EngineConfig(**BASE), seed=0, warmup=False)
+    made = in_process(
+        LLMServer(TINY, EngineConfig(**BASE), seed=0, warmup=False)
+    )
     yield made
     made.shutdown()
 
@@ -123,22 +127,31 @@ def test_the_blocking_call_is_counted_where_it_drains_its_queue(server):
 
 
 def test_instrument_off_queues_bare_tokens_and_reads_zero():
-    server = LLMServer(
+    raw = LLMServer(
         TINY, EngineConfig(instrument=False, **BASE), seed=0, warmup=False
     )
+    server = in_process(raw)
     try:
-        rid, state = server._submit(list(PROMPT), 4, None, None)
-        _wait_idle(server)
-        queued = []
-        while not state.tokens.empty():
-            queued.append(state.tokens.get_nowait())
-        assert queued[-1] is _STREAM_END
-        assert len(queued) == 5
-        assert all(isinstance(token, int) for token in queued[:-1])
+
+        async def filed():
+            rid, state, expiry = await raw._admit(
+                True, list(PROMPT), 4, None, None, None
+            )
+            while not state.ended:
+                await state.wait()
+            await raw._release(rid, expiry)
+            return state
+
+        state = asyncio.run(filed())
+        filed_items = list(state.items)
+        assert filed_items[-1] == (_STREAM_END, 0.0)
+        assert len(filed_items) == 5
+        assert all(
+            isinstance(token, int) and stamp == 0.0
+            for token, stamp in filed_items[:-1]
+        )
         assert (state.stamped, state.offered) == (False, 0)
-        with server._lock:
-            server._retire(rid)
-        time.sleep(0.05)
+        assert not server._requests
         assert len(list(server.generate_stream(list(PROMPT), 4))) == 4
         assert len(server.generate(list(PROMPT), 4)["token_ids"]) == 4
         stats = server.metrics()

@@ -312,3 +312,28 @@ def test_scopes_of_reads_the_innermost_scope():
   %copy.2 = f32[4]{0} copy(%a), metadata={op_name="jit(_decode_step)/llm.moe.routed/mul" source_file="x.py"}
 '''
     assert hr.scopes_of(text) == {"fusion.3": "llm.head", "copy.2": "llm.moe.routed"}
+
+
+def test_a_calls_tables_are_no_tuple_another_thread_can_break(monkeypatch):
+    """`tuple(<generator>)` resizes the tuple it built, which CPython refuses
+    while anything else refers to it, and `gc.get_objects()` refers to
+    everything: called while a table's transfer has released the interpreter
+    (the benchmark's harness does, in another thread), it made the step raise
+    `SystemError`. Here the transfer itself takes the references."""
+    held = []
+
+    class Spying:
+        int32 = np.int32
+
+        @staticmethod
+        def asarray(table, dtype):
+            held.append(gc.get_objects())
+            return np.asarray(table, dtype)
+
+    monkeypatch.setattr(hr, "jnp", Spying)
+    tables = (np.arange(4, dtype=np.int32), np.arange(8, dtype=np.int32))
+    got = hr.HybridRunner._on_device(tables)
+    assert isinstance(got, tuple) and len(held) == 2
+    assert [t.tolist() for t in got] == [t.tolist() for t in tables]
+    with pytest.raises(SystemError):
+        tuple(Spying.asarray(t, np.int32) for t in tables)

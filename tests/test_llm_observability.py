@@ -26,6 +26,7 @@ from ray_tpu.exceptions import ActorDiedError, ReplicaUnavailableRetryExhausted
 from ray_tpu.llm import EngineConfig, LLMEngine, LLMServer
 from ray_tpu.models.gpt import GPT, GPTConfig
 from ray_tpu.util import metrics, tracing
+from llm_in_process import in_process
 
 TINY = GPTConfig(
     vocab_size=128,
@@ -150,7 +151,7 @@ def test_dead_lettered_request_closes_span_with_error():
         match="poison-me",
         exc_factory=lambda: RuntimeError("cosmic ray in prefill"),
     )
-    server = LLMServer(TINY, ECFG_PRESSURE, seed=0, warmup=False)
+    server = in_process(LLMServer(TINY, ECFG_PRESSURE, seed=0, warmup=False))
     with tracing.span("poison-root") as root:
         with pytest.raises(Exception):
             server.generate(
@@ -182,7 +183,7 @@ def test_wedged_engine_closes_inflight_traces_with_error():
     # Steps 1-2 succeed (the request prefillls and decodes), then every
     # step fails unattributably: step 3 retries, step 4 wedges.
     fi.inject("llm.step", nth=3, times=None, message="engine meltdown")
-    server = LLMServer(TINY, ecfg, seed=0, warmup=False)
+    server = in_process(LLMServer(TINY, ecfg, seed=0, warmup=False))
     with tracing.span("wedge-root") as root:
         with pytest.raises(Exception):
             server.generate(
@@ -264,7 +265,7 @@ def test_request_latency_histogram_counts_match_requests_served():
 
 
 def test_flight_recorder_step_records_and_warmup_compile_events():
-    server = LLMServer(TINY, ECFG_SERVE, seed=0, warmup=True)
+    server = in_process(LLMServer(TINY, ECFG_SERVE, seed=0, warmup=True))
     record = server.flight_record()
     # Warmup charged each program/bucket with its cold-compile seconds.
     # Under the default chunked-prefill budget only the chunk-reachable

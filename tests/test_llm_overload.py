@@ -31,6 +31,7 @@ from ray_tpu.exceptions import EngineOverloadedError
 from ray_tpu.llm import EngineConfig, LLMEngine, LLMServer
 from ray_tpu.llm.scheduler import FINISH_EXPIRED
 from ray_tpu.models.gpt import GPT, GPTConfig
+from llm_in_process import in_process
 
 
 TINY = GPTConfig(
@@ -378,7 +379,9 @@ def test_server_deadline_expiry_raises_timeout():
     when the ENGINE enforces it (dead on arrival here — the deadline is
     already spent at submit), the caller sees TimeoutError, and nothing
     was admitted."""
-    server = LLMServer(TINY, EngineConfig(**BASE), seed=0, warmup=False)
+    server = in_process(
+        LLMServer(TINY, EngineConfig(**BASE), seed=0, warmup=False)
+    )
     try:
         with pytest.raises(TimeoutError, match="deadline"):
             server.generate(
@@ -395,7 +398,9 @@ def test_server_stream_idle_timeout_is_separate_knob():
     """Satellite: the old per-token-gap meaning of timeout_s lives in
     stream_idle_timeout_s now; a healthy stream with a tight idle bound
     but a loose deadline completes, token-identical."""
-    server = LLMServer(TINY, EngineConfig(**BASE), seed=0, warmup=False)
+    server = in_process(
+        LLMServer(TINY, EngineConfig(**BASE), seed=0, warmup=False)
+    )
     model = GPT(TINY)
     try:
         prompt = random_prompts((7,))[0]

@@ -44,6 +44,7 @@ from ray_tpu.llm.observability import (
     StepPhaseClock,
 )
 from ray_tpu.models.gpt import GPTConfig
+from llm_in_process import in_process
 
 TINY = GPTConfig(
     vocab_size=128,
@@ -335,7 +336,7 @@ def test_setup_is_on_the_clock_and_rounds_carry_the_split():
     jax.clear_caches()
     before = observability_module.compile_clock().totals()
     t0 = time.perf_counter()
-    server = LLMServer(TINY, ecfg, warmup=True)
+    server = in_process(LLMServer(TINY, ecfg, warmup=True))
     wall = time.perf_counter() - t0
     try:
         stats = server.metrics()

@@ -25,6 +25,7 @@ from ray_tpu.llm import (
 )
 from ray_tpu.llm.cache import BlockAllocator
 from ray_tpu.models.gpt import GPT, GPTConfig
+from llm_in_process import in_process
 
 TINY = GPTConfig(
     vocab_size=128,
@@ -472,7 +473,7 @@ def test_llm_server_warmup_compiles_verify_buckets():
     """Init-time warmup must compile every verify bucket (and the draft
     model's programs) so the first speculative step under live traffic
     never cold-compiles; compile events carry the blame."""
-    server = LLMServer(
+    server = in_process(LLMServer(
         TINY,
         EngineConfig(
             block_size=8, num_blocks=64, max_decode_slots=4,
@@ -481,7 +482,7 @@ def test_llm_server_warmup_compiles_verify_buckets():
         ),
         seed=0,
         warmup=True,
-    )
+    ))
     events = server.flight_record()["compile_events"]
     programs = {(e["program"], e["bucket"]) for e in events}
     for s_bucket in (2, 3, 5):  # k=4 -> fed widths 2, 3, 5

@@ -34,6 +34,7 @@ from ray_tpu._private import fault_injection as fi
 from ray_tpu.exceptions import PoisonRequestError
 from ray_tpu.llm import EngineConfig, LLMEngine, LLMServer
 from ray_tpu.models.gpt import GPT, GPTConfig
+from llm_in_process import in_process
 
 
 def random_prompts(lengths, vocab=128, seed=0):
@@ -459,7 +460,7 @@ def test_tp2_poison_dead_letters_only_culprit_pools_at_boot():
         draft_model_config=DRAFT,
         **BASE,
     )
-    server = LLMServer(TINY, ecfg, seed=0, warmup=False)
+    server = in_process(LLMServer(TINY, ecfg, seed=0, warmup=False))
     prompts = random_prompts((5, 7), vocab=64, seed=10)
     results = {}
 

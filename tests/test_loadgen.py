@@ -31,6 +31,7 @@ from ray_tpu.loadgen import (
 )
 from ray_tpu.loadgen.driver import LoadRunResult, RequestSample
 from ray_tpu.models.gpt import GPTConfig
+from llm_in_process import in_process
 
 TINY = GPTConfig(
     vocab_size=128,
@@ -386,7 +387,7 @@ def test_stream_close_aborts_engine_request_direct():
     ecfg = EngineConfig(
         block_size=8, num_blocks=64, max_decode_slots=4, max_blocks_per_seq=8
     )
-    server = LLMServer(TINY, ecfg, warmup=False)
+    server = in_process(LLMServer(TINY, ecfg, warmup=False))
     engine = server._engine
     assert engine.allocator.num_allocated == 0  # boot size
     for i in range(5):
@@ -418,7 +419,7 @@ def test_stream_close_releases_draft_mirror_blocks():
         max_blocks_per_seq=8, speculation="draft",
         draft_model_config=draft_cfg,
     )
-    server = LLMServer(TINY, ecfg, warmup=False)
+    server = in_process(LLMServer(TINY, ecfg, warmup=False))
     engine = server._engine
     for i in range(3):
         gen = server.generate_stream([1 + i, 2, 3, 4, 5], max_new_tokens=30)

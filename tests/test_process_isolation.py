@@ -236,6 +236,18 @@ def test_async_actor_in_process(proc_runtime):
     assert ray_tpu.get([actor.work.remote(i) for i in range(4)]) == [1, 2, 3, 4]
 
 
+def test_async_generator_only_actor_in_process(proc_runtime):
+    @ray_tpu.remote
+    class Ticker:
+        async def ticks(self, n):
+            for i in range(n):
+                yield i
+
+    actor = Ticker.remote()
+    stream = actor.ticks.options(num_returns="streaming").remote(3)
+    assert [ray_tpu.get(ref) for ref in stream] == [0, 1, 2]
+
+
 def test_threaded_actor_concurrency(proc_runtime):
     @ray_tpu.remote(max_concurrency=4)
     class Threaded:
