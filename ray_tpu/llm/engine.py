@@ -94,10 +94,12 @@ class _InflightStep:
     __slots__ = (
         "seqs", "rids", "tokens_dev", "tokens_host",
         "dispatch_step", "commit_idx", "clock_seq", "t_prepare", "lanes",
+        "serial",
     )
 
     def __init__(
-        self, seqs, rids, tokens_dev, dispatch_step, clock_seq, t_prepare
+        self, seqs, rids, tokens_dev, dispatch_step, clock_seq, t_prepare,
+        serial,
     ):
         self.seqs: List[Sequence] = seqs
         self.rids: List[str] = rids
@@ -105,6 +107,9 @@ class _InflightStep:
         self.tokens_host: Optional[np.ndarray] = None
         self.dispatch_step = dispatch_step
         self.commit_idx = 0
+        # The engine's number of this dispatch among its decodes and
+        # chunks: records commit in this order (`_commit_dispatched`).
+        self.serial = serial
         # StepPhaseClock's number of this dispatch (None uninstrumented):
         # its fetch tells the clock which program finished. And its
         # reading where the dispatch's `prepare` began.
@@ -114,6 +119,53 @@ class _InflightStep:
         # model with recurrent layers decodes a sequence in the lane of
         # its state slot.
         self.lanes: Optional[List[int]] = None
+
+
+class _PendingChunk:
+    """One dispatched prefill chunk whose output the host has not read.
+
+    What a chunk changes without its value (num_cached, num_chunks, the
+    window class, block publication) changed when it was dispatched; this
+    holds what waits for the value: the output array (a last chunk's first
+    token, a routed model's counts), whom it belongs to, and what the
+    spans and the TTFT observation need. At pipeline depth 0, under
+    speculation and in a prefill-role engine the record is read in the
+    statement after its dispatch; otherwise it is read behind the decode
+    dispatch that took the token from the device (`_commit_chunks`).
+    `dispatch_step` and `clock_seq` are an `_InflightStep`'s: a failed
+    chunk program surfaces at the read and is pinned on the step that
+    dispatched it."""
+
+    __slots__ = (
+        "seq", "rid", "take", "offset", "out", "dispatch_step", "clock_seq",
+        "serial", "final", "index", "preemptions", "t0", "kind", "bucket",
+        "fed",
+    )
+
+    def __init__(
+        self, seq, rid, take, offset, out, dispatch_step, clock_seq, serial,
+        final, t0, kind, bucket,
+    ):
+        self.seq: Sequence = seq
+        self.rid: str = rid
+        self.take = take
+        self.offset = offset
+        self.out = out
+        self.dispatch_step = dispatch_step
+        self.clock_seq = clock_seq
+        self.serial = serial
+        self.final = final
+        self.index = seq.num_chunks  # of this admission, before the advance
+        # A sequence preempted since the dispatch is not owed the value:
+        # its resume re-prefills from an empty table.
+        self.preemptions = seq.num_preemptions
+        # Instrumented: the wall time its `prepare` began, and the span's
+        # kind and bucket.
+        self.t0 = t0
+        self.kind = kind
+        self.bucket = bucket
+        # Whether a decode dispatch took the token from `out`, unread.
+        self.fed = False
 
 
 class LLMEngine:
@@ -577,6 +629,26 @@ class LLMEngine:
         # dispatch and the commit of the record before it.
         self._pipeline_depth = 1 if self.engine_config.async_scheduling else 0
         self._inflight: Deque[_InflightStep] = deque()
+        # Chunk programs dispatched and not read, oldest first, and of
+        # those the last chunks whose token no decode dispatch has taken
+        # yet, by sequence. At depth 1 a chunk's output stays on the
+        # device until the decode dispatch its prompt joins has been made
+        # (step()'s docstring). What depends on the value keeps the read
+        # where it was: depth 0, speculation (the proposer reads committed
+        # history) and a prefill-role engine (publication reads the device
+        # anyway) read every chunk at once (`_defers_chunks`), and both
+        # stay empty.
+        self._pending_chunks: Deque[_PendingChunk] = deque()
+        self._unfed: Dict[Sequence, _PendingChunk] = {}
+        # Decode and chunk dispatches made, in one count: the order in
+        # which their records commit.
+        self._dispatch_serial = 0
+        # Last chunks committed, and those of them whose token had
+        # reached its first decode dispatch without having been read.
+        self._prompts_prefilled = 0
+        self._first_tokens_on_device = 0
+        # First tokens committed in the current step, for its flight record.
+        self._step_first_tokens = 0
         # Dispatch index of the record being committed right now: a
         # commit-time failure is attributed against the step that
         # dispatched the failing program (failure_step()).
@@ -797,7 +869,11 @@ class LLMEngine:
         # An in-flight async record is work even when the scheduler is
         # empty (every member aborted mid-flight): one more step drains
         # it, so callers' step loops never strand a dispatched program.
-        return self.scheduler.has_work() or bool(self._inflight)
+        return (
+            self.scheduler.has_work()
+            or bool(self._inflight)
+            or bool(self._pending_chunks)
+        )
 
     # ---------------- poison-request isolation ----------------
 
@@ -985,6 +1061,37 @@ class LLMEngine:
         record's commit, and never reaches a client. Greedy outputs are
         token-identical at both depths across every feature knob.
 
+        At depth 1 a prompt's chunk is a dispatch and a commit too
+        (`_PendingChunk`, `_commit_chunks`). What a chunk changes without
+        its value advances where it is dispatched, as a chained decode's
+        positions do: num_cached, num_chunks, the window class's blocks,
+        block publication. What needs the value waits for the read: the
+        first token's append and emission, the finish check, the TTFT
+        observation and the request's spans (TTFT is observed when the
+        token is on the host, not at the dispatch). A last chunk's token
+        reaches the decode that the prompt joins ON THE DEVICE: the
+        dispatch sets that lane of its token input from the chunk's
+        output (`runner.join_token`, outside the decode program), from
+        committed state in the step that ran the chunk where that step
+        dispatches its own decode, chained at the top of the next step
+        where the chunk followed a chained decode: a join chains (the
+        joiner keeps the lane it will have, the others keep theirs) and
+        counts no flush; where that next step flushes instead, its own
+        decode dispatch takes the token, from committed state. The step
+        thread reads a chunk's output only after the decode dispatch that
+        consumes it: at the end of the step that dispatched that decode
+        from committed state, first thing after a chained dispatch, in
+        either case behind the commit of the decode that ran before the
+        chunk and in front of the decode that fed on it; a chunk that is
+        not a prompt's last is read there as well, for its counters and
+        its failure. The overshoot rule covers the first token: a prompt
+        that its first token ends (max_new_tokens 1, EOS) has been fed to
+        one decode, which lands in the null block or a block freed with
+        the sequence and is skipped at its record's commit. A failed
+        chunk program surfaces at its read, pinned on the chunk's request
+        and dispatch step. Depth 0, speculation and a prefill-role engine
+        read every chunk in the statement after its dispatch.
+
         Instrumented, the step runs on the phase clock: entry opens
         `schedule`, the return opens `between` (or stops the clock when
         nothing is live); a step that raises leaves its phase open and
@@ -996,6 +1103,7 @@ class LLMEngine:
         ecfg = self.engine_config
         preempted_before = self.scheduler.num_preemptions
         self._current_rid = None
+        self._attribution_step = None
         maybe_fail("llm.step")
         instrument = self._instrument
         clock = self._clock if instrument else None
@@ -1008,6 +1116,7 @@ class LLMEngine:
         t_step = time.time() if instrument else 0.0
         bytes_before = self._host_transfer_bytes() if instrument else 0
         self._step_commits = []
+        self._step_first_tokens = 0
 
         # Deadline sweep BEFORE admission and before the chain attempt: a
         # queued request whose deadline passed must never reach
@@ -1024,15 +1133,12 @@ class LLMEngine:
         chained_seqs: Optional[List[Sequence]] = None
         if self._pipeline_depth and self._spec is None and self._inflight:
             chained_seqs = self._try_chain()
-        if chained_seqs is not None:
-            # Commit the record the chain fed from; the chained record
-            # stays in flight for the next iteration.
-            self._commit_head()
-        else:
-            # Flush boundary: commit everything in dispatch order, then
-            # schedule normally from fully committed state.
-            while self._inflight:
-                self._commit_head()
+        # Chained: commit the record the chain fed from and the chunks
+        # dispatched behind it, whose tokens the chained dispatch has just
+        # taken from the device; the chained record stays in flight for
+        # the next iteration. Else a flush boundary: commit everything in
+        # dispatch order, then schedule normally from fully committed state.
+        self._commit_dispatched(keep=0 if chained_seqs is None else 1)
 
         admitted = self.scheduler.schedule_prefills(
             ecfg.max_prefills_per_step
@@ -1071,6 +1177,12 @@ class LLMEngine:
                         # Depth 0; and speculation at any depth, whose
                         # acceptance is value-dependent: commit now.
                         self._commit_head(follows_dispatch=True)
+            if self._pending_chunks:
+                # This step's chunks, and those a flush found unread,
+                # read behind the decode dispatch that took their tokens
+                # from the device (or behind nothing: no sequence decodes
+                # yet).
+                self._commit_chunks()
         return self._finish_step(
             t_step=t_step, bytes_before=bytes_before,
             preempted_before=preempted_before, plans=plans,
@@ -1174,7 +1286,7 @@ class LLMEngine:
                 # Tokens COMMITTED this iteration (prefill finals + decode
                 # or verify commits) — a dispatched-but-uncommitted token
                 # is not out yet.
-                "tokens_out": sum(1 for p in prefill_info if p["final"])
+                "tokens_out": self._step_first_tokens
                 + sum(c["tokens"] for c in self._step_commits),
                 "cache_hit_tokens": step_hit_tokens,
                 "preempted": preempted,
@@ -1466,13 +1578,14 @@ class LLMEngine:
     def _try_chain(self) -> Optional[List[Sequence]]:
         """Chain the in-flight decode into the next dispatch if — and
         only if — its tokens are still on the device and it is the one
-        record in flight, the next decode batch would be EXACTLY the
-        dispatched batch (same sequences, same slot order: the chained
-        token input is slot-aligned on device) AND every +1-position
-        write can be covered without preempting anyone
-        (reserve_decode_lookahead). On success the chained program is
-        already dispatched when this returns; on any mismatch it counts
-        the cause, returns None and the caller flushes."""
+        record in flight, the next decode batch would be the dispatched
+        batch (same sequences, same slot order: the chained token input
+        is slot-aligned on device) and at most prompts whose first token
+        is on the device too (`_unfed`), AND every write can be covered
+        without preempting anyone (reserve_decode_lookahead). On success
+        the chained program is already dispatched when this returns; on
+        any mismatch it counts the cause, returns None and the caller
+        flushes."""
         rec = self._inflight[0]
         flushes = self._pipeline_flushes
         if rec.tokens_host is not None or len(self._inflight) > 1:
@@ -1482,15 +1595,29 @@ class LLMEngine:
             flushes["left"] += 1
             return None
         current = [s for s in self.scheduler.running if not s.prefilling]
+        joiners: List[Sequence] = []
         if current != rec.seqs:  # a Sequence equals only itself
-            flushes["joined"] += 1
-            return None
-        if not self.scheduler.reserve_decode_lookahead(rec.seqs):
+            # A prompt whose last chunk is dispatched and unread joins in
+            # place: its token is on the device like the record's, and
+            # the record's sequences keep their lanes (a state slot is a
+            # lane for life; an index lane holds while the joiners come
+            # behind the record's sequences).
+            unfed = self._unfed
+            joiners = [s for s in current if s in unfed]
+            kept = len(rec.seqs)
+            if (
+                not joiners
+                or len(current) != kept + len(joiners)
+                or not (self._recurrent or current[:kept] == rec.seqs)
+            ):
+                flushes["joined"] += 1
+                return None
+        if not self.scheduler.reserve_decode_lookahead(rec.seqs, joiners):
             flushes["lookahead"] += 1
             return None
         self._chained_dispatches += 1
-        self._dispatch_decode(rec.seqs, chained_from=rec)
-        return rec.seqs
+        self._dispatch_decode(current, chained_from=rec)
+        return current
 
     def _dispatch_decode(
         self,
@@ -1502,7 +1629,12 @@ class LLMEngine:
         record joins `_inflight` for `_commit_head`.
 
         From committed state the inputs are each sequence's last token
-        and num_cached. Chained, the tokens are the in-flight record's
+        and num_cached. A sequence whose last chunk is dispatched and
+        unread (`_unfed`) has no token on the host: its lane's token is
+        set on the device from the chunk's output, its position is where
+        the chunk stopped (nothing of it is in flight, chained or not),
+        and a step without such a sequence pays one dict's truth value
+        for it. Chained, the tokens are the in-flight record's
         on-device `next_tokens` — no host sync anywhere on this path —
         and the in-flight token for slot i has not committed yet, so its
         write position is num_cached + 1 and its context covers
@@ -1554,6 +1686,25 @@ class LLMEngine:
                 table = seq.window_table
                 window_tables[i, : len(table)] = table
                 window_tokens += min(cached, window.horizon)
+        tokens_in = chained_from.tokens_dev if ahead else tokens
+        joins = ()
+        if self._unfed:
+            unfed = self._unfed
+            joins = [
+                (seq.state_slot if recurrent else i, seq)
+                for i, seq in enumerate(seqs)
+                if seq in unfed
+            ]
+            for lane, seq in joins:
+                if ahead:
+                    positions[lane] -= 1
+                    context_lens[lane] -= 1
+                    context_tokens -= 1
+                    if window is not None and seq.num_cached < window.horizon:
+                        window_tokens -= 1
+                tokens_in = self.runner.join_token(
+                    tokens_in, lane, unfed[seq].out
+                )
         # What this dispatch asks the paged kernel to read: len(seqs)
         # sequences, context_tokens cached positions in all (the sum of
         # context_lens), in every layer.
@@ -1564,13 +1715,16 @@ class LLMEngine:
         if window is not None:
             self._decode_window_tokens += window_tokens
         tokens_dev = self.runner.decode(
-            chained_from.tokens_dev if ahead else tokens,
-            positions, block_tables, context_lens, **extra,
+            tokens_in, positions, block_tables, context_lens, **extra,
         )
+        for _, seq in joins:
+            self._unfed.pop(seq).fed = True
         rids = [s.request.request_id for s in seqs]
         clock_seq = clock.dispatches if clock is not None else None
+        self._dispatch_serial += 1
         rec = _InflightStep(
-            seqs, rids, tokens_dev, self._steps, clock_seq, t_prepare
+            seqs, rids, tokens_dev, self._steps, clock_seq, t_prepare,
+            self._dispatch_serial,
         )
         if recurrent:
             rec.lanes = [s.state_slot for s in seqs]
@@ -1663,6 +1817,121 @@ class LLMEngine:
                 took = clock.switch("schedule") - t0
             self._h_step.observe(took, tags=self._step_tags["decode"])
 
+    @property
+    def _defers_chunks(self) -> bool:
+        """Whether a chunk's output stays on the device past its dispatch:
+        read from the state that says what depends on the value (warm-up
+        steps at depth 0 with the engine's own depth put aside)."""
+        return bool(
+            self._pipeline_depth
+            and self._spec is None
+            and not self._publish_on_fill
+        )
+
+    def _commit_dispatched(self, keep: int) -> None:
+        """Commit what earlier dispatches left out, oldest dispatch first,
+        down to the `keep` newest decode records: each decode record and,
+        in front of it, the unread chunks that ran before it. With a
+        record kept (the chained dispatch just made) the chunks that ran
+        before IT are committed too: it took their tokens from the device.
+        On a flush boundary (`keep` 0) the chunks behind the last record
+        stay unread: no decode has taken their tokens yet, the one this
+        step dispatches will, and step() reads them behind it."""
+        inflight, chunks = self._inflight, self._pending_chunks
+        while len(inflight) > keep:
+            if chunks and chunks[0].serial < inflight[0].serial:
+                self._commit_chunks(before=inflight[0].serial)
+            self._commit_head()
+        if keep and chunks:
+            self._commit_chunks(before=inflight[0].serial)
+            if self._instrument:
+                self._clock.switch("schedule")
+
+    def _commit_chunks(self, before: Optional[int] = None) -> None:
+        """Read and commit the pending chunks dispatched before dispatch
+        number `before` (every one when None), oldest first: the read
+        waits for the program and raises if it failed, pinned on the
+        chunk's request and dispatch step (the record stays at the head;
+        once its request is dead-lettered the retry drops it unread).
+        A chunk whose sequence finished, was aborted, expired or was
+        preempted since is owed nothing and is dropped unread. Leaves the
+        clock in `commit`."""
+        chunks = self._pending_chunks
+        clock = self._clock if self._instrument else None
+        emitted = self._step_first_tokens
+        while chunks and (before is None or chunks[0].serial < before):
+            chunk = chunks[0]
+            seq = chunk.seq
+            owed = (
+                seq.is_running
+                and seq.num_preemptions == chunk.preemptions
+                and self.scheduler.is_active(chunk.rid)
+            )
+            tok = 0
+            if owed:
+                self._current_rid = chunk.rid
+                self._attribution_step = chunk.dispatch_step
+                if clock is not None:
+                    clock.switch("wait")
+                tok = self.runner.read_chunk(chunk.out)
+                if clock is not None:
+                    # The value is on the host: the device is idle from
+                    # now if nothing newer is out.
+                    clock.ready(chunk.clock_seq)
+            chunk.out = None
+            chunks.popleft()
+            if self._unfed.get(seq) is chunk:
+                del self._unfed[seq]  # read before any decode took it
+            if owed:
+                self._first_token(chunk, tok)
+            self._attribution_step = None
+        self._current_rid = None
+        if self._step_first_tokens > emitted and self.on_commit is not None:
+            self.on_commit()
+
+    def _first_token(self, chunk: _PendingChunk, tok: int) -> None:
+        """What a chunk owes once its value is on the host: a last chunk's
+        token appended, emitted and checked for a finish; the request's
+        span, the step histogram and, once a request, TTFT."""
+        seq = chunk.seq
+        final = chunk.final
+        if final:
+            seq.generated.append(tok)
+            self._step_first_tokens += 1
+            self._prompts_prefilled += 1
+            self._first_tokens_on_device += chunk.fed
+        if self._instrument:
+            t1 = time.time()
+            phase = "partial_prefill" if chunk.offset else "prefill"
+            # t0 and t1 are the span's timestamps (wall clock: identity
+            # across actors); the histogram's delta rides on the pair.
+            self._h_step.observe(
+                t1 - chunk.t0,
+                tags=(
+                    self._step_tags[phase]
+                    if final
+                    else self._chunk_step_tags[phase]
+                ),
+            )
+            rt = self._req_traces.get(chunk.rid)
+            if rt is not None:
+                first_admission = rt.first_token_s is None
+                rt.on_prefilled(
+                    chunk.t0, t1, chunk.kind, chunk.bucket, chunk.take,
+                    chunk.offset, len(seq.generated),
+                    chunk=chunk.index, final=final,
+                )
+                if final and first_admission:
+                    # TTFT observes exactly once per request: at the
+                    # final chunk of its FIRST admission (chunked or
+                    # not), when the first token is on the host.
+                    self._h_ttft.observe(
+                        t1 - rt.submit_s, tags=self._metric_tags
+                    )
+        if final:
+            self._emit(seq)
+            self._maybe_finish(seq)
+
     def _run_prefill_chunks(
         self,
         plans: List[tuple],
@@ -1670,17 +1939,22 @@ class LLMEngine:
     ) -> int:
         """Run this step's prefill chunk plan ((sequence, token count)
         pairs from Scheduler.schedule_prefill_chunks); returns the prompt
-        tokens served from the prefix cache this step. Each chunk commits
-        independently (num_cached advances only after its program
-        returns), so a failure mid-plan leaves every sequence — including
-        the culprit — consistent: a retry re-plans from committed state,
-        a dead-letter releases all of the culprit's blocks via the normal
-        abort path. Only the FINAL chunk of a prompt produces a token;
-        continuation chunks just stream K/V into the cache. With
-        instrumentation, `info_out` collects one record per chunk for the
-        flight recorder."""
+        tokens served from the prefix cache this step. Every chunk is a
+        dispatch here and a commit (`_first_token`): in the statement after
+        the dispatch at depth 0, under speculation and in a prefill-role
+        engine, else where `_commit_chunks` reads it, behind the decode
+        dispatch that the prompt joins. What needs no value (num_cached,
+        the window class, block publication) advances here either way, so
+        a failure mid-plan leaves every sequence — including the culprit —
+        consistent: a retry re-plans from there, a dead-letter releases
+        all of the culprit's blocks via the normal abort path and its
+        unread chunks are dropped. Only the FINAL chunk of a prompt
+        produces a token; continuation chunks just stream K/V into the
+        cache. With instrumentation, `info_out` collects one record per
+        chunk for the flight recorder of the step that dispatched it."""
         instrument = self._instrument
         clock = self._clock if instrument else None
+        defer = self._defers_chunks
         hit_tokens = 0
         for seq, take in plans:
             # Per-sequence section: an exception below is attributable to
@@ -1693,7 +1967,7 @@ class LLMEngine:
                 continue  # back in the queue: the window class was full
             if clock is not None:
                 # Per chunk: prepare (CoW copy, input build, dispatch),
-                # wait (from the runner's hook to its return), commit
+                # wait (the read, wherever it is made), commit
                 # (publication, spans, emission).
                 clock.switch("prepare")
             first_chunk = seq.num_chunks == 0
@@ -1702,17 +1976,22 @@ class LLMEngine:
                 maybe_fail("llm.prefill", detail=rid)
             maybe_fail("engine.prefill_chunk", detail=rid)
             offset = seq.num_cached  # cache-matched prefix + prior chunks
-            rt = queue_wait = None
+            was_cow = seq.pending_copy is not None
+            t0 = 0.0
+            kind = bucket = None
             if instrument:
                 t0 = time.time()
+                kind = "cow" if was_cow else ("partial" if offset else "full")
+                bucket = self.engine_config.bucket_for(max(take, 1))
                 rt = self._req_traces.get(rid)
                 if rt is not None and rt.queue_start is not None:
                     # The queue ends when the request's FIRST chunk starts
                     # computing (one wait per admission; a preempt-resume
                     # reopens the clock and its first resumed chunk closes
                     # it again).
-                    queue_wait = rt.on_admitted(t0)
-            was_cow = seq.pending_copy is not None
+                    self._h_queue.observe(
+                        rt.on_admitted(t0), tags=self._metric_tags
+                    )
             if was_cow:
                 # Copy-on-write: the last matched block is shared and this
                 # prefill writes its final token's K/V into it. pending_copy
@@ -1738,7 +2017,7 @@ class LLMEngine:
                 else {}
             )
             if offset > 0:
-                tok = self.runner.prefill_suffix(
+                out = self.runner.prefill_suffix(
                     chunk_ids, seq.block_table, offset, *slot, **extra
                 )
                 if first_chunk:
@@ -1748,7 +2027,7 @@ class LLMEngine:
                 # for this chunk's bucket. Slice the table — the sequence
                 # owns blocks for its WHOLE prompt, but this program's
                 # block vector is sized for the chunk's bucket.
-                tok = self.runner.prefill(
+                out = self.runner.prefill(
                     chunk_ids,
                     seq.block_table[
                         : blocks_for_tokens(
@@ -1758,8 +2037,20 @@ class LLMEngine:
                     *slot,
                     **extra,
                 )
-            if clock is not None:
-                clock.ready()
+            self._dispatch_serial += 1
+            chunk = _PendingChunk(
+                seq, rid, take, offset, out, self._steps,
+                clock.dispatches if clock is not None else None,
+                self._dispatch_serial, final, t0, kind, bucket,
+            )
+            tok = 0
+            if not defer:
+                tok = self.runner.read_chunk(out)
+                chunk.out = None
+                if clock is not None:
+                    clock.ready()
+            elif clock is not None:
+                clock.switch("commit")
             self._prefill_tokens += take
             self._prefill_chunk_dispatches += 1
             seq.num_cached = offset + take
@@ -1770,7 +2061,8 @@ class LLMEngine:
                 self._chunked_prefill_requests += 1
             # Publish every block this chunk filled: a concurrent request
             # with the same prompt can share the prefix before the whole
-            # prompt even finishes prefilling.
+            # prompt even finishes prefilling (its chunk runs behind this
+            # one on the device).
             pre_hashes = len(seq.block_hashes)
             self.scheduler.note_filled_blocks(seq)
             if self._publish_on_fill and len(seq.block_hashes) > pre_hashes:
@@ -1792,57 +2084,24 @@ class LLMEngine:
                     self._fabric_spills.inc(
                         pushed, tags=self._metric_tags
                     )
-            if final:
-                seq.generated.append(tok)
-            if instrument:
-                t1 = time.time()
-                kind = "cow" if was_cow else ("partial" if offset else "full")
-                phase = "partial_prefill" if offset else "prefill"
-                bucket = self.engine_config.bucket_for(max(take, 1))
-                # ray-tpu: lint-ignore[RTL302] t0/t1 double as span
-                # timestamps (wall-clock identity across actors); the
-                # histogram delta rides on the same pair
-                self._h_step.observe(
-                    t1 - t0,
-                    tags=(
-                        self._step_tags[phase]
-                        if final
-                        else self._chunk_step_tags[phase]
-                    ),
+            if info_out is not None and instrument:
+                info_out.append(
+                    {
+                        "request_id": rid,
+                        "kind": kind,
+                        "bucket": bucket,
+                        "tokens": take,
+                        "cached_tokens": offset,
+                        "chunk": chunk.index,
+                        "final": final,
+                    }
                 )
-                if queue_wait is not None:
-                    self._h_queue.observe(
-                        queue_wait, tags=self._metric_tags
-                    )
-                if rt is not None:
-                    first_admission = rt.first_token_s is None
-                    rt.on_prefilled(
-                        t0, t1, kind, bucket, take, offset,
-                        len(seq.generated),
-                        chunk=seq.num_chunks - 1, final=final,
-                    )
-                    if final and first_admission:
-                        # TTFT observes exactly once per request: at the
-                        # final chunk of its FIRST admission (chunked or
-                        # not), when the first token actually exists.
-                        self._h_ttft.observe(
-                            t1 - rt.submit_s, tags=self._metric_tags
-                        )
-                if info_out is not None:
-                    info_out.append(
-                        {
-                            "request_id": rid,
-                            "kind": kind,
-                            "bucket": bucket,
-                            "tokens": take,
-                            "cached_tokens": offset,
-                            "chunk": seq.num_chunks - 1,
-                            "final": final,
-                        }
-                    )
-            if final:
-                self._emit(seq)
-                self._maybe_finish(seq)
+            if defer:
+                self._pending_chunks.append(chunk)
+                if final:
+                    self._unfed[seq] = chunk
+            else:
+                self._first_token(chunk, tok)
         self._current_rid = None
         if clock is not None and plans:
             clock.switch("schedule")
@@ -1988,6 +2247,11 @@ class LLMEngine:
             # tokens (depth 1), and the steps that could not chain, in
             # all and by cause (FLUSH_CAUSES).
             "chained_decode_dispatches": self._chained_dispatches,
+            # Prompts whose first token was committed, and those of them
+            # whose token had reached the decode dispatch they joined on
+            # the device, before the step thread read it (depth 1).
+            "prompts_prefilled": self._prompts_prefilled,
+            "first_tokens_on_device": self._first_tokens_on_device,
             "pipeline_flushes": sum(self._pipeline_flushes.values()),
             "pipeline_flushes_by_cause": dict(self._pipeline_flushes),
             "attention_shape": self.runner.attention_shape(),
@@ -2341,13 +2605,15 @@ class LLMServer:
             # counts, and a chained decode dispatches the SAME compiled
             # program anyway (identical avals — a device token array and
             # a host one trace alike), so depth 1 needs no warmup pass
-            # of its own.
+            # of its own but for the one program it adds, which sets a
+            # joining prompt's lane on the device (`_warmup_join`).
             instrumented = self._engine._instrument
             spec = self._engine._spec
             publish = self._engine._publish_on_fill
             on_evict = self._engine.allocator.on_evict
             probe = self._engine.scheduler.fabric_probe
             depth = self._engine._pipeline_depth
+            defers_chunks = self._engine._defers_chunks
             self._engine._instrument = False
             # ray-tpu: lint-ignore[RTL403] deliberate temporary clear —
             # the finally below restores _spec on every path, so no
@@ -2360,6 +2626,8 @@ class LLMServer:
             t_warmup = time.perf_counter()
             try:
                 self._warmup()
+                if defers_chunks:
+                    self._warmup_join()
             finally:
                 self._engine._warmup_s = time.perf_counter() - t_warmup
                 self._engine._instrument = instrumented
@@ -2503,6 +2771,31 @@ class LLMServer:
                 runner.prefill([0] * w, [0], *slot, **extra)
                 runner.prefill_suffix([0] * w, null_table, 0, *slot, **extra)
                 self._record_round("chunk_prefill", w, round_start)
+
+    def _warmup_join(self) -> None:
+        """The program that sets a joining prompt's lane of a decode's
+        token input on the device (`runner.join_token`; depth 1 alone),
+        on the host's buffer and on a decode's output: against the null
+        block and state slot 0, as the chunk programs above."""
+        engine = self._engine
+        ecfg, runner = engine.engine_config, engine.runner
+        slot = (0,) if engine._recurrent else ()
+        windowed = engine._window is not None
+        round_start = self._round_start()
+        out = runner.prefill(
+            [0] * ecfg.chunk_widths()[0], [0], *slot,
+            **({"window_ids": [0]} if windowed else {}),
+        )
+        lanes = np.zeros((ecfg.max_decode_slots,), np.int32)
+        tables = np.zeros(
+            (ecfg.max_decode_slots, ecfg.max_blocks_per_seq), np.int32
+        )
+        decoded = runner.decode(
+            runner.join_token(lanes, 0, out), lanes, tables, lanes,
+            **({"window_tables": tables} if windowed else {}),
+        )
+        runner.join_token(decoded, 0, out)
+        self._record_round("join_token", 0, round_start)
 
     def _warmup_verify(self, spec) -> None:
         """Compile every k-token verify bucket program plus whatever the

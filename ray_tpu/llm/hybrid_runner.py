@@ -70,7 +70,12 @@ import numpy as np
 from ray_tpu._private.jax_setup import ensure_compile_cache
 from ray_tpu.llm.cache import kv_pool_bytes_sharded
 from ray_tpu.llm.config import EngineConfig
-from ray_tpu.llm.model_runner import bytes_by_device, prefill_tiling
+from ray_tpu.llm.model_runner import (
+    bytes_by_device,
+    join_token,
+    prefill_tiling,
+    start_host_copy,
+)
 from ray_tpu.ops.paged_flash import paged_attention_impl, resolve_paged_impl
 from ray_tpu.util.device_report import scopes_of  # noqa: F401  (also the name tests know it by)
 
@@ -624,21 +629,42 @@ class HybridRunner:
         tokens[0, : len(token_ids)] = token_ids
         return tokens
 
-    def _chunk_done(self, pools, out, arrays_in, n: int, offset: int = 0) -> int:
+    def _chunk_done(
+        self, pools, out, arrays_in, n: int, offset: int = 0
+    ) -> jax.Array:
+        """A chunk program is dispatched: what its shape says is counted
+        here, what its output says where `read_chunk` reads it."""
         self._set_pools(pools)
         self._dispatched()
         self._count_transfer(arrays_in, out)
-        token, *routing = (int(v) for v in np.asarray(out))
-        if self.routed:
-            self.counters["prefill_expert_assignments"] += routing[0]
-            self.counters["prefill_expert_rows_walked"] += routing[1]
+        start_host_copy(out)
         if self.recurrent:
             self.counters["prefill_scan_tokens"] += n
         if self.horizon is not None:
             self.counters["prefill_window_pairs"] += visible_pairs(
                 offset, n, self.horizon
             )
+        return out
+
+    def read_chunk(self, out: jax.Array) -> int:
+        """The token a chunk program sampled, on the host, its routing
+        counts (a model with routed experts) into the counters: waits for
+        the program, and raises here if it failed. Once an output."""
+        token, *routing = (int(v) for v in np.asarray(out))
+        if self.routed:
+            self.counters["prefill_expert_assignments"] += routing[0]
+            self.counters["prefill_expert_rows_walked"] += routing[1]
         return token
+
+    def join_token(self, tokens, lane: int, out: jax.Array) -> jax.Array:
+        """As `GPTRunner.join_token`; the host's buffer gets the tail a
+        decode result carries behind its tokens."""
+        if not isinstance(tokens, jax.Array):
+            tokens = jnp.asarray(
+                np.concatenate([tokens, np.zeros(self._tail, np.int32)])
+            )
+            self.host_bytes_in += int(tokens.nbytes)
+        return join_token(tokens, np.int32(lane), out)
 
     @staticmethod
     def _on_device(tables) -> tuple:
@@ -653,11 +679,12 @@ class HybridRunner:
     def prefill(
         self, token_ids: Sequence[int], block_ids: Sequence[int],
         state_slot: int = 0, window_ids: Sequence[int] = (),
-    ) -> int:
+    ) -> jax.Array:
         """Start a sequence: its first chunk, from an empty state (left in
         `state_slot` on a model with recurrent layers), its blocks those of
-        `block_ids` and, in a window class, `window_ids`. Returns the
-        greedily sampled next token."""
+        `block_ids` and, in a window class, `window_ids`. Dispatches and
+        returns the program's output on the device (the greedily sampled
+        next token first), for `read_chunk` or `join_token`."""
         tokens, tables = self._padded(token_ids), self._tables(block_ids, window_ids)
         pools, out = self._programs.prefill_fn(
             self.params, *self._pools, jnp.asarray(tokens),
@@ -669,7 +696,7 @@ class HybridRunner:
     def prefill_suffix(
         self, token_ids: Sequence[int], block_ids: Sequence[int], offset: int,
         state_slot: int = 0, window_ids: Sequence[int] = (),
-    ) -> int:
+    ) -> jax.Array:
         """The next chunk of a sequence whose first `offset` tokens are in
         the cache (and in `state_slot`'s state)."""
         tokens, tables = self._padded(token_ids), self._tables(block_ids, window_ids)
@@ -713,10 +740,7 @@ class HybridRunner:
         )
         self._set_pools(pools)
         self._dispatched()
-        try:
-            out.copy_to_host_async()
-        except (AttributeError, NotImplementedError):  # pragma: no cover
-            pass
+        start_host_copy(out)
         host_in = (positions, *tables, context_lens)
         self._count_transfer(host_in if chained else (tokens,) + host_in, out)
         if self.recurrent:

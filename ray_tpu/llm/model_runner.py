@@ -361,6 +361,25 @@ class _StepPrograms:
         return pools, out
 
 
+@jax.jit
+def join_token(tokens, lane, out):
+    """A decode's token input with lane `lane` set to the token a chunk
+    program sampled (the first value of its output `out`), all of it on
+    the device: how a prompt joins a decode batch before the host has read
+    its first token. Outside the decode program, which keeps its
+    signature; one shape an engine, warmed with the rest."""
+    return tokens.at[lane].set(jnp.ravel(out)[0])
+
+
+def start_host_copy(out: jax.Array) -> None:
+    """Begin the device-to-host copy of a program's output, which whoever
+    reads it later (`np.asarray`, `int`) then finds under way."""
+    try:
+        out.copy_to_host_async()
+    except (AttributeError, NotImplementedError):  # pragma: no cover
+        pass  # backend without async copies: the read blocks
+
+
 _PROGRAM_CACHE: dict = {}
 _PROGRAM_CACHE_LOCK = threading.Lock()
 
@@ -703,9 +722,14 @@ class GPTRunner:
 
     # ---------------- prefill ----------------
 
-    def prefill(self, token_ids: Sequence[int], block_ids: Sequence[int]) -> int:
-        """Run one prompt through the model, scatter its K/V into the given
-        blocks, and return the greedily-sampled next token."""
+    def prefill(
+        self, token_ids: Sequence[int], block_ids: Sequence[int]
+    ) -> jax.Array:
+        """Dispatch one prompt through the model, scattering its K/V into
+        the given blocks, WITHOUT waiting for it: returns the
+        greedily-sampled next token as the program's output on the device,
+        its host copy started. `read_chunk` (or `int`) reads it; `join_token`
+        hands it to a decode unread."""
         ecfg = self.engine_config
         n = len(token_ids)
         bucket = ecfg.bucket_for(n)
@@ -726,17 +750,19 @@ class GPTRunner:
         self._set_pools(pools)
         self._dispatched()
         self._count_transfer((tokens, blocks), next_token)
-        return int(next_token)
+        start_host_copy(next_token)
+        return next_token
 
     # ---------------- partial prefill (prefix caching) ----------------
 
     def prefill_suffix(
         self, token_ids: Sequence[int], block_ids: Sequence[int], offset: int
-    ) -> int:
-        """Prefix-aware prefill: run only the uncached suffix of a prompt
-        whose first `offset` tokens already sit in the paged cache (through
-        `block_ids`, the sequence's whole block table), scatter the suffix
-        K/V, and return the greedily-sampled next token."""
+    ) -> jax.Array:
+        """Prefix-aware prefill: dispatch only the uncached suffix of a
+        prompt whose first `offset` tokens already sit in the paged cache
+        (through `block_ids`, the sequence's whole block table), scattering
+        the suffix K/V; returns the greedily-sampled next token on the
+        device, as `prefill` does."""
         ecfg = self.engine_config
         n = len(token_ids)
         bucket = ecfg.bucket_for(n)
@@ -755,7 +781,23 @@ class GPTRunner:
         self._set_pools(pools)
         self._dispatched()
         self._count_transfer((tokens, table), next_token)
-        return int(next_token)
+        start_host_copy(next_token)
+        return next_token
+
+    def read_chunk(self, out: jax.Array) -> int:
+        """The token a chunk program sampled (`prefill` / `prefill_suffix`'s
+        output), on the host: waits for the program, and raises here if it
+        failed."""
+        return int(out)
+
+    def join_token(self, tokens, lane: int, out: jax.Array) -> jax.Array:
+        """`tokens` (a decode's token input: the host's buffer, or an
+        in-flight decode's output) on the device with lane `lane` taken from
+        a chunk's output `out`, unread."""
+        if not isinstance(tokens, jax.Array):
+            self.host_bytes_in += int(tokens.nbytes)
+            tokens = jnp.asarray(tokens.copy(), jnp.int32)
+        return join_token(tokens, np.int32(lane), out)
 
     def copy_block(self, src: int, dst: int) -> None:
         """Device-copy one block's K/V (and scales) across every layer
@@ -904,10 +946,7 @@ class GPTRunner:
         )
         self._set_pools(pools)
         self._dispatched()
-        try:
-            next_tokens.copy_to_host_async()
-        except (AttributeError, NotImplementedError):  # pragma: no cover
-            pass  # backend without async copies: the commit asarray blocks
+        start_host_copy(next_tokens)
         # Chained token inputs never cross the host boundary — that is
         # part of the win the transfer counters should show.
         host_in = (positions, block_tables, context_lens)

@@ -482,37 +482,45 @@ class Scheduler:
                 seq.window_table, seq.window_first, seq.num_cached
             )
 
-    def reserve_decode_lookahead(self, seqs: List[Sequence]) -> bool:
+    def reserve_decode_lookahead(
+        self, seqs: List[Sequence], joiners: List[Sequence] = ()
+    ) -> bool:
         """Extend block tables so a CHAINED decode step can run before the
         in-flight step commits: the chained write lands at position
         num_cached + 1 (num_cached has not advanced yet — the in-flight
         token commits it later), needing (num_cached + 1) // bs + 1 blocks
-        per sequence. Unlike schedule_decode this NEVER preempts — with a
-        step in flight, preemption would reset a sequence whose uncommitted
-        token is still on device — and never raises: on pool pressure, a
-        per-sequence table cap, or a sequence whose chained write would
-        fall past max_blocks_per_seq * bs, it allocates nothing and returns
-        False so the engine flushes the pipeline and schedules normally.
-        All-or-nothing: the batch chains together or not at all."""
+        per sequence. A joiner (a prompt whose last chunk is dispatched
+        and whose first token nobody has read) has nothing in flight: its
+        write lands at num_cached. Unlike schedule_decode this NEVER
+        preempts — with a step in flight, preemption would reset a
+        sequence whose uncommitted token is still on device — and never
+        raises: on pool pressure, a per-sequence table cap, or a sequence
+        whose chained write would fall past max_blocks_per_seq * bs, it
+        allocates nothing and returns False so the engine flushes the
+        pipeline and schedules normally. All-or-nothing: the batch chains
+        together or not at all."""
         bs = self.allocator.block_size
-        extras: List[Tuple[Sequence, int]] = []
-        window_total = 0
-        for seq in seqs:
-            needed = (seq.num_cached + 1) // bs + 1
-            if needed > self.max_blocks_per_seq:
-                return False
-            extras.append((seq, max(0, needed - len(seq.block_table))))
-            window_total += self._window_missing(seq, seq.num_cached + 1)
-        total = sum(extra for _, extra in extras)
+        extras: List[Tuple[Sequence, int, int]] = []
+        total = window_total = 0
+        for ahead, batch in ((1, seqs), (0, joiners)):
+            for seq in batch:
+                position = seq.num_cached + ahead
+                needed = position // bs + 1
+                if needed > self.max_blocks_per_seq:
+                    return False
+                extra = max(0, needed - len(seq.block_table))
+                extras.append((seq, position, extra))
+                total += extra
+                window_total += self._window_missing(seq, position)
         if total and not self.allocator.can_allocate(total):
             return False
         if window_total and not self.window.allocator.can_allocate(window_total):
             return False
-        for seq, extra in extras:
+        for seq, position, extra in extras:
             if extra:
                 seq.block_table.extend(self.allocator.allocate(extra))
             if window_total:
-                self.window.extend(seq.window_table, seq.num_cached + 1)
+                self.window.extend(seq.window_table, position)
         return True
 
     def reserve_speculative(self, seq: Sequence, num_tokens: int) -> int:

@@ -406,10 +406,12 @@ def test_engine_streaming_order_interleaves(tiny_engine):
     last_of_first = max(i for i, r in enumerate(order) if r == 0)
     assert first_of_last < last_of_first
     # ...and once every request is active, production skew stays bounded by
-    # the admission stagger (1 prefill/step, +1 decode token that step).
+    # the admission stagger (1 prefill/step, +1 decode token that step, +1
+    # since PR 48: a prompt that finds the batch chained joins the decode
+    # dispatched after the one in flight, where a flush took it at once).
     for snap in progress:
         if min(snap.values()) >= 1:
-            assert max(snap.values()) - min(snap.values()) <= 3
+            assert max(snap.values()) - min(snap.values()) <= 4
 
 
 def test_engine_preemption_recompute_matches_reference():

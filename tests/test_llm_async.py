@@ -541,15 +541,47 @@ def test_flush_cause_left_on_finish_and_on_abort():
 
 
 def test_flush_cause_joined_when_a_prompt_joins():
+    """A prompt that joins a chained batch chains with it (PR 48): its
+    chunk is left unread behind the chained decode, the next chained
+    dispatch takes its first token from the device, and no flush is
+    counted. `joined` is left for a join that cannot keep the record's
+    lanes (tests/test_llm_chunk_handoff.py provokes it). Under speculation
+    nothing chains, with a join as without one: every decode commits in
+    its own step and counts `speculation`, the chunk is read at once and
+    `joined` stays 0, as it did before."""
     eng, _ = steady(1)
     before = flushes(eng)
+    chained = eng.stats()["chained_decode_dispatches"]
     eng.add_request(random_prompts((7,), seed=13)[0], max_new_tokens=30)
     eng.step()  # chains (the prompt is still waiting), then prefills it
     assert flushes(eng) == before
+    assert eng.stats()["first_tokens_on_device"] == 1  # the first stream's
     eng.step()  # the decode batch is one wider than the record in flight
-    assert_only(eng, before, "joined")
+    assert flushes(eng) == before
+    record = eng.flight_recorder.snapshot()["steps"][-1]
+    assert record["chained"] and record["batch_size"] == 2
+    stats = eng.stats()
+    assert stats["chained_decode_dispatches"] == chained + 2
+    assert stats["first_tokens_on_device"] == stats["prompts_prefilled"] == 2
     eng.step()
     assert eng.flight_recorder.snapshot()["steps"][-1]["chained"]
+    drain(eng)
+
+    spec = LLMEngine(
+        TINY,
+        EngineConfig(speculation="ngram", num_speculative_tokens=3, **BASE),
+        seed=0,
+    )
+    spec.add_request([3, 4, 5, 3, 4, 5, 3, 4], max_new_tokens=12)
+    for _ in range(3):
+        spec.step()
+    spec.add_request(random_prompts((7,), seed=13)[0], max_new_tokens=6)
+    drain(spec)
+    stats = spec.stats()
+    assert stats["chained_decode_dispatches"] == 0
+    assert stats["prompts_prefilled"] == 2
+    assert stats["first_tokens_on_device"] == 0
+    assert flushes(spec) == only("speculation", stats["pipeline_flushes"])
 
 
 def test_flush_cause_lookahead_under_block_pressure():

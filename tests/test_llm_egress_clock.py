@@ -204,14 +204,24 @@ def test_under_serve_each_hop_has_its_wait_and_its_span(serve_ray):
     )
     engine = ray_tpu.get_actor("llm_engine:egress")
     before = ray_tpu.get(engine.metrics.remote())
-    with tracing.span("client") as root:
-        stream = handle.options(stream=True).remote(
-            {"prompt_ids": list(PROMPT), "max_new_tokens": 6, "stream": True}
-        )
-        tokens = []
-        for item in stream:
-            tokens.append(item["token_id"])
-            time.sleep(0.02)  # the last hop's consumer is the late one
+    # A decode commit sleeps 10 ms a sequence: on a loaded machine the six
+    # steps of this tiny model could otherwise all commit before the
+    # actor's loop got one turn at the interpreter, and the span's
+    # `handoff_s` (over the tokens taken by the last commit) would read 0.
+    slow = fi.inject(
+        "llm.decode.seq", action="delay", delay_s=0.01, every=1, times=None
+    )
+    try:
+        with tracing.span("client") as root:
+            stream = handle.options(stream=True).remote(
+                {"prompt_ids": list(PROMPT), "max_new_tokens": 6, "stream": True}
+            )
+            tokens = []
+            for item in stream:
+                tokens.append(item["token_id"])
+                time.sleep(0.02)  # the last hop's consumer is the late one
+    finally:
+        fi.remove(slow)
     assert len(tokens) == 6
     deadline = time.monotonic() + 30
     while serve_ray._streams and time.monotonic() < deadline:
