@@ -72,6 +72,7 @@ from ray_tpu.llm.cache import kv_pool_bytes_sharded
 from ray_tpu.llm.config import EngineConfig
 from ray_tpu.llm.model_runner import (
     bytes_by_device,
+    decode_tiling,
     join_token,
     prefill_tiling,
     start_host_copy,
@@ -516,6 +517,10 @@ class HybridRunner:
                 **prefill_tiling(
                     self.engine_config, query_heads, cfg.num_key_value_heads,
                     cfg.head_dim, cfg.dtype, self.kv_cache_dtype,
+                ),
+                **decode_tiling(
+                    self.engine_config, cfg.num_key_value_heads, cfg.head_dim,
+                    self.kv_cache_dtype,
                 ),
             }
         by_class = len(self.classes) > 1 or getattr(

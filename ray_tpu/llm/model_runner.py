@@ -65,6 +65,7 @@ from ray_tpu.models.gpt import (
 from ray_tpu.ops.attention import validate_tp_heads
 from ray_tpu.util.device_report import bytes_by_device  # noqa: F401  (hybrid_runner's too)
 from ray_tpu.ops.paged_flash import (
+    decode_tile,
     KV_SCALE_DTYPE,
     q_tile,
     quantize_kv,
@@ -436,6 +437,23 @@ def prefill_tiling(
     }
 
 
+def decode_tiling(
+    ecfg: EngineConfig, kv_heads: int, head_dim: int, kv_dtype
+) -> dict:
+    """How the paged kernel walks a decode lane's context, from the
+    function the kernel asks: cached tokens a compute block, and the K and
+    V bytes of one, which is what the walk copies ahead of the block it
+    folds."""
+    itemsize = np.dtype(kv_dtype).itemsize
+    _, tile, _ = decode_tile(
+        ecfg.block_size, ecfg.max_blocks_per_seq, kv_heads, head_dim, itemsize
+    )
+    return {
+        "decode_tile_tokens": tile,
+        "decode_bytes_in_flight": 2 * tile * kv_heads * head_dim * itemsize,
+    }
+
+
 def build_runner(model_config, engine_config: EngineConfig, params=None,
                  seed: int = 0):
     """The runner of `model_config`'s type: the class its `llm_runner`
@@ -661,6 +679,10 @@ class GPTRunner:
             **prefill_tiling(
                 self.engine_config, local_heads, local_heads, cfg.head_dim,
                 cfg.dtype, self.kv_cache_dtype,
+            ),
+            **decode_tiling(
+                self.engine_config, local_heads, cfg.head_dim,
+                self.kv_cache_dtype,
             ),
         }
 

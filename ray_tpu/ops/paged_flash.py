@@ -6,13 +6,16 @@ and runs a full-matrix softmax over [B, H, Q, K] logits. This kernel walks
 each sequence's block table itself. Its unit of work is a compute block:
 as many table entries as hold 128 cached tokens (8 blocks of 16, 16 of 8),
 gathered into one [128, H*D] tile of VMEM so that a score row fills whole
-lanes. The grid is (batch, q tiles, q tiles), sequential; a slot's context
-is a loop inside one grid step, bounded by the scalar-prefetched
-context_lens[b], so what a table holds past the context costs neither a
-copy nor a step, and an idle slot costs its new-token step alone. The
-pools stay in HBM: the kernel's own async copies bring in the blocks
-table[b, ...] of the context, two tiles deep, the next compute block (or
-the next slot's first) in flight while this one is computed. Block gather,
+lanes. A decode call's compute block is sized in bytes instead, as many
+128-token blocks as keep about 2 MiB of K and V in flight (`decode_tile`):
+a narrow row's 128 tokens leave the HBM pipe empty while a turn's copies
+are started and waited for. The grid is (batch, q tiles, q tiles),
+sequential; a slot's context is a loop inside one grid step, bounded by the
+scalar-prefetched context_lens[b], so what a table holds past the context
+costs neither a copy nor a step, and an idle slot costs its new-token step
+alone. The pools stay in HBM: the kernel's own async copies bring in the
+blocks table[b, ...] of the context, two tiles deep, the next compute block
+(or the next slot's first) in flight while this one is computed. Block gather,
 QK^T, validity masking, streaming (online) softmax, and the weighted-V
 accumulation all happen in one pass; neither the gathered pages nor the
 logits ever touch HBM. After the walk the not-yet-scattered new tokens' K/V
@@ -526,6 +529,43 @@ def _compute_blocks(block_size: int, table_blocks: int) -> Tuple[int, int, int]:
     return entries, entries * block_size, -(-table_blocks // entries)
 
 
+# K and V bytes a decode walk keeps in flight ahead of the compute block it
+# folds: in whole MiB, what one 128-token block is of the one row that fills
+# the HBM pipe at that width, 30 cached heads of 128 in bf16 (1.875 MiB;
+# 88.7% of the roofline: ledger, PR 48). A narrower row gets as many
+# 128-token blocks a compute block as come to that, and no more than
+# _DECODE_TILE_TOKENS: past them a long walk gains nothing and a short or
+# windowed one pays for rows no copy wrote (a call at 4 cached heads of 128
+# and contexts of 350: 299 us at 128 tokens, 214 at 512, 240 at 1,024; at 8
+# of 128 and 3,000: 1,191, 835, 830; under a window of 512: 278, 242, 287;
+# on the chip, PR 51).
+_DECODE_BYTES_IN_FLIGHT = 2 * 1024 * 1024
+_DECODE_TILE_TOKENS = 512
+
+
+def decode_tile(
+    block_size: int, table_blocks: int, kv_heads: int, head_dim: int,
+    kv_itemsize: int,
+) -> Tuple[int, int, int]:
+    """`_compute_blocks` of a decode walk (one fed token a slot): where the
+    kernel copies the blocks itself, as many whole 128-token blocks as keep
+    _DECODE_BYTES_IN_FLIGHT of K and V in flight, the whole table where
+    that is shorter. The two-deep ring is then at most twice those bytes,
+    4 MiB, whatever the row (at a wider row than the constant's, the two
+    128-token tiles it always was). int8 pools (their scales are gathered a
+    128-token block) and the gathered transport keep 128 tokens too."""
+    entries, tile, n_tiles = _compute_blocks(block_size, table_blocks)
+    lanes = kv_heads * head_dim
+    if lanes % _LANES or kv_itemsize == 1:
+        return entries, tile, n_tiles
+    wide = min(
+        _DECODE_BYTES_IN_FLIGHT // (2 * tile * lanes * kv_itemsize),
+        _DECODE_TILE_TOKENS // tile,
+    )
+    entries = min(entries * max(1, wide), table_blocks)
+    return entries, entries * block_size, -(-table_blocks // entries)
+
+
 def q_tile(
     s_len: int, heads: int, kv_heads: int, head_dim: int, itemsize: int,
     block_size: int, table_blocks: int, kv_itemsize: int,
@@ -643,7 +683,16 @@ def paged_flash_attention(
     # epilogue by XLA): no per-score-element scale pass inside.
     q = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
 
-    entries, tile, n_tiles = _compute_blocks(bs, nb)
+    # One fed token a slot (decode) takes every head in one product: q, new
+    # K/V and out as lane-dense [1, H*D] rows, which q's own reshape gives.
+    # Its compute block is sized in bytes; a chunk's is 128 tokens.
+    batched_heads = s_len == 1
+    if batched_heads:
+        entries, tile, n_tiles = decode_tile(
+            bs, nb, hkv, d, k_cache.dtype.itemsize
+        )
+    else:
+        entries, tile, n_tiles = _compute_blocks(bs, nb)
     # The kernel copies blocks out of a pool itself only in whole lanes.
     # Mosaic refuses to slice a narrower or ragged minor axis in HBM (a
     # chip's 5 heads of 64 under tp = 4; every scale pool): XLA gathers the
@@ -694,9 +743,6 @@ def paged_flash_attention(
     )
     nq = -(-s_len // tq)
     pad = nq * tq - s_len
-    # One fed token a slot (decode) takes every head in one product: q, new
-    # K/V and out as lane-dense [1, H*D] rows, which q's own reshape gives.
-    batched_heads = s_len == 1
 
     if batched_heads:
         fed_block, stat_rows, acc_shape = (None, 1, h * d), (h,), (h, hkv * d)

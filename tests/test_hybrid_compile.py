@@ -165,6 +165,10 @@ def test_lagunas_widest_chunk_stacks_a_cached_heads_query_heads(
     assert shape["prefill_rows_per_product"] == rows
     heads, chunk = shape["num_query_heads"], max(LAGUNA_ENGINE.chunk_widths())
     assert (chunk, rows) == (3584, heads // 8 * q_tile)
+    # A decode walk's compute block, either class: 2 MiB of K and V, which
+    # at 8 cached heads of 128 in bf16 are 512 tokens (32 table entries).
+    assert shape["decode_tile_tokens"] == 512
+    assert shape["decode_bytes_in_flight"] == 2 * 512 * 8 * 128 * 2 == 2 * 1024 * 1024
 
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)

@@ -274,6 +274,7 @@ def test_counters_and_shapes_of_a_layer_with_two_mixers(params, observed, depth)
     assert stats["attention_shape"] == {"full": {
         "num_layers": 3, "num_heads": 2, "head_dim": 16, "kv_itemsize": 4,
         "num_query_heads": 10, "prefill_q_tile": 16, "prefill_rows_per_product": 80,
+        "decode_tile_tokens": 96, "decode_bytes_in_flight": 24576,
     }}
     assert stats["layer_mixers"] == {"parallel": ["mamba", "full_attention"]}
     assert stats["head_shape"] == {

@@ -289,6 +289,8 @@ def test_counters(params, observed, depth):
         # The widest chunk is one q tile of 16; a cached head's two query
         # heads are the rows of one product.
         "prefill_q_tile": 16, "prefill_rows_per_product": 32,
+        # A decode walk's compute block is the whole 96-token table.
+        "decode_tile_tokens": 96, "decode_bytes_in_flight": 24576,
     }
 
 

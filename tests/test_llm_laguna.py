@@ -290,10 +290,14 @@ def test_counters(params, observed, depth):
         # three query heads are the rows of one product.
         "full": {"num_layers": 2, "num_heads": 2, "head_dim": 16,
                  "kv_itemsize": 4, "num_query_heads": 4,
-                 "prefill_q_tile": 16, "prefill_rows_per_product": 32},
+                 "prefill_q_tile": 16, "prefill_rows_per_product": 32,
+                 # A decode walk's compute block is the whole 96-token
+                 # table, K and V of 2 heads of 16 in float32.
+                 "decode_tile_tokens": 96, "decode_bytes_in_flight": 24576},
         "window": {"num_layers": 3, "num_heads": 2, "head_dim": 16,
                    "kv_itemsize": 4, "num_query_heads": 6, "horizon": WINDOW,
-                   "prefill_q_tile": 16, "prefill_rows_per_product": 48},
+                   "prefill_q_tile": 16, "prefill_rows_per_product": 48,
+                   "decode_tile_tokens": 96, "decode_bytes_in_flight": 24576},
     }
     classes = stats["cache_classes"]
     assert classes["full"] == {

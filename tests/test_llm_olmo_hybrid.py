@@ -269,6 +269,7 @@ def test_counters_of_a_dense_model(params, observed, depth):
     assert stats["attention_shape"] == {"full": {
         "num_layers": 1, "num_heads": 4, "head_dim": 16, "kv_itemsize": 4,
         "num_query_heads": 4, "prefill_q_tile": 16, "prefill_rows_per_product": 16,
+        "decode_tile_tokens": 96, "decode_bytes_in_flight": 49152,
     }}
     # A dense model routes nothing: no expert shape, no routing counter, and
     # a decode result that is the lanes' tokens alone.

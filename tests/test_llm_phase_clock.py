@@ -265,6 +265,9 @@ def test_decode_work_counters_follow_the_batches(mode):
         # The widest warmed chunk (32 tokens) is one q tile, and a head
         # with a cached head of its own is one product's rows.
         "prefill_q_tile": 32, "prefill_rows_per_product": 32,
+        # Heads narrower than a lane tile: XLA gathers the blocks, and the
+        # decode walk keeps its 128-token compute block.
+        "decode_tile_tokens": 128, "decode_bytes_in_flight": 65536,
     }
 
 

@@ -168,14 +168,11 @@ def _digest(jaxpr) -> str:
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
-def _kernel_jaxpr(b, s, h, d=16, bs=8, nb=4):
+def _kernel_jaxpr(b, s, h, d=16, bs=8, nb=4, dtype=jnp.float32):
     n = 1 + b * nb
     sds = jax.ShapeDtypeStruct
-    f32, i32 = jnp.float32, jnp.int32
-    args = (
-        sds((b, s, h, d), f32), sds((2, n, bs, h * d), f32), sds((2, n, bs, h * d), f32),
-        sds((b, nb), i32), sds((b,), i32), sds((b, s, h, d), f32), sds((b, s, h, d), f32),
-    )
+    fed, pool = sds((b, s, h, d), dtype), sds((2, n, bs, h * d), dtype)
+    args = (fed, pool, pool, sds((b, nb), jnp.int32), sds((b,), jnp.int32), fed, fed)
 
     def fn(q, kc, vc, tables, lens, nk, nv):
         return paged_flash_attention(
@@ -215,12 +212,18 @@ PINNED = {
     "suffix_heads_of_128": "f7568ba10f95a7eb",
     "gpt_decode_program": "48e837bf5aa3aefb",
     "gpt_suffix_program": "82135a7661c46a57",
+    # Taken on commit 939d32a, the parent of PR 51, which sized a decode
+    # walk's compute block in bytes: Olmo Hybrid's 30 heads of 128 in bf16
+    # over its cell's 200-entry tables keep their 128 tokens, so its decode
+    # kernel is the parent's.
+    "decode_olmo_30_heads_of_128": "e94ac298a13bbba5",
 }
 KERNELS = {
     "decode_4_heads": (3, 1, 4),
     "suffix_4_heads": (2, 8, 4),
     "decode_heads_of_128": (2, 1, 2, 128),
     "suffix_heads_of_128": (1, 16, 2, 128),
+    "decode_olmo_30_heads_of_128": (2, 1, 30, 128, 16, 200, jnp.bfloat16),
 }
 
 

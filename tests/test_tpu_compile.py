@@ -79,6 +79,27 @@ def _paged(batch, fed, heads, pool_dtype=jnp.bfloat16, head_dim=64, block=16):
     ]
 
 
+def _cell_decode(slots, table, heads, kv_heads, head_dim, blocks, window=None):
+    """(fn, shapes) for a serving cell's decode call: the lanes, the table
+    length and the pool it has there (three layers, read at the last), so
+    the decode walk's compute block is the one `decode_tile` picks in the
+    cell and its two-deep tiles, the block-diagonal q and the float32
+    accumulator have to fit the scoped VMEM limit together."""
+
+    def fn(q, k_cache, v_cache, tables, lens, new_k, new_v):
+        return paged_flash_attention(
+            q, k_cache, v_cache, tables, lens, new_k=new_k, new_v=new_v,
+            layer=2, num_kv_heads=kv_heads, window=window, interpret=False,
+        )
+
+    pool = ((3, blocks, 16, kv_heads * head_dim), jnp.bfloat16)
+    new = ((slots, 1, kv_heads, head_dim), jnp.bfloat16)
+    return fn, [
+        ((slots, 1, heads, head_dim), jnp.bfloat16), pool, pool,
+        ((slots, table), jnp.int32), ((slots,), jnp.int32), new, new,
+    ]
+
+
 def _flash_train():
     """Forward and backward of the blockwise training kernel at bench.py's
     per-chip GPT-2 125M batch. The packed kernel the model runs at
@@ -108,6 +129,16 @@ CASES = {
     "paged_prefill_largest_bucket": lambda: _paged(1, 256, 20),
     "paged_prefill_largest_bucket_int8": lambda: _paged(1, 256, 20, jnp.int8),
     "flash_fwd_bwd_gpt2_125m": _flash_train,
+    # The serving cells' decode calls, whose compute block is sized in
+    # bytes (PR 51): 512 tokens at Falcon-H1's and Laguna's rows, 384 at
+    # GPT-2 large's, 128 at Olmo Hybrid's.
+    "cell_decode_falcon_20_over_4": lambda: _cell_decode(96, 176, 20, 4, 128, 7424),
+    "cell_decode_laguna_full_48_over_8": lambda: _cell_decode(48, 896, 48, 8, 128, 13312),
+    "cell_decode_laguna_window_72_over_8": lambda: _cell_decode(
+        48, 896, 72, 8, 128, 1858, window=512
+    ),
+    "cell_decode_olmo_30_heads": lambda: _cell_decode(64, 200, 30, 30, 128, 4608),
+    "cell_decode_gpt2_large_20_heads_of_64": lambda: _cell_decode(16, 64, 20, 20, 64, 3072),
 }
 
 
