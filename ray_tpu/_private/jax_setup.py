@@ -30,7 +30,15 @@ def ensure_compile_cache() -> Optional[str]:
     set JAX reads it itself and nothing is set in code; unset, it is
     `<checkout>/.jax_cache`. On the CPU backend nothing is cached and None
     is returned — tests and rehearsals compile in seconds, and a chip-less
-    compile for a described TPU cannot read its own entries back."""
+    compile for a described TPU cannot read its own entries back.
+
+    Beside XLA's executables the directory holds `programs/`: the serving
+    step programs' lowered modules (`ray_tpu.llm.program_store`; StableHLO
+    through `jax.export`, a few hundred kilobytes to a megabyte a program),
+    which a later process reads instead of tracing and lowering each
+    program again to learn its executable's key. Whatever keeps or empties
+    the one keeps or empties the other; `programs/`, like the rest of the
+    directory, is safe to delete at any time."""
     import jax
 
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")

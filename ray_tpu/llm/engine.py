@@ -42,6 +42,7 @@ from ray_tpu.llm.cache import (
     blocks_for_tokens,
     window_class_of,
 )
+from ray_tpu.llm import program_store
 from ray_tpu.llm.config import EngineConfig
 from ray_tpu.llm.model_runner import build_runner
 from ray_tpu.llm.observability import (
@@ -188,6 +189,10 @@ class LLMEngine:
             compile_clock() if self.engine_config.instrument else None
         )
         self._warmup_s = 0.0
+        # What the program store had read and traced when this engine was
+        # built: `stats()` says what it has since.
+        self._program_store = program_store.default()
+        self._programs_before = self._program_store.totals()
         if self.engine_config.draft_model_config is not None:
             # Fail fast with a message that names the DRAFT model before
             # any runner (and its device pools) is built: the draft mirror
@@ -2291,8 +2296,12 @@ class LLMEngine:
             # Set-up on the same footing: wall seconds warming the
             # programs and, with `instrument` on, what JAX spent compiling
             # in this process since the first such engine was built
-            # (CompileClock).
+            # (CompileClock); and how many step programs were read from the
+            # program store and how many traced and lowered (each a miss,
+            # by reason) since this engine was built. All zero where the
+            # store is off (the CPU backend).
             "warmup_s": self._warmup_s,
+            **self._program_store.since(self._programs_before),
             **(
                 {
                     f"jax_{key}": value
@@ -2607,37 +2616,44 @@ class LLMServer:
             # a host one trace alike), so depth 1 needs no warmup pass
             # of its own but for the one program it adds, which sets a
             # joining prompt's lane on the device (`_warmup_join`).
-            instrumented = self._engine._instrument
-            spec = self._engine._spec
-            publish = self._engine._publish_on_fill
-            on_evict = self._engine.allocator.on_evict
-            probe = self._engine.scheduler.fabric_probe
-            depth = self._engine._pipeline_depth
-            defers_chunks = self._engine._defers_chunks
-            self._engine._instrument = False
-            # ray-tpu: lint-ignore[RTL403] deliberate temporary clear —
-            # the finally below restores _spec on every path, so no
-            # exception can skip the consumer of the saved value
-            self._engine._spec = None
-            self._engine._publish_on_fill = False
-            self._engine.allocator.on_evict = None
-            self._engine.scheduler.fabric_probe = None
-            self._engine._pipeline_depth = 0
-            t_warmup = time.perf_counter()
+            # What the program store makes on the way is written once this
+            # server is ready, by a thread of its own (`shutdown` joins it):
+            # a boot that finds no store does not write one before it serves.
+            self._engine._program_store.hold()
             try:
-                self._warmup()
-                if defers_chunks:
-                    self._warmup_join()
+                instrumented = self._engine._instrument
+                spec = self._engine._spec
+                publish = self._engine._publish_on_fill
+                on_evict = self._engine.allocator.on_evict
+                probe = self._engine.scheduler.fabric_probe
+                depth = self._engine._pipeline_depth
+                defers_chunks = self._engine._defers_chunks
+                self._engine._instrument = False
+                # ray-tpu: lint-ignore[RTL403] deliberate temporary clear —
+                # the finally below restores _spec on every path, so no
+                # exception can skip the consumer of the saved value
+                self._engine._spec = None
+                self._engine._publish_on_fill = False
+                self._engine.allocator.on_evict = None
+                self._engine.scheduler.fabric_probe = None
+                self._engine._pipeline_depth = 0
+                t_warmup = time.perf_counter()
+                try:
+                    self._warmup()
+                    if defers_chunks:
+                        self._warmup_join()
+                finally:
+                    self._engine._warmup_s = time.perf_counter() - t_warmup
+                    self._engine._instrument = instrumented
+                    self._engine._spec = spec
+                    self._engine._publish_on_fill = publish
+                    self._engine.allocator.on_evict = on_evict
+                    self._engine.scheduler.fabric_probe = probe
+                    self._engine._pipeline_depth = depth
+                if spec is not None:
+                    self._warmup_verify(spec)
             finally:
-                self._engine._warmup_s = time.perf_counter() - t_warmup
-                self._engine._instrument = instrumented
-                self._engine._spec = spec
-                self._engine._publish_on_fill = publish
-                self._engine.allocator.on_evict = on_evict
-                self._engine.scheduler.fabric_probe = probe
-                self._engine._pipeline_depth = depth
-            if spec is not None:
-                self._warmup_verify(spec)
+                self._engine._program_store.release()
         self._lock = _HandoffLock()
         self._work = threading.Condition(self._lock)
         self._requests: Dict[str, _RequestState] = {}
@@ -2666,17 +2682,30 @@ class LLMServer:
         self._thread.start()
 
     def _round_start(self) -> tuple:
-        clock = self._engine._compile_clock
-        return time.monotonic(), clock and clock.totals()
+        engine = self._engine
+        clock = engine._compile_clock
+        return (
+            time.monotonic(), clock and clock.totals(),
+            engine._program_store.totals(),
+        )
 
     def _record_round(self, program: str, bucket: int, start: tuple) -> None:
-        """One warm-up round into the flight record: its wall seconds and,
-        with `instrument` on, what JAX spent of them tracing and lowering
-        and in the compile step."""
-        t0, before = start
-        self._engine.flight_recorder.record_compile(
-            program, bucket, time.monotonic() - t0,
-            before and self._engine._compile_clock.since(before),
+        """One warm-up round into the flight record: its wall seconds,
+        with `instrument` on what JAX spent of them tracing and lowering
+        and in the compile step, and `loaded`: whether the programs it
+        warmed were read from the program store (False where one was
+        traced; None where the round made none, the store being off or the
+        process having them already)."""
+        t0, before, programs = start
+        engine = self._engine
+        programs = engine._program_store.since(programs)
+        split = engine._compile_clock.since(before) if before else {}
+        split["loaded"] = (
+            False if programs["programs_traced"]
+            else True if programs["programs_loaded"] else None
+        )
+        engine.flight_recorder.record_compile(
+            program, bucket, time.monotonic() - t0, split
         )
 
     def _warmup(self) -> None:
@@ -3390,3 +3419,4 @@ class LLMServer:
             self._flush()
             self._work.notify_all()
         self._thread.join(timeout=10.0)
+        self._engine._program_store.join(timeout=10.0)

@@ -68,6 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private.jax_setup import ensure_compile_cache
+from ray_tpu.llm import program_store
 from ray_tpu.llm.cache import kv_pool_bytes_sharded
 from ray_tpu.llm.config import EngineConfig
 from ray_tpu.llm.model_runner import (
@@ -138,9 +139,10 @@ def visible_pairs(offset: int, tokens: int, horizon: int) -> int:
 
 
 class _HybridPrograms:
-    """The jitted programs of one (model, block size, attention impl)."""
+    """The jitted programs of one (model, block size, attention impl);
+    `jit` as `model_runner._StepPrograms`'."""
 
-    def __init__(self, cfg, block_size: int, attn_impl: str):
+    def __init__(self, cfg, block_size: int, attn_impl: str, jit=jax.jit):
         self.cfg = cfg
         self.model = model_of(cfg)
         self.block_size = block_size
@@ -155,11 +157,12 @@ class _HybridPrograms:
             for kind in self.recurrent
         }
         donated = (1, 2, 3)
-        self.decode_fn = jax.jit(self._decode_step, donate_argnums=donated)
-        self.prefill_fn = jax.jit(self._prefill_step, donate_argnums=donated)
-        self.prefill_suffix_fn = jax.jit(
+        self.decode_fn = jit(self._decode_step, donate_argnums=donated)
+        self.prefill_fn = jit(self._prefill_step, donate_argnums=donated)
+        self.prefill_suffix_fn = jit(
             self._prefill_suffix_step, donate_argnums=donated
         )
+        self.join_token_fn = jit(join_token)
 
     def _sample(self, logits):
         """Greedy, over the last axis. The one place a program's logits
@@ -330,14 +333,25 @@ _PROGRAM_CACHE: dict = {}
 _PROGRAM_CACHE_LOCK = threading.Lock()
 
 
-def _hybrid_programs(cfg, block_size: int, attn_impl: str) -> _HybridPrograms:
+def _hybrid_programs(
+    cfg, block_size: int, attn_impl: str, engine_config: EngineConfig
+) -> _HybridPrograms:
     """One `_HybridPrograms` a configuration and process, as
-    `model_runner._step_programs`: jax's cache keys on the callable."""
-    key = (cfg, block_size, attn_impl)
+    `model_runner._step_programs`: jax's cache keys on the callable, and
+    where the programs' modules are stored a table is of one engine config."""
+    store = program_store.default()
+    table = (cfg, block_size, attn_impl)
+    key = table + ((store.directory, engine_config) if store.directory else ())
     with _PROGRAM_CACHE_LOCK:
         programs = _PROGRAM_CACHE.get(key)
         if programs is None:
-            programs = _PROGRAM_CACHE[key] = _HybridPrograms(*key)
+            programs = _PROGRAM_CACHE[key] = _HybridPrograms(
+                *table,
+                jit=functools.partial(
+                    program_store.stored_jit, store=store,
+                    table=(table, engine_config),
+                ),
+            )
     return programs
 
 
@@ -373,7 +387,9 @@ class HybridRunner:
         self.kv_cache_dtype_str = {jnp.bfloat16: "bf16"}.get(
             cfg.dtype, jnp.dtype(cfg.dtype).name
         )
-        self._programs = _hybrid_programs(cfg, ecfg.block_size, self.attn_impl)
+        self._programs = _hybrid_programs(
+            cfg, ecfg.block_size, self.attn_impl, ecfg
+        )
         self.params = (
             model.init_params(cfg, seed) if params is None else params
         )
@@ -669,7 +685,7 @@ class HybridRunner:
                 np.concatenate([tokens, np.zeros(self._tail, np.int32)])
             )
             self.host_bytes_in += int(tokens.nbytes)
-        return join_token(tokens, np.int32(lane), out)
+        return self._programs.join_token_fn(tokens, np.int32(lane), out)
 
     @staticmethod
     def _on_device(tables) -> tuple:

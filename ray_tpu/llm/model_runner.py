@@ -45,6 +45,7 @@ Serving hot-path knobs (EngineConfig):
 
 from __future__ import annotations
 
+import functools
 import re
 import threading
 from typing import Callable, List, Optional, Sequence, Tuple
@@ -54,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private.jax_setup import ensure_compile_cache, host_cpu_device
+from ray_tpu.llm import program_store
 from ray_tpu.llm.cache import kv_pool_bytes_sharded
 from ray_tpu.llm.config import EngineConfig
 from ray_tpu.models.gpt import (
@@ -85,6 +87,10 @@ class _StepPrograms:
     built later warms up through pure cache hits. Entries hold only
     config-derived state (the model *definition*, mesh, pool sharding) —
     never params or pools — so a cached entry costs bytes, not HBM.
+
+    `jit` makes a program of a traced function: `jax.jit`, or beside a
+    compile cache `program_store.stored_jit`, whose jits run the programs'
+    stored modules and a later PROCESS reads instead of tracing again.
     """
 
     def __init__(
@@ -94,6 +100,7 @@ class _StepPrograms:
         attn_impl: str,
         kv_cache_dtype,
         tensor_parallel_size: int,
+        jit: Callable = jax.jit,
     ):
         self.model_config = model_config
         self.block_size = block_size
@@ -110,24 +117,19 @@ class _StepPrograms:
         else:
             self.mesh = None
             self.pool_sharding = None
-        self.decode_fn = jax.jit(
-            self._decode_step, donate_argnums=(1, 2, 3, 4)
-        )
-        self.verify_fn = jax.jit(
-            self._verify_step, donate_argnums=(1, 2, 3, 4)
-        )
-        self.prefill_fn = jax.jit(
-            self._prefill_step, donate_argnums=(1, 2, 3, 4)
-        )
-        self.prefill_suffix_fn = jax.jit(
+        self.decode_fn = jit(self._decode_step, donate_argnums=(1, 2, 3, 4))
+        self.verify_fn = jit(self._verify_step, donate_argnums=(1, 2, 3, 4))
+        self.prefill_fn = jit(self._prefill_step, donate_argnums=(1, 2, 3, 4))
+        self.prefill_suffix_fn = jit(
             self._prefill_suffix_step, donate_argnums=(1, 2, 3, 4)
         )
-        self.copy_block_fn = jax.jit(
+        self.copy_block_fn = jit(
             self._copy_block_step, donate_argnums=(0, 1, 2, 3)
         )
-        self.restore_block_fn = jax.jit(
+        self.restore_block_fn = jit(
             self._restore_block_step, donate_argnums=(0, 1, 2, 3)
         )
+        self.join_token_fn = jit(join_token)
 
     # ---------------- traced helpers ----------------
 
@@ -362,13 +364,13 @@ class _StepPrograms:
         return pools, out
 
 
-@jax.jit
 def join_token(tokens, lane, out):
     """A decode's token input with lane `lane` set to the token a chunk
     program sampled (the first value of its output `out`), all of it on
     the device: how a prompt joins a decode batch before the host has read
     its first token. Outside the decode program, which keeps its
-    signature; one shape an engine, warmed with the rest."""
+    signature; one shape an engine, warmed with the rest. Jitted with a
+    table's step programs (`join_token_fn`)."""
     return tokens.at[lane].set(jnp.ravel(out)[0])
 
 
@@ -391,6 +393,7 @@ def _step_programs(
     attn_impl: str,
     kv_cache_dtype,
     tensor_parallel_size: int,
+    engine_config: EngineConfig,
 ) -> _StepPrograms:
     """Process-wide config-keyed cache of `_StepPrograms`. The key is
     everything the traced programs close over: the (frozen, hashable)
@@ -398,21 +401,29 @@ def _step_programs(
     bodies read — all other geometry arrives through argument shapes, which
     jax's own cache keys on), the resolved attention impl and pool dtype,
     and the tp degree (the mesh is deterministic given the backend's
-    devices, which are fixed for the process). A constructor failure (e.g.
-    tp exceeding the device count) propagates without caching."""
-    key = (
+    devices, which are fixed for the process). Where the programs' modules
+    are stored, the whole engine config besides: it is part of every
+    entry's key, so a table is of one. A constructor failure (e.g. tp
+    exceeding the device count) propagates without caching."""
+    store = program_store.default()
+    table = (
         model_config,
         block_size,
         attn_impl,
         np.dtype(kv_cache_dtype).name,
         tensor_parallel_size,
     )
+    key = table + ((store.directory, engine_config) if store.directory else ())
     with _PROGRAM_CACHE_LOCK:
         programs = _PROGRAM_CACHE.get(key)
         if programs is None:
             programs = _StepPrograms(
                 model_config, block_size, attn_impl, kv_cache_dtype,
                 tensor_parallel_size,
+                jit=functools.partial(
+                    program_store.stored_jit, store=store,
+                    table=(table, engine_config),
+                ),
             )
             _PROGRAM_CACHE[key] = programs
     return programs
@@ -530,6 +541,7 @@ class GPTRunner:
             self.attn_impl,
             self.kv_cache_dtype,
             self.tensor_parallel_size,
+            engine_config,
         )
         self.model = self._programs.model
         self.mesh = self._programs.mesh
@@ -819,7 +831,7 @@ class GPTRunner:
         if not isinstance(tokens, jax.Array):
             self.host_bytes_in += int(tokens.nbytes)
             tokens = jnp.asarray(tokens.copy(), jnp.int32)
-        return join_token(tokens, np.int32(lane), out)
+        return self._programs.join_token_fn(tokens, np.int32(lane), out)
 
     def copy_block(self, src: int, dst: int) -> None:
         """Device-copy one block's K/V (and scales) across every layer

@@ -620,8 +620,10 @@ class FlightRecorder:
         seconds before the engine reported ready, and `split`, what
         CompileClock saw during the round: seconds tracing and lowering,
         seconds in the compile step (a compilation or a read from the
-        cache) and programs the cache did not hold. The rest of
-        `compile_s` is the round's execution."""
+        cache) and programs the cache did not hold, and `loaded`: whether
+        the round's programs were read from the program store and not
+        traced (None where it made none). The rest of `compile_s` is the
+        round's execution."""
         self.compile_events.append(
             {
                 "program": program,

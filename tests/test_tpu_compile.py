@@ -16,6 +16,7 @@ whole-pool copies a step on the chip). They compile over the tree a runner
 holds, matrices in the compute dtype, and no program rounds one (PR 33).
 """
 
+import functools
 import os
 import re
 import sys
@@ -27,6 +28,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from ray_tpu.llm import program_store
 from ray_tpu.llm.model_runner import _StepPrograms
 from ray_tpu.models.gpt import GPTConfig, serving_params
 from ray_tpu.ops import flash_attention, paged_flash_attention
@@ -188,7 +190,7 @@ def _step_program(programs, name):
     }[name]
 
 
-def _compile_step_program(chip, monkeypatch, program, kv_dtype):
+def _compile_step_program(chip, monkeypatch, program, kv_dtype, jit=jax.jit):
     """(config, compiled program) over the tree a runner holds: the shapes
     of `serving_params` of a seed init, on the described chip."""
     for module in ("ray_tpu.ops.flash_attention", "ray_tpu.ops.paged_flash"):
@@ -198,7 +200,7 @@ def _compile_step_program(chip, monkeypatch, program, kv_dtype):
     )
     # Not through the process-wide program cache: these are traced with the
     # kernels forced out of interpret mode.
-    programs = _StepPrograms(cfg, _BLOCK, "pallas", kv_dtype, 1)
+    programs = _StepPrograms(cfg, _BLOCK, "pallas", kv_dtype, 1, jit=jit)
 
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -229,13 +231,7 @@ def _compile_step_program(chip, monkeypatch, program, kv_dtype):
 _PROGRAMS = ["decode", "prefill_suffix", "prefill_full", "verify"]
 
 
-@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
-@pytest.mark.parametrize("program", _PROGRAMS)
-def test_step_program_holds_no_copy_of_a_pool(
-    chip, monkeypatch, program, kv_dtype
-):
-    kv_dtype = jnp.dtype(kv_dtype)
-    _, compiled = _compile_step_program(chip, monkeypatch, program, kv_dtype)
+def _assert_holds_no_copy_of_a_pool(compiled, program, kv_dtype):
     text = compiled.as_text()
     if program != "prefill_full":  # full prefill reads no cache
         assert "tpu_custom_call" in text
@@ -257,6 +253,66 @@ def test_step_program_holds_no_copy_of_a_pool(
     layer_pool_bytes = _BLOCKS * _BLOCK * _HEADS * _HEAD_DIM * kv_dtype.itemsize
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < layer_pool_bytes, (temp, layer_pool_bytes)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("program", _PROGRAMS)
+def test_step_program_holds_no_copy_of_a_pool(
+    chip, monkeypatch, program, kv_dtype
+):
+    kv_dtype = jnp.dtype(kv_dtype)
+    _, compiled = _compile_step_program(chip, monkeypatch, program, kv_dtype)
+    _assert_holds_no_copy_of_a_pool(compiled, program, kv_dtype)
+
+
+_MODULE_NAMES = {
+    "decode": "jit__decode_step", "prefill_suffix": "jit__prefill_suffix_step",
+    "prefill_full": "jit__prefill_step", "verify": "jit__verify_step",
+}
+
+
+@pytest.mark.parametrize("program", _PROGRAMS)
+def test_stored_step_program_compiles_as_the_traced_one(
+    chip, monkeypatch, tmp_path, program
+):
+    """The same programs through the program store (`llm.program_store`):
+    exported for the TPU, written, read back, deserialized and compiled
+    for the described chip inside the jit that runs a stored module. An
+    export that drops the pools' donation, loses a kernel or renames a
+    program (the benchmark's readers find programs by name) fails here, on
+    the CPU, and not in a trace on the chip."""
+    kv_dtype = jnp.dtype("bfloat16")
+    _, traced = _compile_step_program(chip, monkeypatch, program, kv_dtype)
+    # The store lowers for the process's backend, the CPU here.
+    monkeypatch.setattr(program_store, "_platform", lambda: "tpu")
+
+    def through(store):
+        return functools.partial(program_store.stored_jit, store=store, table="test")
+
+    store = program_store.ProgramStore(str(tmp_path))
+    _compile_step_program(chip, monkeypatch, program, kv_dtype, jit=through(store))
+    assert store.totals()["programs_traced"] == 1
+    store = program_store.ProgramStore(str(tmp_path))
+    _, loaded = _compile_step_program(
+        chip, monkeypatch, program, kv_dtype, jit=through(store)
+    )
+    assert store.totals() == {
+        "programs_loaded": 1, "programs_traced": 0,
+        "program_store_misses_by_reason": {},
+    }
+    _assert_holds_no_copy_of_a_pool(loaded, program, kv_dtype)
+    traced_text, loaded_text = traced.as_text(), loaded.as_text()
+    assert f"HloModule {_MODULE_NAMES[program]}," in traced_text
+    assert f"HloModule {_MODULE_NAMES[program]}," in loaded_text
+    kernel = 'custom_call_target="tpu_custom_call"'
+    assert loaded_text.count(kernel) == traced_text.count(kernel)
+    # Both pools are updated in place: the donation holds through the call.
+    assert traced_text.count("may-alias") == 2
+    assert loaded_text.count("may-alias") == 2
+    assert (
+        loaded.memory_analysis().alias_size_in_bytes
+        == traced.memory_analysis().alias_size_in_bytes
+    )
 
 
 @pytest.mark.parametrize("program", _PROGRAMS)
