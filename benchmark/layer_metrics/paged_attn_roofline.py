@@ -6,7 +6,8 @@ The engine counts what its decode dispatches asked the kernel to read
 `context_lens`) and says the shape that turns tokens into bytes
 (`attention_shape`). The trace gives the kernel's device time: the
 `tpu_custom_call` operations of `jit__decode_step`, one a layer a run. The
-window's mean dispatch, in every layer, times the decode runs in the trace is
+window's mean dispatch, in every layer, times the decode runs in the traced
+window (a run that its edge cuts by its share inside, as its seconds are) is
 the work (`lib/flops.py`); over the published peak (`lib/peaks.py`) and the
 kernel's seconds it is the share. A program without the counters (before PR
 24) gives nothing to read.
@@ -16,7 +17,7 @@ import re
 
 from lib.flops import paged_decode_attention_cost, roofline_share
 from lib.peaks import peaks_for
-from lib.xplane import module_name
+from lib.xplane import module_name, window_runs
 
 KERNEL = re.compile(r"^jit__decode_step/.* tpu_custom_call$")
 
@@ -28,7 +29,7 @@ def read(collected):
     shape = collected["engine_after"]["attention_shape"]
     kernel_s = sum(s for name, s in trace["op_seconds"].items() if KERNEL.match(name))
     runs = sum(
-        m["runs"] for name, m in trace["modules"].items()
+        window_runs(m) for name, m in trace["modules"].items()
         if module_name(name) == "jit__decode_step"
     )
     if not kernel_s or not runs or not window["decode_dispatches"]:

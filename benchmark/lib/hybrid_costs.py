@@ -120,13 +120,15 @@ def busy_share(collected: dict, scope: str) -> Optional[float]:
     return 100.0 * seconds / busy if seconds and busy else None
 
 
-def runs(collected: dict, program: str) -> int:
-    """Executions in the trace of the programs matching `program`."""
-    from lib.xplane import module_name
+def runs(collected: dict, program: str) -> float:
+    """Executions in the traced window of the programs matching `program`, a
+    run that an edge of the window cuts by its share inside: the seconds the
+    work is held against are cut the same way (`lib/xplane.window_runs`)."""
+    from lib.xplane import module_name, window_runs
 
     rx = re.compile(program)
     return sum(
-        m["runs"] for name, m in collected["trace"]["modules"].items()
+        window_runs(m) for name, m in collected["trace"]["modules"].items()
         if rx.search(module_name(name))
     )
 

@@ -192,7 +192,9 @@ def step_batches(mix: dict, seed: int, vocab: int):
 
     if mix.get("loop") != "steps":
         raise ValueError(f"mix loop {mix.get('loop')!r} is not a training mix")
-    rng = np.random.RandomState(seed)
+    # The legacy generator takes one word of 32 bits or a list of them: a
+    # seed past 2**32 - 1 goes in as its two words, any other as it always did.
+    rng = np.random.RandomState(seed if seed < 2**32 else [seed & 0xFFFFFFFF, seed >> 32])
     shape = (mix["sequences_per_step"], mix["tokens_per_sequence"])
     while True:
         yield rng.randint(0, vocab, size=shape).astype(np.int32)

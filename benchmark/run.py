@@ -30,6 +30,10 @@ none of the cell's per-layer readers found a value.
                       served token altered, through the comparison that decides
                       `correct` (a `control` info line); exits 3 where either
                       comes out correct. The driver never asks for it
+    --keep-trace DIR  a traced run: keep the raw `.xplane.pb` and what the
+                      readers were handed in DIR, for
+                      `tests/compare_reductions.py`. The driver never asks
+                      for it
 
 See benchmark/README.md for how a cell, a configuration, a mix or a per-layer
 metric is added as files.
@@ -46,6 +50,7 @@ import importlib.util  # noqa: E402
 import json  # noqa: E402
 import logging  # noqa: E402
 import os  # noqa: E402
+import pickle  # noqa: E402
 import sys  # noqa: E402
 import types  # noqa: E402
 
@@ -66,6 +71,11 @@ def _not_measured(value, key: str = ""):
     if isinstance(value, dict):
         return {k: _not_measured(v, k) for k, v in value.items()}
     return value
+
+
+# What a traced run says of its window on the `summary` line.
+TRACE_WINDOW = ("window_s", "window_from", "host_window_s", "profiled_s", "busy_s",
+                "idle_s", "busy_outside_s", "trace_bytes")
 
 
 def load_runner(name: str):
@@ -90,6 +100,7 @@ def main(argv=None) -> int:
     parser.add_argument("--sweep", default="")
     parser.add_argument("--reference-seed", type=int, default=None)
     parser.add_argument("--control", action="store_true")
+    parser.add_argument("--keep-trace", default=None, metavar="DIR")
     args = parser.parse_args(argv)
 
     # Everything that can be wrong without a chip is found before one is
@@ -136,6 +147,9 @@ def main(argv=None) -> int:
             reference_seed=args.seed if args.reference_seed is None else args.reference_seed,
             control=args.control, device=found, out_dir=out_dir,
             bench_dir=HERE, emit=emit, compiles=device.CompileCounter(),
+            keep_trace=args.keep_trace and os.path.join(
+                args.keep_trace, f"{cell['name']}-seed{args.seed}.xplane.pb"
+            ),
         )
         result = load_runner(cell["config_file"]["runner"]).run(ctx)
         if result.get("sweep"):
@@ -164,11 +178,20 @@ def main(argv=None) -> int:
                 emit("warning", what="per-layer metrics without a value, left out",
                      missing=missing)
             if trace is not None:
-                report["busy_s"], report["window_s"] = trace["busy_s"], trace["window_s"]
+                # Both of one window, the one the trace itself marks; the
+                # host's clock around the same stretch rides beside them.
+                for key in ("busy_s", "window_s", "host_window_s"):
+                    report[key] = trace[key]
         else:
             values = {**result["end_to_end"], "setup_s": collected["setup_s"]}
             metrics = {name: values[name] for name in mine["end_to_end"]}
             units = mine["end_to_end"]
+        if ctx.keep_trace and trace is not None:
+            # Beside the raw trace `reduce_trace` moved there: what the
+            # readers were handed, for `tests/compare_reductions.py`.
+            with open(ctx.keep_trace.replace(".xplane.pb", ".collected.pkl"), "wb") as f:
+                pickle.dump({"workload": cell["name"], "seed": args.seed,
+                             "collected": collected, "metrics": metrics}, f)
         final = {
             "correct": bool(result["correct"]),
             "attempted": result["attempted"],
@@ -191,7 +214,9 @@ def main(argv=None) -> int:
              problems=result["problems"],
              compiles_total=ctx.compiles.count,
              compile_cache_entries=device.cache_entries(),
-             trace_modules=(trace or {}).get("modules"), lines=lines_path,
+             trace_modules=(trace or {}).get("modules"),
+             trace_window=trace and {key: trace[key] for key in TRACE_WINDOW},
+             lines=lines_path,
              memory_stats=device.memory_stats(cell["chips"]))
     if args.rehearse:
         print(json.dumps({"info": "rehearsal_done", **tag,

@@ -105,7 +105,7 @@ def measure(ctx, deployment: Deployment, mix: dict, vocab: int, tag: str,
     drawn from `seed` (the run's own where none is given). Returns what
     the per-layer readers dig into (the client's log reduced, the engine's
     counters and histograms over the window and, with `ctx.trace`, the
-    reduced device trace of its middle seconds), the schedule, and the
+    reduced device trace of its middle seconds, `lib/xplane.TracedWindow`), the schedule, and the
     records of the requests that completed inside the window."""
     ecfg = deployment.ecfg
     seed = ctx.seed if seed is None else seed
@@ -140,23 +140,20 @@ def measure(ctx, deployment: Deployment, mix: dict, vocab: int, tag: str,
         if ctx.trace:
             length = min(TRACE_SECONDS, ctx.seconds / 3.0)
             _sleep_until(clock["open"] + (ctx.seconds - length) / 2.0)
-            trace_dir = os.path.join(out, "trace")
-            xplane.start_trace(trace_dir)
-            t0 = time.monotonic()
+            window = xplane.TracedWindow(os.path.join(out, "trace"))
+            window.open()
             time.sleep(length)
-            window_s = time.monotonic() - t0
-            xplane.stop_trace()
-            traced = (trace_dir, window_s)
+            traced = (window.trace_dir, window.close(), window.profiled_s)
         _sleep_until(clock["close"])
         after = deployment.call("observability_snapshot", 0)
         compiles_in_window = ctx.compiles.count - compiles_before
         # The engine's step thread shares this interpreter: collections by
-        # generation inside the window, and the objects the oldest one walks.
+        # generation inside the window. Not `gc.get_objects()`: walking the
+        # heap beside a stepping engine dead-lettered a request (PERF.md 7.13l).
         client_gc = {
             "gc_collections_in_window": [
                 g["collections"] - b for g, b in zip(gc.get_stats(), gc_before)
             ],
-            "gc_objects_tracked": len(gc.get_objects()),
         }
         # Before the reference runs on this chip: a process's peak never falls.
         memory_peak = device.memory_peak_bytes(ctx.chips)
@@ -209,7 +206,7 @@ def measure(ctx, deployment: Deployment, mix: dict, vocab: int, tag: str,
         "trace": None,
     }
     if traced is not None:
-        collected["trace"] = xplane.reduce_trace(*traced)
+        collected["trace"] = xplane.reduce_trace(*traced, keep=ctx.keep_trace)
     return collected, schedule, complete
 
 

@@ -93,7 +93,7 @@ def run(ctx) -> dict:
         compiles_before = compiles.count
         trace_len = min(TRACE_SECONDS, seconds / 3.0)
         trace_from = (seconds - trace_len) / 2.0
-        traced, trace_t0, before_trace = None, None, None
+        traced, window, before_trace = None, None, None
         # The loop reads a loss back only where it reports one, as a user's
         # does: whatever else closes a step is the program's own doing.
         closed, losses, pending, iterator_wait = [], [], [], 0.0
@@ -114,13 +114,12 @@ def run(ctx) -> dict:
             closed.append((len(losses), now))
             train.report({"step": len(losses), "loss": values[-1]})
             if want_trace and traced is None:
-                if trace_t0 is None and now >= trace_from:
+                if window is None and now >= trace_from:
                     before_trace = closed[-1]
-                    xplane.start_trace(trace_dir)
-                    trace_t0 = time.monotonic()
-                elif trace_t0 is not None and time.monotonic() - trace_t0 >= trace_len:
-                    traced = time.monotonic() - trace_t0
-                    xplane.stop_trace()
+                    window = xplane.TracedWindow(trace_dir)
+                    window.open()
+                elif window is not None and time.monotonic() - window.opened >= trace_len:
+                    traced = window.close()
         rounds = list(profiler.records)[rounds_before:] if profiler is not None else []
         compiles_in_window = compiles.count - compiles_before
         platforms = sorted(
@@ -161,6 +160,7 @@ def run(ctx) -> dict:
                     "gradient_noise": gradient_noise, "reference_s": reference_s,
                     "rounds": rounds, "iterator_wait_s": iterator_wait,
                     "traced_window_s": traced, "before_trace": before_trace,
+                    "traced_profile_s": window.profiled_s if window else None,
                     "compiles_in_window": compiles_in_window,
                     "param_platforms": platforms,
                 }
@@ -244,7 +244,10 @@ def run(ctx) -> dict:
         "trace": None,
     }
     if bench["traced_window_s"]:
-        collected["trace"] = xplane.reduce_trace(trace_dir, bench["traced_window_s"])
+        collected["trace"] = xplane.reduce_trace(
+            trace_dir, bench["traced_window_s"], bench["traced_profile_s"],
+            keep=ctx.keep_trace,
+        )
     problems = []
     if bench["compiles_in_window"]:
         problems.append(f"{bench['compiles_in_window']} compilations inside the window")

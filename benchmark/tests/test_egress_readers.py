@@ -98,9 +98,9 @@ def test_the_new_metrics_are_listed_in_the_four_serving_cells():
     }
     for name in NEW:
         assert by_name[name]["moves"] == "itl_p50_ms"
-        assert by_name[name]["workloads"] == ["gpt2-large.chat-sessions-loaded"]
+        assert "gpt2-large.chat-sessions-loaded" in by_name[name]["workloads"]
         twin = by_name["tput_" + name]
         assert twin["moves"] == "completed_tokens_per_s"
-        assert set(twin["workloads"]) == serving
+        assert serving <= set(twin["workloads"])   # later cells may join the list
         for key in ("unit", "better", "source", "layer"):
             assert twin[key] == by_name[name][key]

@@ -300,7 +300,8 @@ def test_the_cell_has_the_issues_traffic(loaded):
                 "tput_prefix_hit_share", "ssm_busy_share"} & set(mine["per_layer"])
     for name in NEW_METRICS:
         entry = next(m for m in loaded["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "completed_tokens_per_s"
+        # In the list, which later cells may join.
+        assert CELL in entry["workloads"] and entry["moves"] == "completed_tokens_per_s"
 
 
 def test_the_new_manifest_passes_the_drivers_rules(loaded):

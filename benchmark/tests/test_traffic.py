@@ -100,6 +100,26 @@ def test_step_batches_repeat_for_a_seed():
     assert not (first == next(a)).all()
 
 
+@pytest.mark.parametrize("seed", [0, 5, 2**31 + 11, 2**32 - 1])
+def test_step_batches_of_a_seed_numpy_takes_are_the_ones_it_always_drew(seed):
+    import numpy as np
+
+    m = {"loop": "steps", "sequences_per_step": 2, "tokens_per_sequence": 8}
+    expected = np.random.RandomState(seed).randint(0, 100, size=(2, 8)).astype(np.int32)
+    assert (next(traffic.step_batches(m, seed, 100)) == expected).all()
+
+
+@pytest.mark.parametrize("seed", [2**32, 2**32 + 15, 5600000103])
+def test_step_batches_take_a_seed_past_32_bits(seed):
+    """numpy's legacy generator refuses it whole; it goes in as two words,
+    and draws what no seed of one word draws."""
+    m = {"loop": "steps", "sequences_per_step": 2, "tokens_per_sequence": 8}
+    first = next(traffic.step_batches(m, seed, 100))
+    assert (first == next(traffic.step_batches(m, seed, 100))).all()
+    assert not (first == next(traffic.step_batches(m, seed % 2**32, 100))).all()
+    assert not (first == next(traffic.step_batches(m, seed + 1, 100))).all()
+
+
 # ---------------- PR 28: the closed loop's room and the loaded chat mix ----------------
 
 
