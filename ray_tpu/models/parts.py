@@ -40,12 +40,13 @@ def frozen(tree):
     return tree
 
 
-def seeded_tree(shapes, seed: int, dtype, std_of=lambda name: 0.02):
+def seeded_tree(shapes, seed: int, dtype, std_of=lambda name: 0.02, draws=None):
     """A tree of seeded weights from a tree of shapes (tuples), made leaf by
     leaf on the device in `dtype` (a float32 tree of a serving size does not
     fit a chip beside its bfloat16 copy): ones for a leaf whose name starts
-    with "norm", else normal(`std_of(name)`), the key folded from the
-    leaf's place in the tree."""
+    with "norm", `draws[name](key, shape)` (float32, cast) for a leaf a
+    model draws otherwise, else normal(`std_of(name)`), the key folded from
+    the leaf's place in the tree."""
     leaves, tree = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda v: isinstance(v, tuple)
     )
@@ -53,10 +54,13 @@ def seeded_tree(shapes, seed: int, dtype, std_of=lambda name: 0.02):
     made = []
     for index, (path, shape) in enumerate(leaves):
         name = path[-1].key
+        key = jax.random.fold_in(base, index)
         if name.startswith("norm"):
             made.append(jnp.ones(shape, dtype))
+        elif draws and name in draws:
+            made.append(draws[name](key, shape).astype(dtype))
         else:
-            made.append(normal(jax.random.fold_in(base, index), shape, dtype, std_of(name)))
+            made.append(normal(key, shape, dtype, std_of(name)))
     return jax.tree_util.tree_unflatten(tree, made)
 
 
@@ -223,12 +227,14 @@ def experts(cfg, p, x, *, grouped: bool, valid=None):
     path visits for them (`walked`, at least `held` and at most `held +
     absent`: `ray_tpu.ops.grouped_experts.rows_walked`). The router's rule is the
     configuration's (`router_score` and, where it scales the gates,
-    `routed_scaling_factor`; `ray_tpu.ops.grouped_experts.route`)."""
+    `routed_scaling_factor`; `ray_tpu.ops.grouped_experts.route`), with the
+    layer's selection bias where it has one (`router_bias`)."""
     held = cfg.local_of()
     with jax.named_scope("llm.moe.router"):
         ids, gates = route(
             x, p["router"], cfg.num_experts_per_tok, score=cfg.router_score,
             scale=getattr(cfg, "routed_scaling_factor", 1.0),
+            **({"bias": p["router_bias"]} if "router_bias" in p else {}),
         )
         if valid is None:
             valid = jnp.ones(x.shape[:1], bool)

@@ -1,10 +1,12 @@
 """The routed experts a chip holds, without capacity and without drops.
 
 The router scores every expert of the layer (`num_experts`), a token takes
-its `top_k` best with gates by one of two rules (`route`'s `score`): a
-softmax over those `top_k` logits, or a softmax over all the experts of
+its `top_k` best with gates by one of three rules (`route`'s `score`): a
+softmax over those `top_k` logits; a softmax over all the experts of
 which the chosen ones' shares are divided by their sum and multiplied by
-`scale`. This chip computes the part of the result that the experts it holds
+`scale`; or a sigmoid of every logit, the choice made on the score plus a
+selection `bias` an expert, the chosen ones' own scores (without the bias)
+divided by their sum and multiplied by `scale`. This chip computes the part of the result that the experts it holds
 give: what the absent experts would add is left out, the gates are not
 renormalised over the held ones. `local_of` [num_experts] maps an expert's
 id to its row in the held weights, or -1. A token's result depends on no
@@ -47,13 +49,16 @@ from ray_tpu.ops.grouped_matmul import (
 
 def route(
     x: jax.Array, router: jax.Array, top_k: int, *,
-    score: str = "chosen", scale: float = 1.0,
+    score: str = "chosen", scale: float = 1.0, bias=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """x [T, D], router [D, num_experts] -> (expert ids [T, k], gates
     [T, k] float32). Logits and the softmax are float32. `score`
     "chosen": the gates are a softmax over the `top_k` chosen logits.
     "all": a softmax over every expert, the `top_k` largest, their shares
-    divided by their sum and multiplied by `scale`."""
+    divided by their sum and multiplied by `scale`. "sigmoid": a sigmoid
+    of every logit; the `top_k` largest of score + `bias` [num_experts]
+    (which moves the choice and not the weights: nought where None) are
+    chosen, their scores divided by their sum and multiplied by `scale`."""
     logits = jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
@@ -61,9 +66,15 @@ def route(
     if score == "chosen":
         top, ids = jax.lax.top_k(logits, top_k)
         return ids, jax.nn.softmax(top, axis=-1)
-    if score != "all":
+    if score == "all":
+        top, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    elif score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        chooser = scores if bias is None else scores + bias.astype(jnp.float32)
+        ids = jax.lax.top_k(chooser, top_k)[1]
+        top = jnp.take_along_axis(scores, ids, axis=-1)
+    else:
         raise ValueError(f"unknown router score {score!r}")
-    top, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
     return ids, top / jnp.sum(top, axis=-1, keepdims=True) * scale
 
 

@@ -246,7 +246,7 @@ def test_route_softmax_over_all_then_top_k():
     soft = np.exp(top_logits - top_logits.max(-1, keepdims=True))
     assert np.abs(np.asarray(gates_c) - soft / soft.sum(-1, keepdims=True)).max() < 1e-6
     with pytest.raises(ValueError):
-        route(x, router, 3, score="sigmoid")
+        route(x, router, 3, score="tanh")
 
 
 @pytest.mark.parametrize("grouped", [True, False])
